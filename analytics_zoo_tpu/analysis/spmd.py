@@ -461,8 +461,8 @@ def fold_specs(ctx: ModuleContext, node: Optional[ast.AST],
 @dataclasses.dataclass
 class ShardMapSite:
     """One ``shard_map`` entry into a manual region: the decorator form
-    (``@functools.partial(compat.shard_map, mesh=…, in_specs=…,
-    out_specs=…)``) or the call form (``fn = compat.shard_map(local,
+    (``@functools.partial(jax.shard_map, mesh=…, in_specs=…,
+    out_specs=…)``) or the call form (``fn = jax.shard_map(local,
     mesh=…, …)``)."""
 
     line: int

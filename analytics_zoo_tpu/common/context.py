@@ -149,6 +149,14 @@ DEFAULT_CONF: Dict[str, Any] = {
 
 _ENV_PREFIX = "ZOO_TPU_"
 
+#: where compiled programs are kept when ``JAX_COMPILATION_CACHE_DIR`` does
+#: not say: ONE fixed path inside the checkout. The directory's path is part
+#: of what a later process has to reproduce to find an entry again, so it is
+#: never derived from a pid, a time or a temporary name.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 #: normalized ("zoo_failure_retry_times") → canonical ("zoo.failure.retry_times")
 #: so env/kwargs spellings of multi-word leaf keys land on the right conf entry
 _CANONICAL = {k.lower().replace(".", "_"): k for k in DEFAULT_CONF}
@@ -271,6 +279,18 @@ _prng_impl_before_init: Optional[str] = None
 _distributed_initialized = False
 
 
+def _place_compile_cache() -> None:
+    """Give JAX's persistent compilation cache a home before the first
+    compilation. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and this sets nothing; otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`. Every entry point (``bench.py``,
+    ``chip_smoke.py``, the serving launchers, the examples) reaches this
+    through ``init_zoo_context`` — it is the only place that sets it."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+
+
 def _maybe_init_distributed(conf: Mapping[str, Any]) -> None:
     """Multi-host bring-up over DCN: ``jax.distributed.initialize`` when a
     coordinator is configured (``zoo.distributed.*`` conf /
@@ -281,16 +301,13 @@ def _maybe_init_distributed(conf: Mapping[str, Any]) -> None:
     coordinator = str(conf.get("zoo.distributed.coordinator") or "").strip()
     if not coordinator or _distributed_initialized:
         return
-    from jax._src import xla_bridge
-    if getattr(xla_bridge, "_backends", {}):
-        raise RuntimeError(
-            "zoo.distributed.coordinator is set but JAX backends are already "
-            "initialized — init_zoo_context(...) with the coordinator must "
-            "run before any jax.devices()/computation in this process")
     num_processes = int(conf.get("zoo.distributed.num_processes", 1))
     process_id = int(conf.get("zoo.distributed.process_id", 0))
     log.info("initializing JAX multi-host runtime: coordinator=%s "
              "process %d/%d", coordinator, process_id, num_processes)
+    # init_zoo_context(...) with a coordinator must run before any
+    # jax.devices()/computation in this process: jax.distributed.initialize
+    # raises RuntimeError itself once a backend exists
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -348,6 +365,7 @@ def init_zoo_context(
 
     logging.basicConfig(level=merged.get("zoo.log.level", "INFO"))
 
+    _place_compile_cache()
     _maybe_init_distributed(merged)
 
     precision = merged.get("zoo.matmul.precision", "default")
@@ -420,13 +438,12 @@ FALSE_FLAG_SPELLINGS = ("0", "false", "no", "off", "")
 
 def tri_state_conf(key: str, default: str = "auto"):
     """Parse an ``auto|true|false`` context flag to ``"auto"``, ``True``,
-    or ``False`` — the call site decides what ``auto`` resolves to. Falls
-    back to ``default`` when no context is constructible (odd device
-    counts); raises ``ValueError`` on an unrecognized spelling."""
-    try:
-        flag = get_zoo_context().get(key, default)
-    except Exception:  # zoolint: disable=ZL007 context not constructible
-        flag = default
+    or ``False`` — the call site decides what ``auto`` resolves to.
+    ``default`` answers only for a key the conf does not hold; a context
+    that cannot be built (a mesh that does not fit the devices) raises
+    here like everywhere else. Raises ``ValueError`` on an unrecognized
+    spelling."""
+    flag = get_zoo_context().get(key, default)
     if isinstance(flag, str):
         low = flag.strip().lower()
         if low == "auto":

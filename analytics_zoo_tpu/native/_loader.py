@@ -27,8 +27,11 @@ LDFLAGS = ["-shared", "-pthread"]
 
 def build_and_load(so_name: str, src_name: str) -> Optional[ctypes.CDLL]:
     """dlopen ``native/<so_name>``, building it from ``native/<src_name>``
-    first when missing or older than the source. Returns None on any
-    failure (callers fall back to their pure-Python paths)."""
+    first when missing or older than the source (the ``.so`` files are
+    git-ignored: a fresh checkout builds them here, at first use).
+    Returns None on any failure — a missing ``g++``, a failed build — and
+    says so at WARNING, because the callers then run their pure-Python
+    paths for the life of the process."""
     so = os.path.join(NATIVE_DIR, so_name)
     src = os.path.join(NATIVE_DIR, src_name)
     try:
@@ -46,5 +49,6 @@ def build_and_load(so_name: str, src_name: str) -> Optional[ctypes.CDLL]:
             log.info("built native library %s", so)
         return ctypes.CDLL(so)
     except Exception as e:  # noqa: BLE001 — any failure → Python fallback
-        log.info("native library %s unavailable (%s)", so_name, e)
+        log.warning("native library %s unavailable (%s); the pure-Python "
+                    "fallback is in use", so_name, e)
         return None

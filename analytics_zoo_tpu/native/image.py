@@ -58,7 +58,7 @@ def load_native_image() -> Optional[ctypes.CDLL]:
         try:
             _lib = _configure(lib) if lib is not None else False
         except AttributeError as e:   # stale/mismatched binary
-            log.info("native image ops unavailable (%s); using numpy/PIL "
+            log.warning("native image ops unavailable (%s); using numpy/PIL "
                      "fallbacks", e)
             _lib = False
         return _lib or None

@@ -79,10 +79,7 @@ _NEG_PAD = -1e30
 
 def _conf(key: str, default):
     from ..common.context import get_zoo_context
-    try:
-        return get_zoo_context().get(key, default)
-    except Exception:  # context not constructible (odd device counts)
-        return default
+    return get_zoo_context().get(key, default)
 
 
 def pallas_ce_enabled() -> bool:
@@ -305,17 +302,30 @@ def fused_cross_entropy_rows(hidden: jax.Array, w: jax.Array,
     gradient; rows with label >= V are NaN (loss and gradient — the
     full-logits objective fails the same way). Differentiable in
     ``hidden``/``w``/``b`` via the tile-streamed custom VJP; the ``(N, V)``
-    logits tensor is never materialized."""
+    logits tensor is never materialized.
+
+    With the Pallas kernels on and a mesh of several devices the rows run
+    per ``data``/``seq`` shard under ``shard_map`` (the vocab-sharded
+    path's own machinery, whatever the ``model`` axis size): a Mosaic
+    kernel refuses to lower inside a jit that spans several devices
+    ("cannot be automatically partitioned" — found on a four-chip v5e
+    host, PR 21). Inside a ``shard_map`` body the rows are per-shard
+    already and the call is plain."""
     n = hidden.shape[0]
     labels = labels.reshape(-1).astype(jnp.int32)
     if labels.shape[0] != n:
         raise ValueError(f"fused CE: {n} hidden rows vs "
                          f"{labels.shape[0]} labels")
-    chunk = _resolve_chunk(n, chunk)
     if use_pallas is None:
         use_pallas = pallas_ce_enabled()
-    return _fused_rows(hidden, w, b, labels, chunk, bool(use_pallas),
-                       interpret)
+    if use_pallas:
+        from ..parallel import mesh as mesh_lib
+        mesh = mesh_lib.global_mesh()
+        if mesh.devices.size > 1 and not mesh_lib.in_manual_region():
+            return _rows_over_mesh(hidden, w, b, labels, mesh, chunk, True,
+                                   interpret)
+    return _fused_rows(hidden, w, b, labels, _resolve_chunk(n, chunk),
+                       bool(use_pallas), interpret)
 
 
 def fused_sparse_cross_entropy(y_true, hidden, w, b=None, *,
@@ -440,8 +450,6 @@ def _sharded_fwd_global(h, w, b, labels, mesh, chunk, use_pallas,
     """(lse, label_logit) on GLOBAL arrays via shard_map. Both outputs
     are data-sharded rows, replicated across the model axis (every rank
     holds the merged values)."""
-    from ..parallel import compat
-
     had_bias = b is not None
     row_spec, in_specs = _sharded_specs(mesh, had_bias)
     local = functools.partial(_sharded_fwd_local, chunk=chunk,
@@ -452,8 +460,8 @@ def _sharded_fwd_global(h, w, b, labels, mesh, chunk, use_pallas,
     else:
         def run(hh, ww, ll):
             return local(hh, ww, None, ll)
-    fn = compat.shard_map(run, mesh=mesh, in_specs=in_specs,
-                          out_specs=(row_spec, row_spec), check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                       out_specs=(row_spec, row_spec), check_vma=False)
     args = (h, w) + ((b,) if had_bias else ()) + (labels,)
     return fn(*args)
 
@@ -462,8 +470,7 @@ def _sharded_fwd_global(h, w, b, labels, mesh, chunk, use_pallas,
 # are explicit shard_map calls whose bodies own every cross-rank
 # reduction (the fwd merge psum, the bwd dh-psum and the dW/db
 # data-axis allreduce) — nothing is left to shard_map's transpose
-# machinery, whose unmentioned-axis cotangent conventions are exactly
-# the kind of version-sensitive detail compat.py exists to avoid
+# machinery and its unmentioned-axis cotangent conventions
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _sharded_rows(h, w, b, labels, mesh, chunk, v_total, use_pallas,
                   interpret):
@@ -484,8 +491,6 @@ def _sharded_rows_vjp_fwd(h, w, b, labels, mesh, chunk, v_total,
 
 def _sharded_rows_vjp_bwd(mesh, chunk, v_total, use_pallas, interpret,
                           res, g):
-    from ..parallel import compat
-
     h, w, b, labels, lse = res
     had_bias = b is not None
     # the grad scale keys on the GLOBAL label: masked rows zero, rows
@@ -510,9 +515,9 @@ def _sharded_rows_vjp_bwd(mesh, chunk, v_total, use_pallas, interpret,
             dh, dw, _ = local(hh, ww, None, ll, ls, sc)
             return dh, dw
         out_specs = (in_specs[0], w_spec)
-    fn = compat.shard_map(run, mesh=mesh,
-                          in_specs=in_specs + (row_spec, row_spec),
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=in_specs + (row_spec, row_spec),
+                       out_specs=out_specs, check_vma=False)
     args = (h, w) + ((b,) if had_bias else ()) + (labels, lse, scale)
     out = fn(*args)
     dh, dw = out[0], out[1]
@@ -543,22 +548,34 @@ def sharded_fused_cross_entropy_rows(hidden: jax.Array, w: jax.Array,
     pad to the row-sharding divisor with masked labels. On a mesh with
     ``model == 1`` this is exactly the unsharded op."""
     from ..parallel import mesh as mesh_lib
-    from .pallas.common import round_up
 
     mesh = mesh or mesh_lib.global_mesh()
-    n_model = int(mesh.shape[mesh_lib.MODEL_AXIS])
-    if n_model <= 1:
+    if int(mesh.shape[mesh_lib.MODEL_AXIS]) <= 1:
         return fused_cross_entropy_rows(hidden, w, b, labels, chunk=chunk,
                                         use_pallas=use_pallas,
                                         interpret=interpret)
-    n = hidden.shape[0]
-    v = w.shape[1]
     labels = labels.reshape(-1).astype(jnp.int32)
-    if labels.shape[0] != n:
-        raise ValueError(f"sharded fused CE: {n} hidden rows vs "
-                         f"{labels.shape[0]} labels")
+    if labels.shape[0] != hidden.shape[0]:
+        raise ValueError(f"sharded fused CE: {hidden.shape[0]} hidden rows "
+                         f"vs {labels.shape[0]} labels")
     if use_pallas is None:
         use_pallas = pallas_ce_enabled()
+    return _rows_over_mesh(hidden, w, b, labels, mesh, chunk,
+                           bool(use_pallas), interpret)
+
+
+def _rows_over_mesh(hidden, w, b, labels, mesh, chunk, use_pallas: bool,
+                    interpret):
+    """The ``shard_map`` form shared by the vocab-sharded op and the
+    unsharded op's several-device Pallas route: rows over ``data``/
+    ``seq``, head columns over ``model`` (one whole slice when that axis
+    has size 1). ``labels`` are validated flat int32."""
+    from ..parallel import mesh as mesh_lib
+    from .pallas.common import round_up
+
+    n_model = int(mesh.shape[mesh_lib.MODEL_AXIS])
+    n = hidden.shape[0]
+    v = w.shape[1]
 
     # rows pad to the row-sharding divisor with label -1 (inert) and are
     # sliced back off below
@@ -578,8 +595,8 @@ def sharded_fused_cross_entropy_rows(hidden: jax.Array, w: jax.Array,
         bias = b if b is not None else jnp.zeros((v,), jnp.float32)
         b = jnp.pad(bias, (0, vp - v), constant_values=_NEG_PAD)
 
-    rows = _sharded_rows(hidden, w, b, labels, mesh, chunk, v,
-                         bool(use_pallas), interpret)
+    rows = _sharded_rows(hidden, w, b, labels, mesh, chunk, v, use_pallas,
+                         interpret)
     return rows[:n]
 
 

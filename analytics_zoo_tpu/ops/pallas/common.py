@@ -23,13 +23,20 @@ arithmetic.
 
 from __future__ import annotations
 
-from typing import Iterable, Optional, Sequence, Tuple
+import logging
+from typing import Callable, Iterable, Optional, Sequence, Tuple
+
+log = logging.getLogger("analytics_zoo_tpu.pallas")
 
 LANES = 128     # lane width (TPU min tile last dim)
 SUBLANES = 8    # sublane width (TPU min tile second-to-last dim)
 
-#: per-core VMEM (the pallas guide's ~16 MB/core); overridable per run via
-#: ``zoo.pallas.vmem_budget_mb`` for chips with a different budget
+#: the VMEM one kernel may count on: Mosaic's default scoped-VMEM limit,
+#: 16 MiB — checked on a TPU v5e (``device_kind`` "TPU v5 lite", the only
+#: chip this repo has run on), whose physical VMEM is 128 MiB per core. A
+#: kernel that needs more asks for it per call
+#: (``pltpu.CompilerParams(vmem_limit_bytes=...)``); other chips set
+#: ``zoo.pallas.vmem_budget_mb``
 VMEM_BYTES_DEFAULT = 16 * 1024 * 1024
 #: fraction of VMEM the block selectors hand a kernel — the rest stays
 #: with the compiler (spills, the backward's second operand window,
@@ -55,24 +62,43 @@ def pad_to_multiple(x, axis: int, mult: int):
 
 
 def vmem_budget_bytes() -> int:
-    """The live per-core VMEM budget: ``zoo.pallas.vmem_budget_mb`` when a
-    zoo context is constructible and sets it, else the 16 MiB default."""
-    try:
-        from ...common.context import get_zoo_context
-        mb = float(get_zoo_context().get("zoo.pallas.vmem_budget_mb", 0) or 0)
-        if mb > 0:
-            return int(mb * 1024 * 1024)
-    # no context constructible (odd device counts, standalone lint load)
-    # — the default budget holds
-    except Exception:  # zoolint: disable=ZL007
-        pass
-    return VMEM_BYTES_DEFAULT
+    """The live per-core VMEM budget: ``zoo.pallas.vmem_budget_mb`` when
+    the zoo context sets it, else the 16 MiB default. (zoolint, which
+    loads this file without the package, reads ``VMEM_BYTES_DEFAULT``
+    and never calls this.)"""
+    from ...common.context import get_zoo_context
+    mb = float(get_zoo_context().get("zoo.pallas.vmem_budget_mb", 0) or 0)
+    return int(mb * 1024 * 1024) if mb > 0 else VMEM_BYTES_DEFAULT
 
 
 def vmem_usable_bytes(budget_bytes: Optional[int] = None) -> int:
     """The slice of the budget a kernel may claim for its windows."""
     budget = budget_bytes if budget_bytes is not None else vmem_budget_bytes()
     return int(budget * VMEM_USABLE_FRACTION)
+
+
+def sweep_fastest(what: str, candidates: Iterable[Tuple[int, int]],
+                  timer: Callable[[int, int], float]) -> Tuple[int, int]:
+    """The candidate block pair ``timer`` reports fastest — the one-shot
+    on-device sweep shared by the flash and CE-backward autotuners. A
+    candidate the compiler refuses loses the sweep and says so (WARNING
+    with the compiler's message); if every candidate is refused this
+    raises rather than hand back a block nothing could run."""
+    best, best_t, refused = None, float("inf"), None
+    for cand in candidates:
+        try:
+            t = timer(*cand)
+        except Exception as e:  # zoolint: disable=ZL007 logged and chained
+            log.warning("%s block sweep: candidate %dx%d refused: %s",
+                        what, cand[0], cand[1], e)
+            refused = e
+            continue
+        if t < best_t:
+            best, best_t = cand, t
+    if best is None:
+        raise RuntimeError(f"{what} block sweep: every candidate was "
+                           f"refused") from refused
+    return best
 
 
 _ShapeBytes = Tuple[Sequence[int], int]     # ((dims...), itemsize)

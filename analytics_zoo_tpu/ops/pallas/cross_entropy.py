@@ -51,7 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .common import LANES as _LANES
 from .common import SUBLANES as _SUBLANES
 from .common import (ce_bwd_vmem_bytes, ce_vmem_bytes, pad_to_multiple,
-                     round_up, vmem_usable_bytes)
+                     round_up, sweep_fastest, vmem_usable_bytes)
 
 __all__ = ["fused_ce_forward", "fused_ce_backward", "select_ce_blocks"]
 
@@ -172,14 +172,9 @@ def _auto_ce_bwd_blocks(n: int, v: int, hidden: int, dtype,
     sweep (``zoo.pallas.block_sweep``; compiled TPU runs only — the
     interpreter's timings say nothing about the MXU)."""
     dt = jnp.dtype(dtype)
-    sweep = False
-    try:
-        from ...common.context import get_zoo_context
-        sweep = bool(get_zoo_context().get("zoo.pallas.block_sweep", False))
-    # no context constructible — the sweep stays off, heuristic holds
-    except Exception:  # zoolint: disable=ZL007
-        pass
-    sweep = sweep and not interpret and jax.default_backend() == "tpu"
+    from ...common.context import get_zoo_context
+    sweep = (bool(get_zoo_context().get("zoo.pallas.block_sweep", False))
+             and not interpret and jax.default_backend() == "tpu")
     budget = vmem_usable_bytes()
     sig = (budget, "ce_bwd", sweep, n, v, hidden, dt.name, has_bias)
     cached = _CE_BLOCK_CACHE.get(sig)
@@ -188,17 +183,10 @@ def _auto_ce_bwd_blocks(n: int, v: int, hidden: int, dtype,
     choice = select_ce_blocks(n, v, hidden, dt, has_bias=has_bias,
                               bwd=True)
     if sweep:
-        best, best_t = choice, float("inf")
-        for cand in _ce_sweep_candidates(n, v, hidden, dt.itemsize,
-                                         has_bias, choice):
-            try:
-                t = _time_ce_bwd(n, v, hidden, dt, has_bias, *cand)
-            # a candidate that fails to compile/run just loses the sweep
-            except Exception:  # zoolint: disable=ZL007
-                continue
-            if t < best_t:
-                best, best_t = cand, t
-        choice = best
+        choice = sweep_fastest(
+            "CE-backward",
+            _ce_sweep_candidates(n, v, hidden, dt.itemsize, has_bias, choice),
+            lambda bn, bv: _time_ce_bwd(n, v, hidden, dt, has_bias, bn, bv))
     _CE_BLOCK_CACHE[sig] = choice
     _record_ce_block_choice(
         f"bwd_n{n}v{v}h{hidden}{dt.name}{'b' if has_bias else ''}", choice)
@@ -335,6 +323,7 @@ def fused_ce_forward(h: jax.Array, w: jax.Array, b: Optional[jax.Array],
             pltpu.VMEM((block_n, _LANES), jnp.float32),  # label logit
         ],
         interpret=interpret,
+        name="zoo_ce_fwd",
     )(*operands)
     return lse[:n, 0], ll[:n, 0]
 
@@ -503,6 +492,7 @@ def fused_ce_backward(h: jax.Array, w: jax.Array, b: Optional[jax.Array],
         out_shape=jax.ShapeDtypeStruct(hp.shape, dh_dtype or h.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, hp.shape[1]), jnp.float32)],
         interpret=interpret,
+        name="zoo_ce_bwd_dh",
     )(*operands)
 
     dw_kernel = functools.partial(_ce_bwd_dw_kernel, **static)
@@ -531,6 +521,7 @@ def fused_ce_backward(h: jax.Array, w: jax.Array, b: Optional[jax.Array],
         out_shape=out_shape,
         interpret=interpret,
         scratch_shapes=scratch,
+        name="zoo_ce_bwd_dw",
     )(*operands)
 
     dh = dh[:n, :hidden]

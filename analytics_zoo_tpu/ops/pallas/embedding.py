@@ -6,21 +6,31 @@ stream; the last hop — expanding the block back to the ``(N, D)`` row
 stream — is a gather XLA lowers to per-row dynamic slices. This kernel
 does it as a **one-hot MXU contraction** instead: each ``(block_n, D)``
 output tile is ``onehot(inv) @ rows``, a 0/1 matmul that selects exactly
-one row per output position (products exact, a single nonzero term per
-sum), so the result is bit-identical to ``rows[inv]`` in any dtype while
-the memory traffic is a dense, tile-aligned streaming read of the
-unique block.
+one row per output position (a single nonzero term per sum), while the
+memory traffic is a dense, tile-aligned streaming read of the unique
+block.
+
+Bit-identical to ``rows[inv]``? Found on a TPU v5e (PR 21; the CPU
+interpreter cannot show it): in bfloat16 yes — one MXU pass multiplies
+bf16 operands exactly. In float32 NOT at default precision: the MXU
+rounds the f32 rows to bf16 (max abs error 1.3e-2 on N(0,1) rows at
+capacity 4096, D 64). The contraction therefore asks for
+``Precision.HIGHEST`` on f32 tables — Mosaic then splits each row value
+into bf16 pieces whose sum is the value, so the selection is exact again
+(checked bit for bit by ``chip_smoke.py``) at several MXU passes instead
+of one; what that costs against ``jnp.take`` is not measured.
 
 Flag: ``zoo.pallas.embed_gather`` (auto = TPU only). Block sizes come
 from the shared VMEM pricing formula
 (``common.embed_gather_vmem_bytes``) with the flash-attention shrink
 discipline; when even the ``SUBLANES`` floor cannot fit — a huge unique
-block — the caller's ``jnp.take`` path is used instead.
+block — ``jnp.take`` is used instead, with a WARNING.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -33,6 +43,8 @@ from .common import (embed_gather_vmem_bytes, pad_to_multiple, round_up,
                      vmem_usable_bytes)
 
 __all__ = ["embed_expand", "pallas_embed_gather_enabled"]
+
+log = logging.getLogger("analytics_zoo_tpu.pallas")
 
 
 def pallas_embed_gather_enabled() -> bool:
@@ -53,8 +65,7 @@ def _select_block_n(n_pad: int, capacity: int, d_pad: int,
     footprint fits the usable VMEM budget — the ``_budget_blocks``
     shrink discipline, re-landing on the tile floor every step. A pure
     function of the abstract signature, so the jit cache is stable.
-    Returns 0 when even the floor does not fit (caller falls back to
-    ``jnp.take``)."""
+    Returns 0 when even the floor does not fit."""
     budget = vmem_usable_bytes()
     block_n = round_up(min(1024, max(n_pad, 1)), _SUBLANES)
     while (embed_gather_vmem_bytes(block_n, capacity, d_pad,
@@ -75,8 +86,13 @@ def _expand_kernel(inv_ref, rows_ref, out_ref, *, capacity: int):
     onehot = (jax.lax.broadcasted_iota(
         jnp.int32, (inv_ref.shape[0], capacity), 1) == inv
         ).astype(rows_ref.dtype)
+    # f32 rows at default precision would be rounded to bf16 on the MXU
+    # (module docstring); bf16 rows are exact in one pass
+    precision = (jax.lax.Precision.HIGHEST
+                 if rows_ref.dtype == jnp.float32 else None)
     out_ref[...] = jax.lax.dot_general(
         onehot, rows_ref[...], (((1,), (0,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
@@ -85,8 +101,9 @@ def embed_expand(rows: jax.Array, inv: jax.Array,
     """``rows[inv]`` via the one-hot MXU kernel: ``rows`` is the
     ``(capacity, D)`` unique-row block, ``inv`` the ``(N,)`` int32
     inverse indices; returns ``(N, D)``. Bit-identical to ``jnp.take``
-    (which it falls back to when the priced footprint cannot fit even
-    at the sublane-floor block size)."""
+    in bfloat16 and float32 (module docstring) — which it falls back
+    to, saying so, when the priced footprint cannot fit even at the
+    sublane-floor block size."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = inv.shape[0]
@@ -96,6 +113,9 @@ def embed_expand(rows: jax.Array, inv: jax.Array,
     block_n = _select_block_n(round_up(max(n, 1), _SUBLANES), capacity,
                               d_pad, itemsize)
     if block_n == 0:
+        log.warning("embed_expand: a %dx%d %s unique block does not fit "
+                    "the VMEM budget at any block size; using jnp.take",
+                    capacity, d_pad, jnp.dtype(rows.dtype).name)
         return jnp.take(rows, inv, axis=0)
     n_pad = round_up(max(n, 1), block_n)
     ip = jnp.pad(inv.astype(jnp.int32), (0, n_pad - n))
@@ -111,5 +131,6 @@ def embed_expand(rows: jax.Array, inv: jax.Array,
         out_specs=pl.BlockSpec((block_n, d_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), rows.dtype),
         interpret=interpret,
+        name="zoo_embed_expand",
     )(inv2, rp)
     return out[:n, :rows.shape[1]]

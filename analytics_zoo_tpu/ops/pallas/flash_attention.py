@@ -42,7 +42,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .common import LANES as _LANES
 from .common import SUBLANES as _SUBLANES
-from .common import attention_vmem_bytes, pad_to_multiple, vmem_usable_bytes
+from .common import (attention_vmem_bytes, pad_to_multiple, sweep_fastest,
+                     vmem_usable_bytes)
 from .common import round_up as _round_up
 
 __all__ = ["flash_attention", "select_attention_blocks"]
@@ -161,18 +162,9 @@ def _sweep_blocks(b, h, t_q, t_kv, d, dtype, causal, has_mask, heuristic,
     is injectable for tests; the default times a real compiled fwd+bwd."""
     timer = timer or (lambda bq, bk: _time_blocks(
         b, h, t_q, t_kv, d, dtype, causal, has_mask, bq, bk))
-    best, best_t = heuristic, float("inf")
-    for bq, bk in _sweep_candidates(t_q, t_kv, d,
-                                    jnp.dtype(dtype).itemsize, has_mask,
-                                    heuristic):
-        try:
-            t = timer(bq, bk)
-        # a candidate that fails to compile/run just loses the sweep
-        except Exception:  # zoolint: disable=ZL007
-            continue
-        if t < best_t:
-            best, best_t = (bq, bk), t
-    return best
+    return sweep_fastest(
+        "flash", _sweep_candidates(t_q, t_kv, d, jnp.dtype(dtype).itemsize,
+                                   has_mask, heuristic), timer)
 
 
 def _record_block_choice(sig: str, choice) -> None:
@@ -203,14 +195,9 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     entries key on the full shape, since wall time does scale with B·H."""
     b, h, t_q, d = q_shape
     dt = jnp.dtype(dtype)
-    sweep = False
-    try:
-        from ...common.context import get_zoo_context
-        sweep = bool(get_zoo_context().get("zoo.pallas.block_sweep", False))
-    # no context constructible — the sweep stays off, heuristic holds
-    except Exception:  # zoolint: disable=ZL007
-        pass
-    sweep = sweep and not interpret and jax.default_backend() == "tpu"
+    from ...common.context import get_zoo_context
+    sweep = (bool(get_zoo_context().get("zoo.pallas.block_sweep", False))
+             and not interpret and jax.default_backend() == "tpu")
     # the live budget is part of the key — re-initializing the context
     # with zoo.pallas.vmem_budget_mb must take effect at the next call,
     # not silently keep blocks sized for the old budget
@@ -394,6 +381,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
         ],
         interpret=interpret,
+        name="zoo_flash_fwd",
     )(*operands)
     out = res[0]  # out_shape is a list either way
     o = out[:, :t_q, :].reshape(b, h, t_q, d)
@@ -532,6 +520,7 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="zoo_flash_bwd_dq",
     )(*operands)
 
     # dk/dv grid: (bh, ki, qi) — remap the spec index args accordingly
@@ -554,6 +543,7 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="zoo_flash_bwd_dkv",
     )(*operands)
 
     dq = dq[:, :t_q, :].reshape(b, h, t_q, d)
@@ -587,6 +577,34 @@ def _vjp_bwd(causal, block_q, block_k, interpret, res, g):
 
 
 _flash.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def _flash_per_data_shard(q, k, v, mask, causal, block_q, block_k,
+                          interpret):
+    """Run the kernel once per ``data`` shard. A Mosaic kernel refuses to
+    lower inside a jit that spans several devices ("Mosaic kernels cannot
+    be automatically partitioned" — found on a four-chip v5e host, PR 21:
+    the default data-parallel mesh could not train through this kernel at
+    all), and batch rows are independent, so on a mesh with a data axis
+    the call is wrapped in a ``shard_map`` over the batch dim. A plain
+    call where there is nothing to split, where the batch does not
+    divide, and inside a ``shard_map`` body (ring and pipeline stages),
+    whose operands are per-shard already."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel import mesh as mesh_lib
+    mesh = mesh_lib.global_mesh()
+    dp = mesh.shape[mesh_lib.DATA_AXIS]
+    if dp == 1 or q.shape[0] % dp or mesh_lib.in_manual_region():
+        return _flash(q, k, v, mask, causal, block_q, block_k, interpret)
+    args = (q, k, v) if mask is None else (q, k, v, mask)
+
+    def local(q, k, v, m=None):
+        return _flash(q, k, v, m, causal, block_q, block_k, interpret)
+
+    batch = P(mesh_lib.DATA_AXIS)
+    return jax.shard_map(local, mesh=mesh, in_specs=(batch,) * len(args),
+                         out_specs=batch, check_vma=False)(*args)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -631,4 +649,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                 mask is not None, interpret)
         block_q = block_q if block_q is not None else abq
         block_k = block_k if block_k is not None else abk
-    return _flash(q, k, v, mask, causal, block_q, block_k, interpret)
+    return _flash_per_data_shard(q, k, v, mask, causal, block_q, block_k,
+                                 interpret)

@@ -4,7 +4,12 @@ reference's int8 story is OpenVINO VNNI on Xeon,
 
 ``y = x @ (w_q * scale)`` with per-output-column scales, fused so the int8
 weights upcast in VMEM tile-by-tile — HBM traffic stays 1 byte/weight, the
-point of weight-only quantization. Standalone public API: the
+point of weight-only quantization. The product is a float32 matmul at
+``Precision.HIGHEST``: at default precision the MXU rounds both f32
+operands to bf16 (found on a TPU v5e, PR 21: 1.6e-3 of the output's max
+against the dequantised product), which the ``x.dtype`` contract does
+not allow; what the extra passes cost is not measured. Standalone public
+API: the
 ``pipeline/inference`` int8 predict path currently dequantizes in-jit and
 relies on XLA fusing the convert+scale into consumers; this kernel is the
 hand-scheduled alternative for callers that matmul against a quantized
@@ -33,6 +38,7 @@ def _kernel(x_ref, wq_ref, scale_ref, o_ref):
     w = wq_ref[:].astype(jnp.float32)
     acc = jax.lax.dot_general(x_ref[:].astype(jnp.float32), w,
                               (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     o_ref[:] = (acc * scale_ref[0, :][None, :]).astype(o_ref.dtype)
 
@@ -71,5 +77,6 @@ def int8_matmul(x: jax.Array, w_q: jax.Array, scales: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], wp.shape[1]), x.dtype),
         interpret=interpret,
+        name="zoo_int8_matmul",
     )(xp, wp, sp)
     return out[:m, :n]

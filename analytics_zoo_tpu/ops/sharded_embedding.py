@@ -258,7 +258,6 @@ def _sharded_lookup_fwd(table, ids, mesh, capacity, vp, use_pallas,
                         interpret):
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel import compat
     row_spec, table_spec = _row_specs(mesh)
     n_model = model_row_shard_count(mesh)
 
@@ -266,11 +265,10 @@ def _sharded_lookup_fwd(table, ids, mesh, capacity, vp, use_pallas,
         return _sharded_fwd_local(tt, ii, capacity, n_model, use_pallas,
                                   interpret)
 
-    fn = compat.shard_map(run, mesh=mesh,
-                          in_specs=(table_spec, row_spec),
-                          out_specs=(P(row_spec[0], None), row_spec,
-                                     row_spec),
-                          check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(table_spec, row_spec),
+                       out_specs=(P(row_spec[0], None), row_spec, row_spec),
+                       check_vma=False)
     out, uniq, inv = fn(table, ids)
     return out, (uniq, inv, jnp.zeros((), table.dtype))
 
@@ -279,7 +277,6 @@ def _sharded_lookup_bwd(mesh, capacity, vp, use_pallas, interpret, res,
                         g):
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel import compat
     uniq, inv, dtype_token = res
     row_spec, table_spec = _row_specs(mesh)
     n_model = model_row_shard_count(mesh)
@@ -288,10 +285,9 @@ def _sharded_lookup_bwd(mesh, capacity, vp, use_pallas, interpret, res,
     def run(uu, ii, gg):
         return _sharded_bwd_local(uu, ii, gg, vs, dtype_token.dtype)
 
-    fn = compat.shard_map(run, mesh=mesh,
-                          in_specs=(row_spec, row_spec,
-                                    P(row_spec[0], None)),
-                          out_specs=table_spec, check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(row_spec, row_spec, P(row_spec[0], None)),
+                       out_specs=table_spec, check_vma=False)
     dw = fn(uniq, inv, g)
     dids = np.zeros(inv.shape, dtype=jax.dtypes.float0)
     return dw, dids

@@ -101,6 +101,13 @@ def reset_global_mesh() -> None:
     _global_mesh = None
 
 
+def in_manual_region() -> bool:
+    """True while tracing the body of a ``shard_map``: arrays there are
+    per-shard blocks already, so a caller that would otherwise open a
+    ``shard_map`` of its own (the Pallas wrappers in ``ops/``) must not."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
+
+
 def data_parallel_size(mesh: Optional[Mesh] = None) -> int:
     mesh = mesh or global_mesh()
     return mesh.shape[DATA_AXIS]

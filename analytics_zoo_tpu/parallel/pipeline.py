@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compat
 from . import mesh as mesh_lib
 
 
@@ -96,7 +95,7 @@ def gpipe_apply(stage_fn: Callable, stacked_params, x, *, mesh,
     pspec = jax.tree.map(lambda _: P(mesh_lib.PIPE_AXIS), stacked_params)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(pspec, P(mesh_lib.DATA_AXIS)),
         out_specs=P(mesh_lib.DATA_AXIS),
         check_vma=False)
@@ -181,7 +180,7 @@ def hetero_gpipe_apply(stage_fns, stacked_vec, x_wire, *, mesh,
             f"per-shard batch {B // dp} not divisible by n_micro={n_micro}")
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(mesh_lib.PIPE_AXIS), P(mesh_lib.DATA_AXIS)),
         out_specs=P(mesh_lib.DATA_AXIS),
         check_vma=False)

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat
 from . import mesh as mesh_lib
 
 __all__ = ["ring_attention", "ring_self_attention", "ulysses_self_attention"]
@@ -180,7 +179,7 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               dropout_rate=dropout_rate,
                               dropout_rng=rng_loc)
 
-    fn = compat.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=spec,
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=spec,
                        check_vma=False)
     args = (q, k, v) + ((mask,) if mask is not None else ())
     args = args + ((dropout_rng,) if dropout_rng is not None else ())
@@ -243,7 +242,7 @@ def ulysses_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return jax.lax.all_to_all(og, axis_name=axis, split_axis=2,
                                   concat_axis=1, tiled=True)
 
-    fn = compat.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=spec,
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=spec,
                        check_vma=False)
     args = (q, k, v) + ((mask,) if mask is not None else ())
     args = args + ((dropout_rng,) if dropout_rng is not None else ())

@@ -186,8 +186,5 @@ def _head_sharded(head) -> bool:
     ``P(None, model)`` spec (an indivisible head falls back to the
     replicated kernel AND the unsharded fused loss together, so the loss
     collectives always match the param layout)."""
-    try:
-        n_model = vocab_shard_count()
-    except Exception:  # zoolint: disable=ZL007 no mesh constructible
-        return False
+    n_model = vocab_shard_count()
     return n_model > 1 and head.output_dim % n_model == 0

@@ -133,16 +133,12 @@ class MultiHeadSelfAttention(Layer):
             return False
         if mask is not None and self._kv_mask(mask) is None:
             return False
-        try:
-            from .....parallel import mesh as mesh_lib
-            if mesh_lib.global_mesh().shape[mesh_lib.MODEL_AXIS] > 1:
-                # pallas_call has no SPMD partitioning rule: model-sharded
-                # activations must stay on the XLA op (which GSPMD splits)
-                return False
-        # no mesh is constructible here (e.g. an odd device count) — the
-        # single-device pallas decision below is still valid
-        except Exception:  # zoolint: disable=ZL007
-            pass
+        from .....parallel import mesh as mesh_lib
+        if mesh_lib.global_mesh().shape[mesh_lib.MODEL_AXIS] > 1:
+            # the flash entry splits the batch over `data` under shard_map
+            # and nothing else: heads sharded over `model` stay on the XLA
+            # op, which GSPMD partitions itself
+            return False
         from .....common.context import tri_state_conf
         flag = tri_state_conf("zoo.pallas.attention")
         if flag == "auto":
@@ -160,11 +156,8 @@ class MultiHeadSelfAttention(Layer):
         parallelism)."""
         from .....common.context import get_zoo_context
         from ..seq_pipe import forced_seq_mode
-        try:
-            strict = bool(get_zoo_context().get("zoo.seq.strict", False))
-        except Exception:
-            strict = False
-        strict = strict or forced_seq_mode() in ("ring", "ulysses")
+        strict = (bool(get_zoo_context().get("zoo.seq.strict", False))
+                  or forced_seq_mode() in ("ring", "ulysses"))
         if strict and not probe:
             raise RuntimeError(
                 f"{self.name}: zoo.seq.strict is set and {reason} — "
@@ -194,12 +187,9 @@ class MultiHeadSelfAttention(Layer):
             # inside a pipeline stage (or an explicit disable scope):
             # no seq routing, no warning — the caller made the choice
             return None
-        try:
-            from .....parallel import mesh as mesh_lib
-            mesh = mesh_lib.global_mesh()
-            n_seq = mesh.shape[mesh_lib.SEQ_AXIS]
-        except Exception:
-            return None
+        from .....parallel import mesh as mesh_lib
+        mesh = mesh_lib.global_mesh()
+        n_seq = mesh.shape[mesh_lib.SEQ_AXIS]
         if n_seq <= 1:
             return None
         # shape-inference probes (placeholder batch dims) must neither warn
@@ -235,11 +225,7 @@ class MultiHeadSelfAttention(Layer):
         if forced in ("ring", "ulysses"):
             mode = forced
         else:
-            try:
-                mode = str(get_zoo_context().get("zoo.seq.mode",
-                                                 "ring")).lower()
-            except Exception:
-                mode = "ring"
+            mode = str(get_zoo_context().get("zoo.seq.mode", "ring")).lower()
         if mode not in ("ring", "ulysses", "auto"):
             raise ValueError(f"zoo.seq.mode must be ring|ulysses|auto, "
                              f"got {mode!r}")

@@ -17,6 +17,7 @@ actor on each host" is the program itself.
 from __future__ import annotations
 
 import atexit
+import contextlib
 import itertools
 import multiprocessing as mp
 import os
@@ -34,14 +35,25 @@ class RayTaskError(RuntimeError):
     """A task raised; carries the worker-side traceback."""
 
 
-def _mp_context():
-    """forkserver first: the driver is a JAX process (multi-threaded, device
-    handles open) — plain fork of it risks deadlocks in children. Payloads
-    must therefore be picklable, same as ray's own contract."""
-    for method in ("forkserver", "fork", "spawn"):
-        if method in mp.get_all_start_methods():
-            return mp.get_context(method)
-    return mp.get_context()
+_env_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def _cpu_worker_env():
+    """Start workers with ``JAX_PLATFORMS=cpu`` in THEIR environment, set
+    before they import anything. A chip belongs to one process: the driver
+    holds it, and a worker that touched JAX on the default platform would
+    fail or hang. The driver's own variable is put back at once."""
+    with _env_lock:
+        before = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if before is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = before
 
 
 class ObjectRef:
@@ -160,7 +172,12 @@ class RayContext:
     def __init__(self, num_workers: Optional[int] = None):
         self.num_workers = int(num_workers or (os.cpu_count() or 2))
         self._ids = itertools.count()
-        self._mp_ctx = _mp_context()
+        # spawn: the driver is a JAX process (multi-threaded, device
+        # handles open), so a worker is a fresh interpreter, never a fork
+        # of it — and it takes its environment from the driver at the
+        # moment it starts, which _cpu_worker_env relies on. Payloads must
+        # therefore be picklable, same as ray's own contract.
+        self._mp_ctx = mp.get_context("spawn")
         self._procs: List[mp.Process] = []
         self._actors: List[ActorHandle] = []
         self._task_q: Optional[mp.Queue] = None
@@ -179,7 +196,8 @@ class RayContext:
             p = ctx.Process(target=_pool_worker,
                             args=(os.getpid(), self._task_q, self._result_q),
                             daemon=True)
-            p.start()
+            with _cpu_worker_env():
+                p.start()
             self._procs.append(p)
         self._initialized = True
         atexit.register(self.stop)
@@ -222,7 +240,8 @@ class RayContext:
                         args=(os.getpid(), cls, args, kwargs, cmd_q,
                               self._result_q, ack_id),
                         daemon=True)
-        p.start()
+        with _cpu_worker_env():
+            p.start()
         # surface __init__ failures immediately; p is passed so a child
         # dying WITHOUT an ack (segfault, os._exit, unpicklable class in a
         # spawn context) raises instead of hanging the 0.2s poll forever

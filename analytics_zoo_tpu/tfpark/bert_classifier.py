@@ -52,6 +52,12 @@ class _BertClassifierNet(Layer):
     def initial_state(self, input_shape=None):
         return {}
 
+    def param_sharding(self, params):
+        # without this the Layer default replicates every leaf and a
+        # `model` mesh axis buys no tensor parallelism at all
+        return {"bert": self.bert.param_sharding(params["bert"]),
+                "cls": self.cls.param_sharding(params["cls"])}
+
     def call(self, params, x, *, training=False, rng=None):
         r1 = r2 = None
         if rng is not None:

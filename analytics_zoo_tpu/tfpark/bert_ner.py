@@ -85,6 +85,10 @@ class _BertTokenHeadNet(Layer):
     def initial_state(self, input_shape=None):
         return {}
 
+    def param_sharding(self, params):
+        return {"bert": self.bert.param_sharding(params["bert"]),
+                "head": self.head.param_sharding(params["head"])}
+
     def call(self, params, x, *, training=False, rng=None):
         r1 = r2 = None
         if rng is not None:

@@ -20,35 +20,37 @@ import jax
 
 log = logging.getLogger("analytics_zoo_tpu.profiling")
 
-#: Peak dense-matmul FLOP/s per chip by ``jax.Device.device_kind`` substring.
-#: bf16 peaks (the MXU native precision); fp32 runs at a fraction of these.
-#: Sources: public TPU spec sheets (v2 45T, v3 123T, v4 275T, v5e 197T,
-#: v5p 459T, v6e 918T bf16 per chip).
+#: Peak dense bf16 matmul FLOP/s per chip, keyed by the EXACT
+#: ``jax.Device.device_kind`` string. Numbers: Google Cloud TPU
+#: documentation, per chip (v4 275T, v5e 197T, v5p 459T, v6e 918T). Keys:
+#: "TPU v5 lite" is what a v5e machine reports (chip run, PR 21); the
+#: other three are the spellings jax's own table of TPU kinds uses
+#: (its pallas ``tpu_info`` module, jax 0.9.0) and have not been
+#: seen on a machine by this repo. A TPU kind that is not here is an
+#: error (:func:`device_peak_flops`), not a default.
 PEAK_FLOPS_BF16: Dict[str, float] = {
-    "TPU v2": 45e12,
-    "TPU v3": 123e12,
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v5": 459e12,      # plain "TPU v5" reported by some runtimes
+    "TPU v5": 459e12,
     "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-    "TPU7x": 2307e12,
 }
 
 
 def device_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
-    """Best-effort per-chip peak FLOP/s for MFU accounting; None if unknown
-    (e.g. the CPU test mesh)."""
+    """Per-chip peak bf16 FLOP/s for MFU accounting. ``None`` only off-TPU
+    (the CPU test mesh has no published peak); a TPU whose ``device_kind``
+    is not in :data:`PEAK_FLOPS_BF16` raises, so that an MFU is never
+    silently missing on a chip nobody entered."""
     d = device if device is not None else jax.devices()[0]
-    kind = getattr(d, "device_kind", "") or ""
-    # longest match wins so "TPU v5 lite" beats "TPU v5"
-    best = None
-    for k, v in PEAK_FLOPS_BF16.items():
-        if k.lower() in kind.lower() and (best is None or len(k) > best[0]):
-            best = (len(k), v)
-    return best[1] if best else None
+    if d.platform != "tpu":
+        return None
+    try:
+        return PEAK_FLOPS_BF16[d.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for TPU device_kind {d.device_kind!r}: add "
+            f"it, with its source, to utils.profiling.PEAK_FLOPS_BF16 "
+            f"(known: {sorted(PEAK_FLOPS_BF16)})") from None
 
 
 def compiled_flops(compiled) -> Optional[float]:
@@ -60,8 +62,6 @@ def compiled_flops(compiled) -> Optional[float]:
         return None
     if ca is None:
         return None
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
     flops = ca.get("flops")
     if flops is None or flops <= 0:
         return None
@@ -78,7 +78,7 @@ def jit_flops(fn, *args, **kwargs) -> Optional[float]:
 
 def mfu(flops_per_sec: float, n_devices: Optional[int] = None) -> Optional[float]:
     """Achieved model-FLOPs-utilization given sustained FLOP/s across the
-    mesh. None when the chip peak is unknown."""
+    mesh. None off-TPU; raises on a TPU kind without a published peak."""
     peak = device_peak_flops()
     if peak is None:
         return None

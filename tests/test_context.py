@@ -3,7 +3,10 @@ canonicalization (round-1 ADVICE #3: ``ZOO_TPU_FAILURE_RETRY_TIMES`` and
 ``init_zoo_context(failure_retry_times=...)`` must land on
 ``zoo.failure.retry_times``)."""
 
+import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 
@@ -119,3 +122,57 @@ def test_direct_set_policy_owns_across_reinit():
     set_policy(compute_dtype=jnp.float32)       # user's direct override
     init_zoo_context(seed=11)                   # unrelated re-init
     assert compute_dtype() == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# compile-cache placement (process-global jax config: subprocesses)
+# ---------------------------------------------------------------------------
+
+_CACHE_PROBE = """
+import json, os, sys
+import jax
+from analytics_zoo_tpu.common import context
+updates = []
+real_update = jax.config.update
+def spy(name, value):
+    updates.append(name)
+    return real_update(name, value)
+jax.config.update = spy
+context.init_zoo_context()
+print(json.dumps({"pid": os.getpid(),
+                  "dir": jax.config.jax_compilation_cache_dir,
+                  "fixed": context.COMPILE_CACHE_DIR,
+                  "code_set_it": "jax_compilation_cache_dir" in updates}))
+"""
+
+
+def _cache_probe(env_dir=None):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, timeout=120,
+                         check=True).stdout
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def test_compile_cache_env_dir_is_left_to_jax(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the program's code sets nothing
+    and jax's own handling of the variable decides."""
+    got = _cache_probe(str(tmp_path / "placed"))
+    assert got["dir"] == str(tmp_path / "placed")
+    assert got["code_set_it"] is False
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout():
+    """Unset, the directory is <checkout>/.jax_cache — the same in two
+    processes (different pids), never a temporary or per-process name."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    a, b = _cache_probe(), _cache_probe()
+    assert a["pid"] != b["pid"]
+    assert a["dir"] == b["dir"] == a["fixed"] == os.path.join(repo,
+                                                              ".jax_cache")
+    assert a["code_set_it"] is True

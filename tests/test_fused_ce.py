@@ -22,6 +22,18 @@ from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
 RTOL, ATOL = 1e-4, 1e-5
 
 
+def _sub_jaxprs(eqn):
+    """Sub-jaxprs ride an equation's params (scan/cond/shard_map bodies,
+    custom_vjp calls), bare or closed, singly or in a sequence."""
+    from jax.extend import core as jex_core
+    for p in eqn.params.values():
+        for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+            if isinstance(sub, jex_core.ClosedJaxpr):
+                yield sub.jaxpr
+            elif isinstance(sub, jex_core.Jaxpr):
+                yield sub
+
+
 def _setup(n=37, h=24, v=130, seed=0):
     rng = np.random.default_rng(seed)
     hid = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
@@ -199,8 +211,8 @@ def test_no_full_logits_tensor_in_backward():
                     size = int(np.prod(var.aval.shape)) if var.aval.shape \
                         else 1
                     biggest = max(biggest, size)
-        for sub in jax.core.subjaxprs(jx):
-            walk(sub.jaxpr if hasattr(sub, "jaxpr") else sub)
+            for sub in _sub_jaxprs(eqn):
+                walk(sub)
 
     walk(jaxpr.jaxpr)
     # largest live tensor: the (H, V) weight grad / (chunk, V) tile —
@@ -585,8 +597,8 @@ def test_sharded_backward_no_full_vocab_per_rank():
                     continue
                 size = int(np.prod(aval.shape)) if aval.shape else 1
                 biggest = max(biggest, size)
-        for sub in jax.core.subjaxprs(jx):
-            walk_all(sub.jaxpr if hasattr(sub, "jaxpr") else sub)
+            for sub in _sub_jaxprs(eqn):
+                walk_all(sub)
 
     walk_all(jaxpr.jaxpr)
     # largest live tensor anywhere (shard_map bodies included — their

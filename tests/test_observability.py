@@ -754,6 +754,27 @@ def test_fit_mfu_gauge_with_known_peak(monkeypatch):
     assert 0 < snap["zoo_train_mfu"]["value"] < 1
 
 
+def test_device_peak_flops_none_off_tpu_raises_on_unknown_tpu_kind():
+    """Off-TPU there is no published peak (None, and ``mfu`` stays None);
+    a TPU kind nobody entered is an error, not a silently missing MFU."""
+    import types
+
+    import jax
+    import pytest
+
+    from analytics_zoo_tpu.utils import profiling
+
+    assert profiling.device_peak_flops(jax.devices()[0]) is None
+    assert profiling.mfu(1e12) is None
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert profiling.device_peak_flops(v5e) == 197e12
+    # substring guesses are gone: the key is the exact reported string
+    for kind in ("TPU v9 mega", "TPU v5 lite pod", "tpu v5 lite"):
+        with pytest.raises(KeyError, match="no published peak"):
+            profiling.device_peak_flops(
+                types.SimpleNamespace(platform="tpu", device_kind=kind))
+
+
 def test_fit_mfu_flag_enabled_after_first_fit(monkeypatch):
     """The flops flag is re-read per dispatch — a first fit with it off
     must not latch MFU off for later fits on the same compiled model."""

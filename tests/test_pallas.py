@@ -654,3 +654,63 @@ def test_ce_bwd_budget_clamp_and_estimator_agreement():
     # carries the (H, block_v) dW accumulator the forward doesn't)
     assert lint.ce_bwd_vmem_bytes(256, 512, 512, 2) \
         > lint.ce_vmem_bytes(256, 512, 512, 2)
+
+
+# ---------------------------------------------------------------------------
+# several devices: Mosaic calls must sit inside a shard_map
+# ---------------------------------------------------------------------------
+
+def _tpu_lowering_kernels(fn, *avals):
+    """Kernel names of the Mosaic custom calls when ``fn`` is lowered FOR
+    the TPU from here (no chip needed: only the front end runs)."""
+    import re
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return set(re.findall(r'kernel_name = "([^"]+)"', text))
+
+
+def test_flash_lowers_for_tpu_on_a_data_parallel_mesh():
+    """jax refuses to lower a bare Mosaic kernel inside a jit that spans
+    several devices ("cannot be automatically partitioned" — what a
+    four-chip v5e host answered the default data mesh with, PR 21); the
+    public entry runs it per data shard under shard_map instead."""
+    from analytics_zoo_tpu.common.context import init_zoo_context
+    from analytics_zoo_tpu.parallel import mesh as mesh_lib
+
+    mesh = init_zoo_context().mesh                      # {data: 8}
+    bsh = mesh_lib.batch_sharding(mesh)
+    q = jax.ShapeDtypeStruct((8, 2, 256, 64), jnp.bfloat16, sharding=bsh)
+    keep = jax.ShapeDtypeStruct((8, 256), jnp.float32, sharding=bsh)
+
+    def grads(q, k, v, keep):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, mask=keep, interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    assert _tpu_lowering_kernels(grads, q, q, q, keep) == {
+        "zoo_flash_fwd", "zoo_flash_bwd_dq", "zoo_flash_bwd_dkv"}
+
+
+def test_fused_ce_kernels_lower_for_tpu_on_a_data_parallel_mesh():
+    from analytics_zoo_tpu.common.context import init_zoo_context
+    from analytics_zoo_tpu.ops.fused_cross_entropy import (
+        fused_sparse_cross_entropy)
+    from analytics_zoo_tpu.parallel import mesh as mesh_lib
+
+    mesh = init_zoo_context().mesh
+    h = jax.ShapeDtypeStruct((1024, 128), jnp.bfloat16,
+                             sharding=mesh_lib.batch_sharding(mesh))
+    w = jax.ShapeDtypeStruct((128, 1300), jnp.float32,
+                             sharding=mesh_lib.replicated_sharding(mesh))
+    b = jax.ShapeDtypeStruct((1300,), jnp.float32,
+                             sharding=mesh_lib.replicated_sharding(mesh))
+    y = jax.ShapeDtypeStruct((1024,), jnp.int32,
+                             sharding=mesh_lib.batch_sharding(mesh))
+
+    def grads(h, w, b, y):
+        return jax.grad(lambda h, w, b: fused_sparse_cross_entropy(
+            y, h, w, b, use_pallas=True, interpret=False),
+            argnums=(0, 1, 2))(h, w, b)
+
+    assert _tpu_lowering_kernels(grads, h, w, b, y) == {
+        "zoo_ce_fwd", "zoo_ce_bwd_dh", "zoo_ce_bwd_dw"}

@@ -206,3 +206,22 @@ def test_actor_dying_without_ack_raises_not_hangs():
         assert time.monotonic() - t0 < 30
     finally:
         ctx.stop()
+
+
+def _jax_platform_seen_by_worker():
+    import jax
+    return os.environ.get("JAX_PLATFORMS"), jax.default_backend()
+
+
+def test_workers_are_pinned_to_the_cpu(monkeypatch):
+    """The driver holds the chip, so a worker's own environment names the
+    CPU before it imports anything — whatever the driver's says — and the
+    driver's variable is put back."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    c = RayContext(num_workers=1).init()
+    try:
+        assert c.get(c.remote(_jax_platform_seen_by_worker),
+                     timeout=120) == ("cpu", "cpu")
+    finally:
+        c.stop()
+    assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"

@@ -20,6 +20,7 @@ the role Spark's per-partition parallelism plays for the reference.
 from __future__ import annotations
 
 import collections
+import contextlib
 import queue
 import threading
 from typing import Any, Callable, Iterator, List, Optional, Sequence, Tuple, Union
@@ -249,9 +250,12 @@ class _ThreadedIterator:
             pass
 
 
+_EXHAUSTED = object()
+
+
 def prefetch_to_device(it: Iterator, mesh=None, *, buffer_size: int = 2,
                        threaded: bool = True, sharding=None,
-                       ledger=None) -> Iterator:
+                       ledger=None, phases=None) -> Iterator:
     """Double-buffered device transfer: keep ``buffer_size`` batches already
     dispatched to the devices while the current one computes. ``device_put``
     is async in JAX, so this pipeline hides both host batch assembly (via the
@@ -262,14 +266,20 @@ def prefetch_to_device(it: Iterator, mesh=None, *, buffer_size: int = 2,
     *second* axis.
 
     ``ledger`` (a :class:`~..observability.goodput.GoodputLedger`)
-    attributes the step/data seam from inside the pipeline: time spent
-    in here — the blocking source pull (prefetch starvation) plus batch
-    assembly and transfer dispatch — is ``data_wait``; the consumer's
-    time between a yielded batch and its next ``next()`` is the
-    training step (``device_step``); spin-up before the first yield is
-    ``idle``. The notes run on the consumer's thread (generators
-    execute in their caller), which is exactly the thread the ledger
-    accounts."""
+    attributes the step/data seam from inside the pipeline. Time spent
+    in here — the blocking source pull plus batch assembly and transfer
+    dispatch — is offered as ``data_wait``; a ledger that was given the
+    loop's in-flight probe books it so only when the device had run dry
+    at the interval's end, and as ``device_step`` while the device still
+    had steps queued (the host was merely ahead). The consumer's time
+    between a yielded batch and its next ``next()`` is the training step
+    (``device_step``); spin-up before the first pull is ``idle``. The
+    notes run on the consumer's thread (generators execute in their
+    caller), which is exactly the thread the ledger accounts.
+
+    ``phases`` (the training loop's) times WHERE the host spends that time:
+    ``phases.pull`` around the blocking pull from the source,
+    ``phases.put`` around the ``device_put`` of a batch."""
     if sharding is None:
         sharding = mesh_lib.batch_sharding(mesh)
 
@@ -282,12 +292,20 @@ def prefetch_to_device(it: Iterator, mesh=None, *, buffer_size: int = 2,
         if ledger is not None:
             ledger.note(category)
 
+    pulling = phases.pull if phases is not None else contextlib.nullcontext()
+    putting = phases.put if phases is not None else contextlib.nullcontext()
     src = _ThreadedIterator(it, buffer_size=buffer_size + 2) if threaded else it
+    src = iter(src)
     buf: collections.deque = collections.deque()
     note("idle")                    # body first runs at the first next()
     try:
-        for item in src:
-            buf.append(put(item))
+        while True:
+            with pulling:
+                item = next(src, _EXHAUSTED)
+            if item is _EXHAUSTED:
+                break
+            with putting:
+                buf.append(put(item))
             if len(buf) > buffer_size:
                 note("data_wait")
                 yield buf.popleft()

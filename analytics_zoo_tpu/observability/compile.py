@@ -28,6 +28,17 @@ recompile triggered purely by a resharded input counts as a compile but
 never as a retrace, so the retrace signal stays a pure shape-discipline
 alarm.
 
+``instrument_jit`` times a first dispatch from outside and sees only its
+own entry points. What JAX itself spent, on any program, comes from
+``jax.monitoring``'s events (:func:`install_compile_listeners`, once a
+process): Python tracing, lowering to MLIR, the XLA backend, or a load
+from the persistent cache, booked into
+``zoo_xla_compile_seconds_total{fn=,phase=trace|lower|backend|cache_load}``
+and ``zoo_xla_compile_total{fn=,cache=hit|miss}``. ``fn`` is the
+``instrument_jit`` name whose call is on this thread's stack, and
+``"uninstrumented"`` for everything else (an eager ``jnp`` reduction whose
+shape holds the step count, a reader's own ``.trace().lower()``).
+
 ``jax`` is imported lazily so the observability package stays importable
 (and the scrape/status CLI stays fast) in jax-free processes.
 """
@@ -36,13 +47,125 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from .metrics import MetricsRegistry, default_registry
 
-__all__ = ["instrument_jit", "InstrumentedJit"]
+__all__ = ["instrument_jit", "InstrumentedJit", "install_compile_listeners",
+           "xla_compile_totals", "COMPILE_PHASES", "UNINSTRUMENTED"]
 
 _HASHABLE = (int, float, bool, str, bytes, type(None))
+
+#: ``phase`` values of ``zoo_xla_compile_seconds_total``
+COMPILE_PHASES = ("trace", "lower", "backend", "cache_load")
+#: ``fn`` of a compilation with no ``instrument_jit`` call on the stack
+UNINSTRUMENTED = "uninstrumented"
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_install_lock = threading.Lock()
+_installed = False
+
+
+class _ThreadState(threading.local):
+    """What the listeners know of the thread an event fires on."""
+
+    def __init__(self):
+        #: ``(name, {phase: seconds})`` of the innermost instrumented call
+        self.call: Optional[Tuple[str, Dict[str, float]]] = None
+        #: ``(start, end)`` of the events that ended and may lie inside one
+        #: that has not: JAX reports a nested ``jit``'s trace (and an eager
+        #: op compiled while an outer function is traced) on its own AND
+        #: inside the enclosing event's duration
+        self.ended: List[Tuple[float, float]] = []
+        #: the persistent cache answered the compilation in progress
+        self.cache_hit = False
+
+
+_thread = _ThreadState()
+_PHASE_OF = {_TRACE_EVENT: "trace", _LOWER_EVENT: "lower",
+             _BACKEND_EVENT: "backend"}
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    phase = _PHASE_OF.get(event)
+    if phase is None:
+        return
+    # self time: events end innermost first, so whatever ended after this
+    # one began lies inside it and has been booked already
+    end = time.perf_counter()
+    start = end - seconds
+    ended = _thread.ended
+    while ended and ended[-1][0] >= start:
+        inner = ended.pop()
+        seconds -= inner[1] - inner[0]
+    ended.append((start, end))
+    call = _thread.call
+    fn = call[0] if call is not None else UNINSTRUMENTED
+    reg = default_registry()
+    if phase == "backend":
+        # the event spans the cache lookup too: on a hit all of it is the
+        # load (key hashing, read, deserialization), none of it XLA
+        hit, _thread.cache_hit = _thread.cache_hit, False
+        if hit:
+            phase = "cache_load"
+        # fn = an instrument_jit(name=...) constant or "uninstrumented"
+        reg.counter(  # zoolint: disable=ZL015 bounded label set
+            "zoo_xla_compile_total",
+            "programs brought up, by the instrumented entry point on the "
+            "stack and by whether the persistent cache held them",
+            labels={"fn": fn, "cache": "hit" if hit else "miss"}).inc()
+    seconds = max(seconds, 0.0)
+    if call is not None:
+        call[1][phase] = call[1].get(phase, 0.0) + seconds
+    # fn: as above
+    reg.counter(  # zoolint: disable=ZL015 bounded label set
+        "zoo_xla_compile_seconds_total",
+        "seconds JAX spent bringing programs up, by the instrumented entry "
+        "point on the stack and by phase (Python tracing, lowering to MLIR, "
+        "XLA backend compilation, load from the persistent cache); each "
+        "second is booked once, to the innermost event that covers it",
+        labels={"fn": fn, "phase": phase}).inc(seconds)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _thread.cache_hit = True
+
+
+def install_compile_listeners() -> None:
+    """Register the ``jax.monitoring`` listeners, once a process (JAX keeps
+    listeners for the life of the process; the counters they feed are
+    looked up in :func:`default_registry` at each event, so a test's
+    ``reset_default_registry()`` is honored)."""
+    global _installed
+    with _install_lock:
+        if _installed:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _installed = True
+
+
+def xla_compile_totals(registry: Optional[MetricsRegistry] = None
+                       ) -> Dict[str, Dict[str, float]]:
+    """``{fn: {"trace": s, "lower": s, "backend": s, "cache_load": s,
+    "hit": n, "miss": n}}`` as the registry holds them now (keys that were
+    never booked are absent). Two of these, subtracted, are what one
+    ``fit`` compiled (``model.last_fit_report["compile"]``)."""
+    reg = registry if registry is not None else default_registry()
+    out: Dict[str, Dict[str, float]] = {}
+    key_of = {"zoo_xla_compile_seconds_total": "phase",
+              "zoo_xla_compile_total": "cache"}
+    for m in reg.metrics():
+        if m.name in key_of:
+            labels = dict(m.labels)
+            out.setdefault(labels["fn"], {})[labels[key_of[m.name]]] = m.value
+    return out
 
 
 class InstrumentedJit:
@@ -54,6 +177,7 @@ class InstrumentedJit:
     def __init__(self, fn, *, name: str,
                  registry: Optional[MetricsRegistry] = None, **jit_kwargs):
         import jax
+        install_compile_listeners()
         self._jitted = jax.jit(fn, **jit_kwargs)
         self._name = name
         # None = resolve default_registry() per compile event, so a test's
@@ -92,6 +216,15 @@ class InstrumentedJit:
                 else default_registry())
 
     def __call__(self, *args, **kwargs):
+        # the compile listeners book what JAX does inside this call
+        # under this entry point's name
+        outer, _thread.call = _thread.call, (self._name, {})
+        try:
+            return self._call(args, kwargs)
+        finally:
+            _thread.call = outer
+
+    def _call(self, args, kwargs):
         cache_size = getattr(self._jitted, "_cache_size", None)
         if cache_size is not None:
             # fast path: one executable-cache size read (~tens of ns)
@@ -135,7 +268,9 @@ class InstrumentedJit:
             "first-dispatch wall time per compilation "
             "(trace+compile dominated)",
             labels={"fn": self._name}).observe(dur)
-        reg.emit("jit.compile", fn=self._name, dur_s=dur, n_signatures=n_sigs)
+        phases = _thread.call[1] if _thread.call is not None else {}
+        reg.emit("jit.compile", fn=self._name, dur_s=dur, n_signatures=n_sigs,
+                 **{p + "_s": phases.get(p, 0.0) for p in COMPILE_PHASES})
         # retrace = a compile under a NEW abstract signature after the
         # first; a compile with a KNOWN signature (resharded inputs, a
         # concurrent first call racing this one) counts above but is not

@@ -26,6 +26,16 @@ the prefetch stream wrapper, the checkpoint manager's synchronous
 window, the retry/rollback handlers; serving: the lane loop's
 read/shed/route/pump seams).
 
+The notes come from a HOST thread, and a runtime that dispatches
+asynchronously lets the host run several steps ahead of the device: the
+host then spends its life blocked in the input pipeline while the chip
+works. So a training ledger is given the loop's :class:`InflightProbe`
+as ``device_busy``: an interval offered as ``data_wait`` is booked as
+``device_step`` when the device still had dispatched work at its end,
+and as ``data_wait`` only when it had run dry. ``data_wait`` is then
+starvation, and an upper bound of it (the queue may have emptied part
+of the way through the interval).
+
 Exported metric families (docs/guides/OBSERVABILITY.md "Goodput &
 performance attribution"): ``zoo_goodput_ratio``,
 ``zoo_goodput_seconds_total``, ``zoo_badput_seconds_total{category=}``.
@@ -37,14 +47,16 @@ numbers in its ``performance`` block.
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import Callable, Dict, Optional
 
 from .metrics import MetricsRegistry, default_registry
 
-__all__ = ["GoodputLedger", "TRAIN_CATEGORIES", "SERVE_CATEGORIES",
-           "GOOD_CATEGORY", "goodput_enabled", "registry_snapshot"]
+__all__ = ["GoodputLedger", "InflightProbe", "TRAIN_CATEGORIES",
+           "SERVE_CATEGORIES", "GOOD_CATEGORY", "goodput_enabled",
+           "registry_snapshot"]
 
 #: exclusive wall-time categories per role; the FIRST entry is goodput
 TRAIN_CATEGORIES = ("device_step", "data_wait", "compile", "ckpt_stall",
@@ -80,11 +92,17 @@ class GoodputLedger:
     thread being accounted; readers (``/statusz``, tests) may call the
     query methods from any thread. ``clock`` is injectable so tests
     drive the ledger tick by tick and reconcile exactly.
+
+    ``device_busy`` (training: :meth:`InflightProbe.busy`) says, without
+    blocking, whether the device still holds dispatched work. With it, a
+    ``data_wait`` note whose interval ends on a busy device is booked
+    under the good category: the host waited, the chip did not.
     """
 
     def __init__(self, role: str = "train",
                  registry: Optional[MetricsRegistry] = None,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 device_busy: Optional[Callable[[], bool]] = None):
         if role not in GOOD_CATEGORY:
             raise ValueError(f"role must be 'train' or 'serve', got {role!r}")
         self.role = role
@@ -94,6 +112,7 @@ class GoodputLedger:
         self.registry = registry if registry is not None \
             else default_registry()
         self._clock = clock
+        self._device_busy = device_busy
         self._lock = threading.Lock()
         self._mark: Optional[float] = None
         self._opened: Optional[float] = None
@@ -139,6 +158,9 @@ class GoodputLedger:
                 f"unknown category {category!r} for role {self.role!r} "
                 f"(one of {self.categories})")
         now = self._clock() if now is None else now
+        if (category == "data_wait" and self._device_busy is not None
+                and self._device_busy()):
+            category = self.good
         with self._lock:
             if self._mark is None:
                 self._mark = now
@@ -192,6 +214,93 @@ class GoodputLedger:
                 "wall_s": wall,
                 "seconds": dict(self._seconds),
             }
+
+
+class InflightProbe:
+    """How far the host is ahead of the device, in optimizer steps.
+
+    The training loop hands over the loss array of every segment it
+    dispatches (:meth:`dispatched`: one step, one scan chunk of K, one
+    fused epoch) and asks right before the next dispatch
+    (:meth:`at_dispatch`). Segments run on the device in the order they
+    were dispatched, so dropping from the left every array whose
+    ``is_ready()`` is true (non-blocking, well under a microsecond)
+    leaves exactly what the device has not finished: the depth.
+
+    * ``zoo_train_inflight_steps``: the depth seen at each dispatch,
+    * ``zoo_train_dispatch_on_empty_total``: dispatches, other than a
+      fit's first, that found depth 0: the chip had run dry, so the
+      loop and not the chip set the pace.
+
+    One probe serves one ``fit``; :meth:`summary` is its part of
+    ``model.last_fit_report``."""
+
+    def __init__(self, registry: Optional[MetricsRegistry] = None):
+        reg = registry if registry is not None else default_registry()
+        self._pending: collections.deque = collections.deque()
+        self._depth = 0
+        self._seen: collections.Counter = collections.Counter()
+        self.steps = 0
+        self.dispatch_on_empty = 0
+        self._m_depth = reg.histogram(
+            "zoo_train_inflight_steps",
+            "optimizer steps dispatched and not yet finished by the "
+            "device, seen right before each dispatch")
+        self._m_empty = reg.counter(
+            "zoo_train_dispatch_on_empty_total",
+            "dispatches, other than a fit's first, that found the device "
+            "with nothing in flight")
+
+    def _sweep(self) -> int:
+        pending = self._pending
+        while pending and pending[0][0].is_ready():
+            self._depth -= pending.popleft()[1]
+        return self._depth
+
+    def busy(self) -> bool:
+        """Whether the device still holds dispatched steps."""
+        return self._sweep() > 0
+
+    def at_dispatch(self) -> int:
+        """Record the depth a dispatch finds; returns it."""
+        depth = self._sweep()
+        self._m_depth.observe(depth)
+        self._seen[depth] += 1
+        if depth == 0 and self.steps:
+            self.dispatch_on_empty += 1
+            self._m_empty.inc()
+        return depth
+
+    def dispatched(self, loss, steps: int = 1) -> None:
+        """``loss`` is the device array the segment of ``steps`` optimizer
+        steps just dispatched will fill."""
+        self._pending.append((loss, steps))
+        self._depth += steps
+        self.steps += steps
+
+    def clear(self) -> None:
+        """Let go of the arrays: the fit is over."""
+        self._pending.clear()
+        self._depth = 0
+
+    def summary(self) -> Dict[str, object]:
+        """``{"median", "min", "max", "dispatch_on_empty"}`` of the depths
+        seen at this fit's dispatches (``None`` before the first)."""
+        n = sum(self._seen.values())
+        if not n:
+            return {"median": None, "min": None, "max": None,
+                    "dispatch_on_empty": self.dispatch_on_empty}
+        depths = sorted(self._seen)
+
+        def nth(k: int) -> int:         # the k-th smallest depth seen
+            for d in depths:
+                k -= self._seen[d]
+                if k < 0:
+                    return d
+            return depths[-1]
+        return {"median": 0.5 * (nth((n - 1) // 2) + nth(n // 2)),
+                "min": depths[0], "max": depths[-1],
+                "dispatch_on_empty": self.dispatch_on_empty}
 
 
 def registry_snapshot(registry: Optional[MetricsRegistry] = None

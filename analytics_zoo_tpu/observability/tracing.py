@@ -17,6 +17,14 @@ parent-linked per-request phase events (enqueue → dequeue → dispatch →
 publish) carrying that id into the JSON event log — the id is the join
 key, the log is the trace store, and there is still no in-band context
 to thread through the hot path.
+
+Every span is ALSO an event of the profiler's ``/host:CPU`` plane: ``span``
+opens a ``jax.profiler.TraceAnnotation`` of the same name (half a
+microsecond while no trace is being taken), so a ``set_profile`` /
+``ProfilerTrigger`` capture shows the zoo's phases on the clock of the
+device's own events. :class:`HostPhase` is the per-step form: the
+annotation plus one counter add, for phases that run every optimizer step
+and cannot pay a histogram observation and an event each.
 """
 
 from __future__ import annotations
@@ -30,7 +38,47 @@ from typing import Dict, Iterator, Optional
 
 from .metrics import Histogram, MetricsRegistry, default_registry
 
-__all__ = ["span", "current_span", "SpanHandle", "new_trace_id"]
+__all__ = ["span", "current_span", "SpanHandle", "new_trace_id",
+           "trace_annotation", "HostPhase"]
+
+_TraceAnnotation = None
+
+
+def trace_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation(name)`` context manager (a no-op
+    one where jax is not installed: the scrape/status CLIs import this
+    package without it)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = contextlib.nullcontext
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
+class HostPhase:
+    """A host phase that runs every step: ``with phase:`` adds the block's
+    wall seconds to ``counter`` and shows it as ``name`` in the profiler's
+    host plane. Not re-entrant and not shared between threads: one loop
+    thread enters and leaves it, in turn."""
+
+    __slots__ = ("name", "_counter", "_annotation", "_t0")
+
+    def __init__(self, name: str, counter):
+        self.name = name
+        self._counter = counter
+
+    def __enter__(self) -> "HostPhase":
+        self._annotation = trace_annotation(self.name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._counter.inc(time.perf_counter() - self._t0)
+        self._annotation.__exit__(*exc)
 
 
 def new_trace_id() -> str:
@@ -98,6 +146,8 @@ def span(name: str, registry: Optional[MetricsRegistry] = None,
 
     * duration → ``zoo_span_seconds{span=name}`` histogram in ``registry``
       (default: the process-wide registry),
+    * one ``TraceAnnotation(name)`` in the profiler's host plane, discarded
+      or not (the profiler has no way to take an event back),
     * one ``{"kind": "span", "name", "parent", "dur_s", **attrs}`` event
       to the registry's sinks (no-op when none are attached),
     * ``attrs`` ride along on the event only — keep them small and
@@ -112,7 +162,8 @@ def span(name: str, registry: Optional[MetricsRegistry] = None,
     handle = SpanHandle()
     t0 = time.perf_counter()
     try:
-        yield handle
+        with trace_annotation(name):
+            yield handle
     finally:
         dur = time.perf_counter() - t0
         st.pop()

@@ -22,6 +22,7 @@ parameter-server allreduce, ``wp-bigdl.md:113-160``):
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import signal
 import threading
@@ -40,6 +41,10 @@ from ....common.triggers import (EveryEpoch, MaxEpoch, SeveralIteration,
                                  TrainLoopState, Trigger)
 from ....feature.feature_set import FeatureSet, prefetch_to_device
 from ....observability import default_registry, instrument_jit, span
+from ....observability.compile import xla_compile_totals
+from ....observability.goodput import (GoodputLedger, InflightProbe,
+                                       goodput_enabled)
+from ....observability.tracing import HostPhase, trace_annotation
 from ....parallel import mesh as mesh_lib
 from ....utils.checkpoint import CheckpointManager
 from . import metrics as metrics_lib
@@ -394,6 +399,57 @@ class _SentinelMonitor:
 # The training loop (InternalDistriOptimizer / LocalOptimizer unified)
 # ---------------------------------------------------------------------------
 
+#: where the HOST spends a fit, in the order a fit goes through them: the
+#: ``phase`` values of ``zoo_train_host_seconds_total`` and the keys of
+#: ``model.last_fit_report["host_s"]``. Each is also an event ``train.<phase>``
+#: of the profiler's host plane.
+HOST_PHASES = ("fit.enter", "data.pull", "data.put", "step.dispatch",
+               "epoch.tail", "epoch.publish")
+
+
+class _HostPhases(contextlib.ExitStack):
+    """The host side of a fit by phase (:data:`HOST_PHASES`).
+
+    The three that run every step (``pull`` and ``put``, entered by
+    ``prefetch_to_device``, and ``dispatch``) are :class:`HostPhase`
+    objects: one counter add and one profiler annotation. The three that
+    run once a fit or once an epoch follow one another in ``_fit_impl``:
+    :meth:`switch` ends the open one and begins the next as a ``span``
+    (histogram, event, annotation) whose seconds the counter gets too. The
+    stack is entered around each fit attempt, so that an exception ends
+    whatever phase was open."""
+
+    def __init__(self, registry):
+        super().__init__()
+        self._registry = registry
+        # phase iterates HOST_PHASES — a 6-entry module constant
+        self.counters = {
+            phase: registry.counter(  # zoolint: disable=ZL015 bounded label set
+                "zoo_train_host_seconds_total",
+                "wall seconds the training loop's host thread spent in "
+                "each phase of fit (where the host waits; whether the "
+                "chip starves is zoo_badput_seconds_total{category="
+                "data_wait})", labels={"phase": phase})
+            for phase in HOST_PHASES}
+        self.pull = HostPhase("train.data.pull", self.counters["data.pull"])
+        self.put = HostPhase("train.data.put", self.counters["data.put"])
+        self.dispatch = HostPhase("train.step.dispatch",
+                                  self.counters["step.dispatch"])
+
+    def switch(self, phase: Optional[str] = None) -> None:
+        """End the open per-fit/per-epoch phase; begin ``phase`` if given."""
+        self.close()
+        if phase is not None:
+            t0 = time.perf_counter()
+            self.callback(lambda: self.counters[phase].inc(
+                time.perf_counter() - t0))
+            self.enter_context(span("train." + phase,
+                                    registry=self._registry))
+
+    def seconds(self) -> Dict[str, float]:
+        return {phase: c.value for phase, c in self.counters.items()}
+
+
 class TrainingLoop:
     """Owns the jitted step functions for one (model, optimizer, loss) triple."""
 
@@ -499,6 +555,11 @@ class TrainingLoop:
         # created at fit_feature_set entry when zoo.goodput.enabled
         self._goodput = None
         self._gp_restarting = False   # a retry attempt's resume pending
+        # what the device still has to run (one probe per fit) and where
+        # the host spends the fit: with the ledger, the three views that
+        # model.last_fit_report hands out
+        self._probe: Optional[InflightProbe] = None
+        self._phases = _HostPhases(self._registry)
 
     # -- goodput attribution -------------------------------------------------
     def _gp_note(self, category: str) -> None:
@@ -506,6 +567,64 @@ class TrainingLoop:
         (no-op outside an accounted fit)."""
         if self._goodput is not None:
             self._goodput.note(category)
+
+    def _dispatch(self, fn, iteration: int, steps: int, *args):
+        """Dispatch one segment of ``steps`` optimizer steps (a step, a scan
+        chunk, a fused epoch) starting at ``iteration``: the in-flight
+        probe sees the depth it finds and the loss it will fill, the
+        profiler a ``zoo.train.step`` step event."""
+        probe = self._probe
+        probe.at_dispatch()
+        with self._phases.dispatch, jax.profiler.StepTraceAnnotation(
+                "zoo.train.step", step_num=iteration):
+            out = fn(*args)
+        # every step function returns (params, opt_state, net_state, loss)
+        # or, under the sentinels, (..., sentinel state, loss, flags)
+        probe.dispatched(out[-1] if len(out) == 4 else out[-2], steps)
+        return out
+
+    def _drain(self, losses, reduce: bool = True):
+        """Wait for the device to finish what the epoch dispatched, before
+        the epoch's tail reads its losses back; returns their mean as a
+        device scalar (``None`` with ``reduce=False`` or no losses). The
+        mean is dispatched first, behind the steps still in flight, so
+        that its host work (a compilation, whenever the epoch's length is
+        new) hides under them. All of this wait is the device running
+        steps (the host was ahead by so much), so the ledger books it as
+        ``device_step`` and no host phase covers it."""
+        if not losses:
+            return None
+        busy = self._probe.busy()
+        with trace_annotation("train.epoch.drain"):
+            mean = (jnp.mean(jnp.concatenate(
+                [jnp.atleast_1d(l) for l in losses])) if reduce else None)
+            jax.block_until_ready(losses[-1])
+        if busy:
+            self._gp_note("device_step")
+        return mean
+
+    def _fit_report(self, t_open: float, t_end: float,
+                    host_before: Dict[str, float],
+                    compile_before: Dict[str, Dict[str, float]]
+                    ) -> Dict[str, Any]:
+        """``model.last_fit_report``: what the registry's counters hold,
+        as deltas over the fit that just ended."""
+        compiled: Dict[str, Dict[str, float]] = {}
+        for fn, now in xla_compile_totals().items():
+            before = compile_before.get(fn, {})
+            delta = {k: v - before.get(k, 0.0) for k, v in now.items()}
+            if any(delta.values()):
+                compiled[fn] = delta
+        return {
+            "wall_s": t_end - t_open,
+            "steps": self._probe.steps,
+            "ledger": (self._goodput.seconds()
+                       if self._goodput is not None else {}),
+            "host_s": {phase: v - host_before[phase]
+                       for phase, v in self._phases.seconds().items()},
+            "inflight": self._probe.summary(),
+            "compile": compiled,
+        }
 
     # -- jitted steps -------------------------------------------------------
     #: the labels of the most recent fused-CE gauge write in this process —
@@ -1411,11 +1530,15 @@ class TrainingLoop:
         # goodput/badput ledger for this fit (zoo.goodput.enabled):
         # every wall-clock second between here and the finally below is
         # attributed to exactly one category
-        from ....observability.goodput import GoodputLedger, goodput_enabled
-        self._goodput = (GoodputLedger("train", registry=self._registry)
+        t_open = time.perf_counter()
+        self._probe = InflightProbe(self._registry)
+        self._goodput = (GoodputLedger("train", registry=self._registry,
+                                       device_busy=self._probe.busy)
                          if goodput_enabled() else None)
         if self._goodput is not None:
-            self._goodput.open()
+            self._goodput.open(t_open)
+        host_before = self._phases.seconds()
+        compile_before = xla_compile_totals()
         try:
             with profiling.trace(profile_dir), span("train.fit",
                                                     registry=self._registry):
@@ -1428,7 +1551,13 @@ class TrainingLoop:
                     attempts=attempts, window_start=window_start)
         finally:
             # close the ledger's last open interval — teardown is idle
-            self._gp_note("idle")
+            # (the epoch's tail drained the device: it holds no step)
+            t_end = time.perf_counter()
+            if self._goodput is not None:
+                self._goodput.note("idle", t_end)
+            self.model.last_fit_report = self._fit_report(
+                t_open, t_end, host_before, compile_before)
+            self._probe.clear()
             # the boundary clone holds whole param trees — never past fit
             self._boundary_ref = None
             self._segment_t0 = None
@@ -1446,12 +1575,12 @@ class TrainingLoop:
                         retry_times, window_sec, attempts, window_start):
         while True:
             try:
-                history = self._fit_impl(fs, batch_size=batch_size,
-                                         nb_epoch=nb_epoch,
-                                         target_holder=target_holder,
-                                         validation_data=validation_data,
-                                         rng=rng, callbacks=callbacks,
-                                         end_trigger=end_trigger)
+                with self._phases:
+                    history = self._fit_impl(
+                        fs, batch_size=batch_size, nb_epoch=nb_epoch,
+                        target_holder=target_holder,
+                        validation_data=validation_data, rng=rng,
+                        callbacks=callbacks, end_trigger=end_trigger)
                 # end-of-fit join of the async checkpoint writer: a
                 # background save failure surfaces HERE (CheckpointSaveError
                 # → the generic handler below, which re-cuts the lost
@@ -1535,6 +1664,7 @@ class TrainingLoop:
                   ) -> Dict[str, List[float]]:
         ctx = get_zoo_context()
         model = self.model
+        self._phases.switch("fit.enter")
         # fail NOW, not after an epoch of compute: scan fusing stacks K
         # consecutive batches into one array (can't mix widths), and
         # validation/evaluate need one dense array
@@ -1752,6 +1882,7 @@ class TrainingLoop:
             tb = getattr(model, "_train_summary", None)
             epoch = model.finished_epochs
             while epoch < target_epoch:
+                self._phases.switch()
                 g = min(fuse, target_epoch - epoch)
                 t0 = time.time()
                 it0 = jnp.asarray(loop_state.iteration, jnp.int32)
@@ -1762,7 +1893,8 @@ class TrainingLoop:
                         epoch_fn, (params, opt_state, net_state, base_rng,
                                    it0, shuffle_rng, xs_dev, ys_dev),
                         n_steps * batch_size)
-                    params, opt_state, net_state, L = epoch_fn(
+                    params, opt_state, net_state, L = self._dispatch(
+                        epoch_fn, loop_state.iteration, n_steps,
                         params, opt_state, net_state, base_rng, it0,
                         shuffle_rng, xs_dev, ys_dev)
                 else:
@@ -1775,11 +1907,15 @@ class TrainingLoop:
                         mfn, (params, opt_state, net_state, base_rng, it0,
                               keys, xs_dev, ys_dev),
                         g * n_steps * batch_size)
-                    params, opt_state, net_state, L = mfn(
+                    params, opt_state, net_state, L = self._dispatch(
+                        mfn, loop_state.iteration, g * n_steps,
                         params, opt_state, net_state, base_rng, it0, keys,
                         xs_dev, ys_dev)
-                L = np.asarray(jax.block_until_ready(L)).reshape(g, -1)
+                self._drain([L], reduce=False)
+                self._phases.switch("epoch.tail")
+                L = np.asarray(L).reshape(g, -1)
                 dt = (time.time() - t0) / g
+                self._phases.switch("epoch.publish")
                 self._observe_fit_metrics(g * n_steps, dt * g,
                                           g * n_steps * batch_size)
                 loop_state.iteration += g * n_steps
@@ -1836,8 +1972,10 @@ class TrainingLoop:
         epoch = model.finished_epochs  # so nb_epoch=0 is a clean no-op
         for epoch in range(model.finished_epochs + 1, target_epoch + 1):
             # epoch-boundary overhead (metrics, callbacks, validation of
-            # the previous epoch) since the last step lands on idle
+            # the previous epoch) since the last step lands on idle: the
+            # previous epoch's tail drained the device
             self._gp_note("idle")
+            self._phases.switch()       # fit.enter or epoch.publish ends
             t0 = time.time()
             losses = []
             n_seen = 0
@@ -1858,7 +1996,8 @@ class TrainingLoop:
                     n_steps * batch_size)
                 self._segment_begin(mgr, loop_state, params, opt_state,
                                     net_state)
-                params, opt_state, net_state, l = epoch_fn(
+                params, opt_state, net_state, l = self._dispatch(
+                    epoch_fn, prev_iter, n_steps,
                     params, opt_state, net_state, base_rng, it0, shuffle_rng,
                     xs_dev, ys_dev)
                 self._segment_end()
@@ -1881,12 +2020,13 @@ class TrainingLoop:
                 stream = prefetch_to_device(
                     _chunked(batches, scan_steps), self.mesh,
                     sharding=mesh_lib.stacked_batch_sharding(self.mesh),
-                    ledger=self._goodput)
+                    ledger=self._goodput, phases=self._phases)
             else:
                 batches = fs.iter_batches(batch_size, epoch=ctx.seed + epoch,
                                           drop_last=True)
                 stream = prefetch_to_device(batches, self.mesh,
-                                            ledger=self._goodput)
+                                            ledger=self._goodput,
+                                            phases=self._phases)
             for bx_d, by_d in stream:
                 prev_iter = loop_state.iteration
                 k = jax.tree.leaves(bx_d)[0].shape[0] if scan_steps > 1 \
@@ -1922,7 +2062,8 @@ class TrainingLoop:
                              bx_d, by_d), k * batch_size)
                         self._segment_begin(mgr, loop_state, params,
                                             opt_state, net_state)
-                        params, opt_state, net_state, l = self._scan_step(
+                        params, opt_state, net_state, l = self._dispatch(
+                            self._scan_step, prev_iter, k,
                             params, opt_state, net_state, base_rng, it0,
                             bx_d, by_d)
                         self._segment_end()
@@ -1939,7 +2080,8 @@ class TrainingLoop:
                         self._segment_begin(mgr, loop_state, params,
                                             opt_state, net_state)
                         (params, opt_state, net_state, sstate, l,
-                         flags) = self._scan_step(
+                         flags) = self._dispatch(
+                             self._scan_step, prev_iter, k,
                              params, opt_state, net_state, sstate,
                              base_rng, it0, bx_d, by_d, fault)
                         self._segment_end()
@@ -1955,7 +2097,8 @@ class TrainingLoop:
                              by_d), batch_size)
                         self._segment_begin(mgr, loop_state, params,
                                             opt_state, net_state)
-                        params, opt_state, net_state, l = self._train_step(
+                        params, opt_state, net_state, l = self._dispatch(
+                            self._train_step, prev_iter, 1,
                             params, opt_state, net_state, step_rng, bx_d,
                             by_d)
                         self._segment_end()
@@ -1969,7 +2112,8 @@ class TrainingLoop:
                         self._segment_begin(mgr, loop_state, params,
                                             opt_state, net_state)
                         (params, opt_state, net_state, sstate, l,
-                         flags) = self._train_step(
+                         flags) = self._dispatch(
+                             self._train_step, prev_iter, 1,
                              params, opt_state, net_state, sstate,
                              step_rng, fault, bx_d, by_d)
                         self._segment_end()
@@ -1994,6 +2138,13 @@ class TrainingLoop:
                     stop = True
                     break
             completed = not stop  # stop=True means the epoch was cut short
+            if stop and stream:
+                # a mid-epoch stop leaves the pipeline suspended at its
+                # yield: end it here, and with it its last ledger interval
+                # (left to the collector, it would close after the tail)
+                stream.close()
+            mean_loss = self._drain(losses, reduce=monitor is None)
+            self._phases.switch("epoch.tail")
             if monitor is not None:
                 # drain every pending flag first (escalation may raise
                 # here, BEFORE the boundary checkpoint below); in recover
@@ -2006,10 +2157,9 @@ class TrainingLoop:
                 epoch_loss = (float(lv[lmask].mean()) if lmask.any()
                               else float("nan"))
             else:
-                epoch_loss = (float(jnp.mean(jnp.concatenate(
-                    [jnp.atleast_1d(l) for l in losses])))
-                    if losses else float("nan"))
+                epoch_loss = (float(mean_loss) if losses else float("nan"))
             dt = time.time() - t0
+            self._phases.switch("epoch.publish")
             self._observe_fit_metrics(n_seen // batch_size, dt, n_seen)
             history["loss"].append(epoch_loss)
             loop_state.epoch_finished = completed
@@ -2367,6 +2517,8 @@ KerasNet._checkpoint = None
 KerasNet._train_summary = None
 KerasNet._val_summary = None
 KerasNet._lr = None
+#: what the last ``fit`` call cost and where (docs/guides/TRAINING.md)
+KerasNet.last_fit_report = None
 
 KerasNet.compile = _compile
 KerasNet.init_weights = _init_weights

@@ -1,0 +1,18 @@
+"""Seconds of XLA backend compilation (0 where the persistent cache held every
+program), as JAX's own monitoring events report them:
+``zoo_xla_compile_seconds_total`` summed over the instrumented ``fn`` labels
+only (a reader's own ``.trace().lower()`` books under ``uninstrumented``). The
+window compiles nothing, so the seconds are set-up's."""
+
+PHASES = ("backend",)
+
+
+def read(view):
+    try:
+        from analytics_zoo_tpu.observability.compile import (
+            UNINSTRUMENTED, xla_compile_totals)
+    except ImportError:         # a program without the compile listeners
+        return None
+    return sum(seconds.get(phase, 0.0)
+               for fn, seconds in xla_compile_totals().items()
+               if fn != UNINSTRUMENTED for phase in PHASES)

@@ -229,9 +229,18 @@ def test_chaos_fit_goodput_reconciles_to_wall_time(tmp_path):
     assert led.ratio() == pytest.approx(
         sec["device_step"] / led.wall(), rel=1e-12)
     # every failure mode the plan forced left its wall-time fingerprint
+    # (data_wait: these steps are microseconds long, so the device has run
+    # dry by the end of most of the host's turns through the input
+    # pipeline; the ledger asks the loop's in-flight probe at each)
     for cat in ("device_step", "data_wait", "ckpt_stall",
                 "rollback_replay", "restart", "anomaly_skip", "idle"):
         assert sec[cat] > 0.0, f"category {cat} never charged"
+    # the per-fit report hands out the same ledger, through the rollback
+    # and the restart: one fit, one wall clock
+    report = m.last_fit_report
+    assert report["ledger"] == sec
+    assert report["wall_s"] == pytest.approx(led.wall(), rel=1e-9)
+    assert sum(report["host_s"].values()) <= report["wall_s"]
     # the manifest latency DID fire — but on the background writer
     # thread, so the ledger charges only the synchronous join window:
     # async-hidden save time is by design not badput

@@ -142,29 +142,66 @@ def kernel_vmem_bytes(operands: Iterable[_ShapeBytes] = (),
     return total
 
 
+#: the flash forward walks this many of the backward's k tiles as one: its
+#: per-tile work beside the products (two lane reductions a row and the
+#: rescale of the carried accumulator) grows with block_q alone, so a wider
+#: k tile amortises it; the backward kernels carry nothing from tile to tile
+ATTENTION_FWD_K_TILES = 2
+
+
 def attention_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int,
-                         has_mask: bool = False) -> int:
-    """Estimated per-grid-cell VMEM of the flash-attention forward kernel
-    (the backward's tiles are the same sizes): q/k/v operand windows +
-    the acc/m/l scratch carries + o/lse outputs + the f32 score and
-    probability compute tiles. ``block_k`` prices at the lane floor even
-    as a sublane-position window dim because the (block_q, block_k)
-    score tile needs it lane-aligned anyway."""
+                         has_mask: bool = False,
+                         major: Optional[int] = None,
+                         kernel: Optional[str] = None) -> int:
+    """Estimated per-grid-cell VMEM of one flash-attention kernel
+    (``kernel`` = ``"fwd"``, ``"dq"`` or ``"dkv"``) at the compute tile
+    ``(block_q, block_k)``. Left out: the largest of the three at the
+    tiles the pair ``(block_q, block_k)`` gives them (the forward's k tile
+    is ``ATTENTION_FWD_K_TILES`` of them): what a pair has to fit.
+
+    A kernel holds one block of its own side, the streamed side as a
+    resident *major window* of ``major`` rows (K/V for fwd and dq, q/dO
+    for dkv; a whole number of tiles, the tile itself when left out), the
+    row statistics one float wide ((1, rows) along lanes, a sublane tile
+    each in VMEM), its f32 accumulators, and the f32 score-sized tiles its
+    loop body keeps live (s and p forward; p, dP and dS backward).
+    ``block_k`` prices at the lane floor even as a sublane-position window
+    dim because the score tile needs it lane-aligned anyway."""
+    if kernel is None:
+        return max(
+            attention_vmem_bytes(
+                block_q, block_k * (ATTENTION_FWD_K_TILES if kern == "fwd"
+                                    else 1),
+                d, itemsize, has_mask, major, kern)
+            for kern in ("fwd", "dq", "dkv"))
     d_eff = round_up(max(d, 1), LANES)
     bq = round_up(max(block_q, 1), SUBLANES)
     bk = round_up(max(block_k, 1), LANES)
-    ops = [((bq, d_eff), itemsize),             # q window
-           ((bk, d_eff), itemsize),             # k window
-           ((bk, d_eff), itemsize)]             # v window
+    q_blk, k_blk = ((bq, d_eff), itemsize), ((bk, d_eff), itemsize)
+    stat_blk, stat_col = ((1, bq), 4), ((bq, LANES), 4)
+    if kernel == "dkv":
+        mq = round_up(max(major or bq, bq), bq)
+        return kernel_vmem_bytes(
+            operands=[k_blk, k_blk, ((mq, d_eff), itemsize),
+                      ((mq, d_eff), itemsize),               # q, dO windows
+                      ((mq // bq, 1, bq), 4), ((mq // bq, 1, bq), 4)],
+            outputs=[k_blk, k_blk],
+            scratch=[((bk, d_eff), 4)] * 2,                  # dk, dv
+            compute=[((bk, bq), 4)] * 3)
+    mk = round_up(max(major or bk, bk), bk)
+    kv = [((mk, d_eff), itemsize)] * 2                       # K, V windows
     if has_mask:
-        ops.append(((SUBLANES, bk), 4))         # key-padding mask slice
-    outs = [((bq, d_eff), itemsize),            # o
-            ((bq, LANES), 4)]                   # lse
-    scr = [((bq, d_eff), 4),                    # acc
-           ((bq, LANES), 4), ((bq, LANES), 4)]  # running max / denom
-    comp = [((bq, bk), 4), ((bq, bk), 4)]       # s and p tiles, f32
-    return kernel_vmem_bytes(operands=ops, outputs=outs, scratch=scr,
-                             compute=comp)
+        kv.append(((mk // bk, 1, bk), 4))                    # a row a tile
+    carry = [((bq, d_eff), 4), stat_col, stat_col]
+    if kernel == "fwd":
+        return kernel_vmem_bytes(
+            operands=[q_blk] + kv, outputs=[q_blk, stat_blk],  # o, lse
+            scratch=carry,                                   # acc, m, l
+            compute=[((bq, bk), 4)] * 2)
+    return kernel_vmem_bytes(                                # dq
+        operands=[q_blk, q_blk, stat_blk, stat_blk] + kv, outputs=[q_blk],
+        scratch=carry,                                       # acc, lse, delta
+        compute=[((bq, bk), 4)] * 3)
 
 
 def ce_vmem_bytes(block_n: int, block_v: int, hidden: int, itemsize: int,

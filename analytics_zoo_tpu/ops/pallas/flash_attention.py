@@ -3,37 +3,73 @@
 whole-matrix softmax inside ``TransformerLayer.scala:56``/``BERT.scala:66``,
 materializing the (T, T) score matrix in HBM).
 
-Forward design: grid (batch*head, q-blocks, k-blocks) with the k dimension
-innermost — TPU pallas runs the grid sequentially, so the online-softmax
-carry (acc/m/l) lives in VMEM scratch across the k steps of one q block:
-initialized at ``ki == 0``, folded per k block, written out (with the row
-log-sum-exp for the backward) at the last k block. VMEM per cell is
-O(block_q·D + block_k·D) — K/V stream block-by-block, never the whole
-sequence — and both matmuls (QK^T, PV) hit the MXU at tile-aligned sizes in
-the input dtype (bfloat16 operands run the MXU at full rate; accumulation is
-always float32). Causal cells predicate away k blocks strictly right of the
-diagonal. An optional per-batch key-padding mask (B, Tk) streams in
-(1, block_k) slices — this is the BERT ``attention_mask`` path.
+Schedule (all three kernels): two-level tiling with the outer level
+resident. A grid cell is one block of its own side (a q block for fwd and
+dq, a k block for dkv) against a *major window* of the streamed side (K/V,
+or q/dO): the whole sequence where the VMEM estimate lets it (T = 4096,
+D = 64: K and V of a head are 512 KB each), else the fewest equal runs of
+tiles that fit. The cell walks its window with an in-kernel loop over
+``(block_q, block_k)`` compute tiles whose bounds come from ``program_id``:
+up to the causal diagonal only (fwd, dq), from it on (dkv). So no grid step
+is skipped inside a window, nothing is brought in for a tile that is not
+computed, and K/V are read from VMEM, once per head, instead of streamed
+from HBM once per q block. Where the window is not the whole sequence a
+causal step wholly on the far side of the diagonal runs two empty loops and
+repeats its neighbour's block index, which costs no DMA.
+
+The loop is split at the tiles that need a mask: tiles crossed by the
+causal diagonal, the tile the kv padding ends in, and every tile of a call
+with a key-padding mask build the keep-mask (two iotas, compares, a select)
+and guard the running max; interior tiles run ``exp(s - m)`` bare. Both
+matmuls hit the MXU in the input dtype (bfloat16 operands run it at full
+rate; accumulation is always float32), the online-softmax carry (acc/m/l)
+lives in VMEM scratch across tiles and windows, and ``1/sqrt(D)`` is folded
+into the resident block once where it is a power of two (D = 64: 0.125,
+exact in bf16, bit-identical to scaling the scores).
+
+Row statistics (the forward's log-sum-exp, the backward's ``delta``) are
+one float a row, ``f32[B*H, 1, T]`` with q along lanes: what the step keeps
+per layer for the backward is T floats a head. The forward reduces along
+lanes, so it stands its column of statistics down into a row once per q
+block (select-and-sum against an identity pattern: exact, no transpose);
+dq stands it back up once per q block; dkv forms its tile transposed,
+``s^T = k q^T`` of shape (block_k, block_q), so the (1, block_q) statistics
+broadcast over sublanes as stored and ``dV += p^T dO``, ``dK += ds^T q``
+are plain products.
 
 Causal masking is BOTTOM-RIGHT aligned like the XLA oracle
 (``ops/attention.py:41``): query i attends keys ``j <= i + (t_kv - t_q)``.
 Rows with no visible key (t_q > t_kv tails, or fully-masked rows) return
 zeros — the one spot the oracle differs (its -1e9 fill degrades to uniform
-weights there).
+weights there). An optional per-batch key-padding mask (B, Tk) rides in the
+major window as one (1, block_k) row per tile — the BERT ``attention_mask``
+path. In dkv a hidden or padded key only ever touches its own rows of
+dk/dv, so that kernel masks nothing but the diagonal and the wrapper drops
+those rows.
 
 Backward: the standard two-kernel recompute scheme (no (T, T) tensor is ever
 materialized, unlike the r3 XLA-recompute fallback this replaces):
-``delta = rowsum(dO·O)`` in XLA, then a dq kernel (grid bh, qi, ki — k
-innermost, dq accumulates in VMEM) and a dk/dv kernel (grid bh, ki, qi — q
-innermost, dk/dv accumulate in VMEM), each re-forming one (block_q, block_k)
-probability tile at a time from the saved log-sum-exp. Memory stays
+``delta = rowsum(dO·O)`` in XLA, then the dq kernel (grid bh, q block, K/V
+window) and the dk/dv kernel (grid bh, k block, q window), each re-forming
+one probability tile at a time from the saved log-sum-exp. Memory stays
 O(block²) end to end, which is what makes long-context *training* fit.
+
+Chip readings (TPU v5e, PR 27, ``scripts/flash-sweep``: device time of one
+call from the profiler, causal bf16 D = 64, B*H x T = 393216 rows): at
+T = 4096 fwd 9.86 -> 4.02 ms, dq 8.74 -> 4.43 ms, dkv 11.87 -> 5.57 ms
+against the streamed (256, 512) schedule this replaced, 15.5 -> 33.6 % of
+the three kernels' roofline (``benchmark/lib/kernel_cost.py``); T = 2048
+17.27 -> 8.43 ms a call triple (13.6 -> 27.9 %); T = 8192 56.9 -> 25.0 ms
+(16.5 -> 37.7 %). D = 64 half-fills the MXU's depth (QK^T, dO V^T) or width
+(PV, dV, dK, dQ) in every product, so about 50 % is this head size's
+ceiling.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,8 +78,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .common import LANES as _LANES
 from .common import SUBLANES as _SUBLANES
+from .common import ATTENTION_FWD_K_TILES as _FWD_K_TILES
 from .common import (attention_vmem_bytes, pad_to_multiple, sweep_fastest,
-                     vmem_usable_bytes)
+                     vmem_budget_bytes, vmem_usable_bytes)
 from .common import round_up as _round_up
 
 __all__ = ["flash_attention", "select_attention_blocks"]
@@ -56,13 +93,20 @@ __all__ = ["flash_attention", "select_attention_blocks"]
 # same function, property-tested in tests/test_pallas.py)
 # ---------------------------------------------------------------------------
 
-#: preferred default, swept on a v5e (causal, D=64, T=32k, fwd+bwd):
-#: (256, 512) hit 29.3 TF/s vs 21.2 for (256, 256), 23.1 for (512, 512),
-#: 24.4-24.9 for k-blocks of 1024/2048 — the larger k block amortizes the
-#: per-k-step carry fold without outgrowing VMEM
-_PREFERRED_BLOCKS = (256, 512)
+#: preferred default ``(block_q, block_k)`` of dq and dkv; the forward takes
+#: ``_FWD_K_TILES`` k tiles as one. Swept on a v5e for this schedule (PR 27,
+#: ``scripts/flash-sweep``, causal bf16 D=64, ms per fwd+dq+dkv call triple
+#: at T = 2048 / 4096 / 8192, 393216 rows): (512, 512) 8.43 / 14.01 / 24.98;
+#: (1024, 512) 8.96 / 14.17 / 25.25; (512, 1024) 10.02 / 15.21 / 26.07;
+#: (256, 512) 10.21 / 17.04 / 30.72; (512, 256) 10.38 / 17.64 / 32.15;
+#: (256, 256) 12.98 / 22.86 / 42.65. The same pair with the forward NOT
+#: widened: 9.36 / 16.03 / 29.17 (its forward 3.56 / 6.04 / 10.99 ms against
+#: 2.62 / 4.02 / 6.81): the forward's row reductions and carry rescale grow
+#: with block_q alone. A (1024, 1024) forward is 1.5-3 % better still and
+#: does not fit the budget at T = 8192
+_PREFERRED_BLOCKS = (512, 512)
 
-#: abstract signature -> (block_q, block_k), resolved once per process
+#: abstract signature -> _Schedule, resolved once per process
 _BLOCK_CACHE: dict = {}
 
 #: back-compat aliases — the estimator and budget constants moved to
@@ -73,14 +117,34 @@ from .common import VMEM_BYTES_DEFAULT as _VMEM_BYTES_DEFAULT  # noqa: E402
 from .common import VMEM_USABLE_FRACTION as _VMEM_USABLE_FRACTION  # noqa: E402
 
 
+class _Tiling(NamedTuple):
+    """How one kernel cuts the (q, k) plane: the ``(block_q, block_k)``
+    compute tile, and ``major``, the rows of the streamed side each grid
+    cell holds resident and walks tile by tile (K/V rows for fwd and dq,
+    whose cell is a q block; q/dO rows for dkv, whose cell is a k block).
+    A major window is a whole number of tiles; the kernel pads its
+    streamed side to a whole number of major windows."""
+    block_q: int
+    block_k: int
+    major: int
+
+
+class _Schedule(NamedTuple):
+    fwd: _Tiling
+    dq: _Tiling
+    dkv: _Tiling
+
+
 def select_attention_blocks(t_q: int, t_kv: int, d: int, dtype,
                             causal: bool = False, has_mask: bool = False,
                             budget_bytes: Optional[int] = None):
     """VMEM-budget-aware (block_q, block_k): start from the swept
-    ``(256, 512)`` sweet spot, clamp to the sequence lengths, then shrink
-    the larger block until the kernel's estimated footprint fits the
-    budget. Deterministic — a pure function of the abstract signature, so
-    the jit cache is stable."""
+    ``_PREFERRED_BLOCKS``, clamp to the sequence lengths, then shrink the
+    larger block until the largest of the three kernels' estimated
+    footprints at that pair (tile, accumulators, a one-tile window) fits
+    the budget. Deterministic — a pure function of the abstract signature,
+    so the jit cache is stable. ``_resolve_schedule`` turns the pair into
+    the kernels' tilings and major windows."""
     budget = budget_bytes if budget_bytes is not None else \
         vmem_usable_bytes()
     itemsize = jnp.dtype(dtype).itemsize
@@ -101,12 +165,16 @@ def select_attention_blocks(t_q: int, t_kv: int, d: int, dtype,
     return bq, bk
 
 
+#: what ``zoo.pallas.block_sweep`` times beside the heuristic's choice
+_SWEEP_PAIRS = (_PREFERRED_BLOCKS, (1024, 512), (512, 1024), (256, 512),
+                (512, 256), (256, 256))
+
+
 def _sweep_candidates(t_q: int, t_kv: int, d: int, itemsize: int,
                       has_mask: bool, heuristic):
     budget = vmem_usable_bytes()
     out = []
-    for bq, bk in (heuristic, (256, 512), (128, 512), (256, 256),
-                   (512, 512), (128, 1024)):
+    for bq, bk in (heuristic,) + _SWEEP_PAIRS:
         # clamp to the sequence lengths WITH the tile rounding the kernel
         # needs (a raw min() against an unaligned T yields untileable
         # pairs like (128, 1000) that can only fail to compile)
@@ -139,10 +207,12 @@ def _time_blocks(b, h, t_q, t_kv, d, dtype, causal, has_mask, block_q,
         rng.normal(size=(b, h, t_kv, d)).astype(np.float32), dtype))
     m = (jax.device_put(jnp.ones((b, t_kv), jnp.float32))
          if has_mask else None)
+    sched = _resolve_schedule(t_q, t_kv, d, dtype, has_mask, block_q,
+                              block_k)
 
     def fwd_bwd(q, k, v):
         return jax.grad(lambda q: jnp.sum(
-            _flash(q, k, v, m, causal, block_q, block_k, False)
+            _flash(q, k, v, m, causal, sched, False)
             .astype(jnp.float32)))(q)
 
     fn = jax.jit(fwd_bwd)
@@ -167,32 +237,51 @@ def _sweep_blocks(b, h, t_q, t_kv, d, dtype, causal, has_mask, heuristic,
                                    has_mask, heuristic), timer)
 
 
-def _record_block_choice(sig: str, choice) -> None:
+def _choice_label(sched) -> str:
+    """``fwd=512x1024/kmajor4096,...``: each kernel's tile and the rows of
+    the streamed side its grid cell holds resident (q rows for dkv)."""
+    return ",".join(
+        f"{name}={t.block_q}x{t.block_k}/"
+        f"{'q' if name == 'dkv' else 'k'}major{t.major}"
+        for name, t in zip(sched._fields, sched))
+
+
+def _record_block_choice(sig: str, sched, census: dict) -> None:
     try:
         from ...observability import default_registry
+        reg = default_registry()
         # sig/choice are bounded by the distinct abstract kernel
         # signatures a process compiles (each also a jit cache entry)
-        default_registry().gauge(  # zoolint: disable=ZL015 bounded label set
+        reg.gauge(  # zoolint: disable=ZL015 bounded label set
             "zoo_pallas_block_choice",
-            "selected pallas kernel block sizes per abstract signature "
-            "(1 = active choice)",
+            "selected pallas kernel block sizes and resident major "
+            "windows per abstract signature (1 = active choice)",
             labels={"kernel": "flash_attention", "sig": sig,
-                    "choice": f"{choice[0]}x{choice[1]}"}).set(1)
+                    "choice": _choice_label(sched)}).set(1)
+        for kind, n in census.items():
+            reg.gauge(  # zoolint: disable=ZL015 bounded label set
+                "zoo_pallas_flash_tiles",
+                "static tile census of one (batch, head) of a flash "
+                "forward call at this signature: compute tiles that run "
+                "mask-free (interior), that build the mask (masked), and "
+                "grid steps with nothing to compute (skipped_steps)",
+                labels={"sig": sig, "kind": kind}).set(n)
     # metrics must never break the compute path
     except Exception:  # zoolint: disable=ZL007
         pass
 
 
 def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
-                 interpret: bool):
-    """Cached per-signature block choice: the VMEM heuristic, optionally
+                 interpret: bool) -> _Schedule:
+    """Cached per-signature schedule: the VMEM heuristic's tile, optionally
     refined by the one-shot on-device sweep (compiled TPU runs only — the
-    interpreter's timings say nothing about the MXU). The heuristic is a
-    pure function of (T, D, dtype, causal, mask), so its cache key drops
-    batch/heads — a ragged final batch or an evaluate at a different B
-    must not re-resolve (or worse, re-SWEEP: compiling and timing six
-    candidates with live training state resident). Only sweep-timed
-    entries key on the full shape, since wall time does scale with B·H."""
+    interpreter's timings say nothing about the MXU), and the major
+    windows that tile gets. The heuristic is a pure function of
+    (T, D, dtype, causal, mask), so its cache key drops batch/heads — a
+    ragged final batch or an evaluate at a different B must not re-resolve
+    (or worse, re-SWEEP: compiling and timing the candidates with live
+    training state resident). Only sweep-timed entries key on the full
+    shape, since wall time does scale with B·H."""
     b, h, t_q, d = q_shape
     dt = jnp.dtype(dtype)
     from ...common.context import get_zoo_context
@@ -213,44 +302,217 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     if sweep:
         choice = _sweep_blocks(b, h, t_q, t_kv, d, dt, causal, has_mask,
                                choice)
-    _BLOCK_CACHE[sig] = choice
+    sched = _resolve_schedule(t_q, t_kv, d, dt, has_mask, *choice)
+    _BLOCK_CACHE[sig] = sched
     # the metric label mirrors the cache key: heuristic entries apply to
     # EVERY batch/head shape at this (T, D, dtype) signature, so baking
     # the first caller's b/h into the label would misdescribe the scope
     _record_block_choice(
         (f"b{b}h{h}" if sweep else "")
         + f"tq{t_q}tk{t_kv}d{d}{dt.name}"
-        f"{'c' if causal else ''}{'m' if has_mask else ''}", choice)
-    return choice
+        f"{'c' if causal else ''}{'m' if has_mask else ''}", sched,
+        _tile_census(t_q, t_kv, sched.fwd, causal, has_mask))
+    return sched
 
 
-def _visibility(qi, ki, s_shape, *, t_q, t_kv, offset, causal, mask_blk):
-    """The (block_q, block_k) keep-mask of one probability tile: kv padding,
-    causal alignment, and the optional key-padding mask row."""
-    block_q, block_k = s_shape
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+def _resolve_schedule(t_q: int, t_kv: int, d: int, dtype, has_mask: bool,
+                      block_q: int, block_k: int,
+                      budget_bytes: Optional[int] = None) -> _Schedule:
+    """The three kernels' tilings from one ``(block_q, block_k)``: dq and
+    dkv take it, the forward takes ``_FWD_K_TILES`` k tiles as one. Each is
+    clamped to the sequence (back ON the tile floors: a raw min() against
+    an unaligned T hands Mosaic an untileable block; the padding absorbs
+    the round-up and the kernels mask past t_q/t_kv) and then given the
+    largest major window whose estimate the whole per-core budget holds
+    (``select_attention_blocks`` fitted the tile into the usable half of
+    it): the whole sequence where that fits, else the sequence cut into
+    the fewest equal runs of tiles that do. Pure in its arguments and the
+    context's budget."""
+    budget = budget_bytes if budget_bytes is not None else \
+        vmem_budget_bytes()
+    itemsize = jnp.dtype(dtype).itemsize
+    bq = _round_up(min(block_q, max(t_q, 1)), _SUBLANES)
+    bk = _round_up(min(block_k, max(t_kv, 1)), _LANES)
+
+    def tiling(kernel: str) -> _Tiling:
+        wide = _FWD_K_TILES * bk if kernel == "fwd" else bk
+        wide = min(wide, _round_up(max(t_kv, 1), _LANES))
+        t, blk = (t_q, bq) if kernel == "dkv" else (t_kv, wide)
+        n_tiles = -(-max(t, 1) // blk)
+        tiles = 1
+        for parts in range(1, n_tiles + 1):
+            tiles = -(-n_tiles // parts)
+            if _kernel_vmem_bytes(bq, wide, d, itemsize, has_mask,
+                                  major=tiles * blk,
+                                  kernel=kernel) <= budget:
+                break
+        return _Tiling(bq, wide, tiles * blk)
+
+    return _Schedule(*(tiling(kernel) for kernel in _Schedule._fields))
+
+
+def _k_tile_range(qi, *, block_q, block_k, t_q, t_kv, causal, has_mask,
+                  mn=min, mx=max):
+    """``(n_full, hi)`` for q block ``qi`` (fwd and dq): k tiles
+    ``[0, n_full)`` hold no masked element, ``[n_full, hi)`` do (the
+    causal diagonal crosses them, the kv padding ends in them, or the call
+    has a key-padding mask), and tiles from ``hi`` on hold no visible key.
+    Plain arithmetic, so the kernels run it on ``program_id`` (``mn``/``mx``
+    = ``jnp.minimum``/``maximum``) and the census on Python ints."""
+    hi = -(-t_kv // block_k)
+    n_clean = 0 if has_mask else t_kv // block_k
+    if not causal:
+        return n_clean, hi
+    first_row_sees = qi * block_q + (t_kv - t_q)    # bottom-right aligned
+    last_row_sees = first_row_sees + block_q - 1
+    hi = mn(hi, mx(last_row_sees + block_k, 0) // block_k)
+    full = mn(n_clean, mx(first_row_sees + 1, 0) // block_k)
+    return mn(full, hi), hi
+
+
+def _q_tile_range(ki, *, block_q, block_k, t_q, t_kv, causal,
+                  mn=min, mx=max):
+    """``(lo, full, hi)`` for k block ``ki`` (dkv): q tiles ``[lo, full)``
+    are crossed by the causal diagonal, ``[full, hi)`` see every key of the
+    block, tiles under ``lo`` see none of them. Padded keys and keys the
+    key-padding mask hides only touch their own rows of dk/dv, which the
+    wrapper drops, so neither makes a tile a masked one here."""
+    hi = -(-t_q // block_q)
+    if not causal:
+        return 0, 0, hi
+    first_row = ki * block_k - (t_kv - t_q)     # first q row seeing a key
+    all_row = first_row + block_k - 1           # first q row seeing all
+    lo = mn(hi, mx(first_row, 0) // block_q)
+    full = mn(hi, mx(all_row + block_q - 1, 0) // block_q)
+    return lo, mx(full, lo), hi
+
+
+def _tile_census(t_q: int, t_kv: int, tiling: _Tiling, causal: bool,
+                 has_mask: bool) -> dict:
+    """Static census of one (batch, head) of a forward call: compute tiles
+    that run mask-free, tiles that build the mask, and grid steps whose
+    loops are empty (a causal major window wholly right of the diagonal).
+    dq walks the same plane at its own tile; dkv the transposed one."""
+    bq, bk, major = tiling
+    tiles = major // bk
+    n_major = -(-max(t_kv, 1) // major)
+    out = {"interior": 0, "masked": 0, "skipped_steps": 0}
+    for qi in range(-(-max(t_q, 1) // bq)):
+        n_full, hi = _k_tile_range(qi, block_q=bq, block_k=bk, t_q=t_q,
+                                   t_kv=t_kv, causal=causal,
+                                   has_mask=has_mask)
+        out["interior"] += n_full
+        out["masked"] += hi - n_full
+        out["skipped_steps"] += sum(hi <= kj * tiles
+                                    for kj in range(n_major))
+    return out
+
+
+def _tile_loop(lo, hi, body) -> None:
+    """``for j in [lo, hi): body(j)``, rolled; the state lives in refs and
+    the bounds come from ``program_id`` arithmetic."""
+    def step(j, carry):
+        body(j)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, step, 0)
+
+
+def _walk_k_tiles(tile, qi, base, tiles: int, *, block_k, t_kv, causal,
+                  has_mask, **geom) -> None:
+    """fwd and dq: ``tile(j, masked)`` over the k tiles of the major window
+    that starts at global tile ``base``, the mask-free run first. A loop
+    that can never run at this signature is not emitted."""
+    n_full, hi = (jnp.clip(n - base, 0, tiles) for n in _k_tile_range(
+        qi, block_k=block_k, t_kv=t_kv, causal=causal, has_mask=has_mask,
+        mn=jnp.minimum, mx=jnp.maximum, **geom))
+    if not has_mask:
+        _tile_loop(0, n_full, lambda j: tile(j, False))
+    if causal or has_mask or t_kv % block_k:
+        _tile_loop(n_full, hi, lambda j: tile(j, True))
+
+
+def _rows(ref, j, block: int):
+    """Tile ``j`` (``block`` rows) of the major window ``ref`` holds."""
+    return ref[0, pl.ds(pl.multiple_of(j * block, block), block), :]
+
+
+def _visibility(q_tile, k_tile, shape, *, block_q, block_k, t_kv, offset,
+                causal, mask_row=None):
+    """Keep-mask of one (q, k) score tile: kv padding, causal alignment,
+    and the optional key-padding mask row."""
+    k_pos = k_tile * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     ok = k_pos < t_kv
     if causal:
+        q_pos = q_tile * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0)
         ok = ok & (k_pos <= q_pos + offset)
-    if mask_blk is not None:
+    if mask_row is not None:
         # keep-masks are a binary contract (1.0 = attend); >= 1.0 matches
         # the XLA oracle's additive -1e9*(1-mask) on stray soft values too
         # (anything < 1 is effectively hidden there)
-        ok = ok & (mask_blk[None, :] >= 1.0)
+        ok = ok & (mask_row >= 1.0)
     return ok
 
 
-def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int, t_q: int,
-                t_kv: int, causal: bool, has_mask: bool, want_lse: bool):
-    """Grid cell (bh, qi, ki). q (1, block_q, D); k/v (1, block_k, D);
-    [mask (1, SUBLANES, block_k)]; o (1, block_q, D);
-    lse (1, block_q, LANES); scratch acc (block_q, D), m/l (block_q, LANES).
-    Row/key vectors carry 8-sublane/128-lane broadcast dims — TPU blocks
-    need tileable trailing dims (the same layout jax's reference TPU flash
-    kernel uses for segment ids and l/m)."""
+def _stat_chunks(n: int):
+    """Static (start, size) runs the row statistics are re-oriented in:
+    128 rows at a time keeps the identity pattern at 16 vregs."""
+    step = _LANES if n % _LANES == 0 else n
+    return [(lo, step) for lo in range(0, n, step)]
+
+
+def _eye(n: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _store_as_row(row_ref, col) -> None:
+    """Write the (n, 1) column ``col`` into the (1, n) ``row_ref``. The
+    forward reduces along lanes, so its row statistics come out one per
+    sublane; HBM keeps them one float wide, along lanes. Select-and-sum
+    against an identity pattern is exact (one term a column, +inf
+    included) and needs no transpose."""
+    for lo, step in _stat_chunks(col.shape[0]):
+        # a single unaligned run is the whole ref: Mosaic refuses a
+        # 40-wide slice of a window it padded to 128 lanes
+        where = (slice(None), slice(lo, lo + step)) \
+            if step != col.shape[0] else Ellipsis
+        row_ref[where] = jnp.sum(
+            jnp.where(_eye(step), col[lo:lo + step], 0.0), axis=0,
+            keepdims=True)
+
+
+def _store_as_col(col_ref, row_ref) -> None:
+    """The inverse, for the dq kernel, whose (q, k) tile wants its row
+    statistics one per sublane: the (1, n) ``row_ref`` into lane 0 of the
+    (n, LANES) scratch ``col_ref``."""
+    n = row_ref.shape[-1]
+    for lo, step in _stat_chunks(n):
+        row = row_ref[:, lo:lo + step] if step != n else row_ref[...]
+        col_ref[lo:lo + step, :1] = jnp.sum(
+            jnp.where(_eye(step), row, 0.0), axis=1, keepdims=True)
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _dot(a, b, dims):
+    """Operands stay in the input dtype (bf16 operands = full MXU rate);
+    the product accumulates f32."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
+                n_major: int, t_q: int, t_kv: int, causal: bool,
+                has_mask: bool, want_lse: bool):
+    """Grid cell (bh, qi, kj): one q block against major window ``kj`` of
+    K/V, walked tile by tile up to the causal diagonal. q (1, block_q, D);
+    k/v (1, major_k, D); [mask (1, tiles, 1, block_k)]; o (1, block_q, D);
+    lse (1, 1, 1, block_q); scratch acc (block_q, D), m/l (block_q, LANES)
+    carry the online softmax across tiles and major windows."""
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
@@ -258,49 +520,55 @@ def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int, t_q: int,
     o_ref = rest[0]
     lse_ref = rest[1] if want_lse else None
     acc_ref, m_ref, l_ref = rest[-3:]
+    block_q, block_k, major = tiling
+    tiles = major // block_k
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    kj = pl.program_id(2)
+    base = kj * tiles
     offset = t_kv - t_q  # bottom-right causal alignment
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: the first row of this q block sees keys up to
-    # qi*block_q + offset; the last row up to (qi+1)*block_q - 1 + offset.
-    # Blocks fully beyond the latter contribute nothing — skip their math.
-    needed = True
-    if causal:
-        needed = ki * block_k <= (qi + 1) * block_q - 1 + offset
+    q = q_ref[0]
+    if fold_scale:      # a power of two: exact in any float dtype
+        q = q * scale
 
-    @pl.when(needed)
-    def _step():
-        # operands stay in the input dtype (bf16 operands = full MXU rate);
-        # the product accumulates f32 via preferred_element_type
-        q = q_ref[0]
-        s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        ok = _visibility(qi, ki, (block_q, block_k), t_q=t_q, t_kv=t_kv,
-                         offset=offset, causal=causal,
-                         mask_blk=mask_ref[0, 0] if has_mask else None)
-        s = jnp.where(ok, s, -jnp.inf)
-
+    def tile(j, masked: bool):
+        s = _dot(q, _rows(k_ref, j, block_k), _NT)
+        if not fold_scale:
+            s = s * scale
         m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
-        corr = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
+        if masked:
+            ok = _visibility(
+                qi, base + j, s.shape, block_q=block_q, block_k=block_k,
+                t_kv=t_kv, offset=offset, causal=causal,
+                mask_row=mask_ref[0, j] if has_mask else None)
+            s = jnp.where(ok, s, -jnp.inf)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # a row with no visible key yet keeps m = -inf; exp(-inf - 0)
+            # is the 0 its p and its correction need
+            m_use = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        else:
+            # every score is a visible one: m_new is finite, nothing to
+            # guard
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_use = m_new
+        p = jnp.exp(s - m_use)
+        corr = jnp.exp(m_prev - m_use)
         l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=-1,
                                                      keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * corr + _dot(
+            p.astype(v_ref.dtype), _rows(v_ref, j, block_k), _NN)
         m_ref[:, :1] = m_new
 
-    @pl.when(ki == n_k - 1)
+    _walk_k_tiles(tile, qi, base, tiles, block_q=block_q, block_k=block_k,
+                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask)
+
+    @pl.when(kj == n_major - 1)
     def _finish():
         l = l_ref[:, :1]
         m = m_ref[:, :1]
@@ -309,69 +577,110 @@ def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int, t_q: int,
         if want_lse:
             # rows with no visible key: +inf sentinel makes every backward
             # probability exp(s - inf) = 0, matching the zero forward output
-            lse = jnp.where(l == 0.0, jnp.inf, m + jnp.log(jnp.where(
-                l == 0.0, 1.0, l)))
-            lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+            _store_as_row(lse_ref.at[0, 0], jnp.where(
+                l == 0.0, jnp.inf, m + jnp.log(jnp.where(l == 0.0, 1.0, l))))
 
 
-def _prep(q, k, v, mask, block_q, block_k):
-    b, h, t_q, d = q.shape
-    t_kv = k.shape[2]
-    # the short-sequence clamp must land back ON the tile floors: a raw
-    # min() against an unaligned T (t_q=100 -> block_q=100) hands Mosaic
-    # an untileable block on compiled TPU runs — the padding below
-    # absorbs the round-up, and the kernels mask past t_q/t_kv
-    block_q = _round_up(min(block_q, max(t_q, 1)), _SUBLANES)
-    block_k = _round_up(min(block_k, max(t_kv, 1)), _LANES)
-    qr = pad_to_multiple(q.reshape(b * h, t_q, d), 1, block_q)
-    kr = pad_to_multiple(k.reshape(b * h, t_kv, d), 1, block_k)
-    vr = pad_to_multiple(v.reshape(b * h, t_kv, d), 1, block_k)
-    mr = None
-    if mask is not None:
-        mr = pad_to_multiple(mask.astype(jnp.float32), 1, block_k)
-        mr = jnp.broadcast_to(mr[:, None, :],
-                              (mr.shape[0], _SUBLANES, mr.shape[1]))
-    return qr, kr, vr, mr, block_q, block_k
+def _q_cell_specs(tiling: _Tiling, n_major: int, h: int, d: int, **geom):
+    """BlockSpecs of a fwd/dq cell (bh, qi, kj): the q block, the K/V major
+    window, and the mask rows of that window. Window ``kj`` is clamped to
+    the last one the q block needs, so a causal step right of the diagonal
+    repeats a block index and costs no DMA."""
+    block_q, block_k, major = tiling
+    tiles = major // block_k
+
+    def window(qi, kj):
+        if n_major == 1:
+            return 0
+        _, hi = _k_tile_range(qi, block_q=block_q, block_k=block_k,
+                              mn=jnp.minimum, mx=jnp.maximum, **geom)
+        return jnp.minimum(kj, jnp.maximum(hi - 1, 0) // tiles)
+
+    return (pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, major, d),
+                         lambda bh, qi, kj: (bh, window(qi, kj), 0)),
+            pl.BlockSpec((1, tiles, 1, block_k),
+                         lambda bh, qi, kj: (bh // h, window(qi, kj), 0, 0)))
 
 
-def _flash_fwd(q, k, v, mask, causal: bool, block_q: int, block_k: int,
+def _rows_padded(x, mult: int):
+    """(B, H, T, D) as (B*H, T rounded up to ``mult``, D)."""
+    b, h, t, d = x.shape
+    return pad_to_multiple(x.reshape(b * h, t, d), 1, mult)
+
+
+def _mask_rows(mask, tiling: _Tiling):
+    """The (B, Tk) keep-mask as one (1, block_k) row per k tile: a tile's
+    row is a leading-dim index away, and broadcasts over the score tile's
+    sublanes."""
+    mr = pad_to_multiple(mask.astype(jnp.float32), 1, tiling.major)
+    return mr.reshape(mr.shape[0], -1, 1, tiling.block_k)
+
+
+def _stat_rows(x, t_pad: int, block_q: int):
+    """A (B*H, 1, T) row statistic as (B*H, q tiles, 1, block_q): a q
+    tile's statistics are a leading-dim index away, q along lanes."""
+    return pad_to_multiple(x, 2, t_pad).reshape(x.shape[0], -1, 1, block_q)
+
+
+def _compiler_params(kernel: str, tiling: _Tiling, d: int, itemsize: int,
+                     has_mask: bool):
+    """Mosaic's scoped-VMEM default holds every schedule the selector
+    makes at the default budget (it fits them into half of it); a call
+    whose estimate is over that half (a raised ``zoo.pallas.
+    vmem_budget_mb``, or floor tiles that already outgrow it) asks for
+    its own limit."""
+    est = _kernel_vmem_bytes(tiling.block_q, tiling.block_k, d, itemsize,
+                             has_mask, major=tiling.major, kernel=kernel)
+    if 2 * est <= _VMEM_BYTES_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=2 * est)
+
+
+# jitted, inline: a model's layers make the same call, and a `jit` of the
+# same function at the same signature is traced once (a bare `pallas_call`
+# traces its kernel again for every layer: 0.14-0.34 s a call on the chip's
+# host, 36 calls a step); `inline` puts each call's equations into the
+# caller, so the program still holds one Mosaic call per layer under the
+# names it had
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7), inline=True)
+def _flash_fwd(q, k, v, mask, causal: bool, sched: _Schedule,
                interpret: bool, want_lse: bool):
     b, h, t_q, d = q.shape
     t_kv = k.shape[2]
     scale = 1.0 / float(d) ** 0.5
-    qr, kr, vr, mr, block_q, block_k = _prep(q, k, v, mask, block_q, block_k)
+    block_q, block_k, major = tiling = sched.fwd
+    qr = _rows_padded(q, block_q)
+    kr, vr = _rows_padded(k, major), _rows_padded(v, major)
     n_q = qr.shape[1] // block_q
-    n_k = kr.shape[1] // block_k
-    has_mask = mr is not None
+    n_major = kr.shape[1] // major
+    has_mask = mask is not None
 
-    kernel = functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
-                               block_k=block_k, t_q=t_q, t_kv=t_kv,
-                               causal=causal, has_mask=has_mask,
-                               want_lse=want_lse)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-    ]
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, fold_scale=_is_pow2(scale), tiling=tiling,
+        n_major=n_major, t_q=t_q, t_kv=t_kv, causal=causal,
+        has_mask=has_mask, want_lse=want_lse)
+    qspec, kspec, mspec = _q_cell_specs(
+        tiling, n_major, h, d, t_q=t_q, t_kv=t_kv, causal=causal,
+        has_mask=has_mask)
+    in_specs = [qspec, kspec, kspec]
     operands = [qr, kr, vr]
     if has_mask:
-        in_specs.append(pl.BlockSpec(
-            (1, _SUBLANES, block_k), lambda bh, qi, ki: (bh // h, 0, ki)))
-        operands.append(mr)
-    out_specs = [pl.BlockSpec((1, block_q, d),
-                              lambda bh, qi, ki: (bh, qi, 0))]
+        in_specs.append(mspec)
+        operands.append(_mask_rows(mask, tiling))
+    out_specs = [qspec]
     out_shape = [jax.ShapeDtypeStruct(qr.shape, q.dtype)]
     if want_lse:
         # inference/primal calls skip the lse output entirely — pallas
         # outputs are opaque to XLA DCE, so an unconditional write would
         # cost real HBM traffic on every no-grad forward
-        out_specs.append(pl.BlockSpec((1, block_q, _LANES),
-                                      lambda bh, qi, ki: (bh, qi, 0)))
+        out_specs.append(pl.BlockSpec((1, 1, 1, block_q),
+                                      lambda bh, qi, kj: (bh, qi, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct(
-            (qr.shape[0], qr.shape[1], _LANES), jnp.float32))
+            (qr.shape[0], n_q, 1, block_q), jnp.float32))
     res = pl.pallas_call(
         kernel,
-        grid=(b * h, n_q, n_k),
+        grid=(b * h, n_q, n_major),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -380,177 +689,229 @@ def _flash_fwd(q, k, v, mask, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
         ],
+        compiler_params=_compiler_params("fwd", tiling, d, q.dtype.itemsize,
+                                         has_mask),
         interpret=interpret,
         name="zoo_flash_fwd",
     )(*operands)
-    out = res[0]  # out_shape is a list either way
-    o = out[:, :t_q, :].reshape(b, h, t_q, d)
-    return (o, res[1]) if want_lse else o
+    o = res[0][:, :t_q, :].reshape(b, h, t_q, d)
+    if not want_lse:
+        return o
+    # the residual is one float a row, whatever the tile: (B*H, 1, T)
+    return o, res[1].reshape(b * h, 1, -1)[:, :, :t_q]
+
+
+def _is_pow2(x: float) -> bool:
+    return math.frexp(x)[0] == 0.5
 
 
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(*refs, scale: float, block_q: int, block_k: int,
-                   t_q: int, t_kv: int, causal: bool, has_mask: bool):
-    """Grid (bh, qi, ki), k innermost: dq accumulates over k blocks."""
+def _bwd_dq_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
+                   n_major: int, t_q: int, t_kv: int, causal: bool,
+                   has_mask: bool):
+    """Grid (bh, qi, kj), the forward's schedule: dq of one q block
+    accumulates over the k tiles of each major window. lse/delta arrive
+    (1, 1, 1, block_q), one float a row along lanes, and are stood up into
+    sublanes once a q block for the (q, k) tile."""
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, mask_ref, dq_ref,
-         acc_ref) = refs
+         acc_ref, lse_col, dl_col) = refs
     else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, acc_ref = refs
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, acc_ref,
+         lse_col, dl_col) = refs
         mask_ref = None
+    block_q, block_k, major = tiling
+    tiles = major // block_k
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    kj = pl.program_id(2)
+    base = kj * tiles
     offset = t_kv - t_q
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        _store_as_col(lse_col, lse_ref.at[0, 0])
+        _store_as_col(dl_col, dl_ref.at[0, 0])
 
-    needed = True
-    if causal:
-        needed = ki * block_k <= (qi + 1) * block_q - 1 + offset
+    q = q_ref[0]
+    if fold_scale:
+        q = q * scale
+    do = do_ref[0]
 
-    @pl.when(needed)
-    def _step():
-        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        ok = _visibility(qi, ki, (block_q, block_k), t_q=t_q, t_kv=t_kv,
-                         offset=offset, causal=causal,
-                         mask_blk=mask_ref[0, 0] if has_mask else None)
-        lse = lse_ref[0, :, :1]
-        p = jnp.where(ok, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dl_ref[0, :, :1])
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    def tile(j, masked: bool):
+        k = _rows(k_ref, j, block_k)
+        s = _dot(q, k, _NT)
+        if not fold_scale:
+            s = s * scale
+        p = jnp.exp(s - lse_col[:, :1])
+        if masked:
+            p = jnp.where(_visibility(
+                qi, base + j, s.shape, block_q=block_q, block_k=block_k,
+                t_kv=t_kv, offset=offset, causal=causal,
+                mask_row=mask_ref[0, j] if has_mask else None), p, 0.0)
+        dp = _dot(do, _rows(v_ref, j, block_k), _NT)
+        ds = p * (dp - dl_col[:, :1])
+        acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    @pl.when(ki == n_k - 1)
+    _walk_k_tiles(tile, qi, base, tiles, block_q=block_q, block_k=block_k,
+                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask)
+
+    @pl.when(kj == n_major - 1)
     def _finish():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale: float, block_q: int, block_k: int,
-                    t_q: int, t_kv: int, causal: bool, has_mask: bool):
-    """Grid (bh, ki, qi), q innermost: dk/dv accumulate over q blocks."""
-    if has_mask:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, mask_ref, dk_ref,
-         dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
-         dk_acc, dv_acc) = refs
-        mask_ref = None
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, *, scale: float,
+                    fold_scale: bool, tiling: _Tiling, n_major: int,
+                    t_q: int, t_kv: int, causal: bool):
+    """Grid (bh, ki, qj): dk/dv of one k block accumulate over the q tiles
+    of each major window, from the causal diagonal on. The tile is formed
+    transposed, ``s^T = k q^T`` (block_k, block_q): the row statistics
+    (1, block_q) broadcast over its sublanes as they are stored, and
+    ``dV += p^T dO``, ``dK += ds^T q`` are plain products."""
+    block_q, block_k, major = tiling
+    tiles = major // block_q
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    n_q = pl.num_programs(2)
+    qj = pl.program_id(2)
+    base = qj * tiles
     offset = t_kv - t_q
 
-    @pl.when(qi == 0)
+    @pl.when(qj == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    needed = True
+    k = k_ref[0]
+    if fold_scale:
+        k = k * scale
+    v = v_ref[0]
+
+    def tile(i, masked: bool):
+        q = _rows(q_ref, i, block_q)
+        do = _rows(do_ref, i, block_q)
+        st = _dot(k, q, _NT)
+        if not fold_scale:
+            st = st * scale
+        pt = jnp.exp(st - lse_ref[0, i])
+        if masked:      # the causal diagonal, on a (k, q) tile
+            q_pos = (base + i) * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, st.shape, 1)
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, st.shape, 0)
+            pt = jnp.where(k_pos <= q_pos + offset, pt, 0.0)
+        dv_acc[:] += _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - dl_ref[0, i])
+        dk_acc[:] += _dot(dst.astype(q.dtype), q, _NN)
+
+    lo, full, hi = (jnp.clip(n - base, 0, tiles) for n in _q_tile_range(
+        ki, block_q=block_q, block_k=block_k, t_q=t_q, t_kv=t_kv,
+        causal=causal, mn=jnp.minimum, mx=jnp.maximum))
     if causal:
-        needed = ki * block_k <= (qi + 1) * block_q - 1 + offset
+        _tile_loop(lo, full, lambda i: tile(i, True))
+    _tile_loop(full, hi, lambda i: tile(i, False))
 
-    @pl.when(needed)
-    def _step():
-        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        ok = _visibility(qi, ki, (block_q, block_k), t_q=t_q, t_kv=t_kv,
-                         offset=offset, causal=causal,
-                         mask_blk=mask_ref[0, 0] if has_mask else None)
-        lse = lse_ref[0, :, :1]
-        p = jnp.where(ok, jnp.exp(s - lse), 0.0)
-        do = do_ref[0]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dl_ref[0, :, :1])
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    @pl.when(qi == n_q - 1)
+    @pl.when(qj == n_major - 1)
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q, block_k,
+@functools.partial(jax.jit, static_argnums=(7, 8, 9), inline=True)
+def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
                interpret):
     b, h, t_q, d = q.shape
     t_kv = k.shape[2]
     scale = 1.0 / float(d) ** 0.5
-    qr, kr, vr, mr, block_q, block_k = _prep(q, k, v, mask, block_q, block_k)
-    gr = pad_to_multiple(g.reshape(b * h, t_q, d), 1, block_q)
-    orr = pad_to_multiple(out.reshape(b * h, t_q, d), 1, block_q)
-    # delta_i = sum_d dO_id * O_id — rowwise, cheap in XLA (no (T,T) tensor)
-    delta = jnp.sum(gr.astype(jnp.float32) * orr.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, :, None],
-                             (*delta.shape, _LANES))
-    n_q = qr.shape[1] // block_q
-    n_k = kr.shape[1] // block_k
-    has_mask = mr is not None
+    fold = _is_pow2(scale)
+    has_mask = mask is not None
+    itemsize = q.dtype.itemsize
+    # delta_i = sum_d dO_id * O_id — rowwise, cheap in XLA (no (T,T) tensor),
+    # and like lse one float a row
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(b * h, 1, t_q)
+    geom = dict(t_q=t_q, t_kv=t_kv, causal=causal)
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
-    rowspec = pl.BlockSpec((1, block_q, _LANES),
-                           lambda bh, qi, ki: (bh, qi, 0))
-    mspec = pl.BlockSpec((1, _SUBLANES, block_k),
-                         lambda bh, qi, ki: (bh // h, 0, ki))
-    operands = [qr, kr, vr, gr, lse, delta] + ([mr] if has_mask else [])
-
+    block_q, block_k, major = tiling = sched.dq
+    qr, gr = _rows_padded(q, block_q), _rows_padded(g, block_q)
+    kr, vr = _rows_padded(k, major), _rows_padded(v, major)
+    n_major = kr.shape[1] // major
+    qspec, kspec, mspec = _q_cell_specs(tiling, n_major, h, d,
+                                        has_mask=has_mask, **geom)
+    rowspec = pl.BlockSpec((1, 1, 1, block_q),
+                           lambda bh, qi, kj: (bh, qi, 0, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, t_q=t_q, t_kv=t_kv, causal=causal,
-                          has_mask=has_mask),
-        grid=(b * h, n_q, n_k),
+        functools.partial(_bwd_dq_kernel, scale=scale, fold_scale=fold,
+                          tiling=tiling, n_major=n_major, has_mask=has_mask,
+                          **geom),
+        grid=(b * h, qr.shape[1] // block_q, n_major),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec]
                  + ([mspec] if has_mask else []),
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),  # lse
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],  # delta
+        compiler_params=_compiler_params("dq", tiling, d, itemsize,
+                                         has_mask),
         interpret=interpret,
         name="zoo_flash_bwd_dq",
-    )(*operands)
+    )(qr, kr, vr, gr, _stat_rows(lse, qr.shape[1], block_q),
+      _stat_rows(delta, qr.shape[1], block_q),
+      *([_mask_rows(mask, tiling)] if has_mask else []))
 
-    # dk/dv grid: (bh, ki, qi) — remap the spec index args accordingly
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0))
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
-    rowspec2 = pl.BlockSpec((1, block_q, _LANES),
-                            lambda bh, ki, qi: (bh, qi, 0))
-    mspec2 = pl.BlockSpec((1, _SUBLANES, block_k),
-                          lambda bh, ki, qi: (bh // h, 0, ki))
+    # dk/dv grid: (bh, ki, qj) — the q side is the resident major window,
+    # from the first one the k block's causal diagonal reaches
+    block_q, block_k, major = tiling = sched.dkv
+    qr, gr = _rows_padded(q, major), _rows_padded(g, major)
+    kr, vr = _rows_padded(k, block_k), _rows_padded(v, block_k)
+    tiles = major // block_q
+    n_major = qr.shape[1] // major
+
+    def window2(ki, qj):
+        if n_major == 1:
+            return 0
+        lo, _, hi = _q_tile_range(ki, block_q=block_q, block_k=block_k,
+                                  mn=jnp.minimum, mx=jnp.maximum, **geom)
+        return jnp.clip(qj, lo // tiles, jnp.maximum(hi - 1, 0) // tiles)
+
+    qspec2 = pl.BlockSpec((1, major, d),
+                          lambda bh, ki, qj: (bh, window2(ki, qj), 0))
+    kspec2 = pl.BlockSpec((1, block_k, d), lambda bh, ki, qj: (bh, ki, 0))
+    rowspec2 = pl.BlockSpec((1, tiles, 1, block_q),
+                            lambda bh, ki, qj: (bh, window2(ki, qj), 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, t_q=t_q, t_kv=t_kv, causal=causal,
-                          has_mask=has_mask),
-        grid=(b * h, n_k, n_q),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2]
-                 + ([mspec2] if has_mask else []),
+        functools.partial(_bwd_dkv_kernel, scale=scale, fold_scale=fold,
+                          tiling=tiling, n_major=n_major, **geom),
+        grid=(b * h, kr.shape[1] // block_k, n_major),
+        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
         out_shape=[jax.ShapeDtypeStruct(kr.shape, k.dtype),
                    jax.ShapeDtypeStruct(vr.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=_compiler_params("dkv", tiling, d, itemsize, False),
         interpret=interpret,
         name="zoo_flash_bwd_dkv",
-    )(*operands)
+    )(qr, kr, vr, gr, _stat_rows(lse, qr.shape[1], block_q),
+      _stat_rows(delta, qr.shape[1], block_q))
 
     dq = dq[:, :t_q, :].reshape(b, h, t_q, d)
     dk = dk[:, :t_kv, :].reshape(b, h, t_kv, d)
     dv = dv[:, :t_kv, :].reshape(b, h, t_kv, d)
-    dmask = None if mask is None else jnp.zeros_like(mask,
-                                                     dtype=jnp.float32)
+    dmask = None
+    if has_mask:
+        # a hidden key takes part in no row's softmax, so it only ever
+        # touches its own rows of dk/dv: the dkv kernel leaves them
+        # unmasked and they are dropped here (a select, not a product —
+        # exp(s - lse) of a hidden key is unbounded)
+        keep = (mask >= 1.0)[:, None, :, None]
+        dk = jnp.where(keep, dk, jnp.zeros_like(dk))
+        dv = jnp.where(keep, dv, jnp.zeros_like(dv))
+        dmask = jnp.zeros_like(mask, dtype=jnp.float32)
     return dq, dk, dv, dmask
 
 
@@ -558,29 +919,25 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q, block_k,
 # public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, mask, causal, block_q, block_k, interpret):
-    return _flash_fwd(q, k, v, mask, causal, block_q, block_k, interpret,
-                      want_lse=False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, mask, causal, sched, interpret):
+    return _flash_fwd(q, k, v, mask, causal, sched, interpret, False)
 
 
-def _vjp_fwd(q, k, v, mask, causal, block_q, block_k, interpret):
-    out, lse = _flash_fwd(q, k, v, mask, causal, block_q, block_k, interpret,
-                          want_lse=True)
+def _vjp_fwd(q, k, v, mask, causal, sched, interpret):
+    out, lse = _flash_fwd(q, k, v, mask, causal, sched, interpret, True)
     return out, (q, k, v, mask, out, lse)
 
 
-def _vjp_bwd(causal, block_q, block_k, interpret, res, g):
+def _vjp_bwd(causal, sched, interpret, res, g):
     q, k, v, mask, out, lse = res
-    return _flash_bwd(q, k, v, mask, out, lse, g, causal, block_q, block_k,
-                      interpret)
+    return _flash_bwd(q, k, v, mask, out, lse, g, causal, sched, interpret)
 
 
 _flash.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def _flash_per_data_shard(q, k, v, mask, causal, block_q, block_k,
-                          interpret):
+def _flash_per_data_shard(q, k, v, mask, causal, sched, interpret):
     """Run the kernel once per ``data`` shard. A Mosaic kernel refuses to
     lower inside a jit that spans several devices ("Mosaic kernels cannot
     be automatically partitioned" — found on a four-chip v5e host, PR 21:
@@ -596,11 +953,11 @@ def _flash_per_data_shard(q, k, v, mask, causal, block_q, block_k,
     mesh = mesh_lib.global_mesh()
     dp = mesh.shape[mesh_lib.DATA_AXIS]
     if dp == 1 or q.shape[0] % dp or mesh_lib.in_manual_region():
-        return _flash(q, k, v, mask, causal, block_q, block_k, interpret)
+        return _flash(q, k, v, mask, causal, sched, interpret)
     args = (q, k, v) if mask is None else (q, k, v, mask)
 
     def local(q, k, v, m=None):
-        return _flash(q, k, v, m, causal, block_q, block_k, interpret)
+        return _flash(q, k, v, m, causal, sched, interpret)
 
     batch = P(mesh_lib.DATA_AXIS)
     return jax.shard_map(local, mesh=mesh, in_specs=(batch,) * len(args),
@@ -625,14 +982,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (tests).
 
     ``block_q``/``block_k`` default to auto selection
-    (``select_attention_blocks``): the VMEM-budget-aware heuristic around
-    the swept v5e sweet spot (256, 512) — which hit 29.3 TF/s vs 21.2 for
-    (256, 256), 23.1 for (512, 512), 24.4-24.9 for k-blocks of 1024/2048
-    at causal D=64 T=32k fwd+bwd — shrunk when the abstract signature
-    (T, D, dtype, mask) would outgrow VMEM. ``zoo.pallas.block_sweep``
-    refines the heuristic with a one-shot on-device sweep, cached per
-    signature and surfaced as ``zoo_pallas_block_choice`` info metrics.
-    Explicit ints pin the blocks (tests, reproductions)."""
+    (``select_attention_blocks``): the swept v5e default
+    ``_PREFERRED_BLOCKS`` (its comment holds the chip's readings), shrunk
+    when the abstract signature (T, D, dtype, mask) would outgrow the VMEM
+    budget. The pair is dq's and dkv's compute tile; the forward walks
+    ``_FWD_K_TILES`` k tiles as one; each kernel keeps as much of the
+    streamed side resident as the budget holds (the module docstring has
+    the schedule). ``zoo.pallas.block_sweep`` refines the pair with a
+    one-shot on-device sweep, cached per signature; the choice and its
+    static tile census are surfaced as the ``zoo_pallas_block_choice`` and
+    ``zoo_pallas_flash_tiles`` gauges. Explicit ints pin the pair (tests,
+    reproductions)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if mask is not None:
@@ -644,10 +1004,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              f"shape {mask.shape} — reduce broadcast masks "
                              f"at the layer level")
         mask = jax.lax.stop_gradient(mask.astype(jnp.float32))
-    if block_q is None or block_k is None:
-        abq, abk = _auto_blocks(q.shape, k.shape[2], q.dtype, causal,
-                                mask is not None, interpret)
-        block_q = block_q if block_q is not None else abq
-        block_k = block_k if block_k is not None else abk
-    return _flash_per_data_shard(q, k, v, mask, causal, block_q, block_k,
-                                 interpret)
+    has_mask = mask is not None
+    if block_q is None and block_k is None:
+        sched = _auto_blocks(q.shape, k.shape[2], q.dtype, causal, has_mask,
+                             interpret)
+    else:
+        if block_q is None or block_k is None:
+            auto = _auto_blocks(q.shape, k.shape[2], q.dtype, causal,
+                                has_mask, interpret)
+            block_q = block_q if block_q is not None else auto.dq.block_q
+            block_k = block_k if block_k is not None else auto.dq.block_k
+        sched = _resolve_schedule(q.shape[2], k.shape[2], q.shape[3],
+                                  q.dtype, has_mask, block_q, block_k)
+    return _flash_per_data_shard(q, k, v, mask, causal, sched, interpret)

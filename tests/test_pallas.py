@@ -193,8 +193,20 @@ def test_flash_bwd_no_quadratic_memory():
             if hasattr(var.aval, "shape"):
                 n = int(np.prod(var.aval.shape)) if var.aval.shape else 1
                 biggest = max(biggest, n)
-    # largest live tensor should be O(T*D) / O(T*LANES), nowhere near T^2
+    # largest live tensor should be O(T*D), nowhere near T^2
     assert biggest < t * t // 8, f"O(T^2) intermediate found: {biggest}"
+    # row statistics (lse, delta) are ONE float a row — no residual or
+    # intermediate is T x 128 wide (the old lane-broadcast layout: as many
+    # bytes a layer as q+k+v+o together at D=64)
+    stats = [v.aval.shape for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars
+             if getattr(v.aval, "dtype", None) == jnp.float32
+             and len(v.aval.shape) >= 2 and v.aval.shape[-1] == 128
+             and int(np.prod(v.aval.shape)) >= t * 128]
+    assert not stats, f"lane-broadcast row statistics found: {stats}"
+    lse = [v.aval.shape for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars
+           if getattr(v.aval, "dtype", None) == jnp.float32
+           and v.aval.shape == (1, 1, t)]
+    assert lse, "no (B*H, 1, T) row statistic in the backward's jaxpr"
 
 
 def test_flash_gradients_bf16():
@@ -247,6 +259,201 @@ def test_attention_layer_flash_handles_bert_mask():
 
 
 # ---------------------------------------------------------------------------
+# the tile schedule: resident major windows, diagonal-bounded loops,
+# mask-free interior tiles
+# ---------------------------------------------------------------------------
+
+#: name -> (t_q, t_kv, causal, masked keys from, vmem_budget_mb, several).
+#: Blocks are pinned at (128, 128), so the forward's tile is (128, 256).
+#: ``several``: the budget cuts every kernel's streamed side into more than
+#: one major window (else: exactly one).
+_SCHEDULE_CASES = {
+    # the whole sequence resident; diagonal AND interior tiles in one call
+    # (4 q blocks against 2 wide k tiles)
+    "whole_causal_aligned": (512, 512, True, None, None, False),
+    # T not a multiple of the blocks: the last tile is a masked one
+    "whole_causal_unaligned": (450, 450, True, None, None, False),
+    "whole_noncausal_unaligned": (300, 450, False, None, None, False),
+    # a small budget cuts the streamed side into several windows; the
+    # causal ones right of the diagonal are skipped steps
+    "windows_causal": (512, 512, True, None, 0.5, True),
+    "windows_noncausal_unaligned": (450, 450, False, None, 0.5, True),
+    # t_q != t_kv both ways (bottom-right alignment; t_q > t_kv has rows,
+    # and whole q blocks, that see no key)
+    "causal_tq_lt_tkv": (200, 512, True, None, None, False),
+    "causal_tq_gt_tkv": (512, 200, True, None, None, False),
+    "windows_causal_tq_gt_tkv": (640, 384, True, None, 0.5, True),
+    # key padding: keys from 200 on hidden, so the tiles from 256 on are
+    # fully padded ones
+    "mask_padded_tile": (384, 512, False, 200, None, False),
+    "mask_causal_windows": (512, 512, True, 300, 0.5, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SCHEDULE_CASES))
+def test_flash_schedule_matches_xla(name):
+    """Forward and dq/dk/dv of each schedule case against ``ops.attention``
+    through the interpreter, and the case really is the schedule it
+    names."""
+    import importlib
+
+    from analytics_zoo_tpu.common.context import (init_zoo_context,
+                                                  reset_zoo_context)
+    fa_mod = importlib.import_module(
+        "analytics_zoo_tpu.ops.pallas.flash_attention")
+    t_q, t_kv, causal, hide_from, budget_mb, several = _SCHEDULE_CASES[name]
+    q, k, v = _qkv(2, 2, t_q, t_kv, 8, seed=40)
+    mask = None
+    if hide_from is not None:
+        keep = np.ones((2, t_kv), np.float32)
+        keep[1, hide_from:] = 0.0
+        mask = jnp.asarray(keep)
+    g = jnp.asarray(np.random.default_rng(41).normal(size=q.shape),
+                    jnp.float32)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, mask=mask, causal=causal,
+                                       block_q=128, block_k=128) * g)
+
+    def loss_ref(q, k, v):
+        m4 = None if mask is None else mask[:, None, None, :]
+        return jnp.sum(dot_product_attention(q, k, v, mask=m4,
+                                             causal=causal) * g)
+
+    try:
+        reset_zoo_context()
+        if budget_mb:
+            init_zoo_context(conf={"zoo.pallas.vmem_budget_mb": budget_mb})
+        sched = fa_mod._resolve_schedule(t_q, t_kv, 8, q.dtype,
+                                         mask is not None, 128, 128)
+        assert sched.fwd.block_k == 256 and sched.dq.block_k == 128
+        windows = [-(-t // s.major) for t, s in zip((t_kv, t_kv, t_q),
+                                                    sched)]
+        assert all(n > 1 if several else n == 1 for n in windows), sched
+        out = flash_attention(q, k, v, mask=mask, causal=causal,
+                              block_q=128, block_k=128)
+        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    finally:
+        reset_zoo_context()
+    m4 = None if mask is None else mask[:, None, None, :]
+    ref = np.asarray(dot_product_attention(q, k, v, mask=m4, causal=causal))
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    out = np.asarray(out)
+    # rows that see no key: zeros here, uniform weights in the oracle
+    # (causal t_q > t_kv: the first t_q - t_kv rows). They take no part
+    # in any other row, so the comparison leaves them out
+    dead = max(t_q - t_kv, 0) if causal else 0
+    np.testing.assert_array_equal(out[:, :, :dead], 0.0)
+    np.testing.assert_allclose(out[:, :, dead:], ref[:, :, dead:],
+                               rtol=RTOL, atol=ATOL)
+    np.testing.assert_array_equal(np.asarray(gf[0])[:, :, :dead], 0.0)
+    if dead == 0:
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-4)
+    else:
+        # the oracle's uniform rows leak gradient into k and v; compare
+        # against the oracle on the live rows alone
+        def loss_live(q, k, v):
+            o = dot_product_attention(q[:, :, dead:], k, v, causal=causal)
+            return jnp.sum(o * g[:, :, dead:])
+        gl = jax.grad(loss_live, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gl):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-4)
+    if mask is not None:
+        # a hidden key gets no gradient at all
+        np.testing.assert_array_equal(np.asarray(gf[1])[1, :, hide_from:],
+                                      0.0)
+        np.testing.assert_array_equal(np.asarray(gf[2])[1, :, hide_from:],
+                                      0.0)
+
+
+@pytest.mark.parametrize("t_q,t_kv,causal,has_mask,tiling", [
+    (4096, 4096, True, False, (512, 1024, 4096)),
+    (4096, 4096, True, False, (256, 512, 4096)),
+    (4096, 4096, True, False, (256, 512, 1024)),     # several windows
+    (1000, 1000, True, False, (256, 512, 1024)),
+    (512, 200, True, False, (128, 128, 256)),        # q blocks see nothing
+    (2048, 2048, False, True, (512, 1024, 2048)),    # mask: all masked
+    (2048, 2048, False, False, (512, 1024, 2048)),   # none masked
+])
+def test_flash_tile_census_counts_the_loops(t_q, t_kv, causal, has_mask,
+                                            tiling):
+    """The census the gauge publishes against a brute-force count over the
+    (q, k) plane: a tile is needed iff it holds a visible key, interior iff
+    every element of it is visible and the call has no key mask."""
+    from analytics_zoo_tpu.ops.pallas.flash_attention import (_Tiling,
+                                                              _tile_census)
+    bq, bk, major = tiling
+    census = _tile_census(t_q, t_kv, _Tiling(*tiling), causal, has_mask)
+    rows = np.arange(-(-t_q // bq) * bq)[:, None]
+    keys = np.arange(-(-t_kv // major) * major)[None, :]
+    vis = (keys < t_kv) & np.ones_like(rows, bool)
+    if causal:
+        vis = vis & (keys <= rows + (t_kv - t_q))
+    tiles = vis.reshape(rows.shape[0] // bq, bq, keys.shape[1] // bk, bk)
+    # a q block's loops run from tile 0 to the last tile that holds a
+    # visible key; causal visibility is a prefix of the keys, so every
+    # tile before that one holds a visible key too
+    any_vis = tiles.any(axis=(1, 3))
+    all_vis = tiles.all(axis=(1, 3))
+    needed = sum(int(r.nonzero()[0].max()) + 1 if r.any() else 0
+                 for r in any_vis)
+    interior = 0 if has_mask else sum(
+        int(np.cumprod(r).sum()) for r in all_vis)
+    assert census["interior"] + census["masked"] == needed
+    assert census["interior"] == interior
+    per_window = any_vis.reshape(any_vis.shape[0], -1, major // bk)
+    assert census["skipped_steps"] == int(
+        (~per_window.any(axis=2)).sum())
+
+
+def test_auto_schedule_metric_names_windows_and_census():
+    """The ``choice`` label names the schedule and the sibling gauge holds
+    the static census: the benchmark's GPT-1 signature keeps the whole
+    sequence resident, skips no grid step, and interior + masked are the
+    tiles on or under the diagonal."""
+    import importlib
+
+    from analytics_zoo_tpu.observability import default_registry
+    fa_mod = importlib.import_module(
+        "analytics_zoo_tpu.ops.pallas.flash_attention")
+    sched = fa_mod._auto_blocks((8, 12, 4096, 64), 4096, jnp.bfloat16, True,
+                                False, True)
+    assert sched.fwd == (512, 1024, 4096)
+    assert sched.dq == sched.dkv == (512, 512, 4096)
+    snap = default_registry().snapshot()
+    sig = 'sig="tq4096tk4096d64bfloat16c"'
+    choice = [k for k in snap if k.startswith("zoo_pallas_block_choice")
+              and sig in k]
+    assert len(choice) == 1 and ("fwd=512x1024/kmajor4096,"
+                                 "dq=512x512/kmajor4096,"
+                                 "dkv=512x512/qmajor4096") in choice[0]
+    tiles = {kind: snap[k]["value"] for kind in
+             ("interior", "masked", "skipped_steps")
+             for k in snap if k.startswith("zoo_pallas_flash_tiles")
+             and sig in k and f'kind="{kind}"' in k}
+    assert tiles["skipped_steps"] == 0
+    # 8 q blocks of 512 rows against 1024-wide tiles: block i needs
+    # ceil(512 (i + 1) / 1024) of them
+    assert tiles["interior"] + tiles["masked"] == sum(
+        -(-512 * (i + 1) // 1024) for i in range(8)) == 20
+    assert tiles["masked"] == 8          # one diagonal tile a q block
+    # the long-context signature cannot keep 32k keys resident: several
+    # windows, and the causal steps right of the diagonal are counted
+    long = fa_mod._auto_blocks((1, 12, 32768, 64), 32768, jnp.bfloat16,
+                               True, False, True)
+    assert long.fwd.major < 32768 and long.dkv.major < 32768
+    snap = default_registry().snapshot()
+    skipped = [snap[k]["value"] for k in snap
+               if k.startswith("zoo_pallas_flash_tiles")
+               and 'sig="tq32768tk32768d64bfloat16c"' in k
+               and 'kind="skipped_steps"' in k]
+    assert skipped and skipped[0] > 0
+
+
+# ---------------------------------------------------------------------------
 # int8 weight-only matmul
 # ---------------------------------------------------------------------------
 
@@ -272,24 +479,28 @@ def test_int8_matmul_shape_check():
 # ---------------------------------------------------------------------------
 
 def test_select_blocks_defaults_to_swept_sweet_spot():
-    from analytics_zoo_tpu.ops.pallas.flash_attention import \
-        select_attention_blocks
-    # the bench long-context shape: D=64 bf16 fits VMEM at (256, 512)
-    assert select_attention_blocks(32768, 32768, 64, jnp.bfloat16,
-                                   causal=True) == (256, 512)
+    from analytics_zoo_tpu.ops.pallas.flash_attention import (
+        _PREFERRED_BLOCKS, select_attention_blocks)
+    # the bench long-context shape and the benchmark's GPT-1 cell: D=64
+    # bf16 fits VMEM at the swept default
+    assert _PREFERRED_BLOCKS == (512, 512)
+    for t in (32768, 4096):
+        assert select_attention_blocks(t, t, 64, jnp.bfloat16,
+                                       causal=True) == _PREFERRED_BLOCKS
 
 
 def test_select_blocks_shrinks_for_vmem_budget():
     from analytics_zoo_tpu.ops.pallas.flash_attention import (
-        _kernel_vmem_bytes, select_attention_blocks)
-    # a tight explicit budget must shrink the blocks until the estimate fits
+        _PREFERRED_BLOCKS, _kernel_vmem_bytes, select_attention_blocks)
+    # a tight explicit budget must shrink the blocks until the estimate
+    # (the largest of the three kernels at that pair) fits
     bq, bk = select_attention_blocks(8192, 8192, 256, jnp.float32,
                                      budget_bytes=2 * 1024 * 1024)
-    assert (bq, bk) != (256, 512)
+    assert (bq, bk) != _PREFERRED_BLOCKS
     assert _kernel_vmem_bytes(bq, bk, 256, 4) <= 2 * 1024 * 1024
     # monotone: a huge budget returns the preferred default
     assert select_attention_blocks(8192, 8192, 256, jnp.float32,
-                                   budget_bytes=1 << 30) == (256, 512)
+                                   budget_bytes=1 << 30) == _PREFERRED_BLOCKS
 
 
 def test_select_blocks_clamps_to_short_sequences():
@@ -377,7 +588,7 @@ def test_sweep_candidates_are_tile_aligned_on_unaligned_sequences():
     compile and silently shrink the candidate pool."""
     from analytics_zoo_tpu.ops.pallas.flash_attention import (
         _LANES, _SUBLANES, _sweep_candidates)
-    for bq, bk in _sweep_candidates(1000, 1000, 64, 2, False, (256, 512)):
+    for bq, bk in _sweep_candidates(1000, 1000, 64, 2, False, (512, 512)):
         assert bq % _SUBLANES == 0 and bk % _LANES == 0, (bq, bk)
 
 
@@ -390,24 +601,24 @@ def test_block_sweep_picks_fastest_candidate_via_injected_timer():
 
     def timer(bq, bk):
         timed.append((bq, bk))
-        return 0.001 if (bq, bk) == (128, 512) else 1.0
+        return 0.001 if (bq, bk) == (256, 512) else 1.0
 
     best = _sweep_blocks(1, 2, 2048, 2048, 64, jnp.bfloat16, True, False,
-                         (256, 512), timer=timer)
-    assert best == (128, 512)
-    assert (256, 512) in timed and len(timed) >= 3
+                         (512, 512), timer=timer)
+    assert best == (256, 512)
+    assert (512, 512) in timed and len(timed) >= 3
 
 
 def test_sweep_candidate_failure_loses_not_raises():
     from analytics_zoo_tpu.ops.pallas.flash_attention import _sweep_blocks
 
     def timer(bq, bk):
-        if (bq, bk) == (256, 512):
+        if (bq, bk) == (512, 512):
             raise RuntimeError("compile failed")
         return 1.0 if (bq, bk) != (256, 256) else 0.5
 
     best = _sweep_blocks(1, 1, 1024, 1024, 64, jnp.float32, False, False,
-                         (256, 512), timer=timer)
+                         (512, 512), timer=timer)
     assert best == (256, 256)
 
 
@@ -440,24 +651,25 @@ def test_lint_estimate_equals_autotuner_decisions(t_q, t_kv, d, itemsize,
     runtime autotuner — for the FULL raw candidate set, a candidate
     survives `_sweep_candidates` exactly when the lint-side estimate
     fits the usable budget, and the heuristic's final choice fits it
-    too."""
+    too. And it describes the windows the kernels really get: every
+    kernel's tiling of the resolved schedule, major window and all, is
+    priced inside the whole per-core budget by the same function."""
     from analytics_zoo_tpu.analysis.device import footprint_module
     from analytics_zoo_tpu.ops.pallas.common import (
-        LANES, SUBLANES, round_up, vmem_usable_bytes)
+        LANES, SUBLANES, round_up, vmem_budget_bytes, vmem_usable_bytes)
     from analytics_zoo_tpu.ops.pallas.flash_attention import (
-        _PREFERRED_BLOCKS, _sweep_candidates, select_attention_blocks)
+        _SWEEP_PAIRS, _resolve_schedule, _sweep_candidates,
+        select_attention_blocks)
 
     lint = footprint_module()
     assert lint is not None
     budget = vmem_usable_bytes()
-    heuristic = select_attention_blocks(
-        t_q, t_kv, d, jnp.float32 if itemsize == 4 else jnp.bfloat16,
-        has_mask=has_mask)
+    dtype = jnp.float32 if itemsize == 4 else jnp.bfloat16
+    heuristic = select_attention_blocks(t_q, t_kv, d, dtype,
+                                        has_mask=has_mask)
     kept = _sweep_candidates(t_q, t_kv, d, itemsize, has_mask, heuristic)
-    raw = [heuristic, _PREFERRED_BLOCKS, (128, 512), (256, 256),
-           (512, 512), (128, 1024)]
     expected = []
-    for bq, bk in raw:
+    for bq, bk in (heuristic,) + _SWEEP_PAIRS:
         cand = (max(SUBLANES, min(bq, round_up(max(t_q, 1), SUBLANES))),
                 max(LANES, min(bk, round_up(max(t_kv, 1), LANES))))
         if cand in expected:
@@ -474,6 +686,20 @@ def test_lint_estimate_equals_autotuner_decisions(t_q, t_kv, d, itemsize,
     assert (lint.attention_vmem_bytes(bq, bk, d=d, itemsize=itemsize,
                                       has_mask=has_mask) <= budget
             or (bq, bk) == (SUBLANES, LANES))
+    # the windows: each kernel's own tile with its major window, as the
+    # pallas_calls are built, inside the whole budget (a floor tile that
+    # alone is over it keeps a one-tile window)
+    sched = _resolve_schedule(t_q, t_kv, d, dtype, has_mask, bq, bk)
+    assert sched.fwd.block_k == min(
+        lint.ATTENTION_FWD_K_TILES * sched.dq.block_k,
+        round_up(t_kv, LANES))
+    for kernel, t in zip(sched._fields, sched):
+        blk = t.block_q if kernel == "dkv" else t.block_k
+        assert t.major % blk == 0 and t.major >= blk
+        est = lint.attention_vmem_bytes(
+            t.block_q, t.block_k, d=d, itemsize=itemsize,
+            has_mask=has_mask, major=t.major, kernel=kernel)
+        assert est <= vmem_budget_bytes() or t.major == blk, (kernel, t)
 
 
 def test_fused_ce_budget_clamp_consumes_shared_estimator():

@@ -11,10 +11,14 @@ Layer map (mirrors SURVEY.md §1):
              runtime (bf16 + calibrated static int8)
   models/    built-in model zoo (recommendation, anomaly detection, text,
              seq2seq, image classification, object detection, caffe import)
-  ops/       attention + pallas TPU kernels (flash attention, int8 matmul)
+  ops/       attention (window, grouped heads, rotary) + the experts'
+             grouped product + pallas TPU kernels (flash attention, int8
+             matmul)
   parallel/  mesh (data/pipe/seq/expert/model axes), shardings, ring
-             attention, GPipe pipeline schedule; SparseMoE lives with the
-             layers; multi-host bring-up in common/
+             attention, GPipe pipeline schedule; the two expert layers
+             live with the layers (SparseMoE: capacity-bounded dispatch
+             over the `expert` axis; RoutedExperts: dropless, one chip's
+             share of the experts); multi-host bring-up in common/
   serving/   cluster-serving equivalent (stream, batching, backpressure)
   tfpark/    BERT estimators, GANEstimator, torch weight import
   ray/       task/actor runtime (RayOnSpark role)

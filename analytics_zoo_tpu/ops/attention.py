@@ -13,10 +13,12 @@ fine into the MXU; accumulating attention weights in bf16 is not).
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e9
 
@@ -26,19 +28,35 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           causal: bool = False,
                           dropout_rate: float = 0.0,
                           dropout_rng: Optional[jax.Array] = None,
+                          window: Optional[int] = None,
                           ) -> jax.Array:
     """Multi-head scaled dot-product attention.
 
-    q, k, v: (B, n_head, T, d_head); ``mask``: broadcastable to
-    (B, n_head, Tq, Tk), 1.0 = attend / 0.0 = hide. Returns (B, n_head, T, d_head).
+    q: (B, n_head, T, d_head); k, v: (B, n_kv_head, T, d_head) with
+    ``n_head % n_kv_head == 0`` (grouped heads: query head ``h`` attends
+    key/value head ``h // (n_head / n_kv_head)``); ``mask``: broadcastable
+    to (B, n_head, Tq, Tk), 1.0 = attend / 0.0 = hide. ``window`` (causal
+    calls only): a query sees the ``window`` latest keys up to and with its
+    own position, ``i - window < j <= i``. Returns (B, n_head, T, d_head).
     """
     d_head = q.shape[-1]
+    if window is not None and not causal:
+        raise ValueError("a window is defined for causal attention only")
+    if q.shape[1] != k.shape[1]:
+        if q.shape[1] % k.shape[1]:
+            raise ValueError(f"{q.shape[1]} query heads do not divide over "
+                             f"{k.shape[1]} key/value heads")
+        group = q.shape[1] // k.shape[1]
+        k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
     logits = logits / jnp.sqrt(jnp.asarray(d_head, jnp.float32))
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((tq, tk), jnp.bool_), k=tk - tq)
+        if window is not None:
+            cm = cm & ~jnp.tril(jnp.ones((tq, tk), jnp.bool_),
+                                k=tk - tq - window)
         logits = jnp.where(cm[None, None], logits, NEG_INF)
     if mask is not None:
         logits = logits + (1.0 - mask.astype(jnp.float32)) * NEG_INF
@@ -62,3 +80,65 @@ def merge_heads(x: jax.Array) -> jax.Array:
     """(B, n_head, T, d) → (B, T, n_head*d)."""
     b, nh, t, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b, t, nh * d)
+
+
+# ---------------------------------------------------------------------------
+# rotary positions (Su et al. 2021), plain and YaRN (Peng et al. 2023)
+# ---------------------------------------------------------------------------
+
+def rotary_inv_freq(head_dim: int, spec: Mapping) -> Tuple[np.ndarray, float]:
+    """``(inv_freq, attention_factor)`` of one kind of layer, from a
+    ``rope_parameters`` entry as ``transformers`` writes it: ``rope_type``
+    ``default`` (``inv_freq_i = theta^(-2i/d)``) or ``yarn``, computed
+    statically (the same table at every length): below the correction
+    dimension of ``beta_fast`` the frequencies stay, above that of
+    ``beta_slow`` they are divided by ``factor``, between them a linear
+    ramp; cos and sin are both scaled by ``attention_factor``. Host
+    arithmetic in float64, made once per layer kind at build."""
+    half = head_dim // 2
+    base = float(spec["rope_theta"])
+    extrap = base ** (-np.arange(half, dtype=np.float64) * 2.0 / head_dim)
+    kind = spec.get("rope_type", "default")
+    if kind == "default":
+        return extrap.astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"rotary positions of type {kind!r} are not "
+                         f"implemented (default, yarn)")
+    factor = float(spec["factor"])
+    low, high = yarn_correction_range(head_dim, spec)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = extrap / factor * ramp + extrap * (1.0 - ramp)
+    scale = spec.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0
+    return inv_freq.astype(np.float32), float(scale)
+
+
+def yarn_correction_range(head_dim: int, spec: Mapping) -> Tuple[int, int]:
+    """``(low, high)``: the rotary dimensions between which YaRN ramps from
+    the published frequencies to the interpolated ones."""
+    def dim(beta):
+        return (head_dim * math.log(
+            spec["original_max_position_embeddings"] / (beta * 2 * math.pi))
+            / (2 * math.log(spec["rope_theta"])))
+    low = math.floor(dim(spec.get("beta_fast", 32)))
+    high = math.ceil(dim(spec.get("beta_slow", 1)))
+    return max(low, 0), min(high, head_dim - 1)
+
+
+def rotary_tables(inv_freq, scale: float, t: int):
+    """float32 ``(cos, sin)`` of positions ``0..t-1``, each (t, head_dim):
+    the half-split layout, frequencies repeated over both halves."""
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate (B, n_head, T, d_head) by the tables, ``rotate_half`` pairs
+    (dimension i with i + d/2), in float32; the result has x's dtype."""
+    xf = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
+    return (xf * cos + rot * sin).astype(x.dtype)

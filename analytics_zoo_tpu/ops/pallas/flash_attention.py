@@ -47,6 +47,18 @@ path. In dkv a hidden or padded key only ever touches its own rows of
 dk/dv, so that kernel masks nothing but the diagonal and the wrapper drops
 those rows.
 
+Grouped heads and windows (PR 28): q may have ``group`` times the heads of
+k and v. The K/V block specs of fwd and dq index head ``bh // group``, so
+nothing is repeated in HBM and the query heads of a group find their K/V
+block resident; dkv's grid counts key/value heads and walks the major
+windows of each of the group's query heads in turn into one pair of
+accumulators. A causal ``window`` (``i - window < j <= i``) bounds the tile
+loops on BOTH sides (``_k_tile_bands``, ``_q_tile_bands``): tiles behind
+the window are never computed or brought in, and the mask is built on the
+band the diagonal cuts and on the band the window's trailing edge cuts,
+nowhere between. Window calls carry ``_win`` behind the kernel's name.
+With ``group == 1`` and no window every kernel is traced as before.
+
 Backward: the standard two-kernel recompute scheme (no (T, T) tensor is ever
 materialized, unlike the r3 XLA-recompute fallback this replaces):
 ``delta = rowsum(dO·O)`` in XLA, then the dq kernel (grid bh, q block, K/V
@@ -212,7 +224,7 @@ def _time_blocks(b, h, t_q, t_kv, d, dtype, causal, has_mask, block_q,
 
     def fwd_bwd(q, k, v):
         return jax.grad(lambda q: jnp.sum(
-            _flash(q, k, v, m, causal, sched, False)
+            _flash(q, k, v, m, causal, sched, False, None)
             .astype(jnp.float32)))(q)
 
     fn = jax.jit(fwd_bwd)
@@ -263,8 +275,10 @@ def _record_block_choice(sig: str, sched, census: dict) -> None:
                 "zoo_pallas_flash_tiles",
                 "static tile census of one (batch, head) of a flash "
                 "forward call at this signature: compute tiles that run "
-                "mask-free (interior), that build the mask (masked), and "
-                "grid steps with nothing to compute (skipped_steps)",
+                "mask-free (interior), that build the mask (masked), grid "
+                "steps with nothing to compute (skipped_steps) and, for a "
+                "window call, causal tiles behind the window that are "
+                "never computed (skipped_tiles)",
                 labels={"sig": sig, "kind": kind}).set(n)
     # metrics must never break the compute path
     except Exception:  # zoolint: disable=ZL007
@@ -272,7 +286,8 @@ def _record_block_choice(sig: str, sched, census: dict) -> None:
 
 
 def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
-                 interpret: bool) -> _Schedule:
+                 interpret: bool, window: Optional[int] = None,
+                 group: int = 1) -> _Schedule:
     """Cached per-signature schedule: the VMEM heuristic's tile, optionally
     refined by the one-shot on-device sweep (compiled TPU runs only — the
     interpreter's timings say nothing about the MXU), and the major
@@ -281,7 +296,9 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     ragged final batch or an evaluate at a different B must not re-resolve
     (or worse, re-SWEEP: compiling and timing the candidates with live
     training state resident). Only sweep-timed entries key on the full
-    shape, since wall time does scale with B·H."""
+    shape, since wall time does scale with B·H. A window and grouped
+    heads change the census and the label, not the tile: they join the
+    key and the label only where a call has them."""
     b, h, t_q, d = q_shape
     dt = jnp.dtype(dtype)
     from ...common.context import get_zoo_context
@@ -292,6 +309,8 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     # not silently keep blocks sized for the old budget
     budget = vmem_usable_bytes()
     base = (t_q, t_kv, d, dt.name, causal, has_mask)
+    if window is not None or group != 1:
+        base += (window, group)
     sig = (budget, "sweep", b, h) + base if sweep else (budget,) + base
     cached = _BLOCK_CACHE.get(sig)
     if cached is not None:
@@ -310,8 +329,10 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     _record_block_choice(
         (f"b{b}h{h}" if sweep else "")
         + f"tq{t_q}tk{t_kv}d{d}{dt.name}"
-        f"{'c' if causal else ''}{'m' if has_mask else ''}", sched,
-        _tile_census(t_q, t_kv, sched.fwd, causal, has_mask))
+        f"{'c' if causal else ''}{'m' if has_mask else ''}"
+        + (f"w{window}" if window is not None else "")
+        + (f"g{group}" if group != 1 else ""), sched,
+        _tile_census(t_q, t_kv, sched.fwd, causal, has_mask, window))
     return sched
 
 
@@ -370,6 +391,29 @@ def _k_tile_range(qi, *, block_q, block_k, t_q, t_kv, causal, has_mask,
     return mn(full, hi), hi
 
 
+def _k_tile_bands(qi, *, window=None, mn=min, mx=max, **geom):
+    """``(lo, b, c, hi)`` for q block ``qi`` (fwd and dq): k tiles
+    ``[lo, b)`` build the mask (with a window: its trailing edge cuts
+    them), ``[b, c)`` run mask-free, ``[c, hi)`` build the mask (the
+    diagonal, the kv padding, a key-padding mask), and tiles under ``lo``
+    or from ``hi`` on hold no visible key. Without a window ``lo = b = 0``
+    and ``(c, hi)`` is ``_k_tile_range``. A window of ``w`` keys lets row
+    ``i`` see ``i - w < j <= i`` (bottom-right aligned like the
+    diagonal)."""
+    n_full, hi = _k_tile_range(qi, mn=mn, mx=mx, **geom)
+    if window is None:
+        return 0, 0, n_full, hi
+    block_q, block_k = geom["block_q"], geom["block_k"]
+    first_row_sees = qi * block_q + (geom["t_kv"] - geom["t_q"])
+    last_row_sees = first_row_sees + block_q - 1
+    # the first tile any row's window reaches, and the first one that
+    # every row's window holds whole
+    lo = mn(mx(first_row_sees - window + 1, 0) // block_k, hi)
+    whole = (mx(last_row_sees - window + 1, 0) + block_k - 1) // block_k
+    b = mn(mx(whole, lo), hi)
+    return lo, b, mn(mx(n_full, b), hi), hi
+
+
 def _q_tile_range(ki, *, block_q, block_k, t_q, t_kv, causal,
                   mn=min, mx=max):
     """``(lo, full, hi)`` for k block ``ki`` (dkv): q tiles ``[lo, full)``
@@ -387,24 +431,52 @@ def _q_tile_range(ki, *, block_q, block_k, t_q, t_kv, causal,
     return lo, mx(full, lo), hi
 
 
+def _q_tile_bands(ki, *, window=None, mn=min, mx=max, **geom):
+    """``(lo, b, c, hi)`` for k block ``ki`` (dkv), the transposed plane:
+    q tiles ``[lo, b)`` are crossed by the diagonal, ``[b, c)`` see every
+    key of the block, ``[c, hi)`` are cut by the window's trailing edge
+    (some row's window starts inside the block), tiles under ``lo`` or
+    from ``hi`` on see none of its keys. Without a window ``c = hi``."""
+    lo, full, hi = _q_tile_range(ki, mn=mn, mx=mx, **geom)
+    if window is None:
+        return lo, full, hi, hi
+    block_q, block_k = geom["block_q"], geom["block_k"]
+    # row r sees key j iff j > r + offset - window: the rows under
+    # `all_to` see the block's first key (so all of it), the rows under
+    # `any_to` its last
+    all_to = ki * block_k - (geom["t_kv"] - geom["t_q"]) + window
+    any_to = all_to + block_k - 1
+    hi = mn(hi, (mx(any_to, 0) + block_q - 1) // block_q)
+    lo = mn(lo, hi)
+    b = mn(full, hi)
+    return lo, b, mn(mx(mx(all_to, 0) // block_q, b), hi), hi
+
+
 def _tile_census(t_q: int, t_kv: int, tiling: _Tiling, causal: bool,
-                 has_mask: bool) -> dict:
+                 has_mask: bool, window: Optional[int] = None) -> dict:
     """Static census of one (batch, head) of a forward call: compute tiles
     that run mask-free, tiles that build the mask, and grid steps whose
-    loops are empty (a causal major window wholly right of the diagonal).
-    dq walks the same plane at its own tile; dkv the transposed one."""
+    loops are empty (a causal major window wholly right of the diagonal,
+    or wholly behind the window). A window call also counts the tiles of
+    the causal half that it never computes (``skipped_tiles``). dq walks
+    the same plane at its own tile; dkv the transposed one."""
     bq, bk, major = tiling
     tiles = major // bk
     n_major = -(-max(t_kv, 1) // major)
     out = {"interior": 0, "masked": 0, "skipped_steps": 0}
+    if window is not None:
+        out["skipped_tiles"] = 0
     for qi in range(-(-max(t_q, 1) // bq)):
-        n_full, hi = _k_tile_range(qi, block_q=bq, block_k=bk, t_q=t_q,
-                                   t_kv=t_kv, causal=causal,
-                                   has_mask=has_mask)
-        out["interior"] += n_full
-        out["masked"] += hi - n_full
-        out["skipped_steps"] += sum(hi <= kj * tiles
-                                    for kj in range(n_major))
+        geom = dict(block_q=bq, block_k=bk, t_q=t_q, t_kv=t_kv,
+                    causal=causal, has_mask=has_mask)
+        lo, b, c, hi = _k_tile_bands(qi, window=window, **geom)
+        out["interior"] += c - b
+        out["masked"] += (b - lo) + (hi - c)
+        out["skipped_steps"] += sum(
+            hi <= kj * tiles or lo >= (kj + 1) * tiles
+            for kj in range(n_major))
+        if window is not None:
+            out["skipped_tiles"] += lo
     return out
 
 
@@ -419,10 +491,20 @@ def _tile_loop(lo, hi, body) -> None:
 
 
 def _walk_k_tiles(tile, qi, base, tiles: int, *, block_k, t_kv, causal,
-                  has_mask, **geom) -> None:
+                  has_mask, window=None, **geom) -> None:
     """fwd and dq: ``tile(j, masked)`` over the k tiles of the major window
-    that starts at global tile ``base``, the mask-free run first. A loop
+    that starts at global tile ``base``, the mask-free run first (after the
+    band the window's trailing edge cuts, where there is a window). A loop
     that can never run at this signature is not emitted."""
+    if window is not None:
+        lo, b, c, hi = (jnp.clip(n - base, 0, tiles) for n in _k_tile_bands(
+            qi, window=window, block_k=block_k, t_kv=t_kv, causal=causal,
+            has_mask=has_mask, mn=jnp.minimum, mx=jnp.maximum, **geom))
+        _tile_loop(lo, b, lambda j: tile(j, True))
+        if not has_mask:
+            _tile_loop(b, c, lambda j: tile(j, False))
+        _tile_loop(c, hi, lambda j: tile(j, True))
+        return
     n_full, hi = (jnp.clip(n - base, 0, tiles) for n in _k_tile_range(
         qi, block_k=block_k, t_kv=t_kv, causal=causal, has_mask=has_mask,
         mn=jnp.minimum, mx=jnp.maximum, **geom))
@@ -438,15 +520,17 @@ def _rows(ref, j, block: int):
 
 
 def _visibility(q_tile, k_tile, shape, *, block_q, block_k, t_kv, offset,
-                causal, mask_row=None):
-    """Keep-mask of one (q, k) score tile: kv padding, causal alignment,
-    and the optional key-padding mask row."""
+                causal, mask_row=None, window=None):
+    """Keep-mask of one (q, k) score tile: kv padding, causal alignment
+    (and the window behind it), and the optional key-padding mask row."""
     k_pos = k_tile * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     ok = k_pos < t_kv
     if causal:
         q_pos = q_tile * block_q + jax.lax.broadcasted_iota(
             jnp.int32, shape, 0)
         ok = ok & (k_pos <= q_pos + offset)
+        if window is not None:
+            ok = ok & (k_pos > q_pos + (offset - window))
     if mask_row is not None:
         # keep-masks are a binary contract (1.0 = attend); >= 1.0 matches
         # the XLA oracle's additive -1e9*(1-mask) on stray soft values too
@@ -507,9 +591,11 @@ def _dot(a, b, dims):
 
 def _fwd_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
                 n_major: int, t_q: int, t_kv: int, causal: bool,
-                has_mask: bool, want_lse: bool):
+                has_mask: bool, want_lse: bool,
+                window: Optional[int] = None):
     """Grid cell (bh, qi, kj): one q block against major window ``kj`` of
-    K/V, walked tile by tile up to the causal diagonal. q (1, block_q, D);
+    K/V, walked tile by tile up to the causal diagonal (from the window's
+    trailing edge on, where there is a window). q (1, block_q, D);
     k/v (1, major_k, D); [mask (1, tiles, 1, block_k)]; o (1, block_q, D);
     lse (1, 1, 1, block_q); scratch acc (block_q, D), m/l (block_q, LANES)
     carry the online softmax across tiles and major windows."""
@@ -545,7 +631,7 @@ def _fwd_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
         if masked:
             ok = _visibility(
                 qi, base + j, s.shape, block_q=block_q, block_k=block_k,
-                t_kv=t_kv, offset=offset, causal=causal,
+                t_kv=t_kv, offset=offset, causal=causal, window=window,
                 mask_row=mask_ref[0, j] if has_mask else None)
             s = jnp.where(ok, s, -jnp.inf)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -566,7 +652,8 @@ def _fwd_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
         m_ref[:, :1] = m_new
 
     _walk_k_tiles(tile, qi, base, tiles, block_q=block_q, block_k=block_k,
-                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask)
+                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask,
+                  window=window)
 
     @pl.when(kj == n_major - 1)
     def _finish():
@@ -581,26 +668,38 @@ def _fwd_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
                 l == 0.0, jnp.inf, m + jnp.log(jnp.where(l == 0.0, 1.0, l))))
 
 
-def _q_cell_specs(tiling: _Tiling, n_major: int, h: int, d: int, **geom):
+def _q_cell_specs(tiling: _Tiling, n_major: int, h: int, d: int,
+                  group: int = 1, window: Optional[int] = None, **geom):
     """BlockSpecs of a fwd/dq cell (bh, qi, kj): the q block, the K/V major
     window, and the mask rows of that window. Window ``kj`` is clamped to
-    the last one the q block needs, so a causal step right of the diagonal
-    repeats a block index and costs no DMA."""
+    the ones the q block needs, so a causal step right of the diagonal (or
+    one behind the attention window) repeats a block index and costs no
+    DMA. With grouped heads (``group`` query heads to a key/value head)
+    K/V are indexed by ``bh // group``: nothing is repeated in HBM, and
+    the query heads of a group find their K/V block resident."""
     block_q, block_k, major = tiling
     tiles = major // block_k
 
-    def window(qi, kj):
+    def major_window(qi, kj):
         if n_major == 1:
             return 0
-        _, hi = _k_tile_range(qi, block_q=block_q, block_k=block_k,
-                              mn=jnp.minimum, mx=jnp.maximum, **geom)
-        return jnp.minimum(kj, jnp.maximum(hi - 1, 0) // tiles)
+        if window is None:
+            _, hi = _k_tile_range(qi, block_q=block_q, block_k=block_k,
+                                  mn=jnp.minimum, mx=jnp.maximum, **geom)
+            return jnp.minimum(kj, jnp.maximum(hi - 1, 0) // tiles)
+        lo, _, _, hi = _k_tile_bands(
+            qi, window=window, block_q=block_q, block_k=block_k,
+            mn=jnp.minimum, mx=jnp.maximum, **geom)
+        return jnp.clip(kj, lo // tiles, jnp.maximum(hi - 1, 0) // tiles)
+
+    def kv_head(bh):
+        return bh if group == 1 else bh // group
 
     return (pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, major, d),
-                         lambda bh, qi, kj: (bh, window(qi, kj), 0)),
-            pl.BlockSpec((1, tiles, 1, block_k),
-                         lambda bh, qi, kj: (bh // h, window(qi, kj), 0, 0)))
+            pl.BlockSpec((1, major, d), lambda bh, qi, kj: (
+                kv_head(bh), major_window(qi, kj), 0)),
+            pl.BlockSpec((1, tiles, 1, block_k), lambda bh, qi, kj: (
+                bh // h, major_window(qi, kj), 0, 0)))
 
 
 def _rows_padded(x, mult: int):
@@ -643,11 +742,13 @@ def _compiler_params(kernel: str, tiling: _Tiling, d: int, itemsize: int,
 # host, 36 calls a step); `inline` puts each call's equations into the
 # caller, so the program still holds one Mosaic call per layer under the
 # names it had
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7), inline=True)
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8), inline=True)
 def _flash_fwd(q, k, v, mask, causal: bool, sched: _Schedule,
-               interpret: bool, want_lse: bool):
+               interpret: bool, want_lse: bool,
+               window: Optional[int] = None):
     b, h, t_q, d = q.shape
     t_kv = k.shape[2]
+    group = h // k.shape[1]
     scale = 1.0 / float(d) ** 0.5
     block_q, block_k, major = tiling = sched.fwd
     qr = _rows_padded(q, block_q)
@@ -659,10 +760,10 @@ def _flash_fwd(q, k, v, mask, causal: bool, sched: _Schedule,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, fold_scale=_is_pow2(scale), tiling=tiling,
         n_major=n_major, t_q=t_q, t_kv=t_kv, causal=causal,
-        has_mask=has_mask, want_lse=want_lse)
+        has_mask=has_mask, want_lse=want_lse, window=window)
     qspec, kspec, mspec = _q_cell_specs(
-        tiling, n_major, h, d, t_q=t_q, t_kv=t_kv, causal=causal,
-        has_mask=has_mask)
+        tiling, n_major, h, d, group=group, window=window, t_q=t_q,
+        t_kv=t_kv, causal=causal, has_mask=has_mask)
     in_specs = [qspec, kspec, kspec]
     operands = [qr, kr, vr]
     if has_mask:
@@ -692,7 +793,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, sched: _Schedule,
         compiler_params=_compiler_params("fwd", tiling, d, q.dtype.itemsize,
                                          has_mask),
         interpret=interpret,
-        name="zoo_flash_fwd",
+        name="zoo_flash_fwd" + _name_suffix(window),
     )(*operands)
     o = res[0][:, :t_q, :].reshape(b, h, t_q, d)
     if not want_lse:
@@ -705,13 +806,19 @@ def _is_pow2(x: float) -> bool:
     return math.frexp(x)[0] == 0.5
 
 
+def _name_suffix(window: Optional[int]) -> str:
+    """Window calls carry ``_win`` behind the kernel's name, so that a
+    trace tells a window layer's calls from a full layer's."""
+    return "" if window is None else "_win"
+
+
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
                    n_major: int, t_q: int, t_kv: int, causal: bool,
-                   has_mask: bool):
+                   has_mask: bool, window: Optional[int] = None):
     """Grid (bh, qi, kj), the forward's schedule: dq of one q block
     accumulates over the k tiles of each major window. lse/delta arrive
     (1, 1, 1, block_q), one float a row along lanes, and are stood up into
@@ -750,14 +857,15 @@ def _bwd_dq_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
         if masked:
             p = jnp.where(_visibility(
                 qi, base + j, s.shape, block_q=block_q, block_k=block_k,
-                t_kv=t_kv, offset=offset, causal=causal,
+                t_kv=t_kv, offset=offset, causal=causal, window=window,
                 mask_row=mask_ref[0, j] if has_mask else None), p, 0.0)
         dp = _dot(do, _rows(v_ref, j, block_k), _NT)
         ds = p * (dp - dl_col[:, :1])
         acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
 
     _walk_k_tiles(tile, qi, base, tiles, block_q=block_q, block_k=block_k,
-                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask)
+                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask,
+                  window=window)
 
     @pl.when(kj == n_major - 1)
     def _finish():
@@ -767,17 +875,22 @@ def _bwd_dq_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
                     dv_ref, dk_acc, dv_acc, *, scale: float,
                     fold_scale: bool, tiling: _Tiling, n_major: int,
-                    t_q: int, t_kv: int, causal: bool):
+                    t_q: int, t_kv: int, causal: bool, group: int = 1,
+                    window: Optional[int] = None):
     """Grid (bh, ki, qj): dk/dv of one k block accumulate over the q tiles
-    of each major window, from the causal diagonal on. The tile is formed
-    transposed, ``s^T = k q^T`` (block_k, block_q): the row statistics
-    (1, block_q) broadcast over its sublanes as they are stored, and
-    ``dV += p^T dO``, ``dK += ds^T q`` are plain products."""
+    of each major window, from the causal diagonal on (up to the window's
+    trailing edge, where there is a window). With grouped heads ``bh``
+    counts key/value heads and ``qj`` runs over the major windows of each
+    of the ``group`` query heads that share it, one after the other, into
+    the same accumulators. The tile is formed transposed, ``s^T = k q^T``
+    (block_k, block_q): the row statistics (1, block_q) broadcast over its
+    sublanes as they are stored, and ``dV += p^T dO``, ``dK += ds^T q``
+    are plain products."""
     block_q, block_k, major = tiling
     tiles = major // block_q
     ki = pl.program_id(1)
     qj = pl.program_id(2)
-    base = qj * tiles
+    base = (qj if group == 1 else qj % n_major) * tiles
     offset = t_kv - t_q
 
     @pl.when(qj == 0)
@@ -802,29 +915,42 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
                 jnp.int32, st.shape, 1)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, st.shape, 0)
-            pt = jnp.where(k_pos <= q_pos + offset, pt, 0.0)
+            ok = k_pos <= q_pos + offset
+            if window is not None:
+                ok = ok & (k_pos > q_pos + (offset - window))
+            pt = jnp.where(ok, pt, 0.0)
         dv_acc[:] += _dot(pt.astype(do.dtype), do, _NN)
         dst = pt * (_dot(v, do, _NT) - dl_ref[0, i])
         dk_acc[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-    lo, full, hi = (jnp.clip(n - base, 0, tiles) for n in _q_tile_range(
-        ki, block_q=block_q, block_k=block_k, t_q=t_q, t_kv=t_kv,
-        causal=causal, mn=jnp.minimum, mx=jnp.maximum))
-    if causal:
-        _tile_loop(lo, full, lambda i: tile(i, True))
-    _tile_loop(full, hi, lambda i: tile(i, False))
+    geom = dict(block_q=block_q, block_k=block_k, t_q=t_q, t_kv=t_kv,
+                causal=causal, mn=jnp.minimum, mx=jnp.maximum)
+    if window is None:
+        lo, full, hi = (jnp.clip(n - base, 0, tiles)
+                        for n in _q_tile_range(ki, **geom))
+        if causal:
+            _tile_loop(lo, full, lambda i: tile(i, True))
+        _tile_loop(full, hi, lambda i: tile(i, False))
+    else:
+        lo, b, c, hi = (jnp.clip(n - base, 0, tiles)
+                        for n in _q_tile_bands(ki, window=window, **geom))
+        _tile_loop(lo, b, lambda i: tile(i, True))
+        _tile_loop(b, c, lambda i: tile(i, False))
+        _tile_loop(c, hi, lambda i: tile(i, True))
 
-    @pl.when(qj == n_major - 1)
+    @pl.when(qj == group * n_major - 1)
     def _finish():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9), inline=True)
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10), inline=True)
 def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
-               interpret):
+               interpret, window: Optional[int] = None):
     b, h, t_q, d = q.shape
     t_kv = k.shape[2]
+    h_kv = k.shape[1]
+    group = h // h_kv
     scale = 1.0 / float(d) ** 0.5
     fold = _is_pow2(scale)
     has_mask = mask is not None
@@ -839,14 +965,15 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
     qr, gr = _rows_padded(q, block_q), _rows_padded(g, block_q)
     kr, vr = _rows_padded(k, major), _rows_padded(v, major)
     n_major = kr.shape[1] // major
-    qspec, kspec, mspec = _q_cell_specs(tiling, n_major, h, d,
-                                        has_mask=has_mask, **geom)
+    qspec, kspec, mspec = _q_cell_specs(tiling, n_major, h, d, group=group,
+                                        window=window, has_mask=has_mask,
+                                        **geom)
     rowspec = pl.BlockSpec((1, 1, 1, block_q),
                            lambda bh, qi, kj: (bh, qi, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, fold_scale=fold,
                           tiling=tiling, n_major=n_major, has_mask=has_mask,
-                          **geom),
+                          window=window, **geom),
         grid=(b * h, qr.shape[1] // block_q, n_major),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec]
                  + ([mspec] if has_mask else []),
@@ -858,7 +985,7 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
         compiler_params=_compiler_params("dq", tiling, d, itemsize,
                                          has_mask),
         interpret=interpret,
-        name="zoo_flash_bwd_dq",
+        name="zoo_flash_bwd_dq" + _name_suffix(window),
     )(qr, kr, vr, gr, _stat_rows(lse, qr.shape[1], block_q),
       _stat_rows(delta, qr.shape[1], block_q),
       *([_mask_rows(mask, tiling)] if has_mask else []))
@@ -874,19 +1001,28 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
     def window2(ki, qj):
         if n_major == 1:
             return 0
-        lo, _, hi = _q_tile_range(ki, block_q=block_q, block_k=block_k,
-                                  mn=jnp.minimum, mx=jnp.maximum, **geom)
+        if group > 1:
+            qj = qj % n_major
+        lo, _, _, hi = _q_tile_bands(
+            ki, window=window, block_q=block_q, block_k=block_k,
+            mn=jnp.minimum, mx=jnp.maximum, **geom)
         return jnp.clip(qj, lo // tiles, jnp.maximum(hi - 1, 0) // tiles)
 
-    qspec2 = pl.BlockSpec((1, major, d),
-                          lambda bh, ki, qj: (bh, window2(ki, qj), 0))
+    def q_head(bh, qj):
+        # bh counts key/value heads; step qj belongs to query head
+        # qj // n_major of its group
+        return bh if group == 1 else bh * group + qj // n_major
+
+    qspec2 = pl.BlockSpec((1, major, d), lambda bh, ki, qj: (
+        q_head(bh, qj), window2(ki, qj), 0))
     kspec2 = pl.BlockSpec((1, block_k, d), lambda bh, ki, qj: (bh, ki, 0))
-    rowspec2 = pl.BlockSpec((1, tiles, 1, block_q),
-                            lambda bh, ki, qj: (bh, window2(ki, qj), 0, 0))
+    rowspec2 = pl.BlockSpec((1, tiles, 1, block_q), lambda bh, ki, qj: (
+        q_head(bh, qj), window2(ki, qj), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, fold_scale=fold,
-                          tiling=tiling, n_major=n_major, **geom),
-        grid=(b * h, kr.shape[1] // block_k, n_major),
+                          tiling=tiling, n_major=n_major, group=group,
+                          window=window, **geom),
+        grid=(b * h_kv, kr.shape[1] // block_k, group * n_major),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
         out_shape=[jax.ShapeDtypeStruct(kr.shape, k.dtype),
@@ -895,13 +1031,13 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_compiler_params("dkv", tiling, d, itemsize, False),
         interpret=interpret,
-        name="zoo_flash_bwd_dkv",
+        name="zoo_flash_bwd_dkv" + _name_suffix(window),
     )(qr, kr, vr, gr, _stat_rows(lse, qr.shape[1], block_q),
       _stat_rows(delta, qr.shape[1], block_q))
 
     dq = dq[:, :t_q, :].reshape(b, h, t_q, d)
-    dk = dk[:, :t_kv, :].reshape(b, h, t_kv, d)
-    dv = dv[:, :t_kv, :].reshape(b, h, t_kv, d)
+    dk = dk[:, :t_kv, :].reshape(b, h_kv, t_kv, d)
+    dv = dv[:, :t_kv, :].reshape(b, h_kv, t_kv, d)
     dmask = None
     if has_mask:
         # a hidden key takes part in no row's softmax, so it only ever
@@ -919,25 +1055,28 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
 # public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, mask, causal, sched, interpret):
-    return _flash_fwd(q, k, v, mask, causal, sched, interpret, False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, mask, causal, sched, interpret, window=None):
+    return _flash_fwd(q, k, v, mask, causal, sched, interpret, False, window)
 
 
-def _vjp_fwd(q, k, v, mask, causal, sched, interpret):
-    out, lse = _flash_fwd(q, k, v, mask, causal, sched, interpret, True)
+def _vjp_fwd(q, k, v, mask, causal, sched, interpret, window):
+    out, lse = _flash_fwd(q, k, v, mask, causal, sched, interpret, True,
+                          window)
     return out, (q, k, v, mask, out, lse)
 
 
-def _vjp_bwd(causal, sched, interpret, res, g):
+def _vjp_bwd(causal, sched, interpret, window, res, g):
     q, k, v, mask, out, lse = res
-    return _flash_bwd(q, k, v, mask, out, lse, g, causal, sched, interpret)
+    return _flash_bwd(q, k, v, mask, out, lse, g, causal, sched, interpret,
+                      window)
 
 
 _flash.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def _flash_per_data_shard(q, k, v, mask, causal, sched, interpret):
+def _flash_per_data_shard(q, k, v, mask, causal, sched, interpret,
+                          window=None):
     """Run the kernel once per ``data`` shard. A Mosaic kernel refuses to
     lower inside a jit that spans several devices ("Mosaic kernels cannot
     be automatically partitioned" — found on a four-chip v5e host, PR 21:
@@ -953,11 +1092,11 @@ def _flash_per_data_shard(q, k, v, mask, causal, sched, interpret):
     mesh = mesh_lib.global_mesh()
     dp = mesh.shape[mesh_lib.DATA_AXIS]
     if dp == 1 or q.shape[0] % dp or mesh_lib.in_manual_region():
-        return _flash(q, k, v, mask, causal, sched, interpret)
+        return _flash(q, k, v, mask, causal, sched, interpret, window)
     args = (q, k, v) if mask is None else (q, k, v, mask)
 
     def local(q, k, v, m=None):
-        return _flash(q, k, v, m, causal, sched, interpret)
+        return _flash(q, k, v, m, causal, sched, interpret, window)
 
     batch = P(mesh_lib.DATA_AXIS)
     return jax.shard_map(local, mesh=mesh, in_specs=(batch,) * len(args),
@@ -968,8 +1107,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     mask: Optional[jax.Array] = None,
                     causal: bool = False, block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Blockwise-softmax attention: q/k/v (B, H, T, D) → (B, H, Tq, D).
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
+    """Blockwise-softmax attention: q (B, Hq, T, D), k/v (B, Hkv, T, D)
+    with ``Hq % Hkv == 0`` → (B, Hq, Tq, D). Query head ``h`` attends
+    key/value head ``h // (Hq / Hkv)``; K/V are indexed so by the block
+    specs (nothing is repeated in HBM) and dk/dv accumulate over the query
+    heads of a group inside the dkv kernel.
+
+    ``window`` (causal calls): a query sees the ``window`` latest keys up
+    to and with its own position, ``i - window < j <= i``. The k-tile loop
+    of fwd and dq and the q-tile loop of dkv are then bounded on both
+    sides, and the mask is built on the tiles the diagonal or the window's
+    trailing edge cuts; a window no shorter than the keys is no window.
 
     ``mask``: optional per-batch key-padding keep-mask, (B, Tk), a BINARY
     contract: values >= 1.0 attend, anything below is hidden — matching the
@@ -1005,15 +1155,27 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              f"at the layer level")
         mask = jax.lax.stop_gradient(mask.astype(jnp.float32))
     has_mask = mask is not None
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"flash_attention: {q.shape[1]} query heads do not "
+                         f"divide over {k.shape[1]} key / {v.shape[1]} "
+                         f"value heads")
+    group = q.shape[1] // k.shape[1]
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("flash_attention: window needs causal=True "
+                             "and window >= 1")
+        if window >= k.shape[2]:
+            window = None
     if block_q is None and block_k is None:
         sched = _auto_blocks(q.shape, k.shape[2], q.dtype, causal, has_mask,
-                             interpret)
+                             interpret, window, group)
     else:
         if block_q is None or block_k is None:
             auto = _auto_blocks(q.shape, k.shape[2], q.dtype, causal,
-                                has_mask, interpret)
+                                has_mask, interpret, window, group)
             block_q = block_q if block_q is not None else auto.dq.block_q
             block_k = block_k if block_k is not None else auto.dq.block_k
         sched = _resolve_schedule(q.shape[2], k.shape[2], q.shape[3],
                                   q.dtype, has_mask, block_q, block_k)
-    return _flash_per_data_shard(q, k, v, mask, causal, sched, interpret)
+    return _flash_per_data_shard(q, k, v, mask, causal, sched, interpret,
+                                 window)

@@ -5,7 +5,8 @@ from .core import (Activation, Dense, Dropout, Flatten, Reshape, Permute,  # noq
                    TimeDistributed, Highway, SparseDense, get_activation)
 from .embeddings import (Embedding, ShardedEmbedding, SparseEmbedding,  # noqa: F401
                          WordEmbedding)
-from .normalization import BatchNormalization, LayerNorm, L2Normalize  # noqa: F401
+from .normalization import (BatchNormalization, LayerNorm,  # noqa: F401
+                            L2Normalize, RMSNorm)
 from .convolution import (AtrousConvolution1D, AtrousConvolution2D,  # noqa: F401
                           Convolution1D, Convolution2D, Cropping1D,
                           Cropping2D, Deconvolution2D,
@@ -33,7 +34,8 @@ from .elementwise import (AddConstant, CAdd, CMul, Exp, Expand,  # noqa: F401
                           Negative, Power, ResizeBilinear, Scale, Sqrt,
                           Square)
 from .gpipe import GPipe, Pipeline  # noqa: F401
-from .moe import SparseMoE  # noqa: F401
+from .moe import RoutedExperts, SparseMoE  # noqa: F401
 from .recurrent import GRU, LSTM, Bidirectional, SimpleRNN  # noqa: F401
-from .self_attention import (BERT, MultiHeadSelfAttention,  # noqa: F401
+from .self_attention import (BERT, DecoderAttention, DecoderBlock,  # noqa: F401
+                             DecoderStack, MultiHeadSelfAttention,
                              TransformerBlock, TransformerLayer)

@@ -101,6 +101,26 @@ class LayerNorm(Layer):
         return y.astype(x.dtype)
 
 
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the last axis (Zhang & Sennrich
+    2019): ``x / sqrt(mean(x^2) + epsilon) * gamma``, no mean subtracted and
+    no bias. The statistics are taken in float32 whatever the compute
+    dtype."""
+
+    def __init__(self, epsilon: float = 1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self.epsilon = epsilon
+
+    def build(self, rng, input_shape):
+        return {"gamma": jnp.ones((input_shape[-1],), param_dtype())}
+
+    def call(self, params, x, *, training=False, rng=None):
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(
+            jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + self.epsilon)
+        return (y * params["gamma"]).astype(x.dtype)
+
+
 class L2Normalize(Layer):
     """autograd ``l2Normalize`` as a layer (``autograd/math.scala``)."""
 

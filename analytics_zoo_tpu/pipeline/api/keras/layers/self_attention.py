@@ -12,19 +12,27 @@ pyzoo ``pipeline/api/keras/layers/self_attention.py``).
   (``bidirectional=False`` ≙ the reference's maskAttention GPT mode).
 * ``BERT`` — word+position+token-type embeddings, N bidirectional blocks with
   an attention mask input, plus the tanh pooler over [CLS].
+* ``DecoderAttention`` / ``DecoderBlock`` / ``DecoderStack`` — the pre-norm
+  decoder of today's open models: RMSNorm, rotary positions (plain or YaRN)
+  in place of a position table, grouped key/value heads with a head size
+  of their own, a causal window per layer (``layer_types``), no biases, and
+  any feed-forward layer per block (``RoutedExperts``). The post-LN
+  classes above keep their behaviour and their parameter trees.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.ops.attention import (dot_product_attention,
-                                             merge_heads, split_heads)
+from analytics_zoo_tpu.ops.attention import (apply_rotary,
+                                             dot_product_attention,
+                                             merge_heads, rotary_inv_freq,
+                                             rotary_tables, split_heads)
 from ..engine import Layer, compute_dtype, get_initializer, param_dtype
-from .normalization import LayerNorm
+from .normalization import LayerNorm, RMSNorm
 
 
 def _dense_params(rng, d_in, d_out, init="glorot_uniform"):
@@ -483,3 +491,207 @@ class BERT(Layer):
                          rng=br)
         pooled = jnp.tanh(_dense(params["pooler"], h[:, 0, :], cd))
         return [h, pooled]
+
+
+# ---------------------------------------------------------------------------
+# the pre-norm decoder
+# ---------------------------------------------------------------------------
+
+def _project(w, x, cd):
+    return jnp.einsum("...d,dk->...k", x.astype(cd), w.astype(cd),
+                      preferred_element_type=jnp.float32).astype(cd)
+
+
+class DecoderAttention(MultiHeadSelfAttention):
+    """Causal self-attention of a pre-norm decoder: ``n_head`` query heads
+    and ``n_kv_head`` key/value heads of ``head_dim`` (query head ``h``
+    attends key/value head ``h // (n_head / n_kv_head)``; ``n_head *
+    head_dim`` need not be the hidden size), rotary positions on q and k,
+    an optional ``window`` (None = full: a query sees the ``window`` latest
+    keys with its own position), no biases. ``rotary`` is a
+    ``rope_parameters`` entry (``ops.attention.rotary_inv_freq``); its
+    frequencies and attention factor are made once, here. Routing between
+    the Pallas flash kernels and the XLA op is ``_use_flash``'s, as for
+    ``MultiHeadSelfAttention``. Input (B, T, H), or ``[x, (cos, sin)]``
+    with the rotary tables of the call's positions (``DecoderStack`` forms
+    them once per kind of layer)."""
+
+    def __init__(self, hidden_size: int, n_head: int, n_kv_head: int,
+                 head_dim: int, rotary: Mapping[str, Any],
+                 window: Optional[int] = None, **kwargs):
+        Layer.__init__(self, **kwargs)
+        if n_head % n_kv_head:
+            raise ValueError(f"n_head {n_head} not divisible by n_kv_head "
+                             f"{n_kv_head}")
+        self.hidden_size = hidden_size
+        self.n_head, self.n_kv_head, self.head_dim = n_head, n_kv_head, head_dim
+        self.causal = True
+        self.window = window
+        self.inv_freq, self.rotary_scale = rotary_inv_freq(head_dim, rotary)
+
+    def build(self, rng, input_shape):
+        k = jax.random.split(rng, 4)
+        init = get_initializer("glorot_uniform")
+        h, q, kv = (self.hidden_size, self.n_head * self.head_dim,
+                    self.n_kv_head * self.head_dim)
+        return {"Wq": init(k[0], (h, q), param_dtype()),
+                "Wk": init(k[1], (h, kv), param_dtype()),
+                "Wv": init(k[2], (h, kv), param_dtype()),
+                "Wo": init(k[3], (q, h), param_dtype())}
+
+    def param_sharding(self, params):
+        return jax.tree.map(lambda _: None, params)
+
+    def tables(self, t: int):
+        """float32 (cos, sin) of positions 0..t-1 for this layer's kind."""
+        with jax.named_scope("zoo_attn.rope"):
+            return rotary_tables(self.inv_freq, self.rotary_scale, t)
+
+    def call(self, params, x, *, training=False, rng=None):
+        tables = None
+        if isinstance(x, (list, tuple)):
+            x, tables = x
+        cd = compute_dtype()
+        t = x.shape[1]
+        q = split_heads(_project(params["Wq"], x, cd), self.n_head)
+        k = split_heads(_project(params["Wk"], x, cd), self.n_kv_head)
+        v = split_heads(_project(params["Wv"], x, cd), self.n_kv_head)
+        cos, sin = tables if tables is not None else self.tables(t)
+        with jax.named_scope("zoo_attn.rope"):
+            q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        if self._use_flash(None, 0.0, t):
+            from .....ops.pallas import flash_attention
+            out = flash_attention(q, k, v, causal=True, window=self.window)
+        else:
+            out = dot_product_attention(q, k, v, causal=True,
+                                        window=self.window)
+        return _project(params["Wo"], merge_heads(out), cd)
+
+
+class DecoderBlock(Layer):
+    """Pre-norm residual block: ``h = x + Attn(RMSNorm(x))``;
+    ``x' = h + FFN(RMSNorm(h))``. ``ffn`` is the block's feed-forward
+    layer (``RoutedExperts``; any layer from (B, T, H) to (B, T, H)),
+    whose state, if it keeps one, is the block's."""
+
+    def __init__(self, hidden_size: int, attn: DecoderAttention, ffn: Layer,
+                 epsilon: float = 1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self.hidden_size = hidden_size
+        self.attn, self.ffn = attn, ffn
+        self.ln1 = RMSNorm(epsilon=epsilon)
+        self.ln2 = RMSNorm(epsilon=epsilon)
+
+    def build(self, rng, input_shape):
+        k = jax.random.split(rng, 4)
+        return {"ln1": self.ln1.build(k[0], input_shape),
+                "attn": self.attn.build(k[1], input_shape),
+                "ln2": self.ln2.build(k[2], input_shape),
+                "ffn": self.ffn.build(k[3], input_shape)}
+
+    def initial_state(self, input_shape=None):
+        s = self.ffn.initial_state(input_shape)
+        return {"ffn": s} if s else {}
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        tables = None
+        if isinstance(x, (list, tuple)):
+            x, tables = x
+        a = self.ln1.call(params["ln1"], x)
+        h = x + self.attn.call(params["attn"],
+                               [a, tables] if tables is not None else a,
+                               training=training)
+        f, ns = self.ffn.apply(params["ffn"], (state or {}).get("ffn", {}),
+                               self.ln2.call(params["ln2"], h),
+                               training=training, rng=rng)
+        return h + f, ({"ffn": ns} if ns else {})
+
+    def call(self, params, x, *, training=False, rng=None):
+        return self.apply(params, {}, x, training=training, rng=rng)[0]
+
+
+class DecoderStack(Layer):
+    """Token embedding (no position table), one ``DecoderBlock`` per entry
+    of ``layer_types`` (``"sliding_attention"``: the block's attention has
+    the ``sliding_window``; ``"full_attention"``: none), a final RMSNorm.
+    Input int ids (B, T) -> hidden states (B, T, H); put a
+    ``Dense(vocab, bias=False)`` behind it for an untied head.
+
+    ``rope_parameters`` maps each layer type to its rotary specification
+    (or is one specification for all). ``ffn(i)`` returns block ``i``'s
+    feed-forward layer. ``remat=True`` rematerialises each block in the
+    backward pass (``jax.checkpoint``, as ``GPipe(remat=)`` does): the
+    step keeps one block's activations at a time."""
+
+    SLIDING, FULL = "sliding_attention", "full_attention"
+
+    def __init__(self, vocab: int, layer_types: Sequence[str],
+                 hidden_size: int, n_head: int, n_kv_head: int,
+                 head_dim: int, ffn: Callable[[int], Layer],
+                 rope_parameters: Mapping[str, Any],
+                 sliding_window: Optional[int] = None,
+                 epsilon: float = 1e-6, initializer_range: float = 0.02,
+                 remat: bool = False, **kwargs):
+        super().__init__(**kwargs)
+        self.vocab, self.hidden_size = vocab, hidden_size
+        self.layer_types = tuple(layer_types)
+        self.initializer_range = initializer_range
+        self.remat = remat
+        self.blocks = []
+        for i, kind in enumerate(self.layer_types):
+            if kind not in (self.SLIDING, self.FULL):
+                raise ValueError(f"layer_types[{i}] = {kind!r}")
+            if kind == self.SLIDING and not sliding_window:
+                raise ValueError("sliding_attention layers need "
+                                 "sliding_window")
+            attn = DecoderAttention(
+                hidden_size, n_head, n_kv_head, head_dim,
+                rotary=rope_parameters.get(kind, rope_parameters),
+                window=sliding_window if kind == self.SLIDING else None,
+                name=f"{self.name}_block{i}_attn")
+            self.blocks.append(DecoderBlock(
+                hidden_size, attn, ffn(i), epsilon=epsilon,
+                name=f"{self.name}_block{i}"))
+        self.norm = RMSNorm(epsilon=epsilon)
+
+    def build(self, rng, input_shape):
+        keys = jax.random.split(rng, len(self.blocks) + 2)
+        h_shape = (input_shape[0], input_shape[1], self.hidden_size)
+        p: Dict[str, Any] = {
+            "wte": jax.random.normal(keys[0], (self.vocab, self.hidden_size),
+                                     param_dtype()) * self.initializer_range,
+            "norm": self.norm.build(keys[1], h_shape)}
+        for i, blk in enumerate(self.blocks):
+            p[f"block{i}"] = blk.build(keys[i + 2], h_shape)
+        return p
+
+    def initial_state(self, input_shape=None):
+        state = {}
+        for i, blk in enumerate(self.blocks):
+            s = blk.initial_state(input_shape)
+            if s:
+                state[f"block{i}"] = s
+        return state
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        ids = x.astype(jnp.int32)
+        h = jnp.take(params["wte"], ids, axis=0).astype(compute_dtype())
+        # one pair of tables per kind of layer, shared by its blocks
+        tables = {}
+        for blk, kind in zip(self.blocks, self.layer_types):
+            if kind not in tables:
+                tables[kind] = blk.attn.tables(ids.shape[1])
+        new_state = {}
+        for i, (blk, kind) in enumerate(zip(self.blocks, self.layer_types)):
+            def run(p, s, h, tab, blk=blk):
+                return blk.apply(p, s, [h, tab], training=training)
+            if self.remat:
+                run = jax.checkpoint(run)
+            h, ns = run(params[f"block{i}"],
+                        (state or {}).get(f"block{i}", {}), h, tables[kind])
+            if ns:
+                new_state[f"block{i}"] = ns
+        return self.norm.call(params["norm"], h), new_state
+
+    def call(self, params, x, *, training=False, rng=None):
+        return self.apply(params, {}, x, training=training, rng=rng)[0]

@@ -603,6 +603,59 @@ class TrainingLoop:
             self._gp_note("device_step")
         return mean
 
+    def _moe_report(self, before: Dict[str, Dict[str, Any]]
+                    ) -> Optional[Dict[str, Any]]:
+        """The routed layers' counters over the fit that just ended, read
+        from the layer state once, after the fit's last drain (no readback
+        a step), and published: ``zoo_moe_assignments_total{layer=,held=}``,
+        ``zoo_moe_dropped_assignments_total``, and of the fit's last step
+        ``zoo_moe_expert_tokens{layer=,expert=}`` and
+        ``zoo_moe_load_max_over_mean{layer=}`` (largest held expert's
+        tokens over the held experts' mean). ``None`` for a model without
+        such layers."""
+        from .layers.moe import routed_layer_totals
+        after = routed_layer_totals(self.model.net_state)
+        if not after:
+            return None
+        reg = self._registry
+        report: Dict[str, Any] = {"layers": {}, "held": 0, "absent": 0,
+                                  "dropped": 0}
+        for name, now in after.items():
+            was = before.get(name, {})
+            layer = {k: now[k] - was.get(k, 0)
+                     for k in ("held", "absent", "dropped")}
+            layer["expert_tokens"] = now["expert_tokens"]
+            mean = sum(now["held_tokens"]) / max(len(now["held_tokens"]), 1)
+            layer["load_max_over_mean"] = (
+                max(now["held_tokens"]) / mean if mean > 0 else 0.0)
+            report["layers"][name] = layer
+            for key in ("held", "absent", "dropped"):
+                report[key] += layer[key]
+            for held, key in (("true", "held"), ("false", "absent")):
+                reg.counter(  # zoolint: disable=ZL015 one series a layer
+                    "zoo_moe_assignments_total",
+                    "token-to-expert assignments routed by a RoutedExperts "
+                    "layer: computed here (held=true) or left to absent "
+                    "experts (held=false)",
+                    labels={"layer": name, "held": held}).inc(layer[key])
+            for e, n in enumerate(now["expert_tokens"]):
+                reg.gauge(  # zoolint: disable=ZL015 bounded: router width
+                    "zoo_moe_expert_tokens",
+                    "assignments per router output in the last step of "
+                    "the last fit",
+                    labels={"layer": name, "expert": str(e)}).set(n)
+            reg.gauge(  # zoolint: disable=ZL015 one series a layer
+                "zoo_moe_load_max_over_mean",
+                "largest held expert's tokens over the held experts' mean, "
+                "last step of the last fit",
+                labels={"layer": name}).set(layer["load_max_over_mean"])
+        reg.counter(
+            "zoo_moe_dropped_assignments_total",
+            "assignments a RoutedExperts layer placed with no expert "
+            "(0 by construction: the layer has no capacity)"
+        ).inc(report["dropped"])
+        return report
+
     def _fit_report(self, t_open: float, t_end: float,
                     host_before: Dict[str, float],
                     compile_before: Dict[str, Dict[str, float]]
@@ -1539,6 +1592,8 @@ class TrainingLoop:
             self._goodput.open(t_open)
         host_before = self._phases.seconds()
         compile_before = xla_compile_totals()
+        from .layers.moe import routed_layer_totals
+        moe_before = routed_layer_totals(self.model.net_state)
         try:
             with profiling.trace(profile_dir), span("train.fit",
                                                     registry=self._registry):
@@ -1557,6 +1612,13 @@ class TrainingLoop:
                 self._goodput.note("idle", t_end)
             self.model.last_fit_report = self._fit_report(
                 t_open, t_end, host_before, compile_before)
+            try:
+                moe = self._moe_report(moe_before)
+            except Exception:   # accounting must not mask the fit's own error
+                log.exception("routed-layer counters could not be read")
+                moe = None
+            if moe is not None:
+                self.model.last_fit_report["moe"] = moe
             self._probe.clear()
             # the boundary clone holds whole param trees — never past fit
             self._boundary_ref = None
@@ -1705,6 +1767,11 @@ class TrainingLoop:
 
         if model.params is None:
             model.init_weights(rng=rng, sample_input=fs.sample(1))
+        if not model.net_state:
+            # weights installed by hand and the state reset: the layers
+            # that keep state start from their own (none: {} again), so
+            # that the step sees one state structure from its first call
+            model.net_state = model.initial_state()
         if scan_steps > 1 and self._scan_step is None:
             self.build_scan_step()
         if self._train_step is None:

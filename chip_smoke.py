@@ -23,6 +23,12 @@ Phases (any failure makes the exit code non-zero; none is skipped):
   (flash fwd/dq/dkv, fused-CE fwd/dh/dW) and its lowered module must hold
   them as Mosaic custom calls; then each Pallas kernel against its
   float32 ``jax.numpy`` reference.
+* ``decoder`` — the decoder configuration's kernels at its widths: flash
+  attention with 32 query / 4 key-value heads of 128 at T = 8192, a
+  1024-key window and full, forward and dq/dk/dv against ``ops.attention``
+  in float32; the experts' grouped product over 32768 rows in 8 groups of
+  Zipf sizes (2304 x 896), forward and both gradients against a per-group
+  float32 product.
 * ``serve``   — ResNet-50 at 224x224 through the serving stack, 64 frames
   from two producer threads, every answer equal to a direct ``predict``.
 
@@ -555,6 +561,145 @@ def phase_kernels(*, seq_len: int = 4096, batch: int = 4, n_seqs: int = 8,
 
 
 # ---------------------------------------------------------------------------
+# phase: decoder (window / grouped-head flash, the experts' grouped product)
+# ---------------------------------------------------------------------------
+
+def grouped_flash_parity(*, n_head: int, n_kv_head: int, seq_len: int,
+                         head_dim: int, window: int,
+                         require_mosaic: bool = True) -> Dict[str, float]:
+    """Flash forward and dq/dk/dv with grouped heads (B = 1), a
+    ``window``-key window and full, bf16 operands, against
+    ``ops.attention`` in float32 at ``highest`` precision on the same
+    (upcast) inputs. The reference goes one key/value head at a time (its
+    float32 scores of all 32 query heads at T = 8192 would be 8.6 GB).
+    Bounds as ``flash_parity``'s; dk and dv sum ``group`` query heads'
+    contributions in float32 inside the kernel, which adds no rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from analytics_zoo_tpu.ops.attention import dot_product_attention
+    from analytics_zoo_tpu.ops.pallas import flash_attention
+
+    group = n_head // n_kv_head
+    errors: Dict[str, float] = {}
+    rng = np.random.default_rng(41)
+    q = jnp.asarray(rng.normal(size=(1, n_head, seq_len, head_dim)),
+                    jnp.bfloat16)
+    k, v = (jnp.asarray(rng.normal(size=(1, n_kv_head, seq_len, head_dim)),
+                        jnp.bfloat16) for _ in range(2))
+    g = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+    for tag, win in (("window", window), ("full", None)):
+        def kernel(q, k, v, win=win):
+            def f(q, k, v):
+                o = flash_attention(q, k, v, causal=True, window=win)
+                return jnp.sum(o.astype(jnp.float32) * g), o
+            (_, o), grads = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                               has_aux=True)(q, k, v)
+            return (o,) + grads
+
+        def reference(q, k, v, g, win=win):
+            def f(q, k, v):
+                o = dot_product_attention(q, k, v, causal=True, window=win)
+                return jnp.sum(o * g), o
+            (_, o), grads = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                               has_aux=True)(q, k, v)
+            return (o,) + grads
+
+        suffix = "_win" if win is not None and win < seq_len else ""
+        got = _run_kernel(kernel, (q, k, v), tuple(
+            name + suffix for name in ("zoo_flash_fwd", "zoo_flash_bwd_dq",
+                                       "zoo_flash_bwd_dkv")), require_mosaic)
+        ref = jax.jit(reference)
+        worst = dict.fromkeys(("out", "dq", "dk", "dv"), 0.0)
+        for h in range(n_kv_head):
+            heads = slice(h * group, (h + 1) * group)
+            with jax.default_matmul_precision("highest"):
+                want = ref(q[:, heads].astype(jnp.float32),
+                           k[:, h:h + 1].astype(jnp.float32),
+                           v[:, h:h + 1].astype(jnp.float32), g[:, heads])
+            parts = (got[0][:, heads], got[1][:, heads], got[2][:, h:h + 1],
+                     got[3][:, h:h + 1])
+            for name, a, w in zip(worst, parts, want):
+                worst[name] = max(worst[name], _scaled_err(a, w))
+        for name, err in worst.items():
+            errors[f"flash_gqa_{tag}_{name}"] = err
+    _check(errors, {k: (2 if k.endswith("_out") else 4) * BF16_EPS
+                    for k in errors})
+    return errors
+
+
+def grouped_matmul_parity(*, rows: int, groups: int, d_in: int, d_out: int
+                          ) -> Dict[str, float]:
+    """``ops.grouped_matmul`` forward, ``dx`` and ``dW`` on bf16 operands
+    against one float32 product a group. The group sizes follow a Zipf law
+    and leave the last tenth of the rows to no group: those rows of the
+    result and of ``dx`` must come back zero. One rounding to bf16 on the
+    way out of each product: 2 eps (dW accumulates in float32 and is
+    compared in the weights' dtype)."""
+    import jax
+    import jax.numpy as jnp
+
+    from analytics_zoo_tpu.ops.grouped_matmul import grouped_matmul
+
+    rng = np.random.default_rng(43)
+    share = 1.0 / np.arange(1, groups + 1)
+    sizes = np.floor(share / share.sum() * rows * 0.9).astype(np.int32)
+    x = jnp.asarray(rng.normal(size=(rows, d_in)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(groups, d_in, d_out)) / np.sqrt(d_in),
+                    jnp.bfloat16)
+    g = jnp.asarray(rng.normal(size=(rows, d_out)), jnp.bfloat16)
+
+    def kernel(x, w, sizes):
+        y, vjp = jax.vjp(lambda x, w: grouped_matmul(x, w, sizes), x, w)
+        return (y,) + vjp(g)
+
+    def reference(x, w, g):
+        bounds = np.concatenate([[0], np.cumsum(sizes)])
+
+        def f(x, w):
+            parts = [x[lo:hi] @ w[i] for i, (lo, hi) in
+                     enumerate(zip(bounds[:-1], bounds[1:]))]
+            parts.append(jnp.zeros((rows - int(bounds[-1]), d_out),
+                                   jnp.float32))
+            return jnp.concatenate(parts, axis=0)
+        y, vjp = jax.vjp(f, x, w)
+        return (y,) + vjp(g)
+
+    got = jax.jit(kernel)(x, w, jnp.asarray(sizes))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(reference)(*(a.astype(jnp.float32)
+                                    for a in (x, w, g)))
+    errors = {f"grouped_matmul_{name}": _scaled_err(a, b)
+              for name, a, b in zip(("out", "dx", "dw"), got, want)}
+    _check(errors, dict.fromkeys(errors, 2 * BF16_EPS))
+    tail = int(sizes.sum())
+    for name, a in zip(("out", "dx"), got):
+        if np.any(np.asarray(a[tail:], np.float32) != 0.0):
+            raise AssertionError(f"grouped_matmul {name}: rows past the "
+                                 f"last group are not zero")
+    return errors
+
+
+def phase_decoder(*, n_head: int = 32, n_kv_head: int = 4,
+                  seq_len: int = 8192, head_dim: int = 128,
+                  window: int = 1024, gmm_shape=(32768, 8, 2304, 896),
+                  conf: Optional[Mapping[str, Any]] = None,
+                  require_mosaic: bool = True) -> Dict[str, Any]:
+    restore = _with_context(None, conf)
+    try:
+        errors = grouped_flash_parity(
+            n_head=n_head, n_kv_head=n_kv_head, seq_len=seq_len,
+            head_dim=head_dim, window=window, require_mosaic=require_mosaic)
+        rows, groups, d_in, d_out = gmm_shape
+        errors.update(grouped_matmul_parity(rows=rows, groups=groups,
+                                            d_in=d_in, d_out=d_out))
+        return {"reference_errors": {k: float(f"{v:.3e}")
+                                     for k, v in errors.items()}}
+    finally:
+        restore()
+
+
+# ---------------------------------------------------------------------------
 # phase: serve (ResNet-50 through the serving stack)
 # ---------------------------------------------------------------------------
 
@@ -733,6 +878,7 @@ def main() -> int:
             mesh={"data": n_dev // 2, "model": 2},
             expect_model_sharded=True), results)
     run_phase("kernels", phase_kernels, results)
+    run_phase("decoder", phase_decoder, results)
     run_phase("serve", phase_serve, results)
 
     return 0 if print_result(results, device) else 1

@@ -91,6 +91,18 @@ def test_kernels_phase_tiny_interpreted():
         "embed_expand_float32", "int8_matmul"}
 
 
+def test_decoder_phase_tiny_interpreted():
+    """Window / grouped-head flash (T not a multiple of the tile) and the
+    grouped product, each against its float32 reference."""
+    out = chip_smoke.phase_decoder(
+        n_head=4, n_kv_head=2, seq_len=200, head_dim=16, window=24,
+        gmm_shape=(96, 3, 16, 8), require_mosaic=False)
+    assert set(out["reference_errors"]) == {
+        f"flash_gqa_{tag}_{name}" for tag in ("window", "full")
+        for name in ("out", "dq", "dk", "dv")} | {
+        "grouped_matmul_out", "grouped_matmul_dx", "grouped_matmul_dw"}
+
+
 def test_kernels_phase_fails_without_mosaic_calls():
     with pytest.raises(AssertionError, match="lacks Mosaic calls"):
         chip_smoke.phase_kernels(

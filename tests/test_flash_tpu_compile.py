@@ -31,20 +31,23 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_grads(one_chip, b, h, t_q, t_kv, d, dtype, causal, masked):
+def _compile_grads(one_chip, b, h, t_q, t_kv, d, dtype, causal, masked,
+                   window=None, group=1):
     q = jax.ShapeDtypeStruct((b, h, t_q, d), dtype, sharding=one_chip)
-    k = jax.ShapeDtypeStruct((b, h, t_kv, d), dtype, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, h // group, t_kv, d), dtype,
+                             sharding=one_chip)
     keep = jax.ShapeDtypeStruct((b, t_kv), jnp.float32, sharding=one_chip)
 
     # the kernels themselves at the schedule the public entry resolves:
     # the entry's per-data-shard wrapper would take the test process's
     # CPU mesh
-    sched = fa._auto_blocks(q.shape, t_kv, dtype, causal, masked, False)
+    sched = fa._auto_blocks(q.shape, t_kv, dtype, causal, masked, False,
+                            window, group)
 
     def grads(q, k, v, keep):
         return jax.grad(lambda q, k, v: jnp.sum(fa._flash(
             q, k, v, keep if masked else None, causal, sched,
-            False).astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+            False, window).astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
 
     return jax.jit(grads).lower(q, k, k, keep).compile()
 
@@ -79,3 +82,24 @@ def test_flash_kernels_compile_for_v5e(one_chip, b, h, t_q, t_kv, d, dtype,
     # the forward's saved statistic is one float a row: no f32 result of
     # any kernel is 128 wide per row
     assert not re.search(r"f32\[\d+,%d,128\]" % t_q, "\n".join(calls))
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_grouped_window_flash_compiles_for_v5e(one_chip, window):
+    """The two signatures of the decoder configuration's step: 32 query /
+    4 key-value heads of 128 at T = 8192, a 1024-key window and full. K/V
+    come in at 4 heads and dk/dv go out at 4: nothing is repeated."""
+    text = _compile_grads(one_chip, 1, 32, 8192, 8192, 128, jnp.bfloat16,
+                          True, False, window=window, group=8).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    names = sorted(ln.split("=")[0].strip() for ln in calls)
+    assert len(names) == 3, names
+    suffix = "_win" if window else ""
+    for marker in ("zoo_flash_fwd", "zoo_flash_bwd_dq", "zoo_flash_bwd_dkv"):
+        assert sum(marker + suffix in n for n in names) == 1, (marker, names)
+        if not window:
+            assert not any("_win" in n for n in names), names
+    dkv = next(ln for ln in calls if "zoo_flash_bwd_dkv" in ln)
+    assert "bf16[4,8192,128]" in dkv.split("custom-call(")[0], dkv[:200]
+    assert "bf16[32,8192,128]" not in dkv.split("custom-call(")[0]

@@ -287,6 +287,20 @@ _SCHEDULE_CASES = {
     # fully padded ones
     "mask_padded_tile": (384, 512, False, 200, None, False),
     "mask_causal_windows": (512, 512, True, 300, 0.5, True),
+    # a causal window and grouped heads (two more fields: window, query
+    # heads to a key/value head); T = 450 is not a multiple of the tile.
+    # window 8 lies inside one tile, 128 spans a tile's edge
+    "gqa4_full_unaligned": (450, 450, True, None, None, False, None, 4),
+    "window8_unaligned": (450, 450, True, None, None, False, 8, 1),
+    "window8_gqa4_unaligned": (450, 450, True, None, None, False, 8, 4),
+    "window128_unaligned": (450, 450, True, None, None, False, 128, 1),
+    "window128_gqa4_unaligned": (450, 450, True, None, None, False, 128, 4),
+    # several major windows: the steps behind the window are skipped ones
+    "windows_window128_gqa4": (640, 640, True, None, 0.5, True, 128, 4),
+    # a window with a key-padding mask: every tile of it a masked one
+    # (keys hidden from 450 on: the last row's window still holds 65 keys
+    # that are not)
+    "mask_window128": (512, 512, True, 450, None, False, 128, 1),
 }
 
 
@@ -301,8 +315,13 @@ def test_flash_schedule_matches_xla(name):
                                                   reset_zoo_context)
     fa_mod = importlib.import_module(
         "analytics_zoo_tpu.ops.pallas.flash_attention")
-    t_q, t_kv, causal, hide_from, budget_mb, several = _SCHEDULE_CASES[name]
+    (t_q, t_kv, causal, hide_from, budget_mb, several,
+     *extra) = _SCHEDULE_CASES[name]
+    window, group = extra or (None, 1)
     q, k, v = _qkv(2, 2, t_q, t_kv, 8, seed=40)
+    if group > 1:       # `group` query heads to each of the 2 k/v heads
+        q = jnp.asarray(np.random.default_rng(42).normal(
+            size=(2, 2 * group, t_q, 8)), jnp.float32)
     mask = None
     if hide_from is not None:
         keep = np.ones((2, t_kv), np.float32)
@@ -313,12 +332,13 @@ def test_flash_schedule_matches_xla(name):
 
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, mask=mask, causal=causal,
-                                       block_q=128, block_k=128) * g)
+                                       block_q=128, block_k=128,
+                                       window=window) * g)
 
     def loss_ref(q, k, v):
         m4 = None if mask is None else mask[:, None, None, :]
-        return jnp.sum(dot_product_attention(q, k, v, mask=m4,
-                                             causal=causal) * g)
+        return jnp.sum(dot_product_attention(q, k, v, mask=m4, causal=causal,
+                                             window=window) * g)
 
     try:
         reset_zoo_context()
@@ -331,12 +351,14 @@ def test_flash_schedule_matches_xla(name):
                                                     sched)]
         assert all(n > 1 if several else n == 1 for n in windows), sched
         out = flash_attention(q, k, v, mask=mask, causal=causal,
-                              block_q=128, block_k=128)
+                              block_q=128, block_k=128, window=window)
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     finally:
         reset_zoo_context()
+    assert gf[1].shape == k.shape and gf[2].shape == v.shape
     m4 = None if mask is None else mask[:, None, None, :]
-    ref = np.asarray(dot_product_attention(q, k, v, mask=m4, causal=causal))
+    ref = np.asarray(dot_product_attention(q, k, v, mask=m4, causal=causal,
+                                           window=window))
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     out = np.asarray(out)
     # rows that see no key: zeros here, uniform weights in the oracle
@@ -369,44 +391,90 @@ def test_flash_schedule_matches_xla(name):
                                       0.0)
 
 
-@pytest.mark.parametrize("t_q,t_kv,causal,has_mask,tiling", [
-    (4096, 4096, True, False, (512, 1024, 4096)),
-    (4096, 4096, True, False, (256, 512, 4096)),
-    (4096, 4096, True, False, (256, 512, 1024)),     # several windows
-    (1000, 1000, True, False, (256, 512, 1024)),
-    (512, 200, True, False, (128, 128, 256)),        # q blocks see nothing
-    (2048, 2048, False, True, (512, 1024, 2048)),    # mask: all masked
-    (2048, 2048, False, False, (512, 1024, 2048)),   # none masked
+@pytest.mark.parametrize("t_q,t_kv,causal,has_mask,tiling,window", [
+    (4096, 4096, True, False, (512, 1024, 4096), None),
+    (4096, 4096, True, False, (256, 512, 4096), None),
+    (4096, 4096, True, False, (256, 512, 1024), None),   # several windows
+    (1000, 1000, True, False, (256, 512, 1024), None),
+    (512, 200, True, False, (128, 128, 256), None),  # q blocks see nothing
+    (2048, 2048, False, True, (512, 1024, 2048), None),  # mask: all masked
+    (2048, 2048, False, False, (512, 1024, 2048), None),     # none masked
+    # a window: the loops start at the first tile it reaches
+    (8192, 8192, True, False, (512, 1024, 8192), 1024),
+    (8192, 8192, True, False, (512, 512, 2048), 1024),   # several windows
+    (1000, 1000, True, False, (256, 512, 1024), 100),
+    (640, 640, True, False, (128, 128, 640), 8),     # inside one tile
+    (2048, 2048, True, True, (512, 1024, 2048), 512),    # mask: all masked
 ])
 def test_flash_tile_census_counts_the_loops(t_q, t_kv, causal, has_mask,
-                                            tiling):
+                                            tiling, window):
     """The census the gauge publishes against a brute-force count over the
     (q, k) plane: a tile is needed iff it holds a visible key, interior iff
     every element of it is visible and the call has no key mask."""
     from analytics_zoo_tpu.ops.pallas.flash_attention import (_Tiling,
                                                               _tile_census)
     bq, bk, major = tiling
-    census = _tile_census(t_q, t_kv, _Tiling(*tiling), causal, has_mask)
+    census = _tile_census(t_q, t_kv, _Tiling(*tiling), causal, has_mask,
+                          window)
     rows = np.arange(-(-t_q // bq) * bq)[:, None]
     keys = np.arange(-(-t_kv // major) * major)[None, :]
     vis = (keys < t_kv) & np.ones_like(rows, bool)
     if causal:
         vis = vis & (keys <= rows + (t_kv - t_q))
+    causal_tiles = vis.reshape(rows.shape[0] // bq, bq, -1, bk).any(
+        axis=(1, 3))
+    if window is not None:
+        vis = vis & (keys > rows + (t_kv - t_q) - window)
     tiles = vis.reshape(rows.shape[0] // bq, bq, keys.shape[1] // bk, bk)
-    # a q block's loops run from tile 0 to the last tile that holds a
-    # visible key; causal visibility is a prefix of the keys, so every
-    # tile before that one holds a visible key too
+    # a q block's loops run over one run of tiles, from the first to the
+    # last that holds a visible key: visibility is an interval of the keys
+    # in every row, so every tile between those two holds one too
     any_vis = tiles.any(axis=(1, 3))
     all_vis = tiles.all(axis=(1, 3))
-    needed = sum(int(r.nonzero()[0].max()) + 1 if r.any() else 0
-                 for r in any_vis)
-    interior = 0 if has_mask else sum(
-        int(np.cumprod(r).sum()) for r in all_vis)
+    assert all(np.all(np.diff(r.nonzero()[0]) == 1) for r in any_vis)
+    needed = int(any_vis.sum())
+    interior = 0 if has_mask else int((all_vis & any_vis).sum())
     assert census["interior"] + census["masked"] == needed
     assert census["interior"] == interior
     per_window = any_vis.reshape(any_vis.shape[0], -1, major // bk)
     assert census["skipped_steps"] == int(
         (~per_window.any(axis=2)).sum())
+    if window is None:
+        assert "skipped_tiles" not in census
+    else:
+        assert census["skipped_tiles"] == int(causal_tiles.sum()) - needed
+
+
+def test_window_census_worked_by_hand():
+    """T = 8192, window 1024, the forward's tile (512, 1024), the whole
+    sequence resident. Rows 512 b .. 512 b + 511 see keys from
+    512 b - 1023 to 512 b + 511. Even b = 2 m: the keys reach from tile
+    m - 1 (cut by the window's trailing edge: masked) into tile m (cut by
+    the diagonal: masked); block 0 has tile 0 alone: 1 + 7 x 2 = 15
+    masked. Odd b = 2 m + 1: rows 1024 m + 512 .. 1024 m + 1023 see keys
+    1024 m - 511 .. 1024 m + 1023: tile m - 1 (trailing edge) and tile m
+    (the diagonal crosses it; its last row sees all of it); block 1 has
+    tile 0 alone: 1 + 7 x 2 = 15 masked. No tile is wholly visible to
+    all 512 rows (that takes window >= 512 + 1024 - 1), so 0 interior.
+    The causal half has sum_b (b // 2 + 1) = 2 x 36 = 72 tiles; 30 are
+    computed, 42 skipped."""
+    from analytics_zoo_tpu.ops.pallas.flash_attention import (_Tiling,
+                                                              _tile_census)
+    census = _tile_census(8192, 8192, _Tiling(512, 1024, 8192), True, False,
+                          1024)
+    assert census == {"interior": 0, "masked": 30, "skipped_steps": 0,
+                      "skipped_tiles": 42}
+    # the backward's (512, 512) tile has room for mask-free tiles: rows
+    # 512 b .. see keys 512 b - 1023 .. 512 b + 511, i.e. tiles b - 2
+    # (trailing edge), b - 1 (whole, for every row) and b (diagonal)
+    census = _tile_census(8192, 8192, _Tiling(512, 512, 8192), True, False,
+                          1024)
+    assert census == {"interior": 15, "masked": 16 + 14,
+                      "skipped_steps": 0, "skipped_tiles": 136 - 45}
+    # and the full layer's census is what it was without the argument
+    assert _tile_census(8192, 8192, _Tiling(512, 1024, 8192), True,
+                        False) == {"interior": 56, "masked": 16,
+                                   "skipped_steps": 0}
 
 
 def test_auto_schedule_metric_names_windows_and_census():

@@ -30,6 +30,8 @@ def _input_for(kind, shape, rng):
     return rng.normal(size=(B,) + shape).astype(np.float32)
 
 
+_ROPE = {"rope_type": "default", "rope_theta": 10000.0}
+
 # (factory, input_shape(s) sans batch, input kind) — one per exported layer
 CASES = {
     "Dense": (lambda: L.Dense(5), (4,), "float"),
@@ -168,6 +170,21 @@ CASES = {
     "TransformerBlock": (lambda: L.TransformerBlock(8, 2), (6, 8), "float"),
     "TransformerLayer": (lambda: L.TransformerLayer(
         vocab=7, seq_len=6, n_block=2, hidden_size=8, n_head=2), (6,), "int"),
+    # the pre-norm decoder's layers; the routed layer keeps counters in
+    # its state, which persist with the weights
+    "RMSNorm": (lambda: L.RMSNorm(), (4,), "float"),
+    "RoutedExperts": (lambda: L.RoutedExperts(4, 8, top_k=2, held=(1, 3)),
+                      (6,), "float"),
+    "DecoderAttention": (lambda: L.DecoderAttention(
+        8, 4, 2, 4, rotary=_ROPE, window=3), (6, 8), "float"),
+    "DecoderBlock": (lambda: L.DecoderBlock(
+        8, L.DecoderAttention(8, 4, 2, 4, rotary=_ROPE),
+        L.RoutedExperts(4, 8, top_k=2)), (6, 8), "float"),
+    "DecoderStack": (lambda: L.DecoderStack(
+        vocab=7, layer_types=["sliding_attention", "full_attention"],
+        hidden_size=8, n_head=4, n_kv_head=2, head_dim=4,
+        ffn=lambda i: L.RoutedExperts(4, 8, top_k=2),
+        rope_parameters=_ROPE, sliding_window=3), (6,), "int"),
 }
 
 

@@ -34,6 +34,7 @@ read once per ``fit`` (``training.py`` ``_moe_report``).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -220,8 +221,16 @@ class SparseMoE(Layer):
 _WIDE_BITS = 30
 
 
+#: what a run of the layer counts -> the wide counter of the layer state
+WIDE_COUNTERS = {"held": "moe_held", "absent": "moe_absent",
+                 "dropped": "moe_dropped", "rows_run": "moe_rows_run",
+                 "choice_passes": "moe_choice_passes",
+                 "chunk_runs": "moe_chunk_runs",
+                 "compact_runs": "moe_compact_runs"}
+
+
 def _wide_add(acc, n):
-    lo = acc[1] + n.astype(jnp.int32)
+    lo = acc[1] + jnp.asarray(n, jnp.int32)
     return jnp.stack([acc[0] + (lo >> _WIDE_BITS),
                       lo & ((1 << _WIDE_BITS) - 1)])
 
@@ -231,74 +240,219 @@ def wide_value(pair) -> int:
     return (int(pair[0]) << _WIDE_BITS) + int(pair[1])
 
 
-def _take(rows, idx):
-    """Rows by index; every index here comes from a permutation of the
-    assignments, so none is out of bounds."""
-    return rows.at[idx].get(mode="promise_in_bounds")
+#: the row buffers are a whole number of these rows (a tile of the grouped
+#: products)
+_ROW_TILE = 128
 
 
-def _sum_over_choices(rows, pos, weights=None):
-    """``sum_j [weights[n, j]] * rows[pos[n, j]]`` in float32, one choice at
-    a time: the (N, k, d) gather of all choices at once would be as large
-    as the row buffer itself."""
-    total = None
-    for j in range(pos.shape[1]):
-        term = _take(rows, pos[:, j]).astype(jnp.float32)
-        if weights is not None:
-            term = term * weights[:, j, None]
-        total = term if total is None else total + term
-    return total
+def _take(rows, idx, whole=True):
+    """Rows by index. A ``whole`` buffer holds every assignment, and every
+    index here comes from a permutation of them, so none is out of bounds.
+    A buffer cut to the rows held (``_held_rows``) reads zero past its end:
+    the rows that would stand there are absent experts', which are zero in
+    the whole buffer too."""
+    if whole:
+        return rows.at[idx].get(mode="promise_in_bounds")
+    return rows.at[idx].get(mode="fill", fill_value=0)
+
+
+def _loop_passes(k):
+    """The most passes for which a gather-sum is a loop over them. A loop
+    keeps its float32 sum in memory between passes, which each pass reads
+    and writes beside the gathered rows; all ``k`` choices summed at once
+    read each gathered buffer once. Measured at 16384 tokens x 8 choices
+    of 2304 on a v5e (PR 29): all 8 at once 5.9 ms, the loop 0.6 ms and
+    0.97 ms a pass, so the loop is the cheaper up to 5 passes of 8, three
+    quarters of ``k`` less a half."""
+    return (3 * k - 2) // 4
+
+
+def _passes_run(passes, k):
+    """The passes a gather-sum runs where ``passes`` are due: those, or all
+    ``k`` past ``_loop_passes(k)``."""
+    return jnp.where(passes <= _loop_passes(k), passes, k)
+
+
+def _choice_rows(scope, rows, pos):
+    """``idx -> rows[idx]`` under ``scope``, for the indices of one choice of
+    every token (``pos`` says how many assignments there are in all)."""
+    whole = rows.shape[0] >= pos.size
+
+    def take(idx):
+        with jax.named_scope(scope):
+            return _take(rows, idx, whole)
+    return take
+
+
+def _sum_over_choices(scope, rows, pos, passes, weights=None):
+    """``sum_{j < passes} [weights[n, j]] * rows[pos[n, j]]``, summed in
+    float32, the result in ``rows``' dtype. The choices from ``passes`` on
+    are absent experts' in every token (``RoutedExperts._run`` orders them
+    so) and add nothing. ``passes`` None is all ``k``, in plain code; else
+    a traced count, and the sum a ``while`` loop over the passes due where
+    they are few, all ``k`` in one fused sum where they are not. Each opens
+    its ``zoo_moe.*`` scope inside, on the operations: a conditional or a
+    loop shows in a device trace as a whole event around what it runs, and
+    a scope around it would count that time twice
+    (``benchmark/lib/scopes.py``)."""
+    n_tok, k = pos.shape
+    take = _choice_rows(scope, rows, pos)
+
+    def term(picked, j, weights_t):
+        with jax.named_scope(scope):
+            picked = picked.astype(jnp.float32)
+            return (picked if weights_t is None
+                    else picked * weights_t[j][:, None])
+
+    def few(passes, pos_t, weights_t):
+        with jax.named_scope(scope):
+            total = jnp.zeros((n_tok, rows.shape[1]), jnp.float32)
+        total = jax.lax.fori_loop(
+            0, passes,
+            lambda j, total: total + term(take(pos_t[j]), j, weights_t), total)
+        with jax.named_scope(scope):
+            return total.astype(rows.dtype)
+
+    def every(looped, pos_t, weights_t):
+        # XLA keeps a gather an operation of its own either way; looped,
+        # the program holds one of them
+        picked = (jax.lax.map(take, pos_t) if looped
+                  else [take(idx) for idx in pos_t])
+        total = sum(term(picked[j], j, weights_t) for j in range(k))
+        with jax.named_scope(scope):
+            return total.astype(rows.dtype)
+
+    by_choice = (pos.T, None if weights is None else weights.T)     # (k, N)
+    if passes is None:
+        return every(False, *by_choice)
+    return jax.lax.cond(passes <= _loop_passes(k),
+                        functools.partial(few, passes),
+                        functools.partial(every, True), *by_choice)
 
 
 @jax.custom_vjp
-def _dispatch(tokens, order, pos):
+def _dispatch(tokens, order, pos, passes):
     """``rows[r] = tokens[order[r] // k]``: token rows in sorted-assignment
-    order. ``order`` (N*k,) maps a sorted row to its assignment ``n*k + j``,
-    ``pos`` (N, k) is its inverse. The transpose of a gather is a
-    scatter-add; because ``order`` is a permutation it is also the gather
-    ``d tokens[n] = sum_j d rows[pos[n, j]]``, which is what the backward
-    runs."""
-    return _take(tokens, order // pos.shape[1])
+    order. ``order`` (R,) maps a sorted row to its assignment ``n*k + j``
+    (the first R of them), ``pos`` (N, k) is the whole order's inverse. The
+    transpose of a gather is a scatter-add; because ``order`` is a
+    permutation it is also the gather ``d tokens[n] = sum_j d rows[pos[n,
+    j]]``, which is what the backward runs, over the first ``passes``
+    choices."""
+    with jax.named_scope("zoo_moe.dispatch"):
+        return _take(tokens, order // pos.shape[1])
 
 
-def _dispatch_fwd(tokens, order, pos):
-    return _dispatch(tokens, order, pos), (order, pos)
+def _dispatch_fwd(tokens, order, pos, passes):
+    return _dispatch(tokens, order, pos, passes), (pos, passes)
 
 
 def _dispatch_bwd(res, d_rows):
-    order, pos = res
-    return _sum_over_choices(d_rows, pos).astype(d_rows.dtype), None, None
+    pos, passes = res
+    return (_sum_over_choices("zoo_moe.dispatch", d_rows, pos, passes),
+            None, None, None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(rows, weights, order, pos):
-    """``out[n] = sum_j weights[n, j] * rows[pos[n, j]]`` in float32, the
-    result in ``rows``' dtype; the backward again gathers and never
-    scatters."""
-    return _sum_over_choices(rows, pos, weights).astype(rows.dtype)
+def _combine(rows, weights, order, pos, passes):
+    """``out[n] = sum_{j < passes} weights[n, j] * rows[pos[n, j]]`` in
+    float32, the result in ``rows``' dtype; the backward again gathers and
+    never scatters."""
+    return _sum_over_choices("zoo_moe.combine", rows, pos, passes, weights)
 
 
-def _combine_fwd(rows, weights, order, pos):
-    return _combine(rows, weights, order, pos), (rows, weights, order, pos)
+def _combine_fwd(rows, weights, order, pos, passes):
+    return (_combine(rows, weights, order, pos, passes),
+            (rows, weights, order, pos, passes))
 
 
 def _combine_bwd(res, d_out):
-    rows, weights, order, pos = res
+    rows, weights, order, pos, passes = res
     k = pos.shape[1]
-    d_rows = (_take(d_out, order // k).astype(jnp.float32)
-              * _take(weights.reshape(-1), order)[:, None]
-              ).astype(rows.dtype)
-    g = d_out.astype(jnp.float32)
-    d_weights = jnp.stack(
-        [jnp.sum(_take(rows, pos[:, j]).astype(jnp.float32) * g, axis=-1)
-         for j in range(k)], axis=1)
-    return d_rows, d_weights, None, None
+    with jax.named_scope("zoo_moe.combine"):
+        d_rows = (_take(d_out, order // k).astype(jnp.float32)
+                  * _take(weights.reshape(-1), order)[:, None]
+                  ).astype(rows.dtype)
+    take = _choice_rows("zoo_moe.combine", rows, pos)
+
+    def one_pass(j, by_choice):
+        # a choice no token holds is not passed over: its weight is 0 and
+        # takes no gradient
+        picked = take(pos.T[j])
+        with jax.named_scope("zoo_moe.combine"):
+            return by_choice.at[j].set(jnp.sum(
+                picked.astype(jnp.float32) * d_out.astype(jnp.float32),
+                axis=-1))
+    with jax.named_scope("zoo_moe.combine"):
+        by_choice = jnp.zeros((k, pos.shape[0]), jnp.float32)
+    if passes is None:
+        for j in range(k):
+            by_choice = one_pass(j, by_choice)
+    else:       # the sums kept between passes are small: a loop whatever
+        by_choice = jax.lax.fori_loop(0, passes, one_pass, by_choice)
+    d_weights = by_choice.T
+    return d_rows, d_weights, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _held_rows(n_rows, tokens, wgate, wup, wdown, weights, order, pos, sizes,
+               passes):
+    """The layer's row side over the first ``n_rows`` (static) sorted
+    assignments, which have to include every held one: token rows gathered
+    in that order, the SiLU-gated experts' three grouped products, the
+    weighted sum back to tokens. Every buffer here holds ``n_rows`` rows.
+    Under ``jit`` so that a model's layers, which call it with the same
+    shapes forward and backward, trace and lower it once a shape."""
+    order = order[:n_rows]
+    rows = _dispatch(tokens, order, pos, passes)
+    with jax.named_scope("zoo_moe.experts"):
+        gate = grouped_matmul(rows, wgate, sizes)
+        up = grouped_matmul(rows, wup, sizes)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(rows.dtype)
+        rows = grouped_matmul(act, wdown, sizes)
+    return _combine(rows, weights, order, pos, passes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rows_or_all(compact, tokens, wgate, wup, wdown, weights, order,
+                      pos, sizes, passes):
+    """``_held_rows`` over ``compact`` rows where the held assignments fit
+    in them, over all ``N x k`` where they do not: the same result either
+    way. ``grad`` of a plain ``lax.cond`` would hand the backward both
+    branches' residuals, the untaken one's as zeros (row buffers again);
+    this saves the inputs, and the backward is a ``cond`` over ``jax.vjp``
+    of the two, which recomputes the forward it differentiates (as the
+    ``jax.checkpoint`` around a chunk does anyway)."""
+    return jax.lax.cond(
+        jnp.sum(sizes) <= compact, functools.partial(_held_rows, compact),
+        functools.partial(_held_rows, pos.size),
+        tokens, wgate, wup, wdown, weights, order, pos, sizes, passes)
+
+
+def _held_rows_or_all_fwd(compact, *args):
+    return _held_rows_or_all(compact, *args), args
+
+
+def _held_rows_or_all_bwd(compact, args, d_y):
+    diff, rest = args[:5], args[5:]          # ..., weights | order, ...
+    _, pos, sizes, _ = rest
+
+    def back(n_rows, diff, rest, d_y):
+        return jax.vjp(lambda *d: _held_rows(n_rows, *d, *rest), *diff)[1](d_y)
+    grads = jax.lax.cond(
+        jnp.sum(sizes) <= compact, functools.partial(back, compact),
+        functools.partial(back, pos.size), diff, rest, d_y)
+    return (*grads, None, None, None, None)
+
+
+_held_rows_or_all.defvjp(_held_rows_or_all_fwd, _held_rows_or_all_bwd)
 
 
 class RoutedExperts(Layer):
@@ -317,22 +471,49 @@ class RoutedExperts(Layer):
     of layers whose ``held`` partition the experts add up to the whole
     (``tests/test_routed_experts.py``). Input ``(B, d)`` or ``(B, T, d)``.
 
-    Shapes are static: the sorted index is N x k int32 and the row buffers
-    hold N x k rows, the worst case (every choice of every token a held
-    expert); the grouped products skip what the group sizes leave empty,
-    the gathers do not. The layer keeps no capacity to size them by;
-    ``token_chunk`` bounds them instead: N tokens are routed and run
+    Shapes are static and the work follows the routing all the same. The
+    sorted index is N x k int32. Every pass over rows or over a token's
+    choices is bounded by what the routing of these tokens decided, in two
+    places, both exact:
+
+    * **choices.** A token's ``k`` choices are ordered held-first, so the
+      gather-sums (the combine, the backward of the row gather, the
+      weights' gradient) need the first ``passes`` only: the most held
+      choices any token has. The later ones are absent experts' in every
+      token: weight 0, zero rows. Where the passes due are few the sum is
+      a loop over them; past ``_loop_passes(k)`` (5 of 8) the loop's
+      float32 sum, kept in memory between passes, would cost more than
+      all ``k`` choices summed at once, and those run.
+    * **rows.** Sorted assignments put the held ones first, so the row
+      buffers (the gathered rows, the products' operands and results, the
+      gate) hold the first ``C`` rows and not ``N x k``, where ``C`` is
+      twice the expected share ``N k len(held) / num_experts`` (a balanced
+      router's share with as much room again: a router drifts, and a
+      buffer twice the rows held still costs a quarter of the worst case
+      at a share of an eighth), rounded up to a tile of the products and
+      capped at ``N k``. A chunk that holds more than ``C`` assignments
+      runs the same program over all ``N x k`` rows (a ``lax.cond`` on
+      the held count), which costs what every chunk cost before the
+      buffers were cut: nothing is dropped either way.
+
+    A layer that holds every expert has nothing to bound and lowers with
+    no conditional. The layer keeps no capacity to size buffers by;
+    ``token_chunk`` bounds them besides: N tokens are routed and run
     ``token_chunk`` at a time, one chunk after the other (``lax.map``, each
-    chunk rematerialised in the backward pass), so the buffers hold
-    ``token_chunk x k`` rows. Routing is per token, so the result is the
-    same; a token count that is not a whole number of chunks runs whole.
+    chunk rematerialised in the backward pass), so ``N`` above is
+    ``token_chunk``. Routing is per token, so the result is the same; a
+    token count that is not a whole number of chunks runs whole.
 
     Layer state (accumulated on the device, published per ``fit``):
     ``moe_expert_tokens`` (num_experts,) the last step's assignments per
     router output, ``moe_held_tokens`` (len(held),) those of the held
-    experts in ``held``'s order, and the wide counters ``moe_held`` / ``moe_absent`` /
-    ``moe_dropped`` (``wide_value``): assignments computed here, left to
-    absent experts, and placed nowhere (0 by construction)."""
+    experts in ``held``'s order, and the wide counters (``wide_value``)
+    ``moe_held`` / ``moe_absent`` / ``moe_dropped``: assignments computed
+    here, left to absent experts, and placed nowhere (0 by construction);
+    ``moe_rows_run``: rows the row buffers held (``C`` or ``N k`` a chunk),
+    ``moe_choice_passes``: gather-sum passes run, summed over chunks,
+    ``moe_chunk_runs`` and ``moe_compact_runs``: chunks run, and those of
+    them that ran over ``C`` rows."""
 
     def __init__(self, num_experts: int, hidden_dim: int, top_k: int = 2,
                  held=None, norm_topk: bool = True,
@@ -374,7 +555,7 @@ class RoutedExperts(Layer):
         return {"moe_expert_tokens": jnp.zeros((self.num_experts,),
                                                jnp.int32),
                 "moe_held_tokens": jnp.zeros((len(self.held),), jnp.int32),
-                "moe_held": pair, "moe_absent": pair, "moe_dropped": pair}
+                **{key: pair for key in WIDE_COUNTERS.values()}}
 
     def apply(self, params, state, x, *, training=False, rng=None):
         from .....parallel.mesh import EXPERT_AXIS, global_mesh
@@ -389,30 +570,41 @@ class RoutedExperts(Layer):
         n_tok, chunk = tokens.shape[0], self.token_chunk
         if chunk and n_tok > chunk and n_tok % chunk == 0:
             run = jax.checkpoint(self._run)
-            y, sizes, per_expert = jax.lax.map(
+            y, sizes, per_expert, ran = jax.lax.map(
                 lambda t: run(params, t), tokens.reshape(-1, chunk, d))
             y = y.reshape(n_tok, d)
             sizes, per_expert = sizes.sum(0), per_expert.sum(0)
+            ran = {key: n.sum(0) for key, n in ran.items()}
+            ran["chunk_runs"] = n_tok // chunk
         else:
-            y, sizes, per_expert = self._run(params, tokens)
+            y, sizes, per_expert, ran = self._run(params, tokens)
+            ran["chunk_runs"] = 1
 
         held = jnp.sum(sizes)
         placed = jnp.sum(per_expert)
-        new_state = {
-            "moe_expert_tokens": per_expert,
-            "moe_held_tokens": sizes,
-            "moe_held": _wide_add(state["moe_held"], held),
-            "moe_absent": _wide_add(state["moe_absent"], placed - held),
-            "moe_dropped": _wide_add(state["moe_dropped"],
-                                     n_tok * self.top_k - placed),
-        }
+        ran.update(held=held, absent=placed - held,
+                   dropped=n_tok * self.top_k - placed)
+        new_state = {"moe_expert_tokens": per_expert,
+                     "moe_held_tokens": sizes}
+        for name, key in WIDE_COUNTERS.items():
+            new_state[key] = _wide_add(state[key], ran[name])
         return y.reshape(*lead, d), new_state
 
+    def compact_rows(self, n_tok: int) -> int:
+        """``C``: the rows the row buffers hold when ``n_tok`` tokens are
+        routed at once and the held assignments fit: twice the expected
+        share, a whole number of tiles, at most ``n_tok x top_k``."""
+        n_rows = n_tok * self.top_k
+        twice = -(-2 * n_rows * len(self.held) // self.num_experts)
+        return min(-(-twice // _ROW_TILE) * _ROW_TILE, n_rows)
+
     def _run(self, params, tokens):
-        """Route ``tokens`` (n, d) and run the held experts on them:
-        ``(y (n, d), held group sizes, assignments per router output)``."""
+        """Route ``tokens`` (n, d) and run the held experts on them: ``(y
+        (n, d), held group sizes, assignments per router output, {rows_run,
+        choice_passes, compact_runs} of this run)``."""
         cd = tokens.dtype
         n_tok, k, n_held = tokens.shape[0], self.top_k, len(self.held)
+        n_rows, compact = n_tok * k, self.compact_rows(n_tok)
 
         with jax.named_scope("zoo_moe.route"):
             logits = jnp.matmul(tokens, params["Wg"].astype(cd),
@@ -420,6 +612,19 @@ class RoutedExperts(Layer):
             _, weights, experts = top_k_routing(logits, k, self.norm_topk)
             key = jnp.take(jnp.asarray(self._place, jnp.int32),
                            experts)                             # (N, k)
+            passes = None
+            if n_held < self.num_experts:
+                # each token's held choices first, in their order (a stable
+                # partition); autodiff carries it back to the router
+                here = key < n_held
+                before = jnp.cumsum(here, axis=1, dtype=jnp.int32)
+                slot = jnp.where(here, before - 1,
+                                 before[:, -1:] + jnp.arange(k) - before)
+                move = slot[:, :, None] == jnp.arange(k)   # (N, from, to)
+                key = jnp.sum(jnp.where(move, key[:, :, None], 0), axis=1)
+                weights = jnp.sum(jnp.where(move, weights[:, :, None], 0.0),
+                                  axis=1)
+                passes = jnp.max(before[:, -1])
             order = jnp.argsort(key.reshape(-1), stable=True)
             pos = jnp.argsort(order).reshape(n_tok, k).astype(jnp.int32)
             order = order.astype(jnp.int32)
@@ -430,17 +635,22 @@ class RoutedExperts(Layer):
                 experts.reshape(-1, 1) == jnp.arange(self.num_experts),
                 axis=0, dtype=jnp.int32))
 
-        with jax.named_scope("zoo_moe.dispatch"):
-            rows = _dispatch(tokens, order, pos)                # (N*k, d)
-        with jax.named_scope("zoo_moe.experts"):
-            gate = grouped_matmul(rows, params["Wgate"].astype(cd), sizes)
-            up = grouped_matmul(rows, params["Wup"].astype(cd), sizes)
-            act = (jax.nn.silu(gate.astype(jnp.float32))
-                   * up.astype(jnp.float32)).astype(cd)
-            rows = grouped_matmul(act, params["Wdown"].astype(cd), sizes)
-        with jax.named_scope("zoo_moe.combine"):
-            y = _combine(rows, weights, order, pos)
-        return y, sizes, per_expert
+        # no zoo_moe.* scope around the conditionals and loops: each opens
+        # its own inside, on the operations (_sum_over_choices)
+        args = (tokens, *(params[w].astype(cd)
+                          for w in ("Wgate", "Wup", "Wdown")),
+                weights, order, pos, sizes, passes)
+        if compact < n_rows:
+            y = _held_rows_or_all(compact, *args)
+            fits = (jnp.sum(sizes) <= compact).astype(jnp.int32)
+        else:
+            y = _held_rows(n_rows, *args)
+            fits = jnp.int32(0)
+        ran = {"rows_run": n_rows - fits * (n_rows - compact),
+               "choice_passes": (jnp.int32(k) if passes is None
+                                 else _passes_run(passes, k)),
+               "compact_runs": fits}
+        return y, sizes, per_expert, ran
 
     def call(self, params, x, *, training=False, rng=None):
         y, _ = self.apply(params, {}, x, training=training, rng=rng)
@@ -469,10 +679,23 @@ def routed_layer_totals(state):
         return {}
     out = {}
     for name, s in jax.device_get(layers).items():
-        out[name] = {"held": wide_value(s["moe_held"]),
-                     "absent": wide_value(s["moe_absent"]),
-                     "dropped": wide_value(s["moe_dropped"]),
+        out[name] = {**{count: wide_value(s[key])
+                        for count, key in WIDE_COUNTERS.items()},
                      "expert_tokens": [int(n) for n in
                                        s["moe_expert_tokens"]],
                      "held_tokens": [int(n) for n in s["moe_held_tokens"]]}
     return out
+
+
+def bound_ratios(counts):
+    """What bounded the layer's passes, from its counters over some span:
+    ``rows_run_over_held`` (rows the row buffers held over the assignments
+    held: 1 is no waste, ``num_experts / len(held)`` the static worst case
+    under a balanced router), ``choice_passes_mean`` (gather-sum passes a
+    chunk; ``top_k`` is the static worst case) and ``compact_share`` (share
+    of chunks whose buffers were cut to ``C`` rows)."""
+    runs = max(counts["chunk_runs"], 1)
+    return {"rows_run_over_held": (counts["rows_run"] / counts["held"]
+                                   if counts["held"] else 0.0),
+            "choice_passes_mean": counts["choice_passes"] / runs,
+            "compact_share": counts["compact_runs"] / runs}

@@ -611,26 +611,51 @@ class TrainingLoop:
         ``zoo_moe_dropped_assignments_total``, and of the fit's last step
         ``zoo_moe_expert_tokens{layer=,expert=}`` and
         ``zoo_moe_load_max_over_mean{layer=}`` (largest held expert's
-        tokens over the held experts' mean). ``None`` for a model without
-        such layers."""
-        from .layers.moe import routed_layer_totals
+        tokens over the held experts' mean); what bounded the layers' work,
+        ``zoo_moe_rows_run_total{layer=}``, ``zoo_moe_choice_passes_total
+        {layer=}``, ``zoo_moe_chunk_runs_total{layer=,compact=}``, and in
+        the report their ratios (``layers/moe.py::bound_ratios``). ``None``
+        for a model without such layers."""
+        from .layers.moe import (WIDE_COUNTERS, bound_ratios,
+                                 routed_layer_totals)
         after = routed_layer_totals(self.model.net_state)
         if not after:
             return None
         reg = self._registry
-        report: Dict[str, Any] = {"layers": {}, "held": 0, "absent": 0,
-                                  "dropped": 0}
+        counts = tuple(WIDE_COUNTERS)
+        report: Dict[str, Any] = {"layers": {}, **dict.fromkeys(counts, 0)}
         for name, now in after.items():
             was = before.get(name, {})
-            layer = {k: now[k] - was.get(k, 0)
-                     for k in ("held", "absent", "dropped")}
+            layer = {k: now[k] - was.get(k, 0) for k in counts}
+            for key in counts:
+                report[key] += layer[key]
+            layer.update(bound_ratios(layer))
             layer["expert_tokens"] = now["expert_tokens"]
             mean = sum(now["held_tokens"]) / max(len(now["held_tokens"]), 1)
             layer["load_max_over_mean"] = (
                 max(now["held_tokens"]) / mean if mean > 0 else 0.0)
             report["layers"][name] = layer
-            for key in ("held", "absent", "dropped"):
-                report[key] += layer[key]
+            reg.counter(  # zoolint: disable=ZL015 one series a layer
+                "zoo_moe_rows_run_total",
+                "rows a RoutedExperts layer's row buffers held: the cut "
+                "size C a chunk whose held assignments fit in it, tokens x "
+                "top_k a chunk else",
+                labels={"layer": name}).inc(layer["rows_run"])
+            reg.counter(  # zoolint: disable=ZL015 one series a layer
+                "zoo_moe_choice_passes_total",
+                "gather-sum passes over a token's choices a RoutedExperts "
+                "layer ran, a chunk: the most held choices any token had, "
+                "or all top_k where those are many",
+                labels={"layer": name}).inc(layer["choice_passes"])
+            for cut, n in (("true", layer["compact_runs"]),
+                           ("false", layer["chunk_runs"]
+                            - layer["compact_runs"])):
+                reg.counter(  # zoolint: disable=ZL015 one series a layer
+                    "zoo_moe_chunk_runs_total",
+                    "chunks of tokens a RoutedExperts layer ran, by whether "
+                    "its row buffers were cut to the rows held (compact="
+                    "true) or held the worst case",
+                    labels={"layer": name, "compact": cut}).inc(n)
             for held, key in (("true", "held"), ("false", "absent")):
                 reg.counter(  # zoolint: disable=ZL015 one series a layer
                     "zoo_moe_assignments_total",
@@ -654,6 +679,7 @@ class TrainingLoop:
             "assignments a RoutedExperts layer placed with no expert "
             "(0 by construction: the layer has no capacity)"
         ).inc(report["dropped"])
+        report.update(bound_ratios(report))
         return report
 
     def _fit_report(self, t_open: float, t_end: float,

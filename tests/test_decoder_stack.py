@@ -88,7 +88,14 @@ def test_decoder_stack_trains_through_fit_like_the_reference():
         assert layer["held"] + layer["absent"] == n
         assert layer["dropped"] == 0
         assert sum(layer["expert_tokens"]) == n
+        # 256 tokens a step in chunks of 64; 128 assignments a chunk are
+        # one tile, so the buffers are never cut
+        assert layer["chunk_runs"] == 4 and layer["compact_runs"] == 0
+        assert layer["rows_run"] == n
+        assert 0 <= layer["choice_passes"] <= 4 * 2
     assert report["dropped"] == 0
+    assert report["rows_run_over_held"] == report["rows_run"] / report["held"]
+    assert report["compact_share"] == 0.0
 
 
 def test_fp8_control_of_the_tiny_decoder_is_further_than_the_program():

@@ -20,7 +20,7 @@ Design constraints (all enforced here, consumed by
   against its own EWMA baseline. They ride the step's XLA program — no
   extra dispatch, no extra host sync.
 * **One packed scalar.** All flags come back as ONE int32 bitmask per
-  step (a ``(K,)`` vector per scan chunk), read by the host alongside
+  step, read by the host alongside
   the loss it already reads back — see :data:`NAN_LOSS` /
   :data:`NAN_GRAD` / :data:`SPIKE` / :data:`GRAD_CLIPPED`.
 * **Deterministic.** No RNG, no clock: the EWMA baseline is a pure
@@ -111,7 +111,7 @@ class SentinelConfig:
 
     @property
     def active(self) -> bool:
-        """Whether the step builders must emit the extended signature
+        """Whether the step builder must emit the extended signature
         (sentinel state carry and/or packed-flag output)."""
         return self.sentinel or self.grad_clip > 0
 
@@ -154,12 +154,12 @@ def resolve_config() -> SentinelConfig:
 
 
 # ---------------------------------------------------------------------------
-# on-device pieces (called from inside the jitted step builders)
+# on-device pieces (called from inside the jitted step)
 # ---------------------------------------------------------------------------
 
 def init_state() -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fresh EWMA carry ``(baseline_norm, observed_count)`` — two f32
-    scalars threaded through the step/scan like the rest of the carry."""
+    scalars threaded through the step like the rest of its state."""
     return (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
 
 

@@ -50,13 +50,10 @@ DEFAULT_CONF: Dict[str, Any] = {
     "zoo.seq.mode": "ring",              # seq-parallel routing: ring | ulysses | auto
     "zoo.seq.strict": False,             # fail (not warn) when attention can't ride the seq mesh
     "zoo.compute.dtype": "float32",      # float32 | bfloat16
-    "zoo.train.scan_steps": 1,           # optimizer steps fused per dispatch (lax.scan)
-    "zoo.train.device_cache": False,     # HBM-resident dataset, 1 dispatch/epoch
-    "zoo.train.fuse_epochs": 1,          # epochs fused per dispatch (device_cache only)
     "zoo.train.zero_sharding": False,    # ZeRO-1: optimizer state sharded over data axis
     "zoo.train.fused_ce": "auto",        # fused blockwise LM-head CE: auto (V>=1024) | true | false
     "zoo.train.fused_ce_chunk": 512,     # rows per streamed logits tile (O(chunk*V) memory)
-    "zoo.train.remat": False,            # scan-body remat: false | true/dots | full
+    "zoo.train.remat": False,            # step remat: false | true/dots | full
     "zoo.train.seq_attention": "off",    # force seq-parallel attention in the
     #   training step: off | ring | ulysses (needs a seq mesh axis; fallback
     #   to full attention becomes an error instead of a warning)
@@ -69,7 +66,7 @@ DEFAULT_CONF: Dict[str, Any] = {
     #   nan-loss / nan-grad / grad-norm-spike checks folded into the step
     "zoo.train.spike_factor": 10.0,      # grad-norm spike = factor x its EWMA
     "zoo.train.grad_clip": 0.0,          # >0: global-norm gradient clipping in
-    #   the step builders (zoo_train_grad_clip_engaged_total)
+    #   the train step (zoo_train_grad_clip_engaged_total)
     "zoo.train.max_skips_per_epoch": 8,  # recover mode: skips past this in one
     #   epoch escalate to rollback-to-last-good-checkpoint
     "zoo.train.max_rollbacks": 3,        # rollbacks per fit before the loop
@@ -160,6 +157,26 @@ COMPILE_CACHE_DIR = os.path.join(
 #: normalized ("zoo_failure_retry_times") → canonical ("zoo.failure.retry_times")
 #: so env/kwargs spellings of multi-word leaf keys land on the right conf entry
 _CANONICAL = {k.lower().replace(".", "_"): k for k in DEFAULT_CONF}
+
+
+#: keys this package once read and no longer does, by normalized spelling.
+#: An unknown key is accepted in silence, so a job that still sets one of
+#: these would lose the path it asked for without a word: they raise.
+_RETIRED = {k.replace(".", "_"): k for k in (
+    "zoo.train.scan_steps", "zoo.train.device_cache",
+    "zoo.train.fuse_epochs")}
+
+
+def _reject_retired(merged: Mapping[str, Any]) -> None:
+    """Raise for a retired key, whichever channel (yaml, env, conf dict,
+    kwarg) brought it in."""
+    for key in merged:
+        retired = _RETIRED.get(str(key).lower().replace(".", "_"))
+        if retired is not None:
+            raise ValueError(
+                f"{retired} is retired: fit dispatches one optimizer step "
+                f"at a time, and no key selects another path; remove it "
+                f"from the configuration")
 
 
 def _canonical_key(raw: str) -> str:
@@ -352,6 +369,7 @@ def init_zoo_context(
 
     # validate BEFORE any global side-effect (jax config, distributed
     # bring-up): a rejected call must not leave half-applied state.
+    _reject_retired(merged)
     # jnp.dtype normalization accepts both "bfloat16" and jnp.bfloat16.
     import jax.numpy as jnp
     try:

@@ -254,16 +254,12 @@ _EXHAUSTED = object()
 
 
 def prefetch_to_device(it: Iterator, mesh=None, *, buffer_size: int = 2,
-                       threaded: bool = True, sharding=None,
-                       ledger=None, phases=None) -> Iterator:
+                       threaded: bool = True, ledger=None,
+                       phases=None) -> Iterator:
     """Double-buffered device transfer: keep ``buffer_size`` batches already
     dispatched to the devices while the current one computes. ``device_put``
     is async in JAX, so this pipeline hides both host batch assembly (via the
     background thread) and PCIe/DMA transfer behind the previous step.
-
-    ``sharding`` overrides the default leading-dim data sharding — used by the
-    multi-step scan path, whose chunks are ``(K, batch, ...)`` and shard the
-    *second* axis.
 
     ``ledger`` (a :class:`~..observability.goodput.GoodputLedger`)
     attributes the step/data seam from inside the pipeline. Time spent
@@ -280,8 +276,7 @@ def prefetch_to_device(it: Iterator, mesh=None, *, buffer_size: int = 2,
     ``phases`` (the training loop's) times WHERE the host spends that time:
     ``phases.pull`` around the blocking pull from the source,
     ``phases.put`` around the ``device_put`` of a batch."""
-    if sharding is None:
-        sharding = mesh_lib.batch_sharding(mesh)
+    sharding = mesh_lib.batch_sharding(mesh)
 
     def put(item):
         return jax.tree.map(
@@ -455,13 +450,8 @@ class BucketedFeatureSet(FeatureSet):
     one length — bucketing compiles one program per bucket and wastes far
     less padding compute). Batches never mix buckets; batch order
     interleaves buckets, reshuffled per epoch.
-
-    Note: multi-step scan fusing (``zoo.train.scan_steps > 1``) stacks K
-    consecutive batches into one array and therefore cannot mix shapes —
-    use the default ``scan_steps=1`` with bucketed data.
     """
 
-    device_cacheable = False  # ragged across buckets: no one HBM array
     ragged = True             # evaluate/predict need a single dense array
 
     def __init__(self, buckets: Sequence[FeatureSet], shuffle: bool = True,

@@ -2,7 +2,7 @@
 take 40x longer?" (a silent retrace).
 
 :func:`instrument_jit` is a drop-in ``jax.jit`` replacement used by every
-hot-path entry point (the four training dispatch paths, the eval/predict
+hot-path entry point (the training step, the eval/predict
 steps, ``InferenceModel``'s serving predict, ``Seq2seq.infer``'s
 encode/decode closures). On every call it derives the ABSTRACT signature
 of the arguments (pytree structure + per-leaf shape/dtype — the same

@@ -220,9 +220,8 @@ class InflightProbe:
     """How far the host is ahead of the device, in optimizer steps.
 
     The training loop hands over the loss array of every segment it
-    dispatches (:meth:`dispatched`: one step, one scan chunk of K, one
-    fused epoch) and asks right before the next dispatch
-    (:meth:`at_dispatch`). Segments run on the device in the order they
+    dispatches (:meth:`dispatched`: one step) and asks right before the
+    next dispatch (:meth:`at_dispatch`). Segments run on the device in the order they
     were dispatched, so dropping from the left every array whose
     ``is_ready()`` is true (non-blocking, well under a microsecond)
     leaves exactly what the device has not finished: the depth.

@@ -146,13 +146,6 @@ def replicated_sharding(mesh: Optional[Mesh] = None) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def stacked_batch_sharding(mesh: Optional[Mesh] = None) -> NamedSharding:
-    """Sharding for a stacked chunk of K minibatches ``(K, batch, ...)``:
-    the scan axis stays replicated, the batch axis splits over data."""
-    mesh = mesh or global_mesh()
-    return NamedSharding(mesh, P(None, DATA_AXIS))
-
-
 def param_shardings(model, params, mesh: Optional[Mesh] = None):
     """Per-leaf NamedSharding tree for a model's params: layers declare
     PartitionSpecs over the ``model`` axis via ``Layer.param_sharding``

@@ -29,7 +29,7 @@ layer-state channel: ``apply`` returns them under the reserved state key
 differentiated function — see ``training.py`` ``_aux_loss_sum`` — so the
 router receives gradient. ``RoutedExperts`` leaves its per-expert token
 counts in the same channel (``moe_*`` keys), accumulated on the device and
-read once per ``fit`` (``training.py`` ``_moe_report``).
+read once per ``fit`` (:func:`fit_report`).
 """
 
 from __future__ import annotations
@@ -699,3 +699,82 @@ def bound_ratios(counts):
                                    if counts["held"] else 0.0),
             "choice_passes_mean": counts["choice_passes"] / runs,
             "compact_share": counts["compact_runs"] / runs}
+
+
+def fit_report(before, net_state, registry):
+    """The routed layers' counters over the fit that just ended
+    (``model.last_fit_report["moe"]``): ``before`` is
+    :func:`routed_layer_totals` of the state the fit started from,
+    ``net_state`` the state it left, read here once, after the fit's last
+    drain (no readback a step). Published to ``registry``:
+    ``zoo_moe_assignments_total{layer=,held=}``,
+    ``zoo_moe_dropped_assignments_total``, and of the fit's last step
+    ``zoo_moe_expert_tokens{layer=,expert=}`` and
+    ``zoo_moe_load_max_over_mean{layer=}`` (largest held expert's tokens
+    over the held experts' mean); what bounded the layers' work,
+    ``zoo_moe_rows_run_total{layer=}``, ``zoo_moe_choice_passes_total
+    {layer=}``, ``zoo_moe_chunk_runs_total{layer=,compact=}``, and in the
+    report their ratios (:func:`bound_ratios`). ``None`` for a model
+    without such layers."""
+    after = routed_layer_totals(net_state)
+    if not after:
+        return None
+    counts = tuple(WIDE_COUNTERS)
+    report = {"layers": {}, **dict.fromkeys(counts, 0)}
+    for name, now in after.items():
+        was = before.get(name, {})
+        layer = {k: now[k] - was.get(k, 0) for k in counts}
+        for key in counts:
+            report[key] += layer[key]
+        layer.update(bound_ratios(layer))
+        layer["expert_tokens"] = now["expert_tokens"]
+        mean = sum(now["held_tokens"]) / max(len(now["held_tokens"]), 1)
+        layer["load_max_over_mean"] = (
+            max(now["held_tokens"]) / mean if mean > 0 else 0.0)
+        report["layers"][name] = layer
+        registry.counter(  # zoolint: disable=ZL015 one series a layer
+            "zoo_moe_rows_run_total",
+            "rows a RoutedExperts layer's row buffers held: the cut "
+            "size C a chunk whose held assignments fit in it, tokens x "
+            "top_k a chunk else",
+            labels={"layer": name}).inc(layer["rows_run"])
+        registry.counter(  # zoolint: disable=ZL015 one series a layer
+            "zoo_moe_choice_passes_total",
+            "gather-sum passes over a token's choices a RoutedExperts "
+            "layer ran, a chunk: the most held choices any token had, "
+            "or all top_k where those are many",
+            labels={"layer": name}).inc(layer["choice_passes"])
+        for cut, n in (("true", layer["compact_runs"]),
+                       ("false", layer["chunk_runs"]
+                        - layer["compact_runs"])):
+            registry.counter(  # zoolint: disable=ZL015 one series a layer
+                "zoo_moe_chunk_runs_total",
+                "chunks of tokens a RoutedExperts layer ran, by whether "
+                "its row buffers were cut to the rows held (compact="
+                "true) or held the worst case",
+                labels={"layer": name, "compact": cut}).inc(n)
+        for held, key in (("true", "held"), ("false", "absent")):
+            registry.counter(  # zoolint: disable=ZL015 one series a layer
+                "zoo_moe_assignments_total",
+                "token-to-expert assignments routed by a RoutedExperts "
+                "layer: computed here (held=true) or left to absent "
+                "experts (held=false)",
+                labels={"layer": name, "held": held}).inc(layer[key])
+        for e, n in enumerate(now["expert_tokens"]):
+            registry.gauge(  # zoolint: disable=ZL015 bounded: router width
+                "zoo_moe_expert_tokens",
+                "assignments per router output in the last step of "
+                "the last fit",
+                labels={"layer": name, "expert": str(e)}).set(n)
+        registry.gauge(  # zoolint: disable=ZL015 one series a layer
+            "zoo_moe_load_max_over_mean",
+            "largest held expert's tokens over the held experts' mean, "
+            "last step of the last fit",
+            labels={"layer": name}).set(layer["load_max_over_mean"])
+    registry.counter(
+        "zoo_moe_dropped_assignments_total",
+        "assignments a RoutedExperts layer placed with no expert "
+        "(0 by construction: the layer has no capacity)"
+    ).inc(report["dropped"])
+    report.update(bound_ratios(report))
+    return report

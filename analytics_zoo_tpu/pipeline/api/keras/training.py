@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import logging
 import signal
 import threading
@@ -145,17 +146,13 @@ def iter_batches(x, y, batch_size: int, *, shuffle: bool, seed: int,
 
 
 def _pad_to(x, size: int):
-    """Pad the batch dim to ``size`` by repeating the last row — ONE policy
-    for both host (numpy) and device-resident (jax) arrays, so the
-    device-cache fast path pads identically to the host path."""
-    xs = _as_list(x)
+    """Pad the batch dim to ``size`` by repeating the last row."""
     out = []
-    for a in xs:
-        xp = jnp if isinstance(a, jax.Array) else np
-        a = a if isinstance(a, jax.Array) else np.asarray(a)
+    for a in _as_list(x):
+        a = np.asarray(a)
         pad = size - a.shape[0]
         if pad > 0:
-            a = xp.concatenate([a, xp.repeat(a[-1:], pad, axis=0)], axis=0)
+            a = np.concatenate([a, np.repeat(a[-1:], pad, axis=0)], axis=0)
         out.append(a)
     return out if len(out) > 1 else out[0]
 
@@ -175,24 +172,6 @@ def _aux_loss_sum(state):
     return total
 
 
-def _stack_batches(items):
-    """Stack K ``(x, y)`` minibatches into one ``(K, batch, ...)`` chunk for
-    the multi-step scan dispatch. ``None`` labels pass through."""
-    return jax.tree.map(lambda *xs: np.stack([np.asarray(a) for a in xs], axis=0),
-                        *items)
-
-
-def _chunked(it, k: int):
-    buf = []
-    for item in it:
-        buf.append(item)
-        if len(buf) == k:
-            yield _stack_batches(buf)
-            buf = []
-    if buf:
-        yield _stack_batches(buf)
-
-
 class _FullPassEveryEpoch(Trigger):
     """``EveryEpoch`` over a sliced dataset: fires only when the finished
     slice pass completes a FULL pass over all slices
@@ -205,53 +184,52 @@ class _FullPassEveryEpoch(Trigger):
         return state.epoch_finished and state.epoch % self.num_slices == 0
 
 
-def _fired_within(trigger: Optional[Trigger], state: TrainLoopState,
+def _slice_aware(trig: Optional[Trigger],
+                 n_slices: int) -> Optional[Trigger]:
+    """``trig`` as the loop has to see it under a sliced disk set, where one
+    loop "epoch" is ONE slice pass: epoch triggers count FULL passes."""
+    if isinstance(trig, EveryEpoch):
+        return _FullPassEveryEpoch(n_slices)
+    if isinstance(trig, MaxEpoch):
+        return MaxEpoch(trig.max_epoch * n_slices)
+    if trig is not None and not isinstance(
+            trig, (SeveralIteration, _FullPassEveryEpoch)):
+        log.warning("trigger %s under a %d-slice DiskFeatureSet observes "
+                    "SLICE passes as epochs, not full passes",
+                    type(trig).__name__, n_slices)
+    return trig
+
+
+def _fired_within(trigger: Trigger, state: TrainLoopState,
                   prev_iter: int) -> bool:
-    """Whether a trigger fired at any step in ``(prev_iter, state.iteration]``.
-    With fused dispatches the loop only observes chunk boundaries; interval
-    triggers are checked over the whole window so a fire inside the chunk is
-    not lost — it is acted on at the boundary, up to (window-1) steps late:
-    K-1 for scan chunks, a whole epoch for device_cache (which warns when a
-    SeveralIteration interval is finer than the epoch)."""
-    if trigger is None:
-        return False
+    """Whether a trigger fired at any step in ``(prev_iter, state.iteration]``:
+    for a caller that looks only at epoch boundaries (the histogram writer
+    below), so that an interval trigger's fire inside the epoch is acted on
+    at the boundary and not lost."""
     if isinstance(trigger, SeveralIteration):
         return state.iteration // trigger.interval > prev_iter // trigger.interval
     return trigger(state)
 
 
-def _write_param_histograms(tb, params, epochs, iteration,
+def _write_param_histograms(tb, params, epoch: int, iteration: int,
                             n_steps: int = 0) -> None:
     """Per-layer weight histograms when the TrainSummary's "Parameters"
-    trigger fires for any epoch in ``epochs`` (reference:
+    trigger fires for ``epoch`` (reference:
     ``TrainSummary.setSummaryTrigger("Parameters", ...)`` +
-    ``Summary.scala``'s histogram writer). Called only at boundaries where
-    the params are host-visible; under fused-epoch dispatch that is the
-    final epoch of a fused block, covering the whole block's epochs —
-    ``n_steps`` (steps per epoch) reconstructs each covered epoch's own
-    boundary iteration, ending at ``iteration``, and an iteration-based
-    trigger is checked over that epoch's whole ``(boundary - n_steps,
-    boundary]`` window (``_fired_within`` semantics: a fire landing
-    mid-epoch is acted on at the boundary, not dropped)."""
-    epochs = list(epochs)
+    ``Summary.scala``'s histogram writer). Called at the epoch's boundary
+    ``iteration``, where the params are host-visible; an iteration-based
+    trigger is checked over the epoch's whole ``(iteration - n_steps,
+    iteration]`` window (without ``n_steps``: the boundary iteration
+    itself)."""
     trig = getattr(tb, "parameters_trigger", None)
     if trig is not None:
-        # Trigger-like form: evaluated per covered epoch (params are only
-        # host-visible at the block end, but the *decision* must match
-        # what per-epoch dispatch would have decided); without n_steps the
-        # window degrades to the boundary iteration itself
-        last = len(epochs) - 1
-        window = max(n_steps, 1)
-        if not any(_fired_within(
-                trig,
-                TrainLoopState(iteration=iteration - (last - k) * n_steps,
-                               epoch=e, epoch_finished=True),
-                prev_iter=iteration - (last - k) * n_steps - window)
-                   for k, e in enumerate(epochs)):
+        state = TrainLoopState(iteration=iteration, epoch=epoch,
+                               epoch_finished=True)
+        if not _fired_within(trig, state, iteration - max(n_steps, 1)):
             return
     else:
         freq = getattr(tb, "parameters_every_epochs", None)
-        if not freq or not any(e % freq == 0 for e in epochs):
+        if not freq or epoch % freq:
             return
     flat, _ = jax.tree_util.tree_flatten_with_path(params)
     for path, leaf in flat:
@@ -285,8 +263,11 @@ def _clone_tree(tree):
 
 
 class _SentinelMonitor:
-    """Host-side bookkeeping for the packed per-step sentinel flags
-    (``common/anomaly.py``; one int32 per step, ``(K,)`` per scan chunk).
+    """The host side of the guarded step (``common/anomaly.py``): it holds
+    what that step takes and returns beyond the plain one (the EWMA carry
+    and the fault code in; a packed int32 flag word out), so that the loop
+    dispatches one step one way (:meth:`step_args`, :meth:`took`), and it
+    keeps the books on the flags.
 
     Flag readbacks trail the dispatch stream by a small lag window so
     observing them never syncs the pipeline the way an eager per-step
@@ -303,6 +284,10 @@ class _SentinelMonitor:
     def __init__(self, loop: "TrainingLoop", cfg: anomaly.SentinelConfig):
         self.loop = loop
         self.cfg = cfg
+        # EWMA carry (device scalars) — fresh per fit attempt, as the
+        # monitor is: after a rollback the restored params' gradient
+        # scale is the baseline worth learning, not the diverging run's
+        self.sstate = anomaly.init_state()
         self.pending: collections.deque = collections.deque()
         self.epoch = 0
         self.epoch_start = 0                # iteration at epoch start
@@ -327,58 +312,78 @@ class _SentinelMonitor:
         back to the same data window on replay."""
         return (self.epoch, it - self.epoch_start)
 
-    def push(self, first_iter: int, flags_dev) -> None:
-        """Queue one dispatch's flag output (scalar or (K,) vector)."""
-        shape = getattr(flags_dev, "shape", ())
-        k = int(shape[0]) if shape else 1
-        self.epoch_step_iters.extend(range(first_iter, first_iter + k))
-        self.pending.append((first_iter, flags_dev))
+    def flagged(self, it: int) -> bool:
+        """Whether step ``it`` was flagged before a rollback: its data
+        window is not dispatched again on the replay."""
+        return self.step_key(it) in self.loop._anomalous_steps
+
+    def step_args(self, rng, x, y) -> Tuple:
+        """What the guarded step takes after the three state trees."""
+        return (self.sstate, rng, self._fault_input(), x, y)
+
+    def took(self, it: int, out) -> Tuple:
+        """Keep what the guarded step returned beyond the plain step's
+        ``(params, opt_state, net_state, loss)``; hand those four back."""
+        params, opt_state, net_state, self.sstate, loss, flags = out
+        self.epoch_step_iters.append(it)
+        self.pending.append((it, flags))
         if len(self.pending) > self.LAG:
             self._drain_one()
+        return params, opt_state, net_state, loss
 
-    def note_replay_skip(self, k: int) -> None:
-        """``k`` steps of a rollback replay were not re-dispatched (the
+    def _fault_input(self) -> np.ndarray:
+        """Host-side ``train.grads`` fault scheduling: one site call per
+        dispatched optimizer step. Returns the ``[code, scale]`` pair the
+        compiled step consumes (``anomaly.inject_grads``) — zeros (the
+        shared no-fault constant) unless an active plan fires a
+        nan_loss/nan_grad/spike spec at this call index."""
+        spec = faults.inject("train.grads") if self.cfg.faults else None
+        if spec is None:
+            return _NO_FAULT
+        code = anomaly.FAULT_CODES.get(spec.kind)
+        if code is None:        # e.g. a latency spec: already applied
+            return _NO_FAULT
+        return np.asarray([code, spec.scale], np.float32)
+
+    def note_replay_skip(self) -> None:
+        """A step of a rollback replay was not re-dispatched (the
         offending data window) — counted as skipped, no loss recorded."""
-        self.loop._m_skipped.inc(k)
+        self.loop._m_skipped.inc()
 
     def drain(self) -> None:
         while self.pending:
             self._drain_one()
 
     def _drain_one(self) -> None:
-        first_iter, flags_dev = self.pending.popleft()
-        words = np.atleast_1d(np.asarray(flags_dev))
-        for j, word in enumerate(words):
-            f = int(word)
-            self.epoch_flags.append(f)
-            if f & anomaly.GRAD_CLIPPED:
-                self.loop._m_clip.inc()
-            kinds = anomaly.kinds_of(f)
-            if not kinds:
-                continue
-            it = first_iter + j
-            for kind in kinds:
-                self.loop._m_anomaly[kind].inc()
-            self.loop._registry.emit(
-                "train.anomaly", iteration=it, epoch=self.epoch,
-                kinds=",".join(kinds), mode=self.cfg.mode,
-                action="skip" if self.cfg.mode == "recover" else "warn")
-            if self.cfg.mode == "recover":
-                self.loop._m_skipped.inc()
-                self.loop._anomalous_steps.add(self.step_key(it))
-                self.epoch_skips += 1
-                log.warning(
-                    "anomalous step at iteration %d (%s): update "
-                    "discarded (%d/%d skips this epoch)", it,
-                    ",".join(kinds), self.epoch_skips,
-                    self.cfg.max_skips_per_epoch)
-            else:
-                log.warning(
-                    "anomalous step at iteration %d (%s) — "
-                    "zoo.train.sentinel=warn: update APPLIED", it,
-                    ",".join(kinds))
-        if (self.cfg.mode == "recover"
-                and self.epoch_skips > self.cfg.max_skips_per_epoch):
+        it, flags_dev = self.pending.popleft()
+        f = int(np.asarray(flags_dev))
+        self.epoch_flags.append(f)
+        if f & anomaly.GRAD_CLIPPED:
+            self.loop._m_clip.inc()
+        kinds = anomaly.kinds_of(f)
+        if not kinds:
+            return
+        for kind in kinds:
+            self.loop._m_anomaly[kind].inc()
+        self.loop._registry.emit(
+            "train.anomaly", iteration=it, epoch=self.epoch,
+            kinds=",".join(kinds), mode=self.cfg.mode,
+            action="skip" if self.cfg.mode == "recover" else "warn")
+        if self.cfg.mode != "recover":
+            log.warning(
+                "anomalous step at iteration %d (%s) — "
+                "zoo.train.sentinel=warn: update APPLIED", it,
+                ",".join(kinds))
+            return
+        self.loop._m_skipped.inc()
+        self.loop._anomalous_steps.add(self.step_key(it))
+        self.epoch_skips += 1
+        log.warning(
+            "anomalous step at iteration %d (%s): update "
+            "discarded (%d/%d skips this epoch)", it,
+            ",".join(kinds), self.epoch_skips,
+            self.cfg.max_skips_per_epoch)
+        if self.epoch_skips > self.cfg.max_skips_per_epoch:
             raise _RollbackRequested(self.epoch_skips, self.epoch)
 
     def loss_mask(self, n: int) -> np.ndarray:
@@ -393,6 +398,37 @@ class _SentinelMonitor:
                 if f & anomaly.ANOMALY_MASK:
                     mask[i] = False
         return mask
+
+
+@dataclasses.dataclass
+class _FitRun:
+    """What one attempt of a fit carries from ``TrainingLoop._open_fit``
+    through its epochs: the live trees that the donated step consumes and
+    returns, and the loop's bookkeeping."""
+
+    fs: FeatureSet
+    batch_size: int             # rounded up to the data-parallel size
+    params: Any
+    opt_state: Any
+    net_state: Any
+    base_rng: Any               # step i's key is fold_in(base_rng, i)
+    mgr: Optional[CheckpointManager]
+    ckpt_trigger: Trigger
+    end_trigger: Optional[Trigger]
+    target_epoch: int
+    loop_state: TrainLoopState
+    monitor: Optional[_SentinelMonitor]     # None: the plain step
+    history: Dict[str, List[float]] = dataclasses.field(
+        default_factory=lambda: {"loss": []})
+    stop: bool = False          # the end trigger fired inside an epoch
+    epoch_t0: float = 0.0       # the open epoch's start (``time.time()``)
+
+
+def _host_losses(losses) -> np.ndarray:
+    """An epoch's per-step losses, read back to one host vector."""
+    if not losses:
+        return np.zeros(0, np.float32)
+    return np.concatenate([np.atleast_1d(np.asarray(l)) for l in losses])
 
 
 # ---------------------------------------------------------------------------
@@ -413,7 +449,8 @@ class _HostPhases(contextlib.ExitStack):
     The three that run every step (``pull`` and ``put``, entered by
     ``prefetch_to_device``, and ``dispatch``) are :class:`HostPhase`
     objects: one counter add and one profiler annotation. The three that
-    run once a fit or once an epoch follow one another in ``_fit_impl``:
+    run once a fit or once an epoch follow one another (``_open_fit``,
+    ``_close_epoch``):
     :meth:`switch` ends the open one and begins the next as a ``span``
     (histogram, event, annotation) whose seconds the counter gets too. The
     stack is entered around each fit attempt, so that an exception ends
@@ -461,22 +498,14 @@ class TrainingLoop:
         self.metrics = list(metrics)
         self.mesh = mesh_lib.global_mesh()
         self._train_step = None
-        self._scan_step = None
-        self._epoch_fns: Dict[Tuple, Any] = {}
         self._eval_step = None
         self._predict_step = None
-        # device-resident copy of the latest FeatureSet (device_cache path)
-        # — re-uploading per fit call costs a full host→device transfer of
-        # the whole set. The entry HOLDS the fs object: a bare id() key
-        # could be reused by a new FeatureSet after GC and silently serve
-        # the old dataset's arrays.
-        self._data_cache: Dict[Tuple, Any] = {}
         # observability (docs/guides/OBSERVABILITY.md): every fit updates
         # the zoo_train_* family in the process-wide registry
         self._registry = default_registry()
         self._m_step_time = self._registry.histogram(
             "zoo_train_step_seconds",
-            "optimizer-step wall time (amortized over fused dispatches)")
+            "optimizer-step wall time (an epoch's wall time over its steps)")
         self._m_throughput = self._registry.gauge(
             "zoo_train_records_per_sec", "training examples/sec, last epoch")
         self._m_mfu = self._registry.gauge(
@@ -568,19 +597,18 @@ class TrainingLoop:
         if self._goodput is not None:
             self._goodput.note(category)
 
-    def _dispatch(self, fn, iteration: int, steps: int, *args):
-        """Dispatch one segment of ``steps`` optimizer steps (a step, a scan
-        chunk, a fused epoch) starting at ``iteration``: the in-flight
-        probe sees the depth it finds and the loss it will fill, the
-        profiler a ``zoo.train.step`` step event."""
+    def _dispatch(self, fn, iteration: int, *args):
+        """Dispatch optimizer step ``iteration``: the in-flight probe sees
+        the depth it finds and the loss the step will fill, the profiler a
+        ``zoo.train.step`` step event."""
         probe = self._probe
         probe.at_dispatch()
         with self._phases.dispatch, jax.profiler.StepTraceAnnotation(
                 "zoo.train.step", step_num=iteration):
             out = fn(*args)
-        # every step function returns (params, opt_state, net_state, loss)
-        # or, under the sentinels, (..., sentinel state, loss, flags)
-        probe.dispatched(out[-1] if len(out) == 4 else out[-2], steps)
+        # the step returns (params, opt_state, net_state, loss) or, under
+        # the sentinels, (..., sentinel state, loss, flags)
+        probe.dispatched(out[-1] if len(out) == 4 else out[-2], 1)
         return out
 
     def _drain(self, losses, reduce: bool = True):
@@ -602,85 +630,6 @@ class TrainingLoop:
         if busy:
             self._gp_note("device_step")
         return mean
-
-    def _moe_report(self, before: Dict[str, Dict[str, Any]]
-                    ) -> Optional[Dict[str, Any]]:
-        """The routed layers' counters over the fit that just ended, read
-        from the layer state once, after the fit's last drain (no readback
-        a step), and published: ``zoo_moe_assignments_total{layer=,held=}``,
-        ``zoo_moe_dropped_assignments_total``, and of the fit's last step
-        ``zoo_moe_expert_tokens{layer=,expert=}`` and
-        ``zoo_moe_load_max_over_mean{layer=}`` (largest held expert's
-        tokens over the held experts' mean); what bounded the layers' work,
-        ``zoo_moe_rows_run_total{layer=}``, ``zoo_moe_choice_passes_total
-        {layer=}``, ``zoo_moe_chunk_runs_total{layer=,compact=}``, and in
-        the report their ratios (``layers/moe.py::bound_ratios``). ``None``
-        for a model without such layers."""
-        from .layers.moe import (WIDE_COUNTERS, bound_ratios,
-                                 routed_layer_totals)
-        after = routed_layer_totals(self.model.net_state)
-        if not after:
-            return None
-        reg = self._registry
-        counts = tuple(WIDE_COUNTERS)
-        report: Dict[str, Any] = {"layers": {}, **dict.fromkeys(counts, 0)}
-        for name, now in after.items():
-            was = before.get(name, {})
-            layer = {k: now[k] - was.get(k, 0) for k in counts}
-            for key in counts:
-                report[key] += layer[key]
-            layer.update(bound_ratios(layer))
-            layer["expert_tokens"] = now["expert_tokens"]
-            mean = sum(now["held_tokens"]) / max(len(now["held_tokens"]), 1)
-            layer["load_max_over_mean"] = (
-                max(now["held_tokens"]) / mean if mean > 0 else 0.0)
-            report["layers"][name] = layer
-            reg.counter(  # zoolint: disable=ZL015 one series a layer
-                "zoo_moe_rows_run_total",
-                "rows a RoutedExperts layer's row buffers held: the cut "
-                "size C a chunk whose held assignments fit in it, tokens x "
-                "top_k a chunk else",
-                labels={"layer": name}).inc(layer["rows_run"])
-            reg.counter(  # zoolint: disable=ZL015 one series a layer
-                "zoo_moe_choice_passes_total",
-                "gather-sum passes over a token's choices a RoutedExperts "
-                "layer ran, a chunk: the most held choices any token had, "
-                "or all top_k where those are many",
-                labels={"layer": name}).inc(layer["choice_passes"])
-            for cut, n in (("true", layer["compact_runs"]),
-                           ("false", layer["chunk_runs"]
-                            - layer["compact_runs"])):
-                reg.counter(  # zoolint: disable=ZL015 one series a layer
-                    "zoo_moe_chunk_runs_total",
-                    "chunks of tokens a RoutedExperts layer ran, by whether "
-                    "its row buffers were cut to the rows held (compact="
-                    "true) or held the worst case",
-                    labels={"layer": name, "compact": cut}).inc(n)
-            for held, key in (("true", "held"), ("false", "absent")):
-                reg.counter(  # zoolint: disable=ZL015 one series a layer
-                    "zoo_moe_assignments_total",
-                    "token-to-expert assignments routed by a RoutedExperts "
-                    "layer: computed here (held=true) or left to absent "
-                    "experts (held=false)",
-                    labels={"layer": name, "held": held}).inc(layer[key])
-            for e, n in enumerate(now["expert_tokens"]):
-                reg.gauge(  # zoolint: disable=ZL015 bounded: router width
-                    "zoo_moe_expert_tokens",
-                    "assignments per router output in the last step of "
-                    "the last fit",
-                    labels={"layer": name, "expert": str(e)}).set(n)
-            reg.gauge(  # zoolint: disable=ZL015 one series a layer
-                "zoo_moe_load_max_over_mean",
-                "largest held expert's tokens over the held experts' mean, "
-                "last step of the last fit",
-                labels={"layer": name}).set(layer["load_max_over_mean"])
-        reg.counter(
-            "zoo_moe_dropped_assignments_total",
-            "assignments a RoutedExperts layer placed with no expert "
-            "(0 by construction: the layer has no capacity)"
-        ).inc(report["dropped"])
-        report.update(bound_ratios(report))
-        return report
 
     def _fit_report(self, t_open: float, t_end: float,
                     host_before: Dict[str, float],
@@ -715,11 +664,10 @@ class TrainingLoop:
 
     def _loss_application(self):
         """``fn(params, net_state, x, y, rng) -> (loss, new_state)`` — the
-        forward+loss shared by every training-step builder. Resolves the
-        fused LM-head cross-entropy (``fused_loss.resolve_fused_loss``,
-        ``zoo.train.fused_ce``) ONCE per loop — the scan/epoch builders
-        call this at trace time, and re-resolving would re-log and
-        re-write the gauge on every retrace: a big-vocab Dense head with
+        train step's forward+loss. Resolves the fused LM-head
+        cross-entropy (``fused_loss.resolve_fused_loss``,
+        ``zoo.train.fused_ce``) ONCE per loop — re-resolving would re-log
+        and re-write the gauge: a big-vocab Dense head with
         a sparse-CE loss streams through ``ops/fused_cross_entropy`` so the
         ``(B·T, V)`` logits tensor never materializes; everything else runs
         the plain apply + objective (the oracle path, which ``evaluate``
@@ -733,8 +681,8 @@ class TrainingLoop:
         from .sharded_embed import resolve_sharded_embeddings
         # sequence/pipeline step integration (zoo.train.seq_attention /
         # zoo.train.pipe_stages): resolved once per loop like the fused
-        # loss, applied as trace-time scopes around every builder's
-        # forward so existing models ride seq/pipe meshes unchanged
+        # loss, applied as trace-time scopes around the step's forward
+        # so existing models ride seq/pipe meshes unchanged
         seq_mode = resolve_seq_attention()
         pipe_spec = resolve_pipe_spec(model)
         # row-sharded embedding engine (zoo.embed.sharded): resolved once
@@ -804,8 +752,8 @@ class TrainingLoop:
     def _remat_wrapper(self):
         """``zoo.train.remat`` (opt-in): wrap the per-step forward+loss in
         ``jax.checkpoint`` so the backward recomputes activations instead of
-        saving them across the scan — 32k training can raise batch/K
-        instead of sitting at batch 1. ``true``/``dots`` keeps MXU outputs
+        saving them — 32k training can raise the batch instead of sitting
+        at batch 1. ``true``/``dots`` keeps MXU outputs
         (``dots_with_no_batch_dims_saveable`` — recompute the cheap
         elementwise chains, keep the matmuls); ``full`` saves nothing
         (maximum memory relief, a full extra forward of recompute). See
@@ -834,10 +782,10 @@ class TrainingLoop:
 
     def _sentinel_config(self) -> anomaly.SentinelConfig:
         """Resolve the anomaly-sentinel/grad-clip knobs ONCE per loop
-        (like the fused-loss resolution): every step builder of a loop
-        must agree on the step signature, and with ``sentinel=off`` and
-        no clipping the builders emit the historical step exactly —
-        zero sentinel ops, bit-identical numerics."""
+        (like the fused-loss resolution): the step and the loop that
+        dispatches it must agree on the step's signature, and with
+        ``sentinel=off`` and no clipping the step is the historical one
+        exactly — zero sentinel ops, bit-identical numerics."""
         if self._sentinel is None:
             self._sentinel = anomaly.resolve_config()
             cfg = self._sentinel
@@ -855,8 +803,7 @@ class TrainingLoop:
         return self._sentinel
 
     def _make_step_core(self):
-        """The per-step forward/backward/update shared by the single-step
-        and scan builders. Returns ``(core_fn, cfg)``.
+        """The step's forward/backward/update. Returns ``(core_fn, cfg)``.
 
         With the sentinel layer inactive (``zoo.train.sentinel=off`` and
         no ``zoo.train.grad_clip``) the core is EXACTLY the historical
@@ -954,67 +901,6 @@ class TrainingLoop:
                                           donate_argnums=(0, 1, 2))
         return self._train_step
 
-    def _make_scan_body(self, base_rng):
-        """The shared per-step scan body (fold_in rng schedule → grad →
-        optimizer update) used by both the K-step chunk dispatch and the
-        whole-epoch dispatch, so the two fused paths can never diverge
-        numerically from each other or from the single-step path."""
-        core, cfg = self._make_step_core()
-
-        if not cfg.active:
-            def body(carry, batch):
-                params, opt_state, net_state, i = carry
-                x, y = batch
-                rng = jax.random.fold_in(base_rng, i)
-                params, opt_state, ns, l = core(params, opt_state,
-                                                net_state, rng, x, y)
-                return (params, opt_state, ns, i + 1), l
-            return body
-
-        def body(carry, batch):
-            params, opt_state, net_state, sstate, i = carry
-            x, y, fault = batch
-            rng = jax.random.fold_in(base_rng, i)
-            params, opt_state, ns, sstate, l, flags = core(
-                params, opt_state, net_state, sstate, rng, fault, x, y)
-            return (params, opt_state, ns, sstate, i + 1), (l, flags)
-        return body
-
-    def build_scan_step(self):
-        """Multi-step train function: runs K optimizer steps per dispatch via
-        ``lax.scan`` over stacked batches of shape ``(K, batch, ...)``.
-
-        This is the TPU-idiomatic answer to the reference's
-        one-Spark-job-per-iteration scheduling overhead
-        (``wp-bigdl.md:171-173``: >10% of compute lost to task dispatch at
-        scale): here the per-step Python/runtime dispatch cost is amortized
-        K-fold, leaving XLA a single fused program per chunk. With the
-        sentinel layer active the chunk additionally carries the EWMA
-        state and returns a ``(K,)`` packed flag vector alongside the
-        ``(K,)`` losses — one readback, per-step granularity."""
-        cfg = self._sentinel_config()
-
-        if not cfg.active:
-            def chunk(params, opt_state, net_state, base_rng, iter0, xs, ys):
-                (params, opt_state, net_state, _), losses = jax.lax.scan(
-                    self._make_scan_body(base_rng),
-                    (params, opt_state, net_state, iter0), (xs, ys))
-                return params, opt_state, net_state, losses
-        else:
-            def chunk(params, opt_state, net_state, sstate, base_rng,
-                      iter0, xs, ys, fault):
-                (params, opt_state, net_state, sstate, _), \
-                    (losses, flags) = jax.lax.scan(
-                        self._make_scan_body(base_rng),
-                        (params, opt_state, net_state, sstate, iter0),
-                        (xs, ys, fault))
-                return params, opt_state, net_state, sstate, losses, flags
-
-        self._scan_step = instrument_jit(chunk, name="train.scan_chunk",
-                                         registry=self._registry,
-                                         donate_argnums=(0, 1, 2))
-        return self._scan_step
-
     def _shard_opt_state(self, opt_state, psh, repl):
         """Committed placement for optimizer state: param-shaped leaves
         (adam moments) follow the param shardings, counters and the like
@@ -1022,7 +908,7 @@ class TrainingLoop:
         presents identical input shardings to the jitted step — otherwise
         the first call hands uncommitted counters while later calls hand
         committed ones, and each fit() misses the jit cache and recompiles
-        the whole epoch program (~20 s on a real chip).
+        the step (~20 s on a real chip).
 
         ``zoo.train.zero_sharding``: ZeRO-1 — moments additionally shard
         over the ``data`` axis (``mesh_lib.zero_sharding_for``); the jitted
@@ -1062,8 +948,8 @@ class TrainingLoop:
 
     def _pin_opt_state(self, opt_state):
         """In-step sharding constraint keeping ZeRO-sharded moments sharded
-        across scan iterations, and moments on their params' shardings
-        where those are pinned (no-op otherwise)."""
+        from step to step, and moments on their params' shardings where
+        those are pinned (no-op otherwise)."""
         sh = self._opt_state_shardings
         if sh is None:
             return opt_state
@@ -1082,105 +968,6 @@ class TrainingLoop:
         if sh is None:
             return params
         return jax.tree.map(jax.lax.with_sharding_constraint, params, sh)
-
-    def build_epoch_fn(self, n: int, batch_size: int, n_steps: int,
-                       shuffle: bool = True):
-        """Whole-epoch train function over a device-resident dataset
-        (``zoo.train.device_cache``): shuffle (jax.random.permutation) and all
-        ``n_steps`` optimizer steps run in ONE dispatch, so per-step host and
-        dispatch latency vanish entirely.
-
-        This is the HBM analogue of ``CachedDistributedFeatureSet``
-        (``FeatureSet.scala:222-322``): the reference caches the dataset in
-        executor RAM and re-shuffles an index per epoch; here the cache lives
-        in device HBM and the re-shuffle is an on-device gather. The epoch's
-        shuffled view is re-laid-out once per epoch under the stacked batch
-        sharding, so the per-step scan body stays identical to the chunked
-        path (numerically the same rng schedule as well)."""
-        if self._sentinel_config().active:
-            raise RuntimeError(
-                "whole-epoch dispatch is unavailable with the anomaly-"
-                "sentinel/grad-clip layer active (zoo.train.sentinel / "
-                "zoo.train.grad_clip) — fit falls back to the streamed "
-                "path automatically")
-        key = (n, batch_size, n_steps, shuffle)
-        if key in self._epoch_fns:
-            return self._epoch_fns[key]
-        body = self._make_epoch_body(n, batch_size, n_steps, shuffle)
-
-        def epoch(params, opt_state, net_state, base_rng, iter0, shuffle_rng,
-                  xs, ys):
-            (params, opt_state, net_state, _), losses = body(
-                (params, opt_state, net_state, iter0), base_rng, shuffle_rng,
-                xs, ys)
-            return params, opt_state, net_state, losses
-
-        fn = instrument_jit(epoch, name="train.epoch",
-                            registry=self._registry,
-                            donate_argnums=(0, 1, 2))
-        self._epoch_fns[key] = fn
-        return fn
-
-    def _make_epoch_body(self, n, batch_size, n_steps, shuffle):
-        """The shared whole-epoch body (on-device shuffle gather → scan of
-        optimizer steps) behind BOTH the per-epoch and the fused-epoch
-        dispatch, so the two paths cannot diverge numerically."""
-        stacked = mesh_lib.stacked_batch_sharding(self.mesh)
-        n_used = n_steps * batch_size
-
-        def body(carry, base_rng, shuffle_rng, xs, ys):
-            params, opt_state, net_state, it = carry
-            if shuffle:
-                perm = jax.random.permutation(shuffle_rng, n)[:n_used]
-            else:
-                perm = jnp.arange(n_used)
-
-            def shuffled(a):
-                out = jnp.take(a, perm, axis=0).reshape(
-                    (n_steps, batch_size) + a.shape[1:])
-                return jax.lax.with_sharding_constraint(out, stacked)
-
-            return jax.lax.scan(
-                self._make_scan_body(base_rng),
-                (params, opt_state, net_state, it),
-                (jax.tree.map(shuffled, xs), jax.tree.map(shuffled, ys)))
-
-        return body
-
-    def build_multi_epoch_fn(self, n: int, batch_size: int, n_steps: int,
-                             shuffle: bool, n_epochs: int):
-        """``zoo.train.fuse_epochs``: K whole epochs (shuffle + steps) in ONE
-        dispatch — a ``lax.scan`` over per-epoch shuffle keys around the
-        epoch body. The per-epoch dispatch + loss-readback round trips are
-        the remaining host cost after ``device_cache``; this amortizes
-        them K-fold (what that buys on a directly attached chip: not
-        re-measured). The rng schedule is
-        identical to the per-epoch path, so losses match bit-for-bit."""
-        if self._sentinel_config().active:
-            raise RuntimeError(
-                "fused-epoch dispatch is unavailable with the anomaly-"
-                "sentinel/grad-clip layer active (zoo.train.sentinel / "
-                "zoo.train.grad_clip)")
-        key = (n, batch_size, n_steps, shuffle, n_epochs)
-        if key in self._epoch_fns:
-            return self._epoch_fns[key]
-        body = self._make_epoch_body(n, batch_size, n_steps, shuffle)
-
-        def multi(params, opt_state, net_state, base_rng, iter0,
-                  shuffle_rngs, xs, ys):
-            def one_epoch(carry, ep_rng):
-                return body(carry, base_rng, ep_rng, xs, ys)
-
-            (params, opt_state, net_state, _), L = jax.lax.scan(
-                one_epoch, (params, opt_state, net_state, iter0),
-                shuffle_rngs)
-            return params, opt_state, net_state, L  # (n_epochs, n_steps)
-
-        fn = instrument_jit(multi, name="train.multi_epoch",
-                            registry=self._registry,
-                            donate_argnums=(0, 1, 2))
-        self._epoch_fns[key] = fn
-        return fn
 
     def build_eval_step(self):
         model, loss_fn, metrics = self.model, self.loss, self.metrics
@@ -1241,14 +1028,15 @@ class TrainingLoop:
         return self._predict_step
 
     # -- observability ------------------------------------------------------
-    def _maybe_compute_flops(self, fn, args, examples_per_dispatch) -> float:
-        """One-shot XLA cost-analysis pass caching FLOPs/example for the MFU
-        gauge. Opt-in (``zoo.metrics.flops``): the extra ``lower().compile()``
-        costs a compile, wasted on backends with no known peak — and
-        ``lower`` only reads avals/shardings, so calling it on buffers the
-        subsequent dispatch donates is safe. Returns the seconds spent so
-        callers can exclude the compile from their epoch-timing window
-        (the metrics this pass feeds must not be skewed by it)."""
+    def _maybe_compute_flops(self, args, batch_size: int) -> float:
+        """One-shot XLA cost-analysis pass over the train step, caching
+        FLOPs/example for the MFU gauge. Opt-in (``zoo.metrics.flops``): the
+        extra ``lower().compile()`` costs a compile, wasted on backends with
+        no known peak — and ``lower`` only reads avals/shardings, so calling
+        it on buffers the subsequent dispatch donates is safe. Returns the
+        seconds spent so the caller can exclude the compile from the
+        epoch-timing window (the metrics this pass feeds must not be skewed
+        by it)."""
         if self._flops_per_example is not None:
             return 0.0
         if not get_zoo_context().get("zoo.metrics.flops", False):
@@ -1260,12 +1048,12 @@ class TrainingLoop:
         self._gp_note("device_step")    # close the step interval first
         t = time.perf_counter()
         try:
-            flops = profiling.compiled_flops(fn.lower(*args).compile())
+            flops = profiling.compiled_flops(
+                self._train_step.lower(*args).compile())
         except Exception:   # backend-dependent; never fail a fit for MFU
             flops = None
         # 0.0 latches "tried and unavailable" so the compile isn't retried
-        self._flops_per_example = (
-            flops / examples_per_dispatch if flops else 0.0)
+        self._flops_per_example = flops / batch_size if flops else 0.0
         self._gp_note("compile")
         return time.perf_counter() - t
 
@@ -1305,18 +1093,18 @@ class TrainingLoop:
         spec = getattr(self.model, "_checkpoint", None) or {}
         return spec.get("trigger") or EveryEpoch()
 
-    def _save_checkpoint(self, mgr: CheckpointManager, loop_state, params,
-                         opt_state, net_state, sync: bool = False) -> None:
-        """Cut a snapshot. Async by default: the step path pays one host
-        transfer and the serialization/commit rides the manager's writer
-        thread; ``sync=True`` (the SIGTERM path) blocks until committed."""
-        mgr.save(loop_state.iteration,
-                 {"params": params, "opt_state": opt_state,
-                  "net_state": net_state},
-                 meta={"epoch": loop_state.epoch,
-                       "iteration": loop_state.iteration,
-                       "epoch_finished": loop_state.epoch_finished},
-                 sync=sync, mesh=mesh_lib.mesh_metadata(self.mesh))
+    def _save_checkpoint(self, run: "_FitRun", sync: bool = False) -> None:
+        """Cut a snapshot of the run's state where it stands. Async by
+        default: the step path pays one host transfer and the
+        serialization/commit rides the manager's writer thread;
+        ``sync=True`` (the SIGTERM path) blocks until committed."""
+        st = run.loop_state
+        run.mgr.save(st.iteration,
+                     {"params": run.params, "opt_state": run.opt_state,
+                      "net_state": run.net_state},
+                     meta={"epoch": st.epoch, "iteration": st.iteration,
+                           "epoch_finished": st.epoch_finished},
+                     sync=sync, mesh=mesh_lib.mesh_metadata(self.mesh))
 
     def _close_active_ckpt_mgr(self, surface: bool) -> None:
         """Join the active manager's in-flight save. ``surface=True``
@@ -1328,29 +1116,28 @@ class TrainingLoop:
         if mgr is not None:
             mgr.close(raise_pending=surface)
 
-    def _maybe_preempt(self, mgr, loop_state, params, opt_state,
-                       net_state) -> None:
+    def _maybe_preempt(self, run: "_FitRun") -> None:
         """SIGTERM arrived (``zoo.checkpoint.on_sigterm``): cut one final
         SYNCHRONOUS checkpoint at this step boundary, publish in-memory
         state, and exit cleanly via :class:`TrainingPreempted`."""
-        if mgr is None or not self._preempted.is_set():
+        if run.mgr is None or not self._preempted.is_set():
             return
+        iteration = run.loop_state.iteration
         log.warning("SIGTERM: cutting a final synchronous checkpoint at "
-                    "iteration %d before exiting", loop_state.iteration)
+                    "iteration %d before exiting", iteration)
         try:
-            self._save_checkpoint(mgr, loop_state, params, opt_state,
-                                  net_state, sync=True)
+            self._save_checkpoint(run, sync=True)
         except Exception:
             # the process is going down either way; the newest previous
             # snapshot (already committed) remains the resume point
             log.exception("final preemption checkpoint failed")
         model = self.model
         model.params, model.net_state, model.opt_state = _clone_tree(
-            (params, net_state, opt_state))
-        model.finished_iterations = loop_state.iteration
+            (run.params, run.net_state, run.opt_state))
+        model.finished_iterations = iteration
         raise TrainingPreempted(
             f"training preempted by SIGTERM; final checkpoint cut at "
-            f"iteration {loop_state.iteration}")
+            f"iteration {iteration}")
 
     def _on_sigterm(self, signum, frame) -> None:
         grace = self._sigterm_grace
@@ -1361,24 +1148,23 @@ class TrainingLoop:
         self._preempted.set()
 
     # -- SIGTERM grace budget (zoo.checkpoint.sigterm_grace_s) --------------
-    def _segment_begin(self, mgr, loop_state, params, opt_state,
-                       net_state) -> None:
-        """A dispatch segment (one step / scan chunk / fused epoch) is
-        about to enter the device. When the running duration estimate
+    def _segment_begin(self, run: "_FitRun") -> None:
+        """A dispatch segment (one step) is about to enter the device.
+        When the running duration estimate
         already exceeds the grace budget, clone the boundary state NOW —
         the dispatch donates these trees, so by the time the handler
         fires mid-segment the originals are deleted device buffers. A
         segment estimated to finish within the budget skips the clone
         (the handler just waits for the boundary), so the copy is only
         paid in the slow-segment regime it exists for."""
-        if self._sigterm_grace is None or mgr is None:
+        if self._sigterm_grace is None or run.mgr is None:
             return
         est = self._segment_est
         if est is not None and est > self._sigterm_grace:
+            st = run.loop_state
             self._boundary_ref = (
-                mgr, loop_state.iteration, loop_state.epoch,
-                loop_state.epoch_finished,
-                _clone_tree((params, opt_state, net_state)))
+                run.mgr, st.iteration, st.epoch, st.epoch_finished,
+                _clone_tree((run.params, run.opt_state, run.net_state)))
         else:
             self._boundary_ref = None
         self._segment_t0 = time.monotonic()
@@ -1452,20 +1238,6 @@ class TrainingLoop:
             f"training preempted by SIGTERM; grace budget {grace:g}s is "
             f"shorter than the ~{eta:.2f}s to the next step boundary — "
             f"mid-epoch checkpoint cut at iteration {iteration}")
-
-    def _fault_input(self) -> np.ndarray:
-        """Host-side ``train.grads`` fault scheduling: one site call per
-        dispatched optimizer step. Returns the ``[code, scale]`` pair the
-        compiled step consumes (``anomaly.inject_grads``) — zeros (the
-        shared no-fault constant) unless an active plan fires a
-        nan_loss/nan_grad/spike spec at this call index."""
-        spec = faults.inject("train.grads")
-        if spec is None:
-            return _NO_FAULT
-        code = anomaly.FAULT_CODES.get(spec.kind)
-        if code is None:        # e.g. a latency spec: already applied
-            return _NO_FAULT
-        return np.asarray([code, spec.scale], np.float32)
 
     def _try_resume(self, mgr: CheckpointManager, params, opt_state,
                     net_state, psh, repl, allow_regress: bool = False):
@@ -1618,8 +1390,8 @@ class TrainingLoop:
             self._goodput.open(t_open)
         host_before = self._phases.seconds()
         compile_before = xla_compile_totals()
-        from .layers.moe import routed_layer_totals
-        moe_before = routed_layer_totals(self.model.net_state)
+        from .layers import moe
+        moe_before = moe.routed_layer_totals(self.model.net_state)
         try:
             with profiling.trace(profile_dir), span("train.fit",
                                                     registry=self._registry):
@@ -1639,12 +1411,13 @@ class TrainingLoop:
             self.model.last_fit_report = self._fit_report(
                 t_open, t_end, host_before, compile_before)
             try:
-                moe = self._moe_report(moe_before)
+                moe_report = moe.fit_report(moe_before, self.model.net_state,
+                                            self._registry)
             except Exception:   # accounting must not mask the fit's own error
                 log.exception("routed-layer counters could not be read")
-                moe = None
-            if moe is not None:
-                self.model.last_fit_report["moe"] = moe
+                moe_report = None
+            if moe_report is not None:
+                self.model.last_fit_report["moe"] = moe_report
             self._probe.clear()
             # the boundary clone holds whole param trees — never past fit
             self._boundary_ref = None
@@ -1750,18 +1523,34 @@ class TrainingLoop:
                   rng=None, callbacks: Sequence[Callable] = (),
                   end_trigger: Optional[Trigger] = None,
                   ) -> Dict[str, List[float]]:
+        """One attempt of a fit: open it, then run and close one epoch
+        after another until the epoch target or the end trigger."""
+        run = self._open_fit(fs, batch_size=batch_size, nb_epoch=nb_epoch,
+                             target_holder=target_holder,
+                             validation_data=validation_data, rng=rng,
+                             end_trigger=end_trigger)
+        # an empty range (nb_epoch=0) is a clean no-op
+        for epoch in range(self.model.finished_epochs + 1,
+                           run.target_epoch + 1):
+            losses = self._run_epoch(run, epoch)
+            if not self._close_epoch(run, epoch, losses, validation_data,
+                                     callbacks):
+                break
+        return run.history
+
+    # -- fit, part 1: before the first epoch -------------------------------
+    def _open_fit(self, fs: FeatureSet, *, batch_size: int, nb_epoch: int,
+                  target_holder: Dict[str, int], validation_data, rng,
+                  end_trigger: Optional[Trigger]) -> _FitRun:
+        """What a fit attempt does before its first epoch: round the batch
+        size to the mesh, initialise weights and state, build the step,
+        place the donated clones, reuse or reset the optimizer state,
+        resume from a checkpoint, and fix the epoch target."""
         ctx = get_zoo_context()
         model = self.model
         self._phases.switch("fit.enter")
-        # fail NOW, not after an epoch of compute: scan fusing stacks K
-        # consecutive batches into one array (can't mix widths), and
-        # validation/evaluate need one dense array
-        if (getattr(fs, "ragged", False)
-                and int(ctx.get("zoo.train.scan_steps", 1)) > 1):
-            raise ValueError(
-                "bucketed (ragged) datasets cannot use "
-                "zoo.train.scan_steps > 1 — fused chunks stack same-shape "
-                "batches; set scan_steps=1")
+        # fail NOW, not after an epoch of compute: validation/evaluate
+        # need one dense array
         if getattr(validation_data, "ragged", False):
             raise ValueError(
                 "bucketed validation_data is not supported — evaluate per "
@@ -1780,17 +1569,6 @@ class TrainingLoop:
                         "rounding up to %d", batch_size, dp, rounded)
             batch_size = rounded
 
-        # K>1 runs K optimizer steps per dispatch via lax.scan
-        # (zoo.train.scan_steps); triggers are then observed at chunk
-        # boundaries (see _fired_within)
-        scan_steps = max(1, int(ctx.get("zoo.train.scan_steps", 1)))
-
-        # anomaly sentinels (docs/guides/TRAINING.md): resolved once per
-        # loop; active ⇒ the steps carry EWMA state + packed flags and
-        # the host runs a lagged flag monitor
-        sen = self._sentinel_config()
-        monitor = _SentinelMonitor(self, sen) if sen.active else None
-
         if model.params is None:
             model.init_weights(rng=rng, sample_input=fs.sample(1))
         if not model.net_state:
@@ -1798,8 +1576,6 @@ class TrainingLoop:
             # that keep state start from their own (none: {} again), so
             # that the step sees one state structure from its first call
             model.net_state = model.initial_state()
-        if scan_steps > 1 and self._scan_step is None:
-            self.build_scan_step()
         if self._train_step is None:
             self.build_train_step()
 
@@ -1814,27 +1590,7 @@ class TrainingLoop:
         # is a no-op alias and step 1 would delete the model's weights
         params = jax.device_put(_clone_tree(model.params), psh)
         net_state = jax.device_put(_clone_tree(model.net_state), repl)
-        # eval_shape: the CURRENT optimizer's state structure, zero allocation
-        fresh_struct = jax.tree_util.tree_structure(
-            jax.eval_shape(self.optimizer.init, params))
-        if model.opt_state is not None:
-            # reuse stored optimizer state only when it structurally matches
-            # the CURRENT optimizer — a clipping/optimizer change between
-            # train calls (Estimator.scala:75-100) alters the optax state
-            # tree, and feeding the old one would corrupt the update
-            same = (jax.tree_util.tree_structure(model.opt_state)
-                    == fresh_struct)
-            if same:
-                opt_state = self._shard_opt_state(
-                    _clone_tree(model.opt_state), psh, repl)
-            else:
-                log.warning("optimizer structure changed since the last fit; "
-                            "resetting optimizer state")
-                opt_state = self._shard_opt_state(
-                    self.optimizer.init(params), psh, repl)
-        else:
-            opt_state = self._shard_opt_state(self.optimizer.init(params),
-                                              psh, repl)
+        opt_state = self._initial_opt_state(params, psh, repl)
 
         # resume: if a checkpoint directory is configured and holds a snapshot
         # newer than this model's progress, restore it (process-death resume)
@@ -1842,512 +1598,285 @@ class TrainingLoop:
         # registered so _fit_with_retry can join/close the async writer on
         # every exit path (including exceptions and preemption)
         self._active_ckpt_mgr = mgr
-        ckpt_trigger = self._ckpt_trigger()
         if mgr is not None:
-            rollback = self._rollback_pending
-            self._rollback_pending = False
-            params, opt_state, net_state, meta = self._try_resume(
-                mgr, params, opt_state, net_state, psh, repl,
-                allow_regress=rollback)
-            # restore work belongs to the recovery path that demanded
-            # it; a clean first attempt's resume probe is just spin-up
-            self._gp_note("rollback_replay" if rollback
-                          else "restart" if self._gp_restarting
-                          else "idle")
-            self._gp_restarting = False
-            if meta is not None and meta.get("epoch") is not None:
-                resumed_epoch = int(meta["epoch"]) - (
-                    0 if meta.get("epoch_finished") else 1)
-                # a rollback REGRESSES the in-memory progress to the
-                # restored snapshot — the abandoned later epochs retrain
-                # (with the flagged windows skipped)
-                if rollback or resumed_epoch > model.finished_epochs:
-                    model.finished_epochs = resumed_epoch
-                model.finished_iterations = int(meta.get(
-                    "iteration", model.finished_iterations))
-            elif rollback:
-                log.warning("rollback requested but no snapshot could be "
-                            "restored; continuing from the in-memory "
-                            "state (further anomalies will re-escalate "
-                            "within the rollback budget)")
+            params, opt_state, net_state = self._resume_progress(
+                mgr, params, opt_state, net_state, psh, repl)
+
         # sliced disk tier: one loop "epoch" is ONE slice pass; nb_epoch and
         # EveryEpoch-style triggers count FULL passes of num_of_slice slices
         # (DiskFeatureSet + ZooTrigger.scala:44-66 slice awareness)
         n_slices = int(getattr(fs, "num_of_slice", 1) or 1)
+        ckpt_trigger = self._ckpt_trigger()
         if n_slices > 1:
-            def slice_aware(trig):
-                if isinstance(trig, EveryEpoch):
-                    return _FullPassEveryEpoch(n_slices)
-                if isinstance(trig, MaxEpoch):
-                    return MaxEpoch(trig.max_epoch * n_slices)
-                if trig is not None and not isinstance(
-                        trig, (SeveralIteration, _FullPassEveryEpoch)):
-                    log.warning("trigger %s under a %d-slice DiskFeatureSet "
-                                "observes SLICE passes as epochs, not full "
-                                "passes", type(trig).__name__, n_slices)
-                return trig
-            ckpt_trigger = slice_aware(ckpt_trigger)
-            end_trigger = slice_aware(end_trigger)
+            ckpt_trigger = _slice_aware(ckpt_trigger, n_slices)
+            end_trigger = _slice_aware(end_trigger, n_slices)
         if "target" not in target_holder:
             # "train nb_epoch more" counts from post-resume progress, matching
             # the reference's getFinishedEpoch continuation (Topology.scala:373-386)
             target_holder["target"] = (model.finished_epochs
                                        + nb_epoch * n_slices)
-        target_epoch = target_holder["target"]
 
-        # device-cache fast path: dataset lives in HBM, one dispatch per epoch
-        device_cache = bool(ctx.get("zoo.train.device_cache", False))
-        if device_cache and sen.active:
-            # sentinels observe per-step flags at dispatch boundaries and
-            # recovery needs the host in the loop; a whole-epoch dispatch
-            # would defer both to epoch granularity — fall back to the
-            # streamed path (documented in TRAINING.md)
-            log.warning(
-                "zoo.train.device_cache disabled for this fit: the "
-                "anomaly-sentinel/grad-clip layer is active "
-                "(zoo.train.sentinel=%s, zoo.train.grad_clip=%g); using "
-                "the streamed dispatch path", sen.mode, sen.grad_clip)
-            device_cache = False
-        epoch_fn = None
-        xs_dev = ys_dev = None
-        # n_slices first: DiskFeatureSet.y is a property that would gather
-        # the whole label file just to answer the None check
-        if (device_cache and n_slices <= 1
-                and getattr(fs, "device_cacheable", True)
-                and fs.y is not None):
-            n_steps = fs.steps_per_epoch(batch_size, drop_last=True)
-            for trig, role in ((ckpt_trigger, "checkpoint"),
-                               (end_trigger, "end")):
-                if (isinstance(trig, SeveralIteration)
-                        and trig.interval < n_steps):
-                    log.warning(
-                        "device_cache runs one dispatch per epoch, so the %s "
-                        "trigger SeveralIteration(%d) is only observed at "
-                        "epoch boundaries (%d steps) — up to %d steps late",
-                        role, trig.interval, n_steps,
-                        n_steps - trig.interval)
-            # the shuffled gather only reads indices < len(fs), so padding
-            # rows (needed to make the leading dim shardable over dp) are
-            # never selected
-            n_padded = _round_up(len(fs), dp)
+        # anomaly sentinels (docs/guides/TRAINING.md): active ⇒ the step
+        # carries EWMA state + packed flags, which the monitor holds
+        sen = self._sentinel_config()
+        return _FitRun(
+            fs=fs, batch_size=batch_size, params=params,
+            opt_state=opt_state, net_state=net_state,
+            base_rng=rng if rng is not None else ctx.rng(), mgr=mgr,
+            ckpt_trigger=ckpt_trigger, end_trigger=end_trigger,
+            target_epoch=target_holder["target"],
+            loop_state=TrainLoopState(iteration=model.finished_iterations,
+                                      epoch=model.finished_epochs + 1),
+            monitor=_SentinelMonitor(self, sen) if sen.active else None)
 
-            def put(a):
-                # device-resident inputs (extract→fit chain) pad and
-                # relayout ON DEVICE — no host round trip
-                return jax.device_put(jnp.asarray(_pad_to(a, n_padded)),
-                                      mesh_lib.batch_sharding(self.mesh))
+    def _initial_opt_state(self, params, psh, repl):
+        """The optimizer state a fit starts from: the model's stored state
+        only when it structurally matches the CURRENT optimizer — a
+        clipping/optimizer change between train calls
+        (Estimator.scala:75-100) alters the optax state tree, and feeding
+        the old one would corrupt the update — else a fresh one."""
+        stored = self.model.opt_state
+        if stored is not None:
+            # eval_shape: the CURRENT optimizer's state structure, zero
+            # allocation
+            fresh_struct = jax.tree_util.tree_structure(
+                jax.eval_shape(self.optimizer.init, params))
+            if jax.tree_util.tree_structure(stored) == fresh_struct:
+                return self._shard_opt_state(_clone_tree(stored), psh, repl)
+            log.warning("optimizer structure changed since the last fit; "
+                        "resetting optimizer state")
+        return self._shard_opt_state(self.optimizer.init(params), psh, repl)
 
-            epoch_fn = self.build_epoch_fn(len(fs), batch_size, n_steps,
-                                           shuffle=fs.shuffle)
-            cache_key = (id(fs), len(fs), n_padded)
-            if cache_key not in self._data_cache:
-                # keep only the latest dataset resident (HBM is the scarce
-                # resource; switching sets back and forth re-uploads)
-                self._data_cache.clear()
-                self._data_cache[cache_key] = (fs, jax.tree.map(put, fs.x),
-                                               jax.tree.map(put, fs.y))
-            _, xs_dev, ys_dev = self._data_cache[cache_key]
+    def _resume_progress(self, mgr: CheckpointManager, params, opt_state,
+                         net_state, psh, repl):
+        """Restore the newest snapshot at or past the model's progress (any
+        snapshot, on a rollback) and set the model's epoch and iteration
+        counts from it. Returns the three trees, restored or as given."""
+        model = self.model
+        rollback = self._rollback_pending
+        self._rollback_pending = False
+        params, opt_state, net_state, meta = self._try_resume(
+            mgr, params, opt_state, net_state, psh, repl,
+            allow_regress=rollback)
+        # restore work belongs to the recovery path that demanded
+        # it; a clean first attempt's resume probe is just spin-up
+        self._gp_note("rollback_replay" if rollback
+                      else "restart" if self._gp_restarting
+                      else "idle")
+        self._gp_restarting = False
+        if meta is not None and meta.get("epoch") is not None:
+            resumed_epoch = int(meta["epoch"]) - (
+                0 if meta.get("epoch_finished") else 1)
+            # a rollback REGRESSES the in-memory progress to the
+            # restored snapshot — the abandoned later epochs retrain
+            # (with the flagged windows skipped)
+            if rollback or resumed_epoch > model.finished_epochs:
+                model.finished_epochs = resumed_epoch
+            model.finished_iterations = int(meta.get(
+                "iteration", model.finished_iterations))
+        elif rollback:
+            log.warning("rollback requested but no snapshot could be "
+                        "restored; continuing from the in-memory "
+                        "state (further anomalies will re-escalate "
+                        "within the rollback budget)")
+        return params, opt_state, net_state
 
-        base_rng = rng if rng is not None else ctx.rng()
+    # -- fit, part 2: one epoch's steps -------------------------------------
+    def _run_epoch(self, run: _FitRun, epoch: int) -> List[Any]:
+        """Stream one epoch's batches through the step. Returns the losses
+        of the steps dispatched, still on the device; ``run.stop`` says
+        whether the end trigger cut the epoch short."""
+        # epoch-boundary overhead (metrics, callbacks, validation of
+        # the previous epoch) since the last step lands on idle: the
+        # previous epoch's tail drained the device
+        self._gp_note("idle")
+        self._phases.switch()       # fit.enter or epoch.publish ends
+        run.epoch_t0 = time.time()
+        st, mon = run.loop_state, run.monitor
+        st.epoch = epoch
+        # clear the boundary flag: mid-epoch trigger checks must not see
+        # the previous epoch's True (stale EveryEpoch/MaxEpoch fires)
+        st.epoch_finished = False
+        if mon is not None:
+            mon.begin_epoch(epoch, st.iteration)
+        stream = prefetch_to_device(
+            run.fs.iter_batches(run.batch_size,
+                                epoch=get_zoo_context().seed + epoch,
+                                drop_last=True),
+            self.mesh, ledger=self._goodput, phases=self._phases)
         throttle_cpu = jax.default_backend() == "cpu"
-        # sentinel EWMA carry (device scalars) — fresh per fit attempt:
-        # after a rollback the restored params' gradient scale is the
-        # baseline worth learning, not the diverging run's
-        sstate = anomaly.init_state() if sen.active else None
-        # the no-fault input for scan chunks, allocated ONCE per fit and
-        # sliced per dispatch (the single-step path shares _NO_FAULT)
-        no_fault_chunk = (np.zeros((scan_steps, 2), np.float32)
-                          if sen.active and scan_steps > 1 else None)
-        history: Dict[str, List[float]] = {"loss": []}
-        loop_state = TrainLoopState(iteration=model.finished_iterations,
-                                    epoch=model.finished_epochs + 1)
-        stop = False
-
-        # fused-epoch fast path: K epochs per dispatch. Only when nothing
-        # needs the host between epochs — no checkpointing, validation, or
-        # end trigger (nb_epoch still bounds the run); per-epoch losses and
-        # records come out identical to the per-epoch path (same rng
-        # schedule), only the wall timing is amortized across the block.
-        fuse = int(ctx.get("zoo.train.fuse_epochs", 1))
-        if (epoch_fn is not None and fuse > 1 and mgr is None
-                and validation_data is None and end_trigger is None):
-            n_steps = fs.steps_per_epoch(batch_size, drop_last=True)
-            tb = getattr(model, "_train_summary", None)
-            epoch = model.finished_epochs
-            while epoch < target_epoch:
-                self._phases.switch()
-                g = min(fuse, target_epoch - epoch)
-                t0 = time.time()
-                it0 = jnp.asarray(loop_state.iteration, jnp.int32)
-                if g == 1:
-                    shuffle_rng = jax.random.key(
-                        fs.seed + ctx.seed + epoch + 1)
-                    t0 += self._maybe_compute_flops(
-                        epoch_fn, (params, opt_state, net_state, base_rng,
-                                   it0, shuffle_rng, xs_dev, ys_dev),
-                        n_steps * batch_size)
-                    params, opt_state, net_state, L = self._dispatch(
-                        epoch_fn, loop_state.iteration, n_steps,
-                        params, opt_state, net_state, base_rng, it0,
-                        shuffle_rng, xs_dev, ys_dev)
-                else:
-                    mfn = self.build_multi_epoch_fn(
-                        len(fs), batch_size, n_steps, fs.shuffle, g)
-                    keys = jnp.stack(
-                        [jax.random.key(fs.seed + ctx.seed + e)
-                         for e in range(epoch + 1, epoch + g + 1)])
-                    t0 += self._maybe_compute_flops(
-                        mfn, (params, opt_state, net_state, base_rng, it0,
-                              keys, xs_dev, ys_dev),
-                        g * n_steps * batch_size)
-                    params, opt_state, net_state, L = self._dispatch(
-                        mfn, loop_state.iteration, g * n_steps,
-                        params, opt_state, net_state, base_rng, it0, keys,
-                        xs_dev, ys_dev)
-                self._drain([L], reduce=False)
-                self._phases.switch("epoch.tail")
-                L = np.asarray(L).reshape(g, -1)
-                dt = (time.time() - t0) / g
-                self._phases.switch("epoch.publish")
-                self._observe_fit_metrics(g * n_steps, dt * g,
-                                          g * n_steps * batch_size)
-                loop_state.iteration += g * n_steps
-                # publish once per block: the intermediate epochs' params
-                # never materialize on the host (that is the point)
-                model.params, model.net_state, model.opt_state = _clone_tree(
-                    (params, net_state, opt_state))
-                model.finished_iterations = loop_state.iteration
-                thr = (n_steps * batch_size / dt) if dt > 0 else 0.0
-                lr = getattr(model, "_lr", None)
-                # every epoch inside a fused block completes by construction
-                loop_state.epoch_finished = True
-                for j in range(g):
-                    e = epoch + 1 + j
-                    last = j == g - 1
-                    epoch_loss = float(L[j].mean())
-                    history["loss"].append(epoch_loss)
-                    model.finished_epochs = e
-                    loop_state.epoch = e
-                    it_e = loop_state.iteration - (g - 1 - j) * n_steps
-                    # intermediate epochs' weights never materialize on the
-                    # host (that is the point of fusing) — their records say
-                    # so with None rather than smuggling end-of-block params
-                    # under an earlier epoch number
-                    record = {"epoch": e, "loss": epoch_loss,
-                              "iteration": it_e, "throughput": thr,
-                              "params": model.params if last else None,
-                              "opt_state": model.opt_state if last else None,
-                              "net_state": model.net_state if last else None,
-                              "loop_state": loop_state}
-                    if tb is not None:
-                        for k2, lv in enumerate(L[j]):
-                            tb.add_scalar("Loss", float(lv),
-                                          it_e - n_steps + k2 + 1)
-                        tb.add_scalar("Throughput", thr, it_e)
-                        if callable(lr):
-                            tb.add_scalar("LearningRate", float(lr(it_e)),
-                                          it_e)
-                        elif isinstance(lr, (int, float)):
-                            tb.add_scalar("LearningRate", float(lr), it_e)
-                        if last:
-                            _write_param_histograms(
-                                tb, model.params,
-                                range(epoch + 1, epoch + g + 1), it_e,
-                                n_steps=n_steps)
-                        tb.writer.flush()
-                    log.info("Epoch %d: loss=%.6f (%.1f ex/s)", e,
-                             epoch_loss, thr)
-                    for cb in callbacks:
-                        cb(record)
-                epoch += g
-            return history
-
-        epoch = model.finished_epochs  # so nb_epoch=0 is a clean no-op
-        for epoch in range(model.finished_epochs + 1, target_epoch + 1):
-            # epoch-boundary overhead (metrics, callbacks, validation of
-            # the previous epoch) since the last step lands on idle: the
-            # previous epoch's tail drained the device
-            self._gp_note("idle")
-            self._phases.switch()       # fit.enter or epoch.publish ends
-            t0 = time.time()
-            losses = []
-            n_seen = 0
-            loop_state.epoch = epoch
-            # clear the boundary flag: mid-epoch trigger checks must not see
-            # the previous epoch's True (stale EveryEpoch/MaxEpoch fires)
-            loop_state.epoch_finished = False
-            if monitor is not None:
-                monitor.begin_epoch(epoch, loop_state.iteration)
-            if epoch_fn is not None:
-                prev_iter = loop_state.iteration
-                shuffle_rng = jax.random.key(fs.seed + ctx.seed + epoch)
-                it0 = jnp.asarray(prev_iter, jnp.int32)
-                n_steps = fs.steps_per_epoch(batch_size, drop_last=True)
-                t0 += self._maybe_compute_flops(
-                    epoch_fn, (params, opt_state, net_state, base_rng, it0,
-                               shuffle_rng, xs_dev, ys_dev),
-                    n_steps * batch_size)
-                self._segment_begin(mgr, loop_state, params, opt_state,
-                                    net_state)
-                params, opt_state, net_state, l = self._dispatch(
-                    epoch_fn, prev_iter, n_steps,
-                    params, opt_state, net_state, base_rng, it0, shuffle_rng,
-                    xs_dev, ys_dev)
-                self._segment_end()
-                self._gp_note("device_step")   # whole-epoch dispatch
-                losses.append(l)
-                loop_state.iteration += n_steps
-                n_seen += n_steps * batch_size
-                if mgr is not None and _fired_within(ckpt_trigger, loop_state,
-                                                     prev_iter):
-                    self._save_checkpoint(mgr, loop_state, params, opt_state,
-                                          net_state)
-                self._maybe_preempt(mgr, loop_state, params, opt_state,
-                                    net_state)
-                if _fired_within(end_trigger, loop_state, prev_iter):
-                    stop = True
-                stream = ()
-            elif scan_steps > 1:
-                batches = fs.iter_batches(batch_size, epoch=ctx.seed + epoch,
-                                          drop_last=True)
-                stream = prefetch_to_device(
-                    _chunked(batches, scan_steps), self.mesh,
-                    sharding=mesh_lib.stacked_batch_sharding(self.mesh),
-                    ledger=self._goodput, phases=self._phases)
+        losses: List[Any] = []
+        for bx, by in stream:
+            if mon is not None and mon.flagged(st.iteration):
+                # rollback replay: the offending data window is NOT
+                # re-dispatched (its step was flagged before the
+                # rollback); iteration still advances so the rng
+                # schedule and the triggers stay aligned with the
+                # original attempt
+                st.iteration += 1
+                mon.note_replay_skip()
+                self._gp_note("anomaly_skip")
             else:
-                batches = fs.iter_batches(batch_size, epoch=ctx.seed + epoch,
-                                          drop_last=True)
-                stream = prefetch_to_device(batches, self.mesh,
-                                            ledger=self._goodput,
-                                            phases=self._phases)
-            for bx_d, by_d in stream:
-                prev_iter = loop_state.iteration
-                k = jax.tree.leaves(bx_d)[0].shape[0] if scan_steps > 1 \
-                    else 1
-                if (monitor is not None and self._anomalous_steps
-                        and any(monitor.step_key(prev_iter + j)
-                                in self._anomalous_steps
-                                for j in range(k))):
-                    # rollback replay: the offending data window is NOT
-                    # re-dispatched (its steps were flagged before the
-                    # rollback); iteration still advances so the rng
-                    # schedule and trigger windows stay aligned with the
-                    # original attempt
-                    loop_state.iteration += k
-                    monitor.note_replay_skip(k)
-                    self._gp_note("anomaly_skip")
-                    if mgr is not None and _fired_within(
-                            ckpt_trigger, loop_state, prev_iter):
-                        self._save_checkpoint(mgr, loop_state, params,
-                                              opt_state, net_state)
-                    self._maybe_preempt(mgr, loop_state, params, opt_state,
-                                        net_state)
-                    if _fired_within(end_trigger, loop_state, prev_iter):
-                        stop = True
-                        break
-                    continue
-                if scan_steps > 1:
-                    it0 = jnp.asarray(prev_iter, jnp.int32)
-                    if monitor is None:
-                        t0 += self._maybe_compute_flops(
-                            self._scan_step,
-                            (params, opt_state, net_state, base_rng, it0,
-                             bx_d, by_d), k * batch_size)
-                        self._segment_begin(mgr, loop_state, params,
-                                            opt_state, net_state)
-                        params, opt_state, net_state, l = self._dispatch(
-                            self._scan_step, prev_iter, k,
-                            params, opt_state, net_state, base_rng, it0,
-                            bx_d, by_d)
-                        self._segment_end()
-                    else:
-                        fault = (np.stack([self._fault_input()
-                                           for _ in range(k)])
-                                 if sen.faults
-                                 else no_fault_chunk[:k])
-                        t0 += self._maybe_compute_flops(
-                            self._scan_step,
-                            (params, opt_state, net_state, sstate,
-                             base_rng, it0, bx_d, by_d, fault),
-                            k * batch_size)
-                        self._segment_begin(mgr, loop_state, params,
-                                            opt_state, net_state)
-                        (params, opt_state, net_state, sstate, l,
-                         flags) = self._dispatch(
-                             self._scan_step, prev_iter, k,
-                             params, opt_state, net_state, sstate,
-                             base_rng, it0, bx_d, by_d, fault)
-                        self._segment_end()
-                        monitor.push(prev_iter, flags)
-                    loop_state.iteration += k
-                    n_seen += k * batch_size
-                else:
-                    step_rng = jax.random.fold_in(base_rng, prev_iter)
-                    if monitor is None:
-                        t0 += self._maybe_compute_flops(
-                            self._train_step,
-                            (params, opt_state, net_state, step_rng, bx_d,
-                             by_d), batch_size)
-                        self._segment_begin(mgr, loop_state, params,
-                                            opt_state, net_state)
-                        params, opt_state, net_state, l = self._dispatch(
-                            self._train_step, prev_iter, 1,
-                            params, opt_state, net_state, step_rng, bx_d,
-                            by_d)
-                        self._segment_end()
-                    else:
-                        fault = (self._fault_input() if sen.faults
-                                 else _NO_FAULT)
-                        t0 += self._maybe_compute_flops(
-                            self._train_step,
-                            (params, opt_state, net_state, sstate,
-                             step_rng, fault, bx_d, by_d), batch_size)
-                        self._segment_begin(mgr, loop_state, params,
-                                            opt_state, net_state)
-                        (params, opt_state, net_state, sstate, l,
-                         flags) = self._dispatch(
-                             self._train_step, prev_iter, 1,
-                             params, opt_state, net_state, sstate,
-                             step_rng, fault, bx_d, by_d)
-                        self._segment_end()
-                        monitor.push(prev_iter, flags)
-                    loop_state.iteration += 1
-                    n_seen += batch_size
-                losses.append(l)
+                losses.append(self._run_step(run, bx, by))
                 # XLA:CPU only — bound host run-ahead. Its in-process
                 # collective rendezvous aborts (40 s timeout) when dozens
                 # of slow queued programs starve some device threads;
                 # blocking every few dispatches caps the queue. Real TPU
                 # runtimes pipeline deeply and stay unthrottled.
                 if throttle_cpu and len(losses) % 4 == 0:
-                    jax.block_until_ready(l)
-                if mgr is not None and _fired_within(ckpt_trigger, loop_state,
-                                                     prev_iter):
-                    self._save_checkpoint(mgr, loop_state, params, opt_state,
-                                          net_state)
-                self._maybe_preempt(mgr, loop_state, params, opt_state,
-                                    net_state)
-                if _fired_within(end_trigger, loop_state, prev_iter):
-                    stop = True
-                    break
-            completed = not stop  # stop=True means the epoch was cut short
-            if stop and stream:
+                    jax.block_until_ready(losses[-1])
+            if self._step_boundary(run):
+                run.stop = True
                 # a mid-epoch stop leaves the pipeline suspended at its
                 # yield: end it here, and with it its last ledger interval
                 # (left to the collector, it would close after the tail)
                 stream.close()
-            mean_loss = self._drain(losses, reduce=monitor is None)
-            self._phases.switch("epoch.tail")
-            if monitor is not None:
-                # drain every pending flag first (escalation may raise
-                # here, BEFORE the boundary checkpoint below); in recover
-                # mode skipped steps' losses were never applied and are
-                # excluded from the epoch mean
-                lv = (np.concatenate([np.atleast_1d(np.asarray(l))
-                                      for l in losses])
-                      if losses else np.zeros(0, np.float32))
-                lmask = monitor.loss_mask(len(lv))
-                epoch_loss = (float(lv[lmask].mean()) if lmask.any()
-                              else float("nan"))
-            else:
-                epoch_loss = (float(mean_loss) if losses else float("nan"))
-            dt = time.time() - t0
-            self._phases.switch("epoch.publish")
-            self._observe_fit_metrics(n_seen // batch_size, dt, n_seen)
-            history["loss"].append(epoch_loss)
-            loop_state.epoch_finished = completed
-            if hasattr(end_trigger, "record"):
-                end_trigger.record(epoch_loss)
-            # cut a snapshot at the trigger, or unconditionally on a mid-epoch
-            # stop so the truncated epoch's progress survives (its meta says
-            # epoch_finished=False, so a resume retrains that epoch)
-            if mgr is not None and (stop or ckpt_trigger(loop_state)):
-                self._save_checkpoint(mgr, loop_state, params, opt_state,
-                                      net_state)
-
-            # publish progress every epoch — clones, because the live trees
-            # feed the donating train step next epoch; this is also what a
-            # retry attempt falls back to when the newest snapshot is older
-            model.params, model.net_state, model.opt_state = \
-                _clone_tree((params, net_state, opt_state))
-            if completed:
-                model.finished_epochs = epoch
-            model.finished_iterations = loop_state.iteration
-
-            record = {"epoch": epoch, "loss": epoch_loss,
-                      "iteration": loop_state.iteration,
-                      "throughput": n_seen / dt if dt > 0 else 0.0,
-                      "params": model.params, "opt_state": model.opt_state,
-                      "net_state": model.net_state, "loop_state": loop_state}
-            val = None
-            if validation_data is not None:
-                if isinstance(validation_data, FeatureSet):
-                    vx, vy = validation_data.x, validation_data.y
-                else:
-                    vx, vy = validation_data
-                val = self.evaluate(vx, vy, batch_size=batch_size)
-                for k, v in val.items():
-                    history.setdefault("val_" + k, []).append(v)
-                record.update({"val_" + k: v for k, v in val.items()})
-            tb = getattr(model, "_train_summary", None)
-            if tb is not None:
-                # one Loss point per optimizer step (the reference's
-                # per-iteration granularity), written at epoch end so no
-                # device sync lands inside the dispatch pipeline
-                loss_vec = (np.concatenate(
-                    [np.atleast_1d(np.asarray(l)) for l in losses])
-                    if losses else np.zeros(0))
-                if (monitor is not None
-                        and len(monitor.epoch_step_iters) == len(loss_vec)):
-                    # replay-skipped windows advance the iteration
-                    # counter without recording losses — the monitor's
-                    # per-step iteration log keeps each point on its
-                    # real x position
-                    loss_its = [i + 1 for i in monitor.epoch_step_iters]
-                else:
-                    start_it = loop_state.iteration - len(loss_vec)
-                    loss_its = [start_it + j + 1
-                                for j in range(len(loss_vec))]
-                for j, lv in enumerate(loss_vec):
-                    tb.add_scalar("Loss", float(lv), loss_its[j])
-                tb.add_scalar("Throughput", record["throughput"],
-                              loop_state.iteration)
-                lr = getattr(model, "_lr", None)
-                if callable(lr):
-                    tb.add_scalar("LearningRate",
-                                  float(lr(loop_state.iteration)),
-                                  loop_state.iteration)
-                elif isinstance(lr, (int, float)):
-                    tb.add_scalar("LearningRate", float(lr),
-                                  loop_state.iteration)
-                if completed:
-                    # a mid-epoch end_trigger stop retrains this epoch on
-                    # the next fit(); logging its partial params here
-                    # would put two histograms under one epoch number
-                    _write_param_histograms(tb, model.params, (epoch,),
-                                            loop_state.iteration,
-                                            n_steps=len(loss_vec))
-                tb.writer.flush()
-            vtb = getattr(model, "_val_summary", None)
-            if vtb is not None and val is not None:
-                for k, v in val.items():
-                    vtb.add_scalar(k, float(v), loop_state.iteration)
-                vtb.writer.flush()
-            log.info("Epoch %d%s: loss=%.6f (%.1f ex/s)%s", epoch,
-                     "" if completed else " (stopped mid-epoch)", epoch_loss,
-                     record["throughput"],
-                     "".join(f" val_{k}={v:.4f}" for k, v in
-                             (val.items() if val is not None else ())))
-            for cb in callbacks:
-                cb(record)
-            # epoch_finished stays True through this boundary check (it is
-            # cleared at the next epoch's start): MaxEpoch must see the
-            # finished count, else a satisfied end trigger runs one extra
-            # partial epoch
-            if stop or (end_trigger is not None and end_trigger(loop_state)):
                 break
+        return losses
 
-        return history
+    def _run_step(self, run: _FitRun, bx, by):
+        """Dispatch one optimizer step: THE place the loop calls the
+        compiled program. Returns the step's loss (a device scalar)."""
+        st, mon = run.loop_state, run.monitor
+        it = st.iteration
+        rng = jax.random.fold_in(run.base_rng, it)
+        args = (run.params, run.opt_state, run.net_state) + (
+            (rng, bx, by) if mon is None else mon.step_args(rng, bx, by))
+        run.epoch_t0 += self._maybe_compute_flops(args, run.batch_size)
+        self._segment_begin(run)
+        out = self._dispatch(self._train_step, it, *args)
+        self._segment_end()
+        if mon is not None:
+            out = mon.took(it, out)
+        run.params, run.opt_state, run.net_state, loss = out
+        st.iteration = it + 1
+        return loss
+
+    def _step_boundary(self, run: _FitRun) -> bool:
+        """After every step of the stream, dispatched or skipped on a
+        replay: checkpoint if its trigger fired, exit if SIGTERM asked
+        for it, and say whether the end trigger fired."""
+        st = run.loop_state
+        if run.mgr is not None and run.ckpt_trigger(st):
+            self._save_checkpoint(run)
+        self._maybe_preempt(run)
+        return run.end_trigger is not None and run.end_trigger(st)
+
+    # -- fit, part 3: the epoch's tail --------------------------------------
+    def _close_epoch(self, run: _FitRun, epoch: int, losses: List[Any],
+                     validation_data, callbacks: Sequence[Callable]) -> bool:
+        """Wait for the epoch's steps, then everything that happens once
+        an epoch: its loss, the boundary checkpoint, the published clone
+        of the state, validation, summaries, the record and the
+        callbacks. Returns whether the fit goes on."""
+        model, st, mon = self.model, run.loop_state, run.monitor
+        completed = not run.stop    # a stop means the epoch was cut short
+        mean_loss = self._drain(losses, reduce=mon is None)
+        self._phases.switch("epoch.tail")
+        loss_vec = None
+        if mon is not None:
+            # drain every pending flag first (escalation may raise
+            # here, BEFORE the boundary checkpoint below); in recover
+            # mode skipped steps' losses were never applied and are
+            # excluded from the epoch mean
+            loss_vec = _host_losses(losses)
+            lmask = mon.loss_mask(len(loss_vec))
+            epoch_loss = (float(loss_vec[lmask].mean()) if lmask.any()
+                          else float("nan"))
+        else:
+            epoch_loss = float(mean_loss) if losses else float("nan")
+        dt = time.time() - run.epoch_t0
+        self._phases.switch("epoch.publish")
+        n_seen = len(losses) * run.batch_size
+        self._observe_fit_metrics(len(losses), dt, n_seen)
+        run.history["loss"].append(epoch_loss)
+        st.epoch_finished = completed
+        if hasattr(run.end_trigger, "record"):
+            run.end_trigger.record(epoch_loss)
+        # cut a snapshot at the trigger, or unconditionally on a mid-epoch
+        # stop so the truncated epoch's progress survives (its meta says
+        # epoch_finished=False, so a resume retrains that epoch)
+        if run.mgr is not None and (run.stop or run.ckpt_trigger(st)):
+            self._save_checkpoint(run)
+
+        # publish progress every epoch — clones, because the live trees
+        # feed the donating train step next epoch; this is also what a
+        # retry attempt falls back to when the newest snapshot is older
+        model.params, model.net_state, model.opt_state = _clone_tree(
+            (run.params, run.net_state, run.opt_state))
+        if completed:
+            model.finished_epochs = epoch
+        model.finished_iterations = st.iteration
+
+        record = {"epoch": epoch, "loss": epoch_loss,
+                  "iteration": st.iteration,
+                  "throughput": n_seen / dt if dt > 0 else 0.0,
+                  "params": model.params, "opt_state": model.opt_state,
+                  "net_state": model.net_state, "loop_state": st}
+        val = None
+        if validation_data is not None:
+            if isinstance(validation_data, FeatureSet):
+                vx, vy = validation_data.x, validation_data.y
+            else:
+                vx, vy = validation_data
+            val = self.evaluate(vx, vy, batch_size=run.batch_size)
+            for k, v in val.items():
+                run.history.setdefault("val_" + k, []).append(v)
+            record.update({"val_" + k: v for k, v in val.items()})
+        tb = getattr(model, "_train_summary", None)
+        if tb is not None:
+            self._write_train_summary(
+                tb, run, epoch, completed, record["throughput"],
+                loss_vec if loss_vec is not None else _host_losses(losses))
+        vtb = getattr(model, "_val_summary", None)
+        if vtb is not None and val is not None:
+            for k, v in val.items():
+                vtb.add_scalar(k, float(v), st.iteration)
+            vtb.writer.flush()
+        log.info("Epoch %d%s: loss=%.6f (%.1f ex/s)%s", epoch,
+                 "" if completed else " (stopped mid-epoch)", epoch_loss,
+                 record["throughput"],
+                 "".join(f" val_{k}={v:.4f}" for k, v in
+                         (val.items() if val is not None else ())))
+        for cb in callbacks:
+            cb(record)
+        # epoch_finished stays True through this boundary check (it is
+        # cleared at the next epoch's start): MaxEpoch must see the
+        # finished count, else a satisfied end trigger runs one extra
+        # partial epoch
+        return not (run.stop or (run.end_trigger is not None
+                                 and run.end_trigger(st)))
+
+    def _write_train_summary(self, tb, run: _FitRun, epoch: int,
+                             completed: bool, throughput: float,
+                             loss_vec: np.ndarray) -> None:
+        """The epoch's TensorBoard scalars: one Loss point per optimizer
+        step (the reference's per-iteration granularity), written at epoch
+        end so no device sync lands inside the dispatch pipeline."""
+        iteration, mon = run.loop_state.iteration, run.monitor
+        if mon is not None and len(mon.epoch_step_iters) == len(loss_vec):
+            # replay-skipped windows advance the iteration counter
+            # without recording losses — the monitor's per-step
+            # iteration log keeps each point on its real x position
+            loss_its = [i + 1 for i in mon.epoch_step_iters]
+        else:
+            start_it = iteration - len(loss_vec)
+            loss_its = [start_it + j + 1 for j in range(len(loss_vec))]
+        for j, lv in enumerate(loss_vec):
+            tb.add_scalar("Loss", float(lv), loss_its[j])
+        tb.add_scalar("Throughput", throughput, iteration)
+        lr = getattr(self.model, "_lr", None)
+        if callable(lr):
+            tb.add_scalar("LearningRate", float(lr(iteration)), iteration)
+        elif isinstance(lr, (int, float)):
+            tb.add_scalar("LearningRate", float(lr), iteration)
+        if completed:
+            # a mid-epoch end_trigger stop retrains this epoch on
+            # the next fit(); logging its partial params here
+            # would put two histograms under one epoch number
+            _write_param_histograms(tb, self.model.params, epoch,
+                                    iteration, n_steps=len(loss_vec))
+        tb.writer.flush()
 
     # -- evaluate / predict -------------------------------------------------
     def _padded_batches(self, x, y, eff_bs: int, dp: int, *, with_mask: bool):
@@ -2516,9 +2045,7 @@ def _set_tensorboard(self: KerasNet, log_dir: str, app_name: str,
     ``parameters_every_epochs=N`` additionally writes per-layer weight
     HISTOGRAMS every N epochs (the reference's
     ``TrainSummary.setSummaryTrigger("Parameters", ...)`` +
-    ``Summary.scala`` histogram path); under fused-epoch dispatch they
-    land on the final epoch of each fused block, where the params are
-    host-visible."""
+    ``Summary.scala`` histogram path)."""
     from ....utils.tensorboard import TrainSummary, ValidationSummary
     for attr in ("_train_summary", "_val_summary"):
         old = getattr(self, attr, None)

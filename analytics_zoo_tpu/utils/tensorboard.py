@@ -327,8 +327,7 @@ class TrainSummary(_Summary):
         its pre-Trigger keyword spelling ``every_epochs=``) or a
         Trigger-like callable (``common.triggers``: ``EveryEpoch()``,
         ``SeveralIteration(n)``, ...) evaluated at epoch boundaries, where
-        the params are host-visible; under fused-epoch dispatch that is
-        the final epoch of each fused block. The reference's always-on
+        the params are host-visible. The reference's always-on
         scalar families (``Loss``/``Throughput``/``LearningRate``) accept
         any trigger as a no-op."""
         if every_epochs is not None:

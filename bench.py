@@ -50,11 +50,7 @@ XEON_BASELINE_RECS_PER_SEC = 1.0e6
 N_USERS, N_ITEMS, N_CLASSES = 6040, 3706, 5
 N_EXAMPLES = 1_000_000
 BATCH = 8192
-SCAN_STEPS = 16          # optimizer steps fused per dispatch (lax.scan)
-TIMED_EPOCHS = 12   # fused epochs per timed dispatch: the fixed cost of one
-# dispatch + loss readback is amortized over TIMED_EPOCHS*steps_per_epoch
-# sub-millisecond steps (its size on a directly attached chip: not
-# re-measured)
+TIMED_EPOCHS = 12   # epochs per timed fit
 
 
 def load_movielens(path):
@@ -140,16 +136,10 @@ def bench_wide_deep():
            .set_batch_size(8192).set_max_epoch(1))
     clf.fit(table)  # warmup epoch (compile)
     fs = FeatureSet.array(clf._features(table), clf._label(table))
-    # second warmup at the timed shape: with fuse_epochs active the 6-epoch
-    # run is its own fused program — compile it outside the timing. 6 epochs
-    # = ~144 fused steps per dispatch, so the fixed per-dispatch cost is
-    # spread over many sub-millisecond steps (its size on a directly
-    # attached chip: not re-measured)
+    # second warmup at the timed shape, outside the timing
     clf.model._loop.fit_feature_set(fs, batch_size=8192, nb_epoch=6)
-    # three independent timed dispatches, median across them as the
-    # headline (same rationale as ``main``: robust to one stalled
-    # dispatch, and a median of independent measurements rather than
-    # fuse_epochs' max==median artifact, VERDICT r4 weak #4)
+    # three independent timed fits, median across them as the headline
+    # (same rationale as ``main``: robust to one stalled fit)
     disp = []
     for _ in range(3):
         records = []
@@ -164,7 +154,7 @@ def bench_bert_finetune():
     """Parity config #4: BERT-base text-classification fine-tune throughput
     (the TFPark BERTClassifier path, ``tfpark/text/estimator/bert_*.py``).
     Real BERT-base dims (12x768x12, seq 128); weights random-init on device
-    (no host upload), throughput from the fused-epoch dispatch.
+    (no host upload), throughput from the epoch records of ``fit``.
 
     Runs the MXU-native regime: bfloat16 compute policy (params stay fp32 —
     the policy the reference never had; VERDICT r3 weak #1), hardware-RBG
@@ -172,8 +162,7 @@ def bench_bert_finetune():
     per-weight dropout masks measured ~25% of the step), bf16 embedding
     gathers, ``attn_drop=0`` (the flash-attention-era fine-tune recipe;
     the per-probability dropout masks over the (B, 12, T, T) score tensor
-    measured ~10% of the seq-128 step — MFU 0.497 → 0.553), and the
-    fused-epoch dispatch inherited from ``main``'s context. Attention
+    measured ~10% of the seq-128 step — MFU 0.497 → 0.553). Attention
     stays on the fused XLA op at both shapes — measured FASTER than the
     Pallas flash kernel up to seq 1024 on a v5e (1.11x at 512); flash's
     auto threshold is 2048, where XLA stops compiling BERT-base at all.
@@ -191,7 +180,7 @@ def bench_bert_finetune():
     from analytics_zoo_tpu.utils import profiling
 
     def one_config(seq_len, batch, n):
-        # n=4096 at seq 128 → 32 steps/epoch per fused 2-epoch dispatch
+        # n=4096 at seq 128 → 32 steps/epoch
         rng = np.random.default_rng(3)
         tok = rng.integers(1, 30000, (n, seq_len)).astype(np.int32)
         y = rng.integers(0, 2, n).astype(np.int32)
@@ -203,7 +192,7 @@ def bench_bert_finetune():
             x = m.make_inputs(tok)
             m.compile(optimizer=optax.adamw(2e-5), loss="scce")
             fs = FeatureSet.array(x, y, seed=0)
-            # warmup at the timed shape: nb_epoch=2 is its own fused program
+            # warmup at the timed shape
             m.fit(fs, batch_size=batch, nb_epoch=2)
             records = []
             # two timed fits, best-of: one stalled dispatch (observed once
@@ -401,7 +390,7 @@ def bench_long_context():
     V), so the loss-drop gate proves the flash BACKWARD kernel produces
     real gradients, not just a fast forward.
 
-    Reported per seq length: tokens/s (best fused-epoch dispatch) and MFU.
+    Reported per seq length: tokens/s (best epoch record) and MFU.
     FLOPs accounting is analytic — XLA cost analysis can't see inside
     pallas custom calls: fwd/token = n_block*(24H^2 + 2*T*H_causal) +
     2*H*V head; train = 3x fwd (no recompute credit)."""
@@ -440,7 +429,7 @@ def bench_long_context():
             m.compile(optimizer=optax.adam(3e-4), loss="scce_with_logits")
             fs = FeatureSet.array(x, y, seed=0)
             records = []
-            # warmup compiles the fused program; its records join the loss
+            # warmup compiles the step; its records join the loss
             # gate so the drop is measured over the whole run
             m.fit(fs, batch_size=batch, nb_epoch=2, callbacks=[records.append])
             timed = []
@@ -846,103 +835,6 @@ def bench_int8_inference():
     return out
 
 
-def bench_sentinel():
-    """Anomaly-sentinel overhead at the value-model (NCF) shape
-    (ISSUE 10): recover-mode sentinels — on-device nan-loss/nan-grad/
-    spike checks, the packed flag output, and the skip selects — must
-    cost <3% step time vs the sentinel-free step, gated by
-    ``ABSOLUTE_CEILINGS["sentinel_overhead_pct"]``. Device-only
-    measurement: fused K-step scan dispatches, readback-fenced, median
-    of 5 timed windows per mode with the off/recover windows
-    INTERLEAVED (off, on, off, on, ...) so machine-load drift over the
-    run lands on both modes equally — back-to-back per-mode blocks let
-    a background-load swing between the blocks fake (or mask) the
-    delta — and the per-dispatch cost can neither wash out nor fake it."""
-    import jax
-    import jax.numpy as jnp
-
-    from analytics_zoo_tpu.common import anomaly as anomaly_lib
-    from analytics_zoo_tpu.common.context import get_zoo_context
-    from analytics_zoo_tpu.models.recommendation import NeuralCF
-    from analytics_zoo_tpu.parallel import mesh as mesh_lib
-
-    rng_np = np.random.default_rng(11)
-    n = SCAN_STEPS * BATCH
-    x = np.stack([rng_np.integers(1, N_USERS + 1, n).astype(np.int32),
-                  rng_np.integers(1, N_ITEMS + 1, n).astype(np.int32)],
-                 axis=1)
-    y = rng_np.integers(0, N_CLASSES, n).astype(np.int32)
-    xs = x.reshape(SCAN_STEPS, BATCH, 2)
-    ys = y.reshape(SCAN_STEPS, BATCH)
-
-    conf = get_zoo_context().conf
-    prev = conf.get("zoo.train.sentinel", "off")
-
-    def prepare(mode):
-        # conf poke + a FRESH loop: the sentinel config is resolved once
-        # per TrainingLoop, so each mode gets its own compiled step
-        conf["zoo.train.sentinel"] = mode
-        model = NeuralCF(N_USERS, N_ITEMS, N_CLASSES)
-        model.compile(optimizer="adam", loss="scce", lr=1e-3)
-        model.init_weights(sample_input=x[:BATCH])
-        loop = model._loop
-        fn = loop.build_scan_step()
-        repl = mesh_lib.replicated_sharding(loop.mesh)
-        stacked = mesh_lib.stacked_batch_sharding(loop.mesh)
-        params = jax.device_put(jax.tree.map(jnp.copy, model.params), repl)
-        net_state = jax.device_put(jax.tree.map(jnp.copy, model.net_state),
-                                   repl)
-        opt_state = jax.device_put(loop.optimizer.init(params), repl)
-        xs_d = jax.device_put(xs, stacked)
-        ys_d = jax.device_put(ys, stacked)
-        base_rng = jax.random.key(0)
-        it0 = jnp.asarray(0, jnp.int32)
-        sen_on = loop._sentinel_config().active
-        fault = np.zeros((SCAN_STEPS, 2), np.float32)
-        sstate = anomaly_lib.init_state() if sen_on else None
-
-        def dispatch(params, opt_state, net_state, sstate):
-            # donated args: re-feed outputs so buffers stay valid
-            if sen_on:
-                params, opt_state, net_state, sstate, losses, _fl = fn(
-                    params, opt_state, net_state, sstate, base_rng, it0,
-                    xs_d, ys_d, fault)
-            else:
-                params, opt_state, net_state, losses = fn(
-                    params, opt_state, net_state, base_rng, it0, xs_d,
-                    ys_d)
-            return params, opt_state, net_state, sstate, losses
-
-        box = [dispatch(params, opt_state, net_state, sstate)]  # compile
-        np.asarray(box[0][4])       # readback fence
-
-        def window(n_rep=3):
-            t0 = time.perf_counter()
-            for _ in range(n_rep):
-                box[0] = dispatch(*box[0][:4])
-            np.asarray(box[0][4])
-            return (time.perf_counter() - t0) / (n_rep * SCAN_STEPS) * 1e3
-
-        return window
-
-    try:
-        off_win = prepare("off")
-        on_win = prepare("recover")
-    finally:
-        conf["zoo.train.sentinel"] = prev
-    off_windows, on_windows = [], []
-    for _ in range(5):
-        off_windows.append(off_win())
-        on_windows.append(on_win())
-    off_ms = float(np.median(off_windows))
-    on_ms = float(np.median(on_windows))
-    overhead = (max(0.0, on_ms / off_ms - 1.0) * 100.0
-                if off_ms > 0 else 0.0)
-    return {"sentinel_off_step_ms": round(off_ms, 4),
-            "sentinel_on_step_ms": round(on_ms, 4),
-            "sentinel_overhead_pct": round(overhead, 2)}
-
-
 def bench_codec():
     """Serving wire-codec microbench: encode+decode round-trip throughput
     (MB/s of tensor payload) for the v2 raw little-endian format vs the
@@ -1237,7 +1129,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description="analytics_zoo_tpu bench")
     channels = ("ncf", "wide_deep", "int8", "transfer", "bert",
                 "long_context", "long_context_sharded", "fused_ce",
-                "embedding_oocore", "sentinel", "codec", "serving",
+                "embedding_oocore", "codec", "serving",
                 "serving_fleet", "serving_device")
     ap.add_argument("--only", default=None, metavar="CHANNEL_REGEX",
                     help="run only bench channels whose name matches this "
@@ -1255,11 +1147,7 @@ def main(argv=None):
               f"(available: {' '.join(channels)})", file=sys.stderr)
         sys.exit(3)
 
-    # device_cache: the 12 MB dataset lives in HBM; fuse_epochs: the whole
-    # timed run (shuffles + all optimizer steps) is ONE dispatch — no
-    # per-epoch dispatch/readback round trip lands inside the timed window
-    init_zoo_context(train_scan_steps=SCAN_STEPS, train_device_cache=True,
-                     train_fuse_epochs=TIMED_EPOCHS)
+    init_zoo_context()
 
     import jax
     out = {"metric": "ncf_train_recs_per_sec", "value": None,
@@ -1294,20 +1182,14 @@ def main(argv=None):
         fs = FeatureSet.array(x, y, seed=0)
         steps_per_epoch = fs.steps_per_epoch(BATCH)
 
-        # warmup: compiles both the single-epoch fn (ragged final group) and the
-        # TIMED_EPOCHS-fused fn at their real shapes, so the timed run below is
-        # a pure cache-hit dispatch
+        # warmup: compiles the step and, at the timed epoch length, the
+        # epoch's loss reduction, so the timed fits below compile nothing
         model.fit(fs, batch_size=BATCH, nb_epoch=1)
         model.fit(fs, batch_size=BATCH, nb_epoch=TIMED_EPOCHS)
 
-        # THREE independent timed dispatches; the headline is the MEDIAN across
-        # dispatches. One stalled dispatch (observed 2026-07-31 on the
-        # earlier set-up: host overhead 0.03 -> 0.18 ms/step between
-        # identical-code rounds, a uniform -13..-26% swing across every
-        # dispatch-bound config) can no longer poison the round's recorded
-        # number — and the statistic is a
-        # median of independent measurements, not fuse_epochs' max==median
-        # artifact (VERDICT r4 weak #4).
+        # THREE independent timed fits; the headline is the MEDIAN across
+        # them, so that one stalled fit cannot poison the round's recorded
+        # number.
         disp_ths, disp_walls, records = [], [], []
         for _ in range(3):
             recs = []
@@ -1321,46 +1203,41 @@ def main(argv=None):
         wall = float(np.median(disp_walls))
         loss_first, loss_last = records[0]["loss"], records[-1]["loss"]
 
-        # -- device-only epoch time: re-dispatch the resident epoch fn ----------
+        # -- the step alone: re-dispatch the compiled step on one resident
+        # batch, no input pipeline and no epoch tail. The host runs ahead of
+        # the device, so this is the device's time a step unless a step is
+        # shorter than its dispatch ---------------------------------------------
         import jax.numpy as jnp
         from analytics_zoo_tpu.parallel import mesh as mesh_lib
 
         loop = model._loop
-        epoch_fn = loop.build_epoch_fn(len(fs), BATCH, steps_per_epoch,
-                                       shuffle=True)  # cached from fit
+        step = loop._train_step         # compiled by fit
         bsh = mesh_lib.batch_sharding(loop.mesh)
         repl = mesh_lib.replicated_sharding(loop.mesh)
-        xs_dev = jax.device_put(np.asarray(fs.x), bsh)
-        ys_dev = jax.device_put(np.asarray(fs.y), bsh)
+        bx = jax.device_put(np.asarray(fs.x)[:BATCH], bsh)
+        by = jax.device_put(np.asarray(fs.y)[:BATCH], bsh)
         params = jax.device_put(jax.tree.map(jnp.copy, model.params), repl)
         net_state = jax.device_put(jax.tree.map(jnp.copy, model.net_state), repl)
         opt_state = jax.device_put(loop.optimizer.init(params), repl)
-        base_rng = jax.random.key(0)
-        it0 = jnp.asarray(0, jnp.int32)
-        shuffle_rng = jax.random.key(1)
+        rng = jax.random.key(0)
         # donated args: re-feed outputs so buffers stay valid
-        params, opt_state, net_state, l = epoch_fn(
-            params, opt_state, net_state, base_rng, it0, shuffle_rng, xs_dev, ys_dev)
+        params, opt_state, net_state, l = step(
+            params, opt_state, net_state, rng, bx, by)
         np.asarray(l)  # readback fence: the loss is on the host before the
-        # clock starts (whether block_until_ready alone would do on a
-        # directly attached chip: not re-measured)
-        n_rep, td0 = 3, time.perf_counter()
+        # clock starts
+        n_rep, td0 = 3 * steps_per_epoch, time.perf_counter()
         for _ in range(n_rep):
-            params, opt_state, net_state, l = epoch_fn(
-                params, opt_state, net_state, base_rng, it0, shuffle_rng,
-                xs_dev, ys_dev)
+            params, opt_state, net_state, l = step(
+                params, opt_state, net_state, rng, bx, by)
         np.asarray(l)
-        device_step_ms = ((time.perf_counter() - td0)
-                          / (n_rep * steps_per_epoch) * 1e3)
+        device_step_ms = (time.perf_counter() - td0) / n_rep * 1e3
 
         # -- flops accounting from XLA cost analysis -----------------------------
         # None when the backend publishes no cost analysis (flops/MFU are
         # optional extras); lowering a function that just ran must not fail
-        flops_epoch = profiling.compiled_flops(
-            epoch_fn.lower(params, opt_state, net_state, base_rng, it0,
-                           shuffle_rng, xs_dev, ys_dev).compile())
-        flops_per_example = (flops_epoch / (steps_per_epoch * BATCH)
-                             if flops_epoch else None)
+        flops_step = profiling.compiled_flops(
+            step.lower(params, opt_state, net_state, rng, bx, by).compile())
+        flops_per_example = flops_step / BATCH if flops_step else None
         mfu = (profiling.mfu(flops_per_example * best)
                if flops_per_example else None)
 
@@ -1413,7 +1290,6 @@ def main(argv=None):
     channel("long_context", bench_long_context)
     channel("fused_ce", bench_fused_ce)
     channel("embedding_oocore", bench_embedding_oocore)
-    channel("sentinel", bench_sentinel)
     channel("codec", bench_codec)
     channel("serving", lambda: {
         "serving_resnet50_records_per_sec": round(bench_serving(), 1)})
@@ -1464,7 +1340,7 @@ def main(argv=None):
     print(json.dumps(out))
     if selected("ncf"):
         print(f"# wall={wall:.2f}s epochs={TIMED_EPOCHS} batch={BATCH} "
-              f"scan_steps={SCAN_STEPS} steps/epoch={steps_per_epoch} "
+              f"steps/epoch={steps_per_epoch} "
               f"device_kind={jax.devices()[0].device_kind}", file=sys.stderr)
         # correctness gate: the model must beat the zeroth-order
         # predictor — the label-marginal entropy H (= ln 5 for the
@@ -1545,8 +1421,9 @@ ABSOLUTE_FLOORS = {
 }
 # lower-is-better correctness metrics: fail above the ceiling.
 # device_step_ms is the NCF compute-regression backstop for the wide
-# wall-clock tolerance above: it times re-dispatches of the resident epoch
-# fn (readback-fenced), is stable across rounds (0.846/0.848/0.696 ms on
+# wall-clock tolerance above: it times re-dispatches of the compiled step
+# (readback-fenced; as re-dispatches of a whole resident epoch, until PR
+# 30, it was stable across rounds: 0.846/0.848/0.696 ms on
 # identical or faster code), and a real kernel/engine regression must show
 # up here even when dispatch noise hides it from the wall-clock headline
 # ceiling = 1.1: +30% over the slowest healthy round (0.848) — the timing
@@ -1555,13 +1432,7 @@ ABSOLUTE_FLOORS = {
 # 366 steps) can leak in; 1.1 keeps that from false-tripping while a real
 # ≥30% compute regression cannot hide
 ABSOLUTE_CEILINGS = {"int8_top1_delta_pct": 2.0,
-                     "device_step_ms": 1.1,
-                     # recover-mode anomaly sentinels must stay under 3%
-                     # of step time at the value-model shape (ISSUE 10
-                     # acceptance) — both modes are measured device-only
-                     # in the same process, so the ratio excludes the
-                     # dispatch cost by construction
-                     "sentinel_overhead_pct": 3.0}
+                     "device_step_ms": 1.1}
 
 
 def latest_bench_record():

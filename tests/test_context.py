@@ -9,6 +9,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 from analytics_zoo_tpu.common.context import (get_zoo_context,
                                               init_zoo_context,
@@ -42,6 +43,33 @@ def test_env_override_namespaced_key(monkeypatch):
 def test_unknown_key_falls_back_to_dots():
     ctx = init_zoo_context(custom_flag=True)
     assert ctx.get("zoo.custom.flag") is True
+
+
+@pytest.mark.parametrize("kwarg", [{"train_scan_steps": 4},
+                                   {"train_device_cache": True},
+                                   {"train_fuse_epochs": 3}])
+def test_retired_dispatch_keys_raise_by_kwarg(kwarg):
+    """A key that selected a deleted dispatch path raises, naming itself:
+    accepted in silence like any unknown key, it would cost the job the
+    path it asked for without a word."""
+    key = "zoo.train." + next(iter(kwarg))[len("train_"):]
+    with pytest.raises(ValueError, match=key.replace(".", r"\.")):
+        init_zoo_context(**kwarg)
+    # the rejected call left no context behind: the next one is built anew
+    assert key not in get_zoo_context().conf
+
+
+def test_retired_key_raises_by_env(monkeypatch):
+    monkeypatch.setenv("ZOO_TPU_TRAIN_DEVICE_CACHE", "1")
+    reset_zoo_context()
+    with pytest.raises(ValueError, match=r"zoo\.train\.device_cache.*one "
+                                         r"optimizer step at a time"):
+        init_zoo_context()
+
+
+def test_retired_key_raises_by_conf_dict():
+    with pytest.raises(ValueError, match=r"zoo\.train\.fuse_epochs"):
+        init_zoo_context(conf={"zoo.train.fuse_epochs": 3})
 
 
 def test_conf_dict_highest_besides_kwargs():

@@ -293,19 +293,6 @@ def test_fused_engages_and_registers_metric():
                         for v in vals.values()), vals
 
 
-def test_scan_and_device_cache_paths_match():
-    l_off, _ = _fit_once({"zoo.train.fused_ce": False,
-                          "zoo.train.scan_steps": 2})
-    l_on, _ = _fit_once({"zoo.train.fused_ce": True,
-                         "zoo.train.scan_steps": 2})
-    np.testing.assert_allclose(l_off, l_on, rtol=1e-5, atol=1e-6)
-    l_off, _ = _fit_once({"zoo.train.fused_ce": False,
-                          "zoo.train.device_cache": True})
-    l_on, _ = _fit_once({"zoo.train.fused_ce": True,
-                         "zoo.train.device_cache": True})
-    np.testing.assert_allclose(l_off, l_on, rtol=1e-5, atol=1e-6)
-
-
 def test_resolution_declines_non_matching_patterns():
     from analytics_zoo_tpu.pipeline.api.keras.fused_loss import \
         resolve_fused_loss
@@ -435,12 +422,15 @@ def test_remat_rejects_unknown_mode():
         _fit_once({"zoo.train.remat": "bogus"}, v=64)
 
 
-def test_remat_composes_with_fused_and_scan():
-    l_a, _ = _fit_once({"zoo.train.fused_ce": True, "zoo.train.remat": True,
-                        "zoo.train.scan_steps": 2})
-    l_b, _ = _fit_once({"zoo.train.fused_ce": False,
-                        "zoo.train.scan_steps": 2})
+def test_remat_composes_with_fused():
+    """Remat around the fused CE's custom VJP against the plain loss
+    without remat: same losses, same parameters."""
+    l_a, p_a = _fit_once({"zoo.train.fused_ce": True,
+                          "zoo.train.remat": True})
+    l_b, p_b = _fit_once({"zoo.train.fused_ce": False})
     np.testing.assert_allclose(l_a, l_b, rtol=1e-5, atol=1e-6)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5), p_a, p_b)
 
 
 # ---------------------------------------------------------------------------

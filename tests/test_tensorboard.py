@@ -187,8 +187,8 @@ def test_histograms_readable_by_tensorboard(tmp_path):
 
 def test_fit_writes_parameter_histograms(tmp_path):
     """set_tensorboard(parameters_every_epochs=1) logs per-layer weight
-    histograms from fit — including under fused-epoch dispatch, where they
-    land on the fused block's final epoch."""
+    histograms from fit, one per epoch; ``parameters_every_epochs=2`` only
+    at the epochs the frequency divides."""
     from analytics_zoo_tpu.common.context import (init_zoo_context,
                                                   reset_zoo_context)
     from analytics_zoo_tpu.pipeline.api.keras.engine import Sequential
@@ -215,19 +215,20 @@ def test_fit_writes_parameter_histograms(tmp_path):
     assert len(w_pts) == 2          # one per epoch
     assert w_pts[0][1]["num"] == 4 * 8
 
-    # fused-epoch dispatch: histograms land on each fused block's end
+    # every second epoch: of three epochs (4 steps each) only epoch 2
     reset_zoo_context()
-    init_zoo_context(train_fuse_epochs=3, train_device_cache=True)
+    init_zoo_context()
     m2 = Sequential()
     m2.add(Dense(8, activation="relu", input_shape=(4,), name="d1"))
     m2.add(Dense(2, activation="softmax", name="d2"))
     m2.init_weights(sample_input=x)
     m2.compile(optimizer="adam", loss="scce")
-    m2.set_tensorboard(str(tmp_path / "fused"), "app",
-                       parameters_every_epochs=1)
+    m2.set_tensorboard(str(tmp_path / "every2"), "app",
+                       parameters_every_epochs=2)
     m2.fit(x, y, batch_size=16, nb_epoch=3)
-    pts2 = read_histograms(str(tmp_path / "fused" / "app" / "train"))
-    assert pts2, "no histograms under fused dispatch"
+    pts2 = read_histograms(str(tmp_path / "every2" / "app" / "train"))
+    w2 = [p for p in pts2 if "d1" in p[3] and p[3].endswith("W")]
+    assert [p[0] for p in w2] == [8], [p[0] for p in w2]
     reset_zoo_context()
 
 
@@ -308,40 +309,12 @@ def test_parameter_histograms_honor_trigger_object(tmp_path):
     ts = TrainSummary(str(tmp_path), "app")
     params = {"d1": {"W": np.ones((4, 8), np.float32)}}
     ts.set_summary_trigger("Parameters", SeveralIteration(10))
-    _write_param_histograms(ts, params, epochs=(1,), iteration=5)
-    _write_param_histograms(ts, params, epochs=(2,), iteration=10)
+    _write_param_histograms(ts, params, epoch=1, iteration=5)
+    _write_param_histograms(ts, params, epoch=2, iteration=10)
     ts.close()
     pts = read_histograms(str(tmp_path / "app" / "train"))
     assert len(pts) == 1            # only the iteration-10 boundary fired
     assert pts[0][3] == "Parameters/d1/W"
-
-
-def test_fused_block_trigger_sees_per_epoch_iterations(tmp_path):
-    """Under fused-epoch dispatch the Trigger-form check must evaluate each
-    covered epoch at its OWN boundary iteration (reconstructed via
-    n_steps), not the block-final one — a SeveralIteration trigger whose
-    boundary falls mid-block still fires."""
-    from analytics_zoo_tpu.common.triggers import SeveralIteration
-    from analytics_zoo_tpu.pipeline.api.keras.training import (
-        _write_param_histograms)
-    from analytics_zoo_tpu.utils.tensorboard import read_histograms
-
-    params = {"d1": {"W": np.ones((4, 8), np.float32)}}
-    # epochs 1-3 fused, 5 steps each: boundaries at iterations 5, 10, 15.
-    # SeveralIteration(10) fires only at the epoch-2 boundary (10) —
-    # invisible to a check that evaluates everything at iteration 15.
-    ts = TrainSummary(str(tmp_path), "app")
-    ts.set_summary_trigger("Parameters", SeveralIteration(10))
-    _write_param_histograms(ts, params, (1, 2, 3), 15, n_steps=5)
-    ts.close()
-    assert len(read_histograms(str(tmp_path / "app" / "train"))) == 1
-
-    # a block whose boundaries all miss the interval writes nothing
-    ts2 = TrainSummary(str(tmp_path / "b2"), "app")
-    ts2.set_summary_trigger("Parameters", SeveralIteration(100))
-    _write_param_histograms(ts2, params, (1, 2, 3), 15, n_steps=5)
-    ts2.close()
-    assert not read_histograms(str(tmp_path / "b2" / "app" / "train"))
 
 
 def test_trigger_fire_landing_mid_epoch_is_not_dropped(tmp_path):
@@ -358,9 +331,9 @@ def test_trigger_fire_landing_mid_epoch_is_not_dropped(tmp_path):
     ts = TrainSummary(str(tmp_path), "app")
     ts.set_summary_trigger("Parameters", SeveralIteration(7))
     # boundaries 5, 10, 15: fires land at 7 (in (5,10]) and 14 (in (10,15])
-    _write_param_histograms(ts, params, (1,), 5, n_steps=5)
-    _write_param_histograms(ts, params, (2,), 10, n_steps=5)
-    _write_param_histograms(ts, params, (3,), 15, n_steps=5)
+    _write_param_histograms(ts, params, 1, 5, n_steps=5)
+    _write_param_histograms(ts, params, 2, 10, n_steps=5)
+    _write_param_histograms(ts, params, 3, 15, n_steps=5)
     ts.close()
     steps = sorted(s for s, _, _, _ in
                    read_histograms(str(tmp_path / "app" / "train")))

@@ -181,7 +181,7 @@ def test_bucketed_guards():
     from analytics_zoo_tpu.models.textclassification import TextClassifier
 
     reset_zoo_context()
-    init_zoo_context(train_scan_steps=2)
+    init_zoo_context()
     try:
         texts = ["a b c d"] * 16
         labels = np.zeros(16, np.int32)
@@ -192,8 +192,10 @@ def test_bucketed_guards():
         m = TextClassifier(class_num=2, token_length=8, sequence_length=4,
                            encoder="cnn", vocab_size=10)
         m.compile(optimizer="adam", loss="scce")
-        with pytest.raises(ValueError, match="scan_steps"):
-            m.fit(fs, batch_size=8, nb_epoch=1)
+        # validation needs one dense array: refused before any step runs
+        with pytest.raises(ValueError, match="bucketed validation_data"):
+            m.fit(fs, batch_size=8, nb_epoch=1, validation_data=fs)
+        assert m.finished_iterations == 0 and m.params is None
     finally:
         reset_zoo_context()
         init_zoo_context()

@@ -96,112 +96,108 @@ def test_gradient_clipping_runs():
     assert np.isfinite(h["loss"][-1])
 
 
-def test_scan_steps_matches_single_step_path():
-    """K-step lax.scan dispatch must be numerically equivalent to K single
-    dispatches: same rng fold_in(base, iteration) schedule, same updates."""
-    from analytics_zoo_tpu.common.context import reset_zoo_context
-
-    def build():
-        m = Sequential([Dense(16, activation="relu", input_shape=(6,)),
-                        Dense(1, activation="sigmoid")])
-        m.compile(optimizer="adam", loss="binary_crossentropy", lr=0.01)
-        return m
-
-    rng = np.random.default_rng(7)
-    x = rng.normal(size=(256, 6)).astype(np.float32)
-    y = (x.sum(axis=1) > 0).astype(np.float32)[:, None]
-
-    init_zoo_context()
-    m1 = build()
-    h1 = m1.fit(x, y, batch_size=32, nb_epoch=3)
-    p1 = m1.predict(x, batch_size=64)
-
-    reset_zoo_context()
-    init_zoo_context(train_scan_steps=4)
-    m2 = build()
-    h2 = m2.fit(x, y, batch_size=32, nb_epoch=3)
-    p2 = m2.predict(x, batch_size=64)
-
-    np.testing.assert_allclose(h1["loss"], h2["loss"], rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(p1, p2, rtol=1e-4, atol=1e-5)
-
-
-def test_scan_steps_ragged_tail_chunk():
-    """steps_per_epoch not divisible by scan_steps: the tail chunk is smaller
-    and must still train correctly."""
-    init_zoo_context(train_scan_steps=4)
-    x, y = _xor_data(n=64 * 6)  # 6 steps/epoch -> chunks of 4 + 2
-    m = Sequential([Dense(32, activation="relu", input_shape=(2,)),
-                    Dense(1, activation="sigmoid")])
-    m.compile(optimizer="adam", loss="binary_crossentropy", lr=0.01)
-    h = m.fit(x, y, batch_size=64, nb_epoch=10)
-    assert m._loop is not None
-    assert h["loss"][-1] < h["loss"][0]
-
-
-def test_fused_epochs_match_per_epoch_path():
-    """zoo.train.fuse_epochs: K epochs per dispatch must produce IDENTICAL
-    per-epoch losses and final weights to the per-epoch device_cache path
-    (same rng schedule), including a ragged final group (7 epochs, fuse=3)."""
-    from analytics_zoo_tpu.common.context import reset_zoo_context
-
-    def build():
-        m = Sequential([Dense(16, activation="relu", input_shape=(6,)),
-                        Dense(1, activation="sigmoid")])
-        m.compile(optimizer="adam", loss="binary_crossentropy", lr=0.01)
-        return m
-
-    rng = np.random.default_rng(8)
-    x = rng.normal(size=(256, 6)).astype(np.float32)
-    y = (x.sum(axis=1) > 0).astype(np.float32)[:, None]
-
-    init_zoo_context(train_device_cache=True)
-    m1 = build()
-    h1 = m1.fit(x, y, batch_size=32, nb_epoch=7)
-    p1 = m1.predict(x, batch_size=64)
-
-    reset_zoo_context()
-    init_zoo_context(train_device_cache=True, train_fuse_epochs=3)
-    m2 = build()
-    records = []
-    h2 = m2.fit(x, y, batch_size=32, nb_epoch=7, callbacks=[records.append])
-    p2 = m2.predict(x, batch_size=64)
-
-    np.testing.assert_allclose(h1["loss"], h2["loss"], rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(p1, p2, rtol=1e-4, atol=1e-5)
-    assert m2.finished_epochs == 7
-    assert m2.finished_iterations == 7 * 8
-    assert [r["epoch"] for r in records] == list(range(1, 8))
-    assert all(np.isfinite(r["throughput"]) for r in records)
-
-
-def test_fused_epochs_defer_to_loop_when_host_needed(tmp_path):
-    """fuse_epochs must NOT engage when a checkpoint manager or validation
-    needs the host between epochs — bookkeeping stays per-epoch exact."""
-    init_zoo_context(train_device_cache=True, train_fuse_epochs=4)
-    x, y = _xor_data(n=64 * 4)
+def _mlp(**compile_kwargs):
     m = Sequential([Dense(16, activation="relu", input_shape=(2,)),
                     Dense(1, activation="sigmoid")])
-    m.compile(optimizer="adam", loss="binary_crossentropy", lr=0.01)
-    m.set_checkpoint(str(tmp_path))
-    h = m.fit(x, y, batch_size=64, nb_epoch=4)
-    assert len(h["loss"]) == 4
-    assert m.finished_epochs == 4
-    import os
-    assert any(os.scandir(str(tmp_path))), "checkpoints were skipped"
+    m.compile(optimizer="adam", loss="binary_crossentropy", lr=0.01,
+              **compile_kwargs)
+    return m
 
 
-def test_device_cache_epoch_path_trains():
-    """HBM-resident one-dispatch-per-epoch path (zoo.train.device_cache):
-    must converge and keep epoch/iteration bookkeeping consistent."""
-    init_zoo_context(train_device_cache=True)
-    x, y = _xor_data(n=64 * 6)
-    m = Sequential([Dense(32, activation="relu", input_shape=(2,)),
-                    Dense(1, activation="sigmoid")])
-    m.compile(optimizer="adam", loss="binary_crossentropy", lr=0.01)
-    h = m.fit(x, y, batch_size=64, nb_epoch=12)
-    assert h["loss"][-1] < h["loss"][0]
-    assert m.finished_epochs == 12
-    assert m.finished_iterations == 12 * 6
-    res = m.evaluate(x, y, batch_size=64)
-    assert res["loss"] < h["loss"][0]
+def _step_counts(opt_state):
+    """Every ``count`` leaf of an optax state tree (Adam keeps one)."""
+    return [int(leaf) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(opt_state)[0]
+            if getattr(path[-1], "name", None) == "count"]
+
+
+def test_second_fit_reuses_matching_optimizer_state():
+    """A second fit goes on from the stored optimizer state where its tree
+    matches the optimizer's: Adam's step count continues, it does not
+    restart."""
+    init_zoo_context()
+    x, y = _xor_data(n=64 * 5)
+    m = _mlp()
+    m.fit(x, y, batch_size=64, nb_epoch=1)
+    assert _step_counts(m.opt_state) == [5]
+    m.fit(x, y, batch_size=64, nb_epoch=2)
+    assert _step_counts(m.opt_state) == [15]
+    assert m.finished_iterations == 15
+
+
+def test_changed_optimizer_structure_resets_state_with_warning(caplog):
+    """A stored optimizer state whose tree no longer matches the optimizer
+    (clipping added between fits) is dropped with a warning, and the new
+    state starts from step 0."""
+    import logging
+    init_zoo_context()
+    x, y = _xor_data(n=64 * 5)
+    m = _mlp()
+    m.fit(x, y, batch_size=64, nb_epoch=1)
+    stale = jax.tree_util.tree_structure(m.opt_state)
+    m.compile(optimizer="adam", loss="binary_crossentropy", lr=0.01,
+              clip_norm=1.0)
+    with caplog.at_level(logging.WARNING,
+                         logger="analytics_zoo_tpu.training"):
+        h = m.fit(x, y, batch_size=64, nb_epoch=1)
+    assert any("optimizer structure changed" in r.getMessage()
+               for r in caplog.records)
+    assert jax.tree_util.tree_structure(m.opt_state) != stale
+    assert _step_counts(m.opt_state) == [5]      # not 10: it was reset
+    assert np.isfinite(h["loss"][-1])
+
+
+def test_fit_compiles_one_training_program(tmp_path):
+    """Checkpoints, validation and an end trigger all ride the one step:
+    a fit that uses the three compiles ``train.step`` once and no other
+    training program, and the loop has no other builder to reach for."""
+    from analytics_zoo_tpu.common.triggers import MaxIteration
+    from analytics_zoo_tpu.observability import default_registry
+    from analytics_zoo_tpu.pipeline.api.keras.training import TrainingLoop
+
+    init_zoo_context()
+    x, y = _xor_data(n=64 * 5)
+    m = _mlp()
+    m.set_checkpoint(str(tmp_path / "ckpt"))
+    h = m.fit(x, y, batch_size=64, nb_epoch=3, validation_data=(x, y),
+              end_trigger=MaxIteration(12))
+    assert m.finished_iterations == 12 and len(h["val_loss"]) == 3
+    compiled = {key.split('fn="')[1].rstrip('"}'): v["count"]
+                for key, v in default_registry().snapshot().items()
+                if key.startswith("zoo_jit_compile_seconds{")}
+    train_fns = {fn: n for fn, n in compiled.items()
+                 if fn.startswith("train.")}
+    assert train_fns == {"train.step": 1, "train.eval_step": 1}, compiled
+    assert "train.step" in m.last_fit_report["compile"]
+    for gone in ("build_scan_step", "build_epoch_fn", "build_multi_epoch_fn",
+                 "_make_scan_body", "_make_epoch_body"):
+        assert not hasattr(TrainingLoop, gone), gone
+    assert not any(hasattr(m._loop, a)
+                   for a in ("_scan_step", "_epoch_fns", "_data_cache"))
+
+
+def test_iteration_end_trigger_stops_mid_epoch_and_snapshots(tmp_path):
+    """``SeveralIteration(7)`` as the END trigger at 5 steps an epoch: the
+    fit stops on iteration 7, inside epoch 2, which does not count as
+    finished; the snapshot cut at the stop says so, so that a resume
+    trains that epoch again."""
+    from analytics_zoo_tpu.common.triggers import SeveralIteration
+    from analytics_zoo_tpu.utils.checkpoint import CheckpointManager
+
+    init_zoo_context()
+    x, y = _xor_data(n=64 * 5)
+    m = _mlp()
+    m.set_checkpoint(str(tmp_path / "ckpt"), keep=0)
+    records = []
+    h = m.fit(x, y, batch_size=64, nb_epoch=4, callbacks=[records.append],
+              end_trigger=SeveralIteration(7))
+    assert m.finished_iterations == 7
+    assert m.finished_epochs == 1
+    assert len(h["loss"]) == 2
+    assert [r["iteration"] for r in records] == [5, 7]
+    assert records[-1]["loop_state"].epoch_finished is False
+    mgr = CheckpointManager(str(tmp_path / "ckpt"))
+    assert mgr.steps() == [5, 7]
+    metas = {s: mgr._read_manifest(s)["meta"] for s in mgr.steps()}
+    assert metas[5]["epoch_finished"] is True and metas[5]["epoch"] == 1
+    assert metas[7]["epoch_finished"] is False and metas[7]["epoch"] == 2

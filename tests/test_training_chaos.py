@@ -168,16 +168,14 @@ def test_warn_mode_detects_but_applies_updates():
 # skip-mode bit-identity vs a poison-free control
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("scan_steps", [1, 4])
-def test_skip_mode_matches_control_bit_for_bit(scan_steps):
+def test_skip_mode_matches_control_bit_for_bit():
     """The acceptance scenario: a recovered run's final losses AND
     params are bit-identical to a control run trained without the
-    poison batches — on the single-step and the scan-chunk paths.
+    poison batches.
     (Both runs compile the identical guarded step; the rng schedule is
     consumed by a dropout-free model, so skipping a batch leaves the
     surviving steps' math untouched.)"""
-    init_zoo_context(faults_enabled=True, train_sentinel="recover",
-                     train_scan_steps=scan_steps)
+    init_zoo_context(faults_enabled=True, train_sentinel="recover")
     x, y = _data()
     poisoned = (2, 6)
 
@@ -364,12 +362,11 @@ def test_escalation_without_checkpoint_raises_training_diverged():
 # zoo.train.grad_clip (satellite)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("scan_steps", [1, 4])
-def test_grad_clip_engages_and_counts(scan_steps):
+def test_grad_clip_engages_and_counts():
     """A tiny clip norm engages on every step (counted exactly); a huge
     one never engages and leaves the trajectory unchanged."""
     x, y = _data()
-    init_zoo_context(train_grad_clip=1e-4, train_scan_steps=scan_steps)
+    init_zoo_context(train_grad_clip=1e-4)
     before = _counters("zoo_train_grad_clip_engaged_total")
     m = _model()
     assert m._loop._sentinel_config().grad_clip == 1e-4
@@ -378,14 +375,14 @@ def test_grad_clip_engages_and_counts(scan_steps):
     assert after["zoo_train_grad_clip_engaged_total"] \
         - before["zoo_train_grad_clip_engaged_total"] == 8
 
-    init_zoo_context(train_grad_clip=1e9, train_scan_steps=scan_steps)
+    init_zoo_context(train_grad_clip=1e9)
     m_hi = _model()
     h_hi = m_hi.fit(x, y, batch_size=BATCH, nb_epoch=1, shuffle=False)
     after2 = _counters("zoo_train_grad_clip_engaged_total")
     assert after2["zoo_train_grad_clip_engaged_total"] \
         == after["zoo_train_grad_clip_engaged_total"]
 
-    init_zoo_context(train_grad_clip=0.0, train_scan_steps=scan_steps)
+    init_zoo_context(train_grad_clip=0.0)
     m_off = _model()
     h_off = m_off.fit(x, y, batch_size=BATCH, nb_epoch=1, shuffle=False)
     np.testing.assert_allclose(h_hi["loss"], h_off["loss"], rtol=1e-6)

@@ -204,6 +204,42 @@ def attention_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int,
         compute=[((bq, bk), 4)] * 3)
 
 
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int,
+                   kernel: str = "fwd", pair: bool = False,
+                   residuals: bool = False) -> int:
+    """Estimated per-grid-cell VMEM of one grouped-product kernel
+    (``grouped_matmul``) at ``tm`` rows, ``tk`` of the contraction and
+    ``tn`` of the output's width. ``"fwd"`` (also the plain ``dx``, which
+    is the same kernel reading ``w`` transposed): the rows' window, one
+    weight window (``pair``: the gated pair's two, and with ``residuals``
+    three outputs for one), a float32 accumulator a weight where the
+    contraction is cut, and the float32 product tile. ``"dx"``, the gated
+    pair's backward: three (tm, tk) row windows in, two out beside ``dx``,
+    both weight windows, the prologue's float32 tiles and the product's.
+    ``"dw"``: x (tm, tk) and one or two dy (tm, tn) in, a float32 (tk, tn)
+    output block each (the accumulator), x stood up for the matrix unit and
+    the product tile."""
+    tm = round_up(max(tm, 1), SUBLANES)
+    tk = round_up(max(tk, 1), LANES)
+    tn = round_up(max(tn, 1), LANES)
+    n_w = 2 if pair else 1
+    if kernel == "dw":
+        return kernel_vmem_bytes(
+            operands=[((tm, tk), itemsize)] + [((tm, tn), itemsize)] * n_w,
+            outputs=[((tk, tn), 4)] * n_w,
+            compute=[((tk, tm), itemsize), ((tk, tn), 4)])
+    if kernel == "dx":
+        return kernel_vmem_bytes(
+            operands=[((tm, tk), itemsize)] * 3 + [((tn, tk), itemsize)] * 2,
+            outputs=[((tm, tn), itemsize)] + [((tm, tk), itemsize)] * 2,
+            compute=[((tm, tk), 4)] * 4 + [((tm, tn), 4)])
+    return kernel_vmem_bytes(
+        operands=[((tm, tk), itemsize)] + [((tk, tn), itemsize)] * n_w,
+        outputs=[((tm, tn), itemsize)] * (3 if pair and residuals else 1),
+        scratch=[((tm, tn), 4)] * n_w,
+        compute=[((tm, tn), 4)] * n_w)
+
+
 def ce_vmem_bytes(block_n: int, block_v: int, hidden: int, itemsize: int,
                   has_bias: bool = True) -> int:
     """Estimated per-grid-cell VMEM of the fused-CE forward kernel

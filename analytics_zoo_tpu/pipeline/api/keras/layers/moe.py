@@ -40,7 +40,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.ops.grouped_matmul import grouped_matmul
+from analytics_zoo_tpu.ops.grouped_matmul import (
+    gated_grouped_matmul, grouped_matmul, visited_tile_rows)
 from ..engine import Layer, compute_dtype, get_initializer, param_dtype
 from .core import get_activation
 
@@ -226,7 +227,8 @@ WIDE_COUNTERS = {"held": "moe_held", "absent": "moe_absent",
                  "dropped": "moe_dropped", "rows_run": "moe_rows_run",
                  "choice_passes": "moe_choice_passes",
                  "chunk_runs": "moe_chunk_runs",
-                 "compact_runs": "moe_compact_runs"}
+                 "compact_runs": "moe_compact_runs",
+                 "gmm_tile_rows": "moe_gmm_tile_rows"}
 
 
 def _wide_add(acc, n):
@@ -412,10 +414,7 @@ def _held_rows(n_rows, tokens, wgate, wup, wdown, weights, order, pos, sizes,
     order = order[:n_rows]
     rows = _dispatch(tokens, order, pos, passes)
     with jax.named_scope("zoo_moe.experts"):
-        gate = grouped_matmul(rows, wgate, sizes)
-        up = grouped_matmul(rows, wup, sizes)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(rows.dtype)
+        act = gated_grouped_matmul(rows, wgate, wup, sizes)
         rows = grouped_matmul(act, wdown, sizes)
     return _combine(rows, weights, order, pos, passes)
 
@@ -513,7 +512,8 @@ class RoutedExperts(Layer):
     ``moe_rows_run``: rows the row buffers held (``C`` or ``N k`` a chunk),
     ``moe_choice_passes``: gather-sum passes run, summed over chunks,
     ``moe_chunk_runs`` and ``moe_compact_runs``: chunks run, and those of
-    them that ran over ``C`` rows."""
+    them that ran over ``C`` rows, ``moe_gmm_tile_rows``: rows of the row
+    tiles the grouped products' kernels visited (0 where XLA's run)."""
 
     def __init__(self, num_experts: int, hidden_dim: int, top_k: int = 2,
                  held=None, norm_topk: bool = True,
@@ -601,7 +601,7 @@ class RoutedExperts(Layer):
     def _run(self, params, tokens):
         """Route ``tokens`` (n, d) and run the held experts on them: ``(y
         (n, d), held group sizes, assignments per router output, {rows_run,
-        choice_passes, compact_runs} of this run)``."""
+        choice_passes, compact_runs, gmm_tile_rows} of this run)``."""
         cd = tokens.dtype
         n_tok, k, n_held = tokens.shape[0], self.top_k, len(self.held)
         n_rows, compact = n_tok * k, self.compact_rows(n_tok)
@@ -640,13 +640,18 @@ class RoutedExperts(Layer):
         args = (tokens, *(params[w].astype(cd)
                           for w in ("Wgate", "Wup", "Wdown")),
                 weights, order, pos, sizes, passes)
+        tile_rows = functools.partial(visited_tile_rows, args[1], sizes)
         if compact < n_rows:
             y = _held_rows_or_all(compact, *args)
             fits = (jnp.sum(sizes) <= compact).astype(jnp.int32)
+            visited = jnp.where(fits == 1, tile_rows(compact),
+                                tile_rows(n_rows))
         else:
             y = _held_rows(n_rows, *args)
             fits = jnp.int32(0)
+            visited = tile_rows(n_rows)
         ran = {"rows_run": n_rows - fits * (n_rows - compact),
+               "gmm_tile_rows": visited,
                "choice_passes": (jnp.int32(k) if passes is None
                                  else _passes_run(passes, k)),
                "compact_runs": fits}
@@ -692,13 +697,18 @@ def bound_ratios(counts):
     ``rows_run_over_held`` (rows the row buffers held over the assignments
     held: 1 is no waste, ``num_experts / len(held)`` the static worst case
     under a balanced router), ``choice_passes_mean`` (gather-sum passes a
-    chunk; ``top_k`` is the static worst case) and ``compact_share`` (share
-    of chunks whose buffers were cut to ``C`` rows)."""
+    chunk; ``top_k`` is the static worst case), ``compact_share`` (share of
+    chunks whose buffers were cut to ``C`` rows) and ``gmm_tile_fill``
+    (assignments held over the rows of the row tiles the grouped products'
+    kernels visited: what is short of 1 is padding at group boundaries; 0
+    where XLA's kernels run)."""
     runs = max(counts["chunk_runs"], 1)
     return {"rows_run_over_held": (counts["rows_run"] / counts["held"]
                                    if counts["held"] else 0.0),
             "choice_passes_mean": counts["choice_passes"] / runs,
-            "compact_share": counts["compact_runs"] / runs}
+            "compact_share": counts["compact_runs"] / runs,
+            "gmm_tile_fill": (counts["held"] / counts["gmm_tile_rows"]
+                              if counts["gmm_tile_rows"] else 0.0)}
 
 
 def fit_report(before, net_state, registry):

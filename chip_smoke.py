@@ -27,8 +27,9 @@ Phases (any failure makes the exit code non-zero; none is skipped):
   attention with 32 query / 4 key-value heads of 128 at T = 8192, a
   1024-key window and full, forward and dq/dk/dv against ``ops.attention``
   in float32; the experts' grouped product over 32768 rows in 8 groups of
-  Zipf sizes (2304 x 896), forward and both gradients against a per-group
-  float32 product.
+  Zipf sizes (2304 x 896), plain and with the fused gate, forward and
+  gradients against a per-group float32 product, and the device time of
+  one call of each kernel on both paths.
 * ``serve``   — ResNet-50 at 224x224 through the serving stack, 64 frames
   from two producer threads, every answer equal to a direct ``predict``.
 
@@ -48,7 +49,9 @@ The phase bodies take their sizes as arguments so that
 from __future__ import annotations
 
 import collections
+import functools
 import json
+import os
 import re
 import sys
 import threading
@@ -628,32 +631,80 @@ def grouped_flash_parity(*, n_head: int, n_kv_head: int, seq_len: int,
     return errors
 
 
+def _gmm_call_ms(fn, args, reps: int = 3) -> Dict[str, list]:
+    """``{kernel: [device ms a call, calls a run]}`` of the grouped
+    products' kernels in ``reps`` runs of the compiled ``fn``, from the
+    device trace: XLA's ``ragged-dot*`` or the ``zoo_moe_gmm*`` calls, by
+    instruction name and result shape."""
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        for _ in range(reps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        paths = [os.path.join(root, f) for root, _, files in os.walk(tmp)
+                 for f in files if f.endswith(".xplane.pb")]
+        data = ProfileData.from_file(paths[0])
+    spent: Dict[str, list] = {}
+    for plane in data.planes:
+        if plane.name != "/device:TPU:0":
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                own, _, rest = ev.name.partition(" = ")
+                if "ragged-dot" in own or "zoo_moe_gmm" in own:
+                    key = (re.sub(r"\.\d+$", "", own.lstrip("%")) + " "
+                           + re.sub(r"\{[^}]*\}", "", rest.split(" ")[0]))
+                    ns, n = spent.get(key, (0, 0))
+                    spent[key] = [ns + int(ev.duration_ns), n + 1]
+    return {k: [round(ns / n / 1e6, 4), n // reps]
+            for k, (ns, n) in sorted(spent.items())}
+
+
 def grouped_matmul_parity(*, rows: int, groups: int, d_in: int, d_out: int
                           ) -> Dict[str, float]:
-    """``ops.grouped_matmul`` forward, ``dx`` and ``dW`` on bf16 operands
-    against one float32 product a group. The group sizes follow a Zipf law
-    and leave the last tenth of the rows to no group: those rows of the
-    result and of ``dx`` must come back zero. One rounding to bf16 on the
-    way out of each product: 2 eps (dW accumulates in float32 and is
-    compared in the weights' dtype)."""
+    """``ops.grouped_matmul`` forward, ``dx`` and ``dW``, and the same of the
+    fused gate ``ops.gated_grouped_matmul``, on bf16 operands against one
+    float32 product a group. The group sizes follow a Zipf law and leave
+    the last tenth of the rows to no group: those rows of the results and
+    of ``dx`` must come back zero. One rounding to bf16 on the way out of
+    each product: 2 eps (dW accumulates in float32 and is compared in the
+    weights' dtype); the gated pair rounds gate, up and their product, and
+    its gradients pass the rounded ``d_gate`` / ``d_up``: 4 eps. On the chip
+    the phase also times one call of each kernel on both paths (XLA's
+    ``ragged-dot`` and the Pallas ``zoo_moe_gmm*``) from the device trace
+    and logs them."""
     import jax
     import jax.numpy as jnp
 
-    from analytics_zoo_tpu.ops.grouped_matmul import grouped_matmul
+    from analytics_zoo_tpu.ops import grouped_matmul as gm
 
     rng = np.random.default_rng(43)
     share = 1.0 / np.arange(1, groups + 1)
     sizes = np.floor(share / share.sum() * rows * 0.9).astype(np.int32)
     x = jnp.asarray(rng.normal(size=(rows, d_in)), jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(groups, d_in, d_out)) / np.sqrt(d_in),
-                    jnp.bfloat16)
+    w, wup = (jnp.asarray(rng.normal(size=(groups, d_in, d_out))
+                          / np.sqrt(d_in), jnp.bfloat16) for _ in range(2))
     g = jnp.asarray(rng.normal(size=(rows, d_out)), jnp.bfloat16)
+    names = ("out", "dx", "dw", "gated_out", "gated_dx", "gated_dwgate",
+             "gated_dwup")
 
-    def kernel(x, w, sizes):
-        y, vjp = jax.vjp(lambda x, w: grouped_matmul(x, w, sizes), x, w)
-        return (y,) + vjp(g)
+    def kernel(x, w, wup, sizes, impl=None):
+        impl = impl or gm._impl(x, w, wup)
+        y, vjp = jax.vjp(lambda x, w: gm.product(x, w, sizes, impl), x, w)
+        act, gated_vjp = jax.vjp(
+            lambda x, w, wup: gm.gated_product(x, w, wup, sizes, impl),
+            x, w, wup)
+        return (y,) + vjp(g) + (act,) + gated_vjp(g)
 
-    def reference(x, w, g):
+    def reference(x, w, wup, g):
         bounds = np.concatenate([[0], np.cumsum(sizes)])
 
         def f(x, w):
@@ -663,20 +714,30 @@ def grouped_matmul_parity(*, rows: int, groups: int, d_in: int, d_out: int
                                    jnp.float32))
             return jnp.concatenate(parts, axis=0)
         y, vjp = jax.vjp(f, x, w)
-        return (y,) + vjp(g)
+        act, gated_vjp = jax.vjp(
+            lambda x, w, wup: jax.nn.silu(f(x, w)) * f(x, wup), x, w, wup)
+        return (y,) + vjp(g) + (act,) + gated_vjp(g)
 
-    got = jax.jit(kernel)(x, w, jnp.asarray(sizes))
+    got = jax.jit(kernel)(x, w, wup, jnp.asarray(sizes))
     with jax.default_matmul_precision("highest"):
         want = jax.jit(reference)(*(a.astype(jnp.float32)
-                                    for a in (x, w, g)))
+                                    for a in (x, w, wup, g)))
     errors = {f"grouped_matmul_{name}": _scaled_err(a, b)
-              for name, a, b in zip(("out", "dx", "dw"), got, want)}
-    _check(errors, dict.fromkeys(errors, 2 * BF16_EPS))
+              for name, a, b in zip(names, got, want)}
+    _check(errors, {k: (4 if "gated" in k else 2) * BF16_EPS
+                    for k in errors})
     tail = int(sizes.sum())
-    for name, a in zip(("out", "dx"), got):
-        if np.any(np.asarray(a[tail:], np.float32) != 0.0):
+    for name, a in zip(names, got):
+        if name.endswith(("out", "dx")) and np.any(
+                np.asarray(a[tail:], np.float32) != 0.0):
             raise AssertionError(f"grouped_matmul {name}: rows past the "
                                  f"last group are not zero")
+    if jax.default_backend() == "tpu":
+        for impl in ("xla", gm._impl(x, w, wup)):
+            fn = jax.jit(functools.partial(kernel, impl=impl))
+            print(f"[chip_smoke] grouped products, {impl}: " + json.dumps(
+                _gmm_call_ms(fn, (x, w, wup, jnp.asarray(sizes)))),
+                flush=True)
     return errors
 
 

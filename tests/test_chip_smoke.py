@@ -100,7 +100,9 @@ def test_decoder_phase_tiny_interpreted():
     assert set(out["reference_errors"]) == {
         f"flash_gqa_{tag}_{name}" for tag in ("window", "full")
         for name in ("out", "dq", "dk", "dv")} | {
-        "grouped_matmul_out", "grouped_matmul_dx", "grouped_matmul_dw"}
+        "grouped_matmul_out", "grouped_matmul_dx", "grouped_matmul_dw",
+        "grouped_matmul_gated_out", "grouped_matmul_gated_dx",
+        "grouped_matmul_gated_dwgate", "grouped_matmul_gated_dwup"}
 
 
 def test_kernels_phase_fails_without_mosaic_calls():

@@ -1,5 +1,6 @@
-"""The flash kernels compiled FOR the chip from here: Mosaic's layout and
-scoped-VMEM refusals (what interpret mode cannot show) without a chip.
+"""The flash kernels, and the experts' grouped products, compiled FOR the
+chip from here: Mosaic's layout and scoped-VMEM refusals (what interpret
+mode cannot show) without a chip.
 
 The TPU's compiler is installed in this sandbox and compiles for a chip
 that is described, not attached. Only one process may hold the TPU
@@ -103,3 +104,44 @@ def test_grouped_window_flash_compiles_for_v5e(one_chip, window):
     dkv = next(ln for ln in calls if "zoo_flash_bwd_dkv" in ln)
     assert "bf16[4,8192,128]" in dkv.split("custom-call(")[0], dkv[:200]
     assert "bf16[32,8192,128]" not in dkv.split("custom-call(")[0]
+
+
+@pytest.mark.parametrize("rows,groups,d,h,dtype", [
+    # the decoder cell's row buffers, cut to the rows held and whole
+    (32768, 8, 2304, 896, jnp.bfloat16),
+    (131072, 8, 2304, 896, jnp.bfloat16),
+    # float32 operands, rows that are no whole tile
+    (1000, 4, 512, 256, jnp.float32),
+], ids=["cell", "cell_whole_buffers", "float32_ragged_rows"])
+def test_grouped_products_compile_for_v5e(one_chip, rows, groups, d, h,
+                                          dtype):
+    """A routed layer's step through the entry's custom VJPs on the kernels'
+    path: six Mosaic calls under the names the benchmark's readers know
+    (``zoo_moe_gmm*``), ``dW`` in float32, no ``ragged-dot``."""
+    from analytics_zoo_tpu.ops import grouped_matmul as gm
+
+    x = jax.ShapeDtypeStruct((rows, d), dtype, sharding=one_chip)
+    up = jax.ShapeDtypeStruct((groups, d, h), dtype, sharding=one_chip)
+    down = jax.ShapeDtypeStruct((groups, h, d), dtype, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+
+    def step(x, wgate, wup, wdown, sizes):
+        def f(x, wgate, wup, wdown):
+            act = gm.gated_product(x, wgate, wup, sizes, "pallas")
+            y = gm.product(act, wdown, sizes, "pallas")
+            return jnp.sum(y.astype(jnp.float32)), y
+        return jax.value_and_grad(f, argnums=(0, 1, 2, 3), has_aux=True)(
+            x, wgate, wup, wdown)
+
+    text = jax.jit(step).lower(x, up, up, down, sizes).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    names = sorted(re.sub(r"\.\d+$", "", ln.split("=")[0].strip()
+                          .lstrip("%")) for ln in calls)
+    assert sorted(n.split("zoo_moe_")[-1].rstrip("_") for n in names) == [
+        "gmm", "gmm_dw", "gmm_dw", "gmm_dx", "gmm_dx_gated", "gmm_gated",
+        ], names
+    assert "ragged-dot" not in text
+    for ln in calls:
+        if "zoo_moe_gmm_dw" in ln:
+            assert f"f32[{groups}," in ln.split("custom-call(")[0], ln[:200]

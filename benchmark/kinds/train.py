@@ -175,13 +175,6 @@ def badput_seconds(category: str) -> float:
                and dict(m.labels).get("category") == category)
 
 
-def peak_bytes() -> int:
-    import jax
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in jax.local_devices()]
-    return int(max(peaks))
-
-
 def _adam_mu(opt_state):
     """The first-moment tree inside an optax state, wherever it sits."""
     import jax
@@ -357,7 +350,8 @@ def run(cell: Dict[str, Any], cfg: Dict[str, Any], traffic: Dict[str, Any],
     if steps != planned:
         raise RuntimeError(f"the window made {steps} steps, not the "
                            f"{planned} it was planned for")
-    memory_peak = peak_bytes()
+    memory_peak = reference_run.memory_stat("peak_bytes_in_use") or 0
+    reserved_peak = reference_run.memory_stat("peak_bytes_reserved")
     rate = tokens / wall
     stamps = list(trigger.stamps)
     _log(f"window: {steps} steps (step {step_s:.4f} s in set-up), {tokens} "
@@ -373,7 +367,9 @@ def run(cell: Dict[str, Any], cfg: Dict[str, Any], traffic: Dict[str, Any],
                                f"{os.getpid()}.json"), "w") as f:
             json.dump({"stamps": stamps, "wall": wall, "steps": steps,
                        "rate": rate, "phases": phases,
-                       "compile": compiles_before}, f)
+                       "compile": compiles_before,
+                       "moe": (getattr(model, "last_fit_report", None)
+                               or {}).get("moe")}, f)
 
     metrics: Dict[str, Dict[str, Any]] = {}
     breakdown = None
@@ -384,7 +380,9 @@ def run(cell: Dict[str, Any], cfg: Dict[str, Any], traffic: Dict[str, Any],
                 "window_s": wall, "steps": steps,
                 "setup_compile_s": compiles_before["seconds"],
                 "data_wait_s": badput_seconds("data_wait") - wait_before,
-                "memory_peak_bytes": memory_peak, "batch": verify[0]}
+                "memory_peak_bytes": memory_peak,
+                "memory_reserved_peak_bytes": reserved_peak,
+                "batch": verify[0]}
         from ..lib import trace as trace_lib
         view["trace"] = trace_lib.reduce(trace_dir, wall)
         if dump:
@@ -412,7 +410,11 @@ def run(cell: Dict[str, Any], cfg: Dict[str, Any], traffic: Dict[str, Any],
     t = time.perf_counter()
     want = reference_run.three_steps(
         ref, cfg, seed, verify, int(traffic["reference_rows_per_chip"]))
-    _log(f"reference: three steps in {time.perf_counter() - t:.1f} s")
+    held, n = want["device_bytes"], want["parameters"]
+    _log(f"reference: three steps in {time.perf_counter() - t:.1f} s; "
+         + ("the device counts no bytes in use" if held is None else
+            f"at most {held / 1e9:.3f} GB in use on the device = "
+            f"{held / n:.2f} bytes a parameter ({n} parameters)"))
     correct, rows = compare.compare(got, want, limits)
     for r in rows:
         _log("compared {name}: {value:.3e} (limit {limit}) {mark} [{note}]"

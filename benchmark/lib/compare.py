@@ -22,20 +22,27 @@ NUMBERS = ("loss_step1", "loss_step2", "loss_step3", "grad_norm_worst_leaf",
            "change_norm_worst_leaf")
 
 
+def by_path(tree) -> Dict[str, float]:
+    """``{path: value}`` of a tree of scalars."""
+    import jax
+    import numpy as np
+    flat = jax.tree_util.tree_flatten_with_path(jax.device_get(tree))[0]
+    return {jax.tree_util.keystr(p): float(np.asarray(v)) for p, v in flat}
+
+
 def leaf_norms(tree) -> Dict[str, float]:
     """``{path: l2 norm}`` of every leaf, computed where the tree lives."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
-    norms = jax.jit(lambda t: jax.tree.map(
+    return by_path(jax.jit(lambda t: jax.tree.map(
         lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32)))), t))(
-            tree)
-    flat = jax.tree_util.tree_flatten_with_path(jax.device_get(norms))[0]
-    return {jax.tree_util.keystr(p): float(np.asarray(v)) for p, v in flat}
+            tree))
 
 
 def error_norms(got_tree, want_tree) -> Dict[str, float]:
-    """``{path: l2 norm of the difference}`` of two trees of one shape."""
+    """``{path: l2 norm of the difference}`` of two trees of one shape.
+    Both sides hand their first gradient over as numpy trees on the host;
+    they go to the device here, where nothing else is left by then."""
     import jax
     return leaf_norms(jax.jit(lambda a, b: jax.tree.map(
         lambda x, y: x - y, a, b))(got_tree, want_tree))

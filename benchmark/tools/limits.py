@@ -10,8 +10,10 @@ have, planted in the reference put in the program's place: half of the batch
 left out (and, on several chips, all but one chip's share, which is what a
 step without the exchange of gradients computes). A state left unchanged
 reads 1 by ``compare``'s measure and needs no run. Training's readings need
-no measured window. Writes ``chiprun_out/limits_<workload>.json`` and prints
-one line per reading.
+no measured window. Every side's record is host data (``three_steps`` hands
+its first gradient out as a numpy tree) and the device is emptied between
+sides, so each side has the chip to itself as a run's reference has. Writes
+``chiprun_out/limits_<workload>.json`` and prints one line per reading.
 """
 
 import json
@@ -58,20 +60,25 @@ def main(workload: str, n_seeds: int, n_fault_seeds: int = 3,
     faults = {"half_batch": 0.5}
     if int(cell["chips"]) > 1:
         faults["no_exchange"] = 1.0 / int(cell["chips"])
+
+    def one_side(seed, **kw):
+        """One side's record, which is host data; the device is emptied
+        behind it, so that the next side starts as a run's reference does."""
+        rec = reference_run.three_steps(ref, cfg, seed, batches[seed],
+                                        rows_per_chip, **kw)
+        train.free_program_state()
+        return rec
+
     readings = []
     for i, seed in enumerate(seeds):
         t = time.perf_counter()
-        want = reference_run.three_steps(ref, cfg, seed, batches[seed],
-                                         rows_per_chip)
+        want = one_side(seed)
         sides = {"program": got[seed]}
         if i < n_control_seeds:
-            sides["control_fp8"] = reference_run.three_steps(
-                ref, cfg, seed, batches[seed], rows_per_chip, mode="fp8")
+            sides["control_fp8"] = one_side(seed, mode="fp8")
         if i < n_fault_seeds:
             for name, keep in faults.items():
-                sides["fault_" + name] = reference_run.three_steps(
-                    ref, cfg, seed, batches[seed], rows_per_chip,
-                    keep_rows=keep)
+                sides["fault_" + name] = one_side(seed, keep_rows=keep)
         for side, rec in sides.items():
             nums = compare.numbers(rec, want)
             readings.append({"seed": seed, "side": side,

@@ -44,7 +44,7 @@ DEFAULT_CONF: Dict[str, Any] = {
     "zoo.pallas.attention": "auto",      # auto (TPU only) | true | false
     "zoo.pallas.cross_entropy": "auto",  # fused-CE forward kernel: auto (TPU) | true | false
     "zoo.pallas.block_sweep": False,     # one-shot on-device block sweep per kernel signature
-    "zoo.pallas.vmem_budget_mb": 0,      # 0 = the per-core default (16 MiB) for block selection
+    "zoo.pallas.vmem_budget_mb": 0,      # 0 = the per-core default (16 MiB) for block selection (flash at a head wider than 128: two of them)
     "zoo.pallas.embed_gather": "auto",   # one-hot MXU expand-gather: auto (TPU) | true | false
     "zoo.rng.impl": "auto",              # auto (rbg on TPU) | default | rbg
     "zoo.seq.mode": "ring",              # seq-parallel routing: ring | ulysses | auto

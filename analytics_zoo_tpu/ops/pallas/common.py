@@ -149,6 +149,22 @@ def kernel_vmem_bytes(operands: Iterable[_ShapeBytes] = (),
 ATTENTION_FWD_K_TILES = 2
 
 
+def attention_budget_scale(d: int) -> int:
+    """How much of the VMEM budget a flash schedule of head width ``d`` is
+    fitted into, in budgets: 1 up to a lane tile (D = 64, 128: the choices
+    swept in PR 27 stand), 2 from D = 256 on. A kernel's windows grow with
+    the head width and so does the work each tile does on them, so a wide
+    head that is held to a narrow head's budget falls to a tile that
+    starves the matrix unit: at D = 256, T = 8192 the (256, 512) tile with
+    half the sequence resident reads 89.9 ms a forward + dq + dkv call
+    (80 heads, bf16, causal; 69.9 % of roofline), (512, 512) with the whole
+    sequence resident 77.2 ms (81.3 %; chip run, PR 33,
+    ``scripts/flash-sweep``). The calls ask Mosaic for their own limit
+    (``flash_attention._compiler_params``), well inside a v5e core's
+    128 MiB. Capped at 2: nothing wider has run."""
+    return 2 if d > LANES else 1
+
+
 def attention_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int,
                          has_mask: bool = False,
                          major: Optional[int] = None,

@@ -91,8 +91,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .common import LANES as _LANES
 from .common import SUBLANES as _SUBLANES
 from .common import ATTENTION_FWD_K_TILES as _FWD_K_TILES
-from .common import (attention_vmem_bytes, pad_to_multiple, sweep_fastest,
-                     vmem_budget_bytes, vmem_usable_bytes)
+from .common import (attention_budget_scale, attention_vmem_bytes,
+                     pad_to_multiple, sweep_fastest, vmem_budget_bytes,
+                     vmem_usable_bytes)
 from .common import round_up as _round_up
 
 __all__ = ["flash_attention", "select_attention_blocks"]
@@ -156,9 +157,11 @@ def select_attention_blocks(t_q: int, t_kv: int, d: int, dtype,
     footprints at that pair (tile, accumulators, a one-tile window) fits
     the budget. Deterministic — a pure function of the abstract signature,
     so the jit cache is stable. ``_resolve_schedule`` turns the pair into
-    the kernels' tilings and major windows."""
+    the kernels' tilings and major windows. Without an explicit budget a
+    head wider than a lane tile is fitted into ``attention_budget_scale``
+    budgets (D = 256: (512, 512) again, where one budget gave (256, 512))."""
     budget = budget_bytes if budget_bytes is not None else \
-        vmem_usable_bytes()
+        vmem_usable_bytes() * attention_budget_scale(d)
     itemsize = jnp.dtype(dtype).itemsize
     bq, bk = _PREFERRED_BLOCKS
     bq = max(_SUBLANES, min(bq, _round_up(max(t_q, 1), _SUBLANES)))
@@ -184,7 +187,7 @@ _SWEEP_PAIRS = (_PREFERRED_BLOCKS, (1024, 512), (512, 1024), (256, 512),
 
 def _sweep_candidates(t_q: int, t_kv: int, d: int, itemsize: int,
                       has_mask: bool, heuristic):
-    budget = vmem_usable_bytes()
+    budget = vmem_usable_bytes() * attention_budget_scale(d)
     out = []
     for bq, bk in (heuristic,) + _SWEEP_PAIRS:
         # clamp to the sequence lengths WITH the tile rounding the kernel
@@ -307,7 +310,7 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     # the live budget is part of the key — re-initializing the context
     # with zoo.pallas.vmem_budget_mb must take effect at the next call,
     # not silently keep blocks sized for the old budget
-    budget = vmem_usable_bytes()
+    budget = vmem_usable_bytes() * attention_budget_scale(d)
     base = (t_q, t_kv, d, dt.name, causal, has_mask)
     if window is not None or group != 1:
         base += (window, group)
@@ -350,7 +353,7 @@ def _resolve_schedule(t_q: int, t_kv: int, d: int, dtype, has_mask: bool,
     the fewest equal runs of tiles that do. Pure in its arguments and the
     context's budget."""
     budget = budget_bytes if budget_bytes is not None else \
-        vmem_budget_bytes()
+        vmem_budget_bytes() * attention_budget_scale(d)
     itemsize = jnp.dtype(dtype).itemsize
     bq = _round_up(min(block_q, max(t_q, 1)), _SUBLANES)
     bk = _round_up(min(block_k, max(t_kv, 1)), _LANES)

@@ -2,7 +2,8 @@ from ..engine import Input, InputLayer, Lambda  # noqa: F401
 from .core import (Activation, Dense, Dropout, Flatten, Reshape, Permute,  # noqa: F401
                    RepeatVector, Merge, merge, Select, Squeeze, ExpandDim,
                    Narrow, Masking, GaussianNoise, GaussianDropout,
-                   TimeDistributed, Highway, SparseDense, get_activation)
+                   TimeDistributed, Highway, SparseDense, get_activation,
+                   GatedFeedForward)
 from .embeddings import (Embedding, ShardedEmbedding, SparseEmbedding,  # noqa: F401
                          WordEmbedding)
 from .normalization import (BatchNormalization, LayerNorm,  # noqa: F401
@@ -37,5 +38,6 @@ from .gpipe import GPipe, Pipeline  # noqa: F401
 from .moe import RoutedExperts, SparseMoE  # noqa: F401
 from .recurrent import GRU, LSTM, Bidirectional, SimpleRNN  # noqa: F401
 from .self_attention import (BERT, DecoderAttention, DecoderBlock,  # noqa: F401
-                             DecoderStack, MultiHeadSelfAttention,
+                             DecoderStack, LatentAttention,
+                             MultiHeadSelfAttention,
                              TransformerBlock, TransformerLayer)

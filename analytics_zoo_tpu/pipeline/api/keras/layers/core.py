@@ -120,6 +120,53 @@ class Dense(Layer):
         return y
 
 
+def gated_feed_forward(params, x, cd):
+    """``(silu(x Wgate) * (x Wup)) Wdown`` on (..., d) in compute dtype
+    ``cd``, products accumulated in float32. No scope of its own: the
+    callers name what it is part of."""
+    x = x.astype(cd)
+
+    def mm(a, w):
+        return jnp.matmul(a, w.astype(cd),
+                          preferred_element_type=jnp.float32).astype(cd)
+    act = jax.nn.silu(mm(x, params["Wgate"])) * mm(x, params["Wup"])
+    return mm(act, params["Wdown"])
+
+
+class GatedFeedForward(Layer):
+    """The dense SiLU-gated feed-forward layer of today's decoders
+    (SwiGLU): ``(silu(x Wgate) * (x Wup)) Wdown`` at width ``hidden_dim``,
+    no biases. Input and output (..., d). A ``DecoderBlock(ffn=)`` for a
+    model's dense layers; ``RoutedExperts(shared_dim=)`` holds one as its
+    shared expert. Device time shows under the scope ``zoo_ffn.gated``."""
+
+    def __init__(self, hidden_dim: int, init: str = "glorot_uniform",
+                 **kwargs):
+        super().__init__(**kwargs)
+        self.hidden_dim = hidden_dim
+        self.init = init
+
+    def build(self, rng, input_shape):
+        d, h = input_shape[-1], self.hidden_dim
+        init = get_initializer(self.init)
+        k = jax.random.split(rng, 3)
+        return {"Wgate": init(k[0], (d, h), param_dtype()),
+                "Wup": init(k[1], (d, h), param_dtype()),
+                "Wdown": init(k[2], (h, d), param_dtype())}
+
+    def param_sharding(self, params):
+        """Megatron's split: gate and up column-parallel over ``model``,
+        down row-parallel."""
+        from jax.sharding import PartitionSpec as P
+        from .....parallel.mesh import MODEL_AXIS
+        return {"Wgate": P(None, MODEL_AXIS), "Wup": P(None, MODEL_AXIS),
+                "Wdown": P(MODEL_AXIS, None)}
+
+    def call(self, params, x, *, training=False, rng=None):
+        with jax.named_scope("zoo_ffn.gated"):
+            return gated_feed_forward(params, x, compute_dtype())
+
+
 class Dropout(Layer):
     """``keras/layers/Dropout.scala`` — inverted dropout, active only in
     training; a no-op under jit at inference so XLA removes it entirely."""

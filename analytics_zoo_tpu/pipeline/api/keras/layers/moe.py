@@ -20,8 +20,11 @@ greenfield"), so both are designed TPU-first. Which one to take:
   no dropped assignment, work proportional to the assignments held. What
   the absent experts would have added is left out. SiLU-gated experts.
 
-Both define routing in one place, ``top_k_routing`` (softmax, top-k,
-renormalise).
+Both define routing in one place, ``top_k_routing`` (scores, top-k,
+renormalise): softmax scores, or sigmoid scores with a selection bias and a
+scale on the routed sum (the aux-loss-free routers of 2025's open models).
+``RoutedExperts`` can carry a shared expert every token passes through
+(``GatedFeedForward``, added to the routed sum once, whatever ``held`` is).
 
 Auxiliary losses (``SparseMoE``: load-balance + router z-loss) ride the
 layer-state channel: ``apply`` returns them under the reserved state key
@@ -39,24 +42,45 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from analytics_zoo_tpu.ops.grouped_matmul import (
     gated_grouped_matmul, grouped_matmul, visited_tile_rows)
 from ..engine import Layer, compute_dtype, get_initializer, param_dtype
-from .core import get_activation
+from .core import GatedFeedForward, gated_feed_forward, get_activation
 
 
-def top_k_routing(logits, k: int, renormalize: bool = True):
-    """The one definition of token-choice routing: float32 softmax over
-    every router output, the ``k`` largest probabilities and their experts,
-    the chosen weights renormalised to sum to 1 (``norm_topk_prob``).
-    Returns ``(probs (N, E), weights (N, k), experts (N, k) int32)``."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+def top_k_routing(logits, k: int, renormalize: bool = True,
+                  scoring: str = "softmax", bias=None, scale: float = 1.0):
+    """The one definition of token-choice routing: float32 scores over
+    every router output (``scoring``: ``"softmax"``, or ``"sigmoid"``, each
+    output scored on its own), the ``k`` largest and their experts, the
+    chosen weights renormalised to sum to 1 (``norm_topk_prob``) and
+    multiplied by ``scale`` (``routed_scaling_factor``). ``bias`` (E,), if
+    given, enters the CHOICE and not the weight: the chosen are the top-k
+    of ``scores + bias`` and their weights the scores without it (the
+    ``e_score_correction_bias`` of routers balanced without an auxiliary
+    loss; it takes no gradient). Returns ``(scores (N, E), weights (N, k),
+    experts (N, k) int32)``."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring={scoring!r}: softmax or sigmoid")
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalize:
         weights = weights / jnp.maximum(
             weights.sum(-1, keepdims=True), 1e-9)
-    return probs, weights, experts
+    if scale != 1.0:
+        weights = weights * scale
+    return scores, weights, experts
 
 
 class SparseMoE(Layer):
@@ -460,7 +484,17 @@ class RoutedExperts(Layer):
 
     ``num_experts`` is the published router width; ``held`` the expert ids
     this layer computes (a range or list; default all). Routing runs over
-    all ``num_experts`` (``top_k_routing``), the N x k assignments are
+    all ``num_experts`` (``top_k_routing``: ``scoring`` softmax or sigmoid,
+    ``norm_topk``, ``routed_scale`` on the chosen weights;
+    ``selection_bias`` adds a per-expert bias to the CHOICE alone, kept in
+    the layer state as ``moe_select_bias`` so that no optimizer touches
+    it: ``num_experts`` values to start it at (zeros are the published
+    start), ``None`` for a router without one; a step passes it through
+    unchanged, and whoever balances the router sets it). ``shared_dim`` adds a shared
+    expert of that width (``GatedFeedForward`` under the parameter key
+    ``shared``, scope ``zoo_moe.shared``) that every token passes through:
+    it is added to the routed sum ONCE, whatever ``held`` is, so of the
+    layers that share the experts one carries it. The N x k assignments are
     sorted by expert with the absent experts' after the held ones, the rows
     gathered in that order, and the three grouped products of the
     SiLU-gated experts (``silu(x Wgate) * (x Wup)) Wdown``) run over the
@@ -518,10 +552,14 @@ class RoutedExperts(Layer):
     def __init__(self, num_experts: int, hidden_dim: int, top_k: int = 2,
                  held=None, norm_topk: bool = True,
                  token_chunk: Optional[int] = None,
-                 init: str = "glorot_uniform", **kwargs):
+                 init: str = "glorot_uniform", scoring: str = "softmax",
+                 selection_bias=None, routed_scale: float = 1.0,
+                 shared_dim: Optional[int] = None, **kwargs):
         super().__init__(**kwargs)
         if not 1 <= top_k <= num_experts:
             raise ValueError(f"top_k={top_k} not in [1, {num_experts}]")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={scoring!r}: softmax or sigmoid")
         held = tuple(range(num_experts) if held is None
                      else (int(e) for e in held))
         if (not held or len(set(held)) != len(held)
@@ -535,6 +573,17 @@ class RoutedExperts(Layer):
         self.norm_topk = norm_topk
         self.token_chunk = token_chunk
         self.init = init
+        self.scoring = scoring
+        self.selection_bias = (None if selection_bias is None else
+                               np.asarray(selection_bias, np.float32))
+        if (self.selection_bias is not None
+                and self.selection_bias.shape != (num_experts,)):
+            raise ValueError(f"selection_bias: ({num_experts},) values "
+                             f"expected")
+        self.routed_scale = float(routed_scale)
+        self.shared = (GatedFeedForward(shared_dim, init=init,
+                                        name=f"{self.name}_shared")
+                       if shared_dim else None)
         # an assignment's sort key by expert: the expert's place among the
         # held ones, or len(held) for every absent expert
         self._place = [len(held)] * num_experts
@@ -545,17 +594,24 @@ class RoutedExperts(Layer):
         d, h, n = input_shape[-1], self.hidden_dim, len(self.held)
         init = get_initializer(self.init)
         k = jax.random.split(rng, 4)
-        return {"Wg": init(k[0], (d, self.num_experts), param_dtype()),
-                "Wgate": init(k[1], (n, d, h), param_dtype()),
-                "Wup": init(k[2], (n, d, h), param_dtype()),
-                "Wdown": init(k[3], (n, h, d), param_dtype())}
+        p = {"Wg": init(k[0], (d, self.num_experts), param_dtype()),
+             "Wgate": init(k[1], (n, d, h), param_dtype()),
+             "Wup": init(k[2], (n, d, h), param_dtype()),
+             "Wdown": init(k[3], (n, h, d), param_dtype())}
+        if self.shared is not None:
+            p["shared"] = self.shared.build(jax.random.fold_in(rng, 4),
+                                             input_shape)
+        return p
 
     def initial_state(self, input_shape=None):
         pair = jnp.zeros((2,), jnp.int32)
-        return {"moe_expert_tokens": jnp.zeros((self.num_experts,),
-                                               jnp.int32),
-                "moe_held_tokens": jnp.zeros((len(self.held),), jnp.int32),
-                **{key: pair for key in WIDE_COUNTERS.values()}}
+        state = {"moe_expert_tokens": jnp.zeros((self.num_experts,),
+                                                jnp.int32),
+                 "moe_held_tokens": jnp.zeros((len(self.held),), jnp.int32),
+                 **{key: pair for key in WIDE_COUNTERS.values()}}
+        if self.selection_bias is not None:
+            state["moe_select_bias"] = jnp.asarray(self.selection_bias)
+        return state
 
     def apply(self, params, state, x, *, training=False, rng=None):
         from .....parallel.mesh import EXPERT_AXIS, global_mesh
@@ -568,17 +624,23 @@ class RoutedExperts(Layer):
         lead, d = x.shape[:-1], x.shape[-1]
         tokens = x.reshape(-1, d).astype(compute_dtype())
         n_tok, chunk = tokens.shape[0], self.token_chunk
+        bias = (state["moe_select_bias"] if self.selection_bias is not None
+                else None)
         if chunk and n_tok > chunk and n_tok % chunk == 0:
             run = jax.checkpoint(self._run)
             y, sizes, per_expert, ran = jax.lax.map(
-                lambda t: run(params, t), tokens.reshape(-1, chunk, d))
+                lambda t: run(params, t, bias), tokens.reshape(-1, chunk, d))
             y = y.reshape(n_tok, d)
             sizes, per_expert = sizes.sum(0), per_expert.sum(0)
             ran = {key: n.sum(0) for key, n in ran.items()}
             ran["chunk_runs"] = n_tok // chunk
         else:
-            y, sizes, per_expert, ran = self._run(params, tokens)
+            y, sizes, per_expert, ran = self._run(params, tokens, bias)
             ran["chunk_runs"] = 1
+        if self.shared is not None:
+            with jax.named_scope("zoo_moe.shared"):
+                y = y + gated_feed_forward(params["shared"], tokens,
+                                           tokens.dtype)
 
         held = jnp.sum(sizes)
         placed = jnp.sum(per_expert)
@@ -586,6 +648,8 @@ class RoutedExperts(Layer):
                    dropped=n_tok * self.top_k - placed)
         new_state = {"moe_expert_tokens": per_expert,
                      "moe_held_tokens": sizes}
+        if bias is not None:
+            new_state["moe_select_bias"] = bias
         for name, key in WIDE_COUNTERS.items():
             new_state[key] = _wide_add(state[key], ran[name])
         return y.reshape(*lead, d), new_state
@@ -598,7 +662,7 @@ class RoutedExperts(Layer):
         twice = -(-2 * n_rows * len(self.held) // self.num_experts)
         return min(-(-twice // _ROW_TILE) * _ROW_TILE, n_rows)
 
-    def _run(self, params, tokens):
+    def _run(self, params, tokens, bias=None):
         """Route ``tokens`` (n, d) and run the held experts on them: ``(y
         (n, d), held group sizes, assignments per router output, {rows_run,
         choice_passes, compact_runs, gmm_tile_rows} of this run)``."""
@@ -609,7 +673,9 @@ class RoutedExperts(Layer):
         with jax.named_scope("zoo_moe.route"):
             logits = jnp.matmul(tokens, params["Wg"].astype(cd),
                                 preferred_element_type=jnp.float32)
-            _, weights, experts = top_k_routing(logits, k, self.norm_topk)
+            _, weights, experts = top_k_routing(
+                logits, k, self.norm_topk, scoring=self.scoring, bias=bias,
+                scale=self.routed_scale)
             key = jnp.take(jnp.asarray(self._place, jnp.int32),
                            experts)                             # (N, k)
             passes = None

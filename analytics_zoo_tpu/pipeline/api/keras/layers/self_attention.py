@@ -16,8 +16,14 @@ pyzoo ``pipeline/api/keras/layers/self_attention.py``).
   decoder of today's open models: RMSNorm, rotary positions (plain or YaRN)
   in place of a position table, grouped key/value heads with a head size
   of their own, a causal window per layer (``layer_types``), no biases, and
-  any feed-forward layer per block (``RoutedExperts``). The post-LN
-  classes above keep their behaviour and their parameter trees.
+  any feed-forward layer per block (``RoutedExperts``,
+  ``GatedFeedForward``) and, through ``DecoderStack(attn=)``, any attention
+  layer per block. The post-LN classes above keep their behaviour and
+  their parameter trees.
+* ``LatentAttention`` — multi-head latent attention (MLA): queries and
+  keys/values through low-rank latents with an RMSNorm each, rotary
+  positions on a slice of the head only, one rotary key head shared by all
+  heads. Training computes the expanded form.
 """
 
 from __future__ import annotations
@@ -568,13 +574,118 @@ class DecoderAttention(MultiHeadSelfAttention):
         return _project(params["Wo"], merge_heads(out), cd)
 
 
+class LatentAttention(MultiHeadSelfAttention):
+    """Multi-head latent attention (DeepSeek-V2's MLA; GLM-4.7-Flash), the
+    expanded form that training computes. With ``x`` (B, T, H):
+
+    * ``c_q = RMSNorm(x Wqa)`` (H -> ``q_lora_rank``); ``q = c_q Wqb``
+      (-> ``n_head`` heads of ``[q_nope (qk_nope_dim), q_pe (qk_rope_dim)]``);
+    * ``[c_kv, k_pe] = x Wkva`` (H -> ``kv_lora_rank + qk_rope_dim``);
+      ``c_kv = RMSNorm(c_kv)``; ``c_kv Wkvb`` (-> ``n_head`` heads of
+      ``[k_nope (qk_nope_dim), v (v_dim)]``);
+    * rotary positions (half-split pairs, ``rotary`` a ``rope_parameters``
+      entry over ``qk_rope_dim``) on ``q_pe`` of every head and on the ONE
+      ``k_pe``, which all heads share: ``k = [k_nope, k_pe]``;
+    * ``o = softmax(q k^T / sqrt(qk_nope_dim + qk_rope_dim) + causal) v``;
+      ``y = concat(o) Wo``.
+
+    Keys and values of all ``n_head`` heads are materialised, as the
+    published implementations do in training; the absorbed form (attention
+    in the latent space) is decoding's and is not here. ``q, k, v`` go to
+    the Pallas flash kernels under ``_use_flash``'s rule where the value
+    heads are as wide as the key heads (one head size a call), to the XLA
+    op elsewhere. No biases. Device time shows under ``zoo_mla.q_latent``,
+    ``.kv_latent``, ``.expand`` (the two up-projections and the broadcast
+    of ``k_pe``), ``.rope``, ``.attend`` and ``.out``. Input (B, T, H), or
+    ``[x, (cos, sin)]`` with tables of ``qk_rope_dim`` columns."""
+
+    def __init__(self, hidden_size: int, n_head: int, q_lora_rank: int,
+                 kv_lora_rank: int, qk_nope_dim: int, qk_rope_dim: int,
+                 v_dim: int, rotary: Mapping[str, Any],
+                 epsilon: float = 1e-6, **kwargs):
+        Layer.__init__(self, **kwargs)
+        self.hidden_size, self.n_head = hidden_size, n_head
+        self.q_lora_rank, self.kv_lora_rank = q_lora_rank, kv_lora_rank
+        self.qk_nope_dim, self.qk_rope_dim = qk_nope_dim, qk_rope_dim
+        self.v_dim = v_dim
+        self.causal = True
+        self.q_norm = RMSNorm(epsilon=epsilon)
+        self.kv_norm = RMSNorm(epsilon=epsilon)
+        self.inv_freq, self.rotary_scale = rotary_inv_freq(qk_rope_dim,
+                                                           rotary)
+
+    def build(self, rng, input_shape):
+        k = jax.random.split(rng, 5)
+        init = get_initializer("glorot_uniform")
+        h, n = self.hidden_size, self.n_head
+        qk = self.qk_nope_dim + self.qk_rope_dim
+        return {
+            "Wqa": init(k[0], (h, self.q_lora_rank), param_dtype()),
+            "q_norm": self.q_norm.build(k[0], (self.q_lora_rank,)),
+            "Wqb": init(k[1], (self.q_lora_rank, n * qk), param_dtype()),
+            "Wkva": init(k[2], (h, self.kv_lora_rank + self.qk_rope_dim),
+                         param_dtype()),
+            "kv_norm": self.kv_norm.build(k[2], (self.kv_lora_rank,)),
+            "Wkvb": init(k[3], (self.kv_lora_rank,
+                                n * (self.qk_nope_dim + self.v_dim)),
+                         param_dtype()),
+            "Wo": init(k[4], (n * self.v_dim, h), param_dtype())}
+
+    def param_sharding(self, params):
+        return jax.tree.map(lambda _: None, params)
+
+    def tables(self, t: int):
+        """float32 (cos, sin) of positions 0..t-1, ``qk_rope_dim`` wide."""
+        with jax.named_scope("zoo_mla.rope"):
+            return rotary_tables(self.inv_freq, self.rotary_scale, t)
+
+    def call(self, params, x, *, training=False, rng=None):
+        tables = None
+        if isinstance(x, (list, tuple)):
+            x, tables = x
+        cd = compute_dtype()
+        t, n, nope = x.shape[1], self.n_head, self.qk_nope_dim
+        with jax.named_scope("zoo_mla.q_latent"):
+            c_q = self.q_norm.call(params["q_norm"],
+                                   _project(params["Wqa"], x, cd))
+        with jax.named_scope("zoo_mla.kv_latent"):
+            kva = _project(params["Wkva"], x, cd)
+            c_kv = self.kv_norm.call(params["kv_norm"],
+                                     kva[..., :self.kv_lora_rank])
+            k_pe = kva[..., None, :, self.kv_lora_rank:]     # (B, 1, T, r)
+        with jax.named_scope("zoo_mla.expand"):
+            q = split_heads(_project(params["Wqb"], c_q, cd), n)
+            kv = split_heads(_project(params["Wkvb"], c_kv, cd), n)
+        cos, sin = tables if tables is not None else self.tables(t)
+        with jax.named_scope("zoo_mla.rope"):
+            q_pe = apply_rotary(q[..., nope:], cos, sin)
+            k_pe = apply_rotary(k_pe, cos, sin)
+        with jax.named_scope("zoo_mla.expand"):
+            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_pe, kv.shape[:-1] + (k_pe.shape[-1],))],
+                axis=-1)
+            v = kv[..., nope:]
+        with jax.named_scope("zoo_mla.attend"):
+            if v.shape[-1] == q.shape[-1] and self._use_flash(None, 0.0, t):
+                from .....ops.pallas import flash_attention
+                out = flash_attention(q, k, v, causal=True)
+            else:
+                out = dot_product_attention(q, k, v, causal=True)
+        with jax.named_scope("zoo_mla.out"):
+            return _project(params["Wo"], merge_heads(out), cd)
+
+
 class DecoderBlock(Layer):
     """Pre-norm residual block: ``h = x + Attn(RMSNorm(x))``;
-    ``x' = h + FFN(RMSNorm(h))``. ``ffn`` is the block's feed-forward
-    layer (``RoutedExperts``; any layer from (B, T, H) to (B, T, H)),
-    whose state, if it keeps one, is the block's."""
+    ``x' = h + FFN(RMSNorm(h))``. ``attn`` is the block's attention layer
+    (``DecoderAttention``, ``LatentAttention``; any layer that takes
+    ``[x, (cos, sin)]``), ``ffn`` its feed-forward layer (``RoutedExperts``,
+    ``GatedFeedForward``; any layer from (B, T, H) to (B, T, H)), whose
+    state, if it keeps one, is the block's."""
 
-    def __init__(self, hidden_size: int, attn: DecoderAttention, ffn: Layer,
+    def __init__(self, hidden_size: int, attn: Layer, ffn: Layer,
                  epsilon: float = 1e-6, **kwargs):
         super().__init__(**kwargs)
         self.hidden_size = hidden_size
@@ -619,38 +730,57 @@ class DecoderStack(Layer):
 
     ``rope_parameters`` maps each layer type to its rotary specification
     (or is one specification for all). ``ffn(i)`` returns block ``i``'s
-    feed-forward layer. ``remat=True`` rematerialises each block in the
+    feed-forward layer. ``attn(i)``, where given, returns block ``i``'s
+    attention layer (``LatentAttention``; anything with ``tables(t)`` and a
+    call on ``[x, (cos, sin)]``) in place of the ``DecoderAttention`` that
+    ``n_head``, ``n_kv_head``, ``head_dim``, ``rope_parameters`` and
+    ``sliding_window`` describe, which are then not needed; blocks of one
+    layer type share the rotary tables the first of them makes.
+    ``remat=True`` rematerialises each block in the
     backward pass (``jax.checkpoint``, as ``GPipe(remat=)`` does): the
     step keeps one block's activations at a time."""
 
     SLIDING, FULL = "sliding_attention", "full_attention"
 
     def __init__(self, vocab: int, layer_types: Sequence[str],
-                 hidden_size: int, n_head: int, n_kv_head: int,
-                 head_dim: int, ffn: Callable[[int], Layer],
-                 rope_parameters: Mapping[str, Any],
+                 hidden_size: int, n_head: Optional[int] = None,
+                 n_kv_head: Optional[int] = None,
+                 head_dim: Optional[int] = None,
+                 ffn: Optional[Callable[[int], Layer]] = None,
+                 rope_parameters: Optional[Mapping[str, Any]] = None,
                  sliding_window: Optional[int] = None,
                  epsilon: float = 1e-6, initializer_range: float = 0.02,
-                 remat: bool = False, **kwargs):
+                 remat: bool = False,
+                 attn: Optional[Callable[[int], Layer]] = None, **kwargs):
         super().__init__(**kwargs)
         self.vocab, self.hidden_size = vocab, hidden_size
         self.layer_types = tuple(layer_types)
         self.initializer_range = initializer_range
         self.remat = remat
         self.blocks = []
+        if ffn is None:
+            raise ValueError("ffn(i), each block's feed-forward layer, is "
+                             "needed")
+        if attn is None and None in (n_head, n_kv_head, head_dim,
+                                     rope_parameters):
+            raise ValueError("without attn(i), n_head, n_kv_head, head_dim "
+                             "and rope_parameters describe the attention")
         for i, kind in enumerate(self.layer_types):
             if kind not in (self.SLIDING, self.FULL):
                 raise ValueError(f"layer_types[{i}] = {kind!r}")
-            if kind == self.SLIDING and not sliding_window:
-                raise ValueError("sliding_attention layers need "
-                                 "sliding_window")
-            attn = DecoderAttention(
-                hidden_size, n_head, n_kv_head, head_dim,
-                rotary=rope_parameters.get(kind, rope_parameters),
-                window=sliding_window if kind == self.SLIDING else None,
-                name=f"{self.name}_block{i}_attn")
+            if attn is not None:
+                layer = attn(i)
+            else:
+                if kind == self.SLIDING and not sliding_window:
+                    raise ValueError("sliding_attention layers need "
+                                     "sliding_window")
+                layer = DecoderAttention(
+                    hidden_size, n_head, n_kv_head, head_dim,
+                    rotary=rope_parameters.get(kind, rope_parameters),
+                    window=sliding_window if kind == self.SLIDING else None,
+                    name=f"{self.name}_block{i}_attn")
             self.blocks.append(DecoderBlock(
-                hidden_size, attn, ffn(i), epsilon=epsilon,
+                hidden_size, layer, ffn(i), epsilon=epsilon,
                 name=f"{self.name}_block{i}"))
         self.norm = RMSNorm(epsilon=epsilon)
 

@@ -245,8 +245,18 @@ def _copy_leaves(leaves):
 
 def _clone_tree(tree):
     """Fresh buffers for every array leaf. The donated train step deletes its
-    input buffers, so any tree that outlives a step (``model.params``, the
-    retry snapshot) must never alias one that enters the step.
+    input buffers, so a tree that has to outlive a step must never alias one
+    that enters the step. ``fit`` itself no longer keeps such a tree: the
+    model's trees are handed to the loop (``_open_fit``), which holds the
+    only copy while steps run and hands it back when it ends
+    (``_publish``). Who still clones, and only with a checkpoint
+    directory configured: ``_close_epoch``, at the end of an epoch that
+    another follows, the boundary state the retry loop falls back to when
+    the newest snapshot is older or torn (no step is in flight then; the
+    copy of the boundary before is dropped first, so two sets at the most);
+    and ``_segment_begin``, the boundary state the SIGTERM grace budget cuts
+    a mid-epoch snapshot from, while a segment is estimated to outlast the
+    budget.
 
     All device leaves are copied in ONE jitted dispatch: a per-leaf
     ``jnp.copy`` costs a separate ``jit(copy)`` trace and compile per leaf
@@ -260,6 +270,56 @@ def _clone_tree(tree):
             leaves[i] = c
     leaves = [np.copy(a) if isinstance(a, np.ndarray) else a for a in leaves]
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _any_deleted(tree) -> bool:
+    """Whether a device leaf of ``tree`` has been given up to a step."""
+    return any(isinstance(a, jax.Array) and a.is_deleted()
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def _usable(tree) -> bool:
+    """Whether every device leaf of ``tree`` can still be read: none given
+    up to a step, and none the output of a step that failed on the device
+    (such an array is not deleted; it raises when it is waited for)."""
+    if _any_deleted(tree):
+        return False
+    try:
+        jax.block_until_ready(tree)
+    except Exception:  # zoolint: disable=ZL007 whatever the runtime raises
+        return False
+    return True
+
+
+def _buffers(a) -> Tuple:
+    """The device buffers behind an array, as addresses."""
+    try:
+        return tuple(s.data.unsafe_buffer_pointer()
+                     for s in a.addressable_shards)
+    except Exception:  # zoolint: disable=ZL007 a backend without pointers
+        return (id(a),)
+
+
+def _unshared(tree):
+    """``tree`` with every device leaf on buffers of its own: a leaf that
+    stands on the buffers of an earlier one (a layer's counters that start
+    from one zero) is copied, for a step cannot be given one buffer
+    twice."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    seen = set()
+    for i, a in enumerate(leaves):
+        if isinstance(a, jax.Array) and not a.is_deleted():
+            held = _buffers(a)
+            if seen.intersection(held):
+                leaves[i] = jnp.copy(a)
+            else:
+                seen.update(held)
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _tree_bytes(tree) -> int:
+    return sum(int(getattr(a, "nbytes", 0) or 0)
+               for a in jax.tree_util.tree_leaves(tree))
 
 
 class _SentinelMonitor:
@@ -404,7 +464,9 @@ class _SentinelMonitor:
 class _FitRun:
     """What one attempt of a fit carries from ``TrainingLoop._open_fit``
     through its epochs: the live trees that the donated step consumes and
-    returns, and the loop's bookkeeping."""
+    returns (the ONLY copy of weights, layer state and optimizer state on
+    the device while steps run; the model's own were handed over), and the
+    loop's bookkeeping."""
 
     fs: FeatureSet
     batch_size: int             # rounded up to the data-parallel size
@@ -589,6 +651,8 @@ class TrainingLoop:
         # model.last_fit_report hands out
         self._probe: Optional[InflightProbe] = None
         self._phases = _HostPhases(self._registry)
+        # last_fit_report["state"]
+        self._state_report: Dict[str, Any] = {}
 
     # -- goodput attribution -------------------------------------------------
     def _gp_note(self, category: str) -> None:
@@ -652,6 +716,13 @@ class TrainingLoop:
                        for phase, v in self._phases.seconds().items()},
             "inflight": self._probe.summary(),
             "compile": compiled,
+            # the one copy of weights, layer state and optimizer state the
+            # loop held: its bytes, how it came (``handed_over``: the
+            # model's own buffers; ``placed``: copied onto the mesh, from
+            # the host or another sharding; ``restored``: a checkpoint's)
+            # and how it went back (``handed_back``, or ``cloned``: the
+            # retry loop's boundary copy, only with a checkpoint directory)
+            "state": dict(self._state_report),
         }
 
     # -- jitted steps -------------------------------------------------------
@@ -1131,10 +1202,8 @@ class TrainingLoop:
             # the process is going down either way; the newest previous
             # snapshot (already committed) remains the resume point
             log.exception("final preemption checkpoint failed")
-        model = self.model
-        model.params, model.net_state, model.opt_state = _clone_tree(
-            (run.params, run.net_state, run.opt_state))
-        model.finished_iterations = iteration
+        self._publish(run.params, run.net_state, run.opt_state)
+        self.model.finished_iterations = iteration
         raise TrainingPreempted(
             f"training preempted by SIGTERM; final checkpoint cut at "
             f"iteration {iteration}")
@@ -1230,10 +1299,10 @@ class TrainingLoop:
             # going down either way; the newest committed snapshot
             # remains the resume point
             log.exception("grace-budget preemption checkpoint failed")
-        model = self.model
-        model.params, model.net_state, model.opt_state = _clone_tree(
-            (params, net_state, opt_state))
-        model.finished_iterations = iteration
+        # the boundary clone is the model's from here: the step in flight
+        # has consumed the live trees
+        self._publish(params, net_state, opt_state)
+        self.model.finished_iterations = iteration
         raise TrainingPreempted(
             f"training preempted by SIGTERM; grace budget {grace:g}s is "
             f"shorter than the ~{eta:.2f}s to the next step boundary — "
@@ -1391,7 +1460,10 @@ class TrainingLoop:
         host_before = self._phases.seconds()
         compile_before = xla_compile_totals()
         from .layers import moe
-        moe_before = moe.routed_layer_totals(self.model.net_state)
+        # (a state that a failed step consumed has no counters to start
+        # from: ``_open_fit`` restores it, or says that it cannot)
+        moe_before = ({} if _any_deleted(self.model.net_state)
+                      else moe.routed_layer_totals(self.model.net_state))
         try:
             with profiling.trace(profile_dir), span("train.fit",
                                                     registry=self._registry):
@@ -1529,14 +1601,47 @@ class TrainingLoop:
                              target_holder=target_holder,
                              validation_data=validation_data, rng=rng,
                              end_trigger=end_trigger)
-        # an empty range (nb_epoch=0) is a clean no-op
-        for epoch in range(self.model.finished_epochs + 1,
-                           run.target_epoch + 1):
-            losses = self._run_epoch(run, epoch)
-            if not self._close_epoch(run, epoch, losses, validation_data,
-                                     callbacks):
-                break
+        try:
+            # an empty range (nb_epoch=0) is a clean no-op
+            for epoch in range(self.model.finished_epochs + 1,
+                               run.target_epoch + 1):
+                losses = self._run_epoch(run, epoch)
+                if not self._close_epoch(run, epoch, losses,
+                                         validation_data, callbacks):
+                    break
+        except BaseException:
+            # the attempt ends mid-epoch (a failed step, an exception in
+            # the stream, a save or a callback). Trees of the model's that
+            # no step has taken stay: the last boundary's copy (what a
+            # retry goes on from), what a preemption published, or the
+            # loop's own before a step ran. Where a step took them the
+            # model gets the live trees, its progress counted as at the
+            # last boundary; unless those are gone too (the failed step
+            # had consumed them, or it failed on the device and they are
+            # its outputs): the model then holds deleted arrays, and the
+            # next attempt restores a checkpoint or says that none is
+            # configured (``_open_fit``)
+            model, live = self.model, (run.params, run.net_state,
+                                       run.opt_state)
+            if _any_deleted((model.params, model.net_state,
+                             model.opt_state)) and _usable(live):
+                self._publish(*live)
+            raise
         return run.history
+
+    def _publish(self, params, net_state, opt_state,
+                 copy: bool = False) -> None:
+        """Hand the loop's state to the model, by reference: no copy. The
+        arrays are the ones the next step of this fit, or the next fit,
+        consumes (``jax.device_get(model.params)``, or a ``tfpark``
+        model's ``get_weights()``, gives a copy that lasts). ``copy``
+        says that the trees are a copy no step will take: the model's to
+        keep until the next publish."""
+        model = self.model
+        model.params, model.net_state, model.opt_state = (
+            params, net_state, opt_state)
+        self._state_report["published"] = ("cloned" if copy
+                                           else "handed_back")
 
     # -- fit, part 1: before the first epoch -------------------------------
     def _open_fit(self, fs: FeatureSet, *, batch_size: int, nb_epoch: int,
@@ -1544,8 +1649,11 @@ class TrainingLoop:
                   end_trigger: Optional[Trigger]) -> _FitRun:
         """What a fit attempt does before its first epoch: round the batch
         size to the mesh, initialise weights and state, build the step,
-        place the donated clones, reuse or reset the optimizer state,
-        resume from a checkpoint, and fix the epoch target."""
+        take the model's trees over (no clone: from here to the end of the
+        fit the loop holds the one copy, and arrays read from the model
+        before are consumed by the first step), reuse or reset the
+        optimizer state, resume from a checkpoint, and fix the epoch
+        target."""
         ctx = get_zoo_context()
         model = self.model
         self._phases.switch("fit.enter")
@@ -1585,22 +1693,54 @@ class TrainingLoop:
         psh = mesh_lib.param_shardings(model, model.params, self.mesh)
         self._param_shardings = psh if any(
             not s.is_fully_replicated for s in jax.tree.leaves(psh)) else None
-        # clone: the donated train step must own its buffers exclusively —
-        # without the copy, device_put of an already-replicated model.params
-        # is a no-op alias and step 1 would delete the model's weights
-        params = jax.device_put(_clone_tree(model.params), psh)
-        net_state = jax.device_put(_clone_tree(model.net_state), repl)
+        mgr = self._ckpt_manager()
+        if _any_deleted((model.params, model.net_state)):
+            # a step of an earlier attempt took the state and failed: only
+            # a snapshot can bring it back (shapes are all the restore
+            # needs of its templates)
+            if mgr is None or mgr.latest() is None:
+                raise RuntimeError(
+                    "the model's weights were consumed by a training step "
+                    "that failed, and no checkpoint is configured to "
+                    "restore them from: initialise or load weights again")
+            self._rollback_pending = True       # any snapshot will do
+            model.params, model.net_state = jax.tree.map(
+                lambda a: np.zeros(a.shape, a.dtype),
+                (model.params, model.net_state))
+            model.opt_state = None
+        # no clone: device_put of trees already placed so is an alias, and
+        # the donating step then consumes the model's own buffers. The
+        # model is pointed at the loop's trees at once, so that nothing
+        # keeps a second set alive; _publish hands back what they become
+        handed = jax.tree_util.tree_leaves((model.params, model.net_state))
+        params, net_state = _unshared(
+            (jax.device_put(model.params, psh),
+             jax.device_put(model.net_state, repl)))
         opt_state = self._initial_opt_state(params, psh, repl)
+        self._state_report = {
+            "source": ("handed_over" if all(
+                a is b for a, b in zip(handed, jax.tree_util.tree_leaves(
+                    (params, net_state)))) else "placed")}
+        model.params, model.net_state, model.opt_state = (
+            params, net_state, opt_state)
+        del handed
 
         # resume: if a checkpoint directory is configured and holds a snapshot
         # newer than this model's progress, restore it (process-death resume)
-        mgr = self._ckpt_manager()
         # registered so _fit_with_retry can join/close the async writer on
         # every exit path (including exceptions and preemption)
         self._active_ckpt_mgr = mgr
         if mgr is not None:
+            was = params
             params, opt_state, net_state = self._resume_progress(
                 mgr, params, opt_state, net_state, psh, repl)
+            if params is not was:
+                self._state_report["source"] = "restored"
+                model.params, model.net_state, model.opt_state = (
+                    params, net_state, opt_state)
+            del was
+        self._state_report["bytes"] = _tree_bytes(
+            (params, net_state, opt_state))
 
         # sliced disk tier: one loop "epoch" is ONE slice pass; nb_epoch and
         # EveryEpoch-style triggers count FULL passes of num_of_slice slices
@@ -1642,7 +1782,8 @@ class TrainingLoop:
             fresh_struct = jax.tree_util.tree_structure(
                 jax.eval_shape(self.optimizer.init, params))
             if jax.tree_util.tree_structure(stored) == fresh_struct:
-                return self._shard_opt_state(_clone_tree(stored), psh, repl)
+                # handed over like the weights: no clone
+                return self._shard_opt_state(_unshared(stored), psh, repl)
             log.warning("optimizer structure changed since the last fit; "
                         "resetting optimizer state")
         return self._shard_opt_state(self.optimizer.init(params), psh, repl)
@@ -1766,9 +1907,11 @@ class TrainingLoop:
     def _close_epoch(self, run: _FitRun, epoch: int, losses: List[Any],
                      validation_data, callbacks: Sequence[Callable]) -> bool:
         """Wait for the epoch's steps, then everything that happens once
-        an epoch: its loss, the boundary checkpoint, the published clone
-        of the state, validation, summaries, the record and the
-        callbacks. Returns whether the fit goes on."""
+        an epoch: its loss, the boundary checkpoint, the state handed to
+        the model (by reference: validation and the callbacks read the
+        loop's own trees, which the next epoch's first step consumes; a
+        copy only for the retry loop, see below), validation, summaries,
+        the record and the callbacks. Returns whether the fit goes on."""
         model, st, mon = self.model, run.loop_state, run.monitor
         completed = not run.stop    # a stop means the epoch was cut short
         mean_loss = self._drain(losses, reduce=mon is None)
@@ -1799,11 +1942,23 @@ class TrainingLoop:
         if run.mgr is not None and (run.stop or run.ckpt_trigger(st)):
             self._save_checkpoint(run)
 
-        # publish progress every epoch — clones, because the live trees
-        # feed the donating train step next epoch; this is also what a
-        # retry attempt falls back to when the newest snapshot is older
-        model.params, model.net_state, model.opt_state = _clone_tree(
-            (run.params, run.net_state, run.opt_state))
+        # publish progress every epoch: no step is in flight, and the
+        # model's trees are the loop's until the next epoch's first step
+        # takes them (a callback that keeps arrays copies them). With a
+        # checkpoint directory and another epoch to come the model gets a
+        # copy instead: what a retry attempt falls back to when the
+        # newest snapshot is older or torn. The copy of the boundary
+        # before goes first, so that there are never three sets
+        live = (run.params, run.net_state, run.opt_state)
+        if run.mgr is not None and completed and epoch < run.target_epoch:
+            model.params = model.net_state = model.opt_state = None
+            try:
+                self._publish(*_clone_tree(live), copy=True)
+            except BaseException:
+                self._publish(*live)
+                raise
+        else:
+            self._publish(*live)
         if completed:
             model.finished_epochs = epoch
         model.finished_iterations = st.iteration

@@ -38,6 +38,7 @@ from ...models.common.zoo_model import load_model
 from ...observability import default_registry, instrument_jit
 from ...parallel import mesh as mesh_lib
 from ..api.keras.engine import KerasNet, intercept_layer_calls
+from ..api.keras.training import _copy_leaves
 from ...utils.checkpoint import CheckpointManager
 
 __all__ = ["InferenceModel"]
@@ -52,6 +53,19 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _own_arrays(tree, theirs):
+    """``tree`` with every leaf that is one of the device arrays of
+    ``theirs`` replaced by a copy (all of them in one dispatch)."""
+    taken = {id(a) for a in jax.tree_util.tree_leaves(theirs)
+             if isinstance(a, jax.Array)}
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    shared = [i for i, a in enumerate(leaves) if id(a) in taken]
+    if shared:
+        for i, a in zip(shared, _copy_leaves([leaves[i] for i in shared])):
+            leaves[i] = a
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 #: chunked predicts keep at most this many chunk OUTPUTS resident in HBM:
@@ -259,7 +273,12 @@ class InferenceModel:
         else:
             raise ValueError(f"unknown quantize mode {quantize!r}; "
                              "use None or 'int8'")
-        self._net_state = net_state
+        # arrays of its own: a later ``model.fit`` hands the model's arrays
+        # to its loop, whose first step consumes them, and what is served
+        # here must survive every call. A cast or a quantization made new
+        # ones already; a leaf it passed through is still the model's
+        self._params, self._net_state = _own_arrays(
+            (self._params, net_state), (params, net_state))
         model, dtype, scales = self._model, self._dtype, self._scales
         act_scales = self._act_scales
 

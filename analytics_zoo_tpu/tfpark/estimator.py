@@ -234,8 +234,12 @@ class TFEstimator:
         x = list(ds.features) + list(ds.labels)
         y = np.zeros((n,), np.float32)  # unused by the identity objective
         end = MaxIteration(steps) if steps is not None else None
-        m.fit(x, y, batch_size=bs, nb_epoch=nb_epoch, end_trigger=end)
-        self._share_params_into_predict()
+        try:
+            m.fit(x, y, batch_size=bs, nb_epoch=nb_epoch, end_trigger=end)
+        finally:
+            # fit consumed the arrays the predict model shared with this
+            # one: it gets what fit left, however fit ended
+            self._share_params_into_predict()
         self._save_weights()
         return self
 

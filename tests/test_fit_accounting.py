@@ -228,7 +228,9 @@ def test_last_fit_report_reconciles(tmp_path):
     m.fit(x, y, batch_size=16, nb_epoch=2)
     r = m.last_fit_report
     assert set(r) == {"wall_s", "steps", "ledger", "host_s", "inflight",
-                      "compile"}
+                      "compile", "state"}
+    assert set(r["state"]) == {"source", "bytes", "published"}
+    assert r["state"]["published"] == "handed_back" and r["state"]["bytes"] > 0
     assert r["steps"] == 8
     assert set(r["ledger"]) == set(TRAIN_CATEGORIES)
     assert sum(r["ledger"].values()) == pytest.approx(r["wall_s"], rel=1e-9)

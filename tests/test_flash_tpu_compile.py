@@ -106,6 +106,34 @@ def test_grouped_window_flash_compiles_for_v5e(one_chip, window):
     assert "bf16[32,8192,128]" not in dkv.split("custom-call(")[0]
 
 
+def test_latent_attention_flash_compiles_for_v5e_with_the_sequence_resident(
+        one_chip):
+    """The GLM configuration's signature: 20 query and 20 key heads of 256
+    at T = 8192. A head wider than a lane tile is fitted into two VMEM
+    budgets (``common.attention_budget_scale``), so the tile stays
+    (512, 512) and the whole sequence resident, as at D = 64 and 128; the
+    calls ask Mosaic for their own limit and the chip's compiler takes
+    them."""
+    sched = fa._auto_blocks((1, 20, 8192, 256), 8192, jnp.bfloat16, True,
+                            False, False)
+    assert fa._choice_label(sched) == (
+        "fwd=512x1024/kmajor8192,dq=512x512/kmajor8192,"
+        "dkv=512x512/qmajor8192")
+    for d in (64, 128):     # the accepted cells' choices stand
+        assert fa._choice_label(fa._auto_blocks(
+            (1, 12, 8192, d), 8192, jnp.bfloat16, True, False, False)) == (
+            "fwd=512x1024/kmajor8192,dq=512x512/kmajor8192,"
+            "dkv=512x512/qmajor8192")
+    text = _compile_grads(one_chip, 1, 20, 8192, 8192, 256, jnp.bfloat16,
+                          True, False).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    names = sorted(ln.split("=")[0].strip() for ln in calls)
+    assert len(names) == 3, names
+    for marker in ("zoo_flash_fwd", "zoo_flash_bwd_dq", "zoo_flash_bwd_dkv"):
+        assert sum(marker in n for n in names) == 1, (marker, names)
+
+
 @pytest.mark.parametrize("rows,groups,d,h,dtype", [
     # the decoder cell's row buffers, cut to the rows held and whole
     (32768, 8, 2304, 896, jnp.bfloat16),

@@ -731,7 +731,10 @@ def test_lint_estimate_equals_autotuner_decisions(t_q, t_kv, d, itemsize,
 
     lint = footprint_module()
     assert lint is not None
-    budget = vmem_usable_bytes()
+    # a head wider than a lane tile is fitted into two budgets (PR 33)
+    scale = lint.attention_budget_scale(d)
+    assert scale == (2 if d > 128 else 1)
+    budget = vmem_usable_bytes() * scale
     dtype = jnp.float32 if itemsize == 4 else jnp.bfloat16
     heuristic = select_attention_blocks(t_q, t_kv, d, dtype,
                                         has_mask=has_mask)
@@ -767,7 +770,8 @@ def test_lint_estimate_equals_autotuner_decisions(t_q, t_kv, d, itemsize,
         est = lint.attention_vmem_bytes(
             t.block_q, t.block_k, d=d, itemsize=itemsize,
             has_mask=has_mask, major=t.major, kernel=kernel)
-        assert est <= vmem_budget_bytes() or t.major == blk, (kernel, t)
+        assert est <= vmem_budget_bytes() * scale or t.major == blk, (
+            kernel, t)
 
 
 def test_fused_ce_budget_clamp_consumes_shared_estimator():

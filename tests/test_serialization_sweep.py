@@ -185,6 +185,22 @@ CASES = {
         hidden_size=8, n_head=4, n_kv_head=2, head_dim=4,
         ffn=lambda i: L.RoutedExperts(4, 8, top_k=2),
         rope_parameters=_ROPE, sliding_window=3), (6,), "int"),
+    "GatedFeedForward": (lambda: L.GatedFeedForward(12), (6, 8), "float"),
+    "LatentAttention": (lambda: L.LatentAttention(
+        8, 2, q_lora_rank=6, kv_lora_rank=4, qk_nope_dim=4, qk_rope_dim=2,
+        v_dim=6, rotary=_ROPE), (6, 8), "float"),
+    "RoutedExperts_sigmoid_shared": (lambda: L.RoutedExperts(
+        4, 8, top_k=2, held=(1, 3), scoring="sigmoid",
+        selection_bias=[0.1, -0.2, 0.3, 0.0], routed_scale=1.8,
+        shared_dim=8), (6, 8), "float"),
+    "DecoderStack_latent": (lambda: L.DecoderStack(
+        vocab=7, layer_types=["full_attention"] * 2, hidden_size=8,
+        attn=lambda i: L.LatentAttention(
+            8, 2, q_lora_rank=6, kv_lora_rank=4, qk_nope_dim=4,
+            qk_rope_dim=2, v_dim=6, rotary=_ROPE),
+        ffn=lambda i: (L.GatedFeedForward(12) if i == 0
+                       else L.RoutedExperts(4, 8, top_k=2, shared_dim=8))),
+        (6,), "int"),
 }
 
 

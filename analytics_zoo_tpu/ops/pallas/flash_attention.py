@@ -66,6 +66,20 @@ window) and the dk/dv kernel (grid bh, k block, q window), each re-forming
 one probability tile at a time from the saved log-sum-exp. Memory stays
 O(block²) end to end, which is what makes long-context *training* fit.
 
+What a rematerialising caller keeps (PR 35): the backward needs ``(q, k,
+v, mask, out, lse)``. Under a ``jax.checkpoint`` q, k and v come back from
+the projections, which are recomputed for their own gradients anyway; out
+and lse can only come from the forward kernel, the most expensive call of
+a block. So the forward rule names the two (``FLASH_SAVED``:
+``zoo_flash_out``, ``zoo_flash_lse``, by ``checkpoint_name``) and hands
+the named values on as output and residuals. A checkpoint whose policy is
+``save_only_these_names(*FLASH_SAVED)`` (``DecoderStack(remat=True)``)
+keeps them, one tensor of the output's size and one float a row, and its
+backward pass holds no forward kernel; every other policy
+(``zoo.train.remat``, ``GPipe(remat=)``, the routed layer's chunks) and a
+gradient outside any checkpoint see an identity that lowers to nothing.
+``saved_bytes_log`` tells such a caller at trace time what it keeps.
+
 Chip readings (TPU v5e, PR 27, ``scripts/flash-sweep``: device time of one
 call from the profiler, causal bf16 D = 64, B*H x T = 393216 rows): at
 T = 4096 fwd 9.86 -> 4.02 ms, dq 8.74 -> 4.43 ms, dkv 11.87 -> 5.57 ms
@@ -79,12 +93,15 @@ ceiling.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -96,7 +113,44 @@ from .common import (attention_budget_scale, attention_vmem_bytes,
                      vmem_usable_bytes)
 from .common import round_up as _round_up
 
-__all__ = ["flash_attention", "select_attention_blocks"]
+__all__ = ["FLASH_SAVED", "flash_attention", "saved_bytes_log",
+           "select_attention_blocks"]
+
+#: the names (``jax.ad_checkpoint.checkpoint_name``) the forward rule gives
+#: the kernel's output and its row statistics, the two residuals only the
+#: forward kernel can make again. A ``jax.checkpoint`` whose policy is
+#: ``save_only_these_names(*FLASH_SAVED)`` keeps them, and its backward
+#: pass recomputes q, k and v but not the kernel; to every other policy,
+#: and outside a checkpoint, a name is an identity that lowers to nothing
+FLASH_SAVED = ("zoo_flash_out", "zoo_flash_lse")
+
+#: where ``saved_bytes_log`` is open: name -> bytes, added to as forward
+#: rules are traced
+_SAVED_LOG: contextvars.ContextVar = contextvars.ContextVar(
+    "zoo_flash_saved_log", default=None)
+
+
+@contextlib.contextmanager
+def saved_bytes_log():
+    """A dict that every forward rule traced inside the block adds the
+    bytes of its two named residuals to, by name (``FLASH_SAVED``): how a
+    rematerialising caller learns at trace time what its checkpoints keep
+    (``DecoderStack``). Empty where no kernel was differentiated: the XLA
+    op ran, or nothing took a gradient."""
+    log: dict = {}
+    token = _SAVED_LOG.set(log)
+    try:
+        yield log
+    finally:
+        _SAVED_LOG.reset(token)
+
+
+def _saved(x, name: str):
+    """``x`` under ``name``, its bytes added to the open log."""
+    log = _SAVED_LOG.get()
+    if log is not None:
+        log[name] = log.get(name, 0) + x.size * x.dtype.itemsize
+    return checkpoint_name(x, name)
 
 
 # ---------------------------------------------------------------------------
@@ -1066,6 +1120,11 @@ def _flash(q, k, v, mask, causal, sched, interpret, window=None):
 def _vjp_fwd(q, k, v, mask, causal, sched, interpret, window):
     out, lse = _flash_fwd(q, k, v, mask, causal, sched, interpret, True,
                           window)
+    # named HERE, and the named values handed on both as the output and as
+    # residuals: a name put on the layer's output from outside marks
+    # another variable than the residual, and a checkpoint that keeps it
+    # still runs the kernel again for the backward
+    out, lse = (_saved(x, name) for x, name in zip((out, lse), FLASH_SAVED))
     return out, (q, k, v, mask, out, lse)
 
 

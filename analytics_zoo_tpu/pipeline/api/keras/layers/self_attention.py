@@ -721,6 +721,34 @@ class DecoderBlock(Layer):
         return self.apply(params, {}, x, training=training, rng=rng)[0]
 
 
+def remat_saved_bytes(record: Optional[Mapping[str, int]] = None
+                      ) -> Dict[str, int]:
+    """``zoo_remat_saved_bytes`` by ``what`` (``flash_out``, ``flash_lse``:
+    the ``FLASH_SAVED`` names without their prefix): what the block
+    checkpoints of the rematerialised ``DecoderStack`` training step traced
+    last keep on a device beside the blocks' inputs. ``record`` (bytes by
+    ``FLASH_SAVED`` name; a name left out is 0) sets it first: the stack
+    while it is traced, and the train loop with ``{}`` before it traces a
+    step, so that a model without such a stack reads 0 and not the last
+    model's."""
+    from analytics_zoo_tpu.ops.pallas.flash_attention import FLASH_SAVED
+    from .....observability import default_registry
+    reg, out = default_registry(), {}
+    for name in FLASH_SAVED:
+        what = name.removeprefix("zoo_")
+        gauge = reg.gauge(  # zoolint: disable=ZL015 the two of FLASH_SAVED
+            "zoo_remat_saved_bytes",
+            "bytes a device keeps for the backward pass beside the blocks' "
+            "inputs in the rematerialised DecoderStack step traced last, "
+            "summed over its blocks: the flash kernels' outputs (flash_out) "
+            "and row statistics (flash_lse); 0 where the XLA attention op "
+            "ran", labels={"what": what})
+        if record is not None:
+            gauge.set(record.get(name, 0))
+        out[what] = int(gauge.value)
+    return out
+
+
 class DecoderStack(Layer):
     """Token embedding (no position table), one ``DecoderBlock`` per entry
     of ``layer_types`` (``"sliding_attention"``: the block's attention has
@@ -736,9 +764,17 @@ class DecoderStack(Layer):
     ``n_head``, ``n_kv_head``, ``head_dim``, ``rope_parameters`` and
     ``sliding_window`` describe, which are then not needed; blocks of one
     layer type share the rotary tables the first of them makes.
-    ``remat=True`` rematerialises each block in the
-    backward pass (``jax.checkpoint``, as ``GPipe(remat=)`` does): the
-    step keeps one block's activations at a time."""
+    ``remat=True`` rematerialises each block in the backward pass
+    (``jax.checkpoint``): the step keeps one block's activations at a
+    time, and of every block its inputs and, where its attention ran on
+    the flash kernels, that call's output and row statistics
+    (``flash_attention.FLASH_SAVED``: one tensor of ``B x heads x T x
+    head_dim`` in the compute dtype a layer and one float a row), so the
+    backward pass recomputes the projections, norms, rotary and the
+    feed-forward layer but does not run the flash forward a second time.
+    What that keeps is counted while the step is traced, in
+    ``zoo_remat_saved_bytes{what=}`` and ``model.last_fit_report
+    ["remat_saved_bytes"]``."""
 
     SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -811,16 +847,26 @@ class DecoderStack(Layer):
         for blk, kind in zip(self.blocks, self.layer_types):
             if kind not in tables:
                 tables[kind] = blk.attn.tables(ids.shape[1])
+        from analytics_zoo_tpu.ops.pallas.flash_attention import (
+            FLASH_SAVED, saved_bytes_log)
+        keep = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
         new_state = {}
-        for i, (blk, kind) in enumerate(zip(self.blocks, self.layer_types)):
-            def run(p, s, h, tab, blk=blk):
-                return blk.apply(p, s, [h, tab], training=training)
-            if self.remat:
-                run = jax.checkpoint(run)
-            h, ns = run(params[f"block{i}"],
-                        (state or {}).get(f"block{i}", {}), h, tables[kind])
-            if ns:
-                new_state[f"block{i}"] = ns
+        with saved_bytes_log() as kept:
+            for i, (blk, kind) in enumerate(zip(self.blocks,
+                                                self.layer_types)):
+                def run(p, s, h, tab, blk=blk):
+                    return blk.apply(p, s, [h, tab], training=training)
+                if self.remat:
+                    # a block whose attention is the XLA op holds no such
+                    # name, and its checkpoint keeps the inputs alone
+                    run = jax.checkpoint(run, policy=keep)
+                h, ns = run(params[f"block{i}"],
+                            (state or {}).get(f"block{i}", {}), h,
+                            tables[kind])
+                if ns:
+                    new_state[f"block{i}"] = ns
+        if self.remat and training:
+            remat_saved_bytes(kept)
         return self.norm.call(params["norm"], h), new_state
 
     def call(self, params, x, *, training=False, rng=None):

@@ -653,6 +653,8 @@ class TrainingLoop:
         self._phases = _HostPhases(self._registry)
         # last_fit_report["state"]
         self._state_report: Dict[str, Any] = {}
+        # last_fit_report["remat_saved_bytes"], set where the step is traced
+        self._remat_saved: Dict[str, int] = {}
 
     # -- goodput attribution -------------------------------------------------
     def _gp_note(self, category: str) -> None:
@@ -723,6 +725,11 @@ class TrainingLoop:
             # and how it went back (``handed_back``, or ``cloned``: the
             # retry loop's boundary copy, only with a checkpoint directory)
             "state": dict(self._state_report),
+            # what the compiled step keeps for its backward pass beside the
+            # inputs of a rematerialised DecoderStack's blocks, by kind
+            # (zoo_remat_saved_bytes{what=}): the flash kernels' outputs
+            # and row statistics; zeros for any other model
+            "remat_saved_bytes": dict(self._remat_saved),
         }
 
     # -- jitted steps -------------------------------------------------------
@@ -887,6 +894,7 @@ class TrainingLoop:
         an anomalous step's params/opt-state/net-state updates are
         discarded on device (the carry keeps the pre-step values); the
         host observes the flag later and handles budget escalation."""
+        from .layers.self_attention import remat_saved_bytes
         opt = self.optimizer
         apply_loss = self._loss_application()
         remat = self._remat_wrapper()
@@ -897,7 +905,13 @@ class TrainingLoop:
                 l, ns = apply_loss(p, net_state, x, y, rng)
                 aux = _aux_loss_sum(ns)
                 return (l if aux is None else l + aux), ns
-            return jax.value_and_grad(remat(lfn), has_aux=True)(params)
+            # trace time, once a compiled step: a rematerialised
+            # DecoderStack counts what its checkpoints keep while the
+            # gradient is traced; a model without one reads 0
+            remat_saved_bytes({})
+            out = jax.value_and_grad(remat(lfn), has_aux=True)(params)
+            self._remat_saved = remat_saved_bytes()
+            return out
 
         if not cfg.active:
             def plain(params, opt_state, net_state, rng, x, y):

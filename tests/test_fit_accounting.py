@@ -228,8 +228,10 @@ def test_last_fit_report_reconciles(tmp_path):
     m.fit(x, y, batch_size=16, nb_epoch=2)
     r = m.last_fit_report
     assert set(r) == {"wall_s", "steps", "ledger", "host_s", "inflight",
-                      "compile", "state"}
+                      "compile", "state", "remat_saved_bytes"}
     assert set(r["state"]) == {"source", "bytes", "published"}
+    # no rematerialised DecoderStack in this model: nothing kept
+    assert r["remat_saved_bytes"] == {"flash_out": 0, "flash_lse": 0}
     assert r["state"]["published"] == "handed_back" and r["state"]["bytes"] > 0
     assert r["steps"] == 8
     assert set(r["ledger"]) == set(TRAIN_CATEGORIES)

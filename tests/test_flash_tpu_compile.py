@@ -134,6 +134,33 @@ def test_latent_attention_flash_compiles_for_v5e_with_the_sequence_resident(
         assert sum(marker in n for n in names) == 1, (marker, names)
 
 
+@pytest.mark.parametrize("policy,forwards", [(False, 2), (True, 1)],
+                         ids=["bare_checkpoint", "keeps_flash_saved"])
+def test_a_checkpoint_that_keeps_the_named_residuals_compiles_one_forward(
+        one_chip, policy, forwards):
+    """The GLM cell's call (20 heads of 256 at T = 8192, one row) between
+    two projections under ``jax.checkpoint``, compiled by the chip's
+    compiler: the program holds the forward kernel twice under a bare
+    checkpoint, once where the policy keeps ``FLASH_SAVED``."""
+    shape = (1, 20, 8192, 256)
+    sched = fa._auto_blocks(shape, 8192, jnp.bfloat16, True, False, False)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((256, 256), jnp.bfloat16, sharding=one_chip)
+
+    def block(w, x):
+        q = x @ w
+        return fa._flash(q, q, x, None, True, sched, False, None) @ w
+    keep = jax.checkpoint_policies.save_only_these_names(*fa.FLASH_SAVED)
+    run = jax.checkpoint(block, policy=keep if policy else None)
+    # a loss that needs the block's output, or the first forward is dead
+    text = jax.jit(jax.grad(lambda w, x: jnp.sum(jnp.square(
+        run(w, x).astype(jnp.float32))))).lower(w, x).compile().as_text()
+    names = [ln.split("=")[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert sum("zoo_flash_fwd" in n for n in names) == forwards, names
+    assert sum("zoo_flash_bwd" in n for n in names) == 2, names
+
+
 @pytest.mark.parametrize("rows,groups,d,h,dtype", [
     # the decoder cell's row buffers, cut to the rows held and whole
     (32768, 8, 2304, 896, jnp.bfloat16),

@@ -64,6 +64,11 @@ def find_head(model) -> Optional[Tuple[object, Tuple[str, ...]]]:
         return hook()
     if isinstance(model, Sequential) and model.layers:
         head = model.layers[-1]
+        hook = getattr(head, "fused_head", None)
+        if callable(hook):
+            # a head tied to a table of the last layer's own parameters
+            found = hook()
+            return found and (found[0], (head.name,) + tuple(found[1]))
         if (isinstance(head, Dense)
                 and sum(1 for l in model.layers if l is head) == 1):
             return head, (head.name,)
@@ -82,19 +87,22 @@ class FusedHeadSpec:
     and the fused blockwise loss over the head's own params. ``sharded``
     marks the vocab-sharded (model-parallel) form — resolved once per
     loop from the mesh, so every step builder of a loop compiles the
-    same collective structure."""
+    same collective structure. ``tied`` marks a head that is a (V, H)
+    table the trunk also embeds with (``layers.TiedHead``): the path
+    leads to the table and the loss reads it transposed."""
 
     def __init__(self, head, param_path: Tuple[str, ...],
                  sharded: bool = False):
         self.head = head
         self.param_path = tuple(param_path)
-        self.sharded = bool(sharded)
+        self.tied = bool(getattr(head, "tied", False))
+        self.sharded = bool(sharded) and not self.tied
 
     def head_params(self, params):
         p = params
         for k in self.param_path:
             p = p[k]
-        return p
+        return {"W": p.T} if self.tied else p
 
     def apply_and_loss(self, model, params, net_state, x, y, *, rng=None):
         """(loss, new_state) with the head fused into the loss."""
@@ -176,7 +184,15 @@ def resolve_fused_loss(model, loss_fn: Callable) -> Optional[FusedHeadSpec]:
         return None
     if mode == "auto" and head.output_dim < AUTO_MIN_VOCAB:
         return None
-    return FusedHeadSpec(head, path, sharded=_head_sharded(head))
+    sharded = _head_sharded(head)
+    if sharded and getattr(head, "tied", False):
+        # FusedHeadSpec never shards a tied head: the table is embedded
+        # with whole, so its loss is the unsharded one on every rank
+        log.warning("fused LM-head cross-entropy: head=%s is tied to the "
+                    "token table, which a `model` mesh axis does not shard: "
+                    "every rank runs the UNSHARDED fused loss over all %d "
+                    "classes", head.name, head.output_dim)
+    return FusedHeadSpec(head, path, sharded=sharded)
 
 
 def _head_sharded(head) -> bool:

@@ -39,5 +39,5 @@ from .moe import RoutedExperts, SparseMoE  # noqa: F401
 from .recurrent import GRU, LSTM, Bidirectional, SimpleRNN  # noqa: F401
 from .self_attention import (BERT, DecoderAttention, DecoderBlock,  # noqa: F401
                              DecoderStack, LatentAttention,
-                             MultiHeadSelfAttention,
+                             MultiHeadSelfAttention, ShortConvMixer,
                              TransformerBlock, TransformerLayer)

@@ -17,17 +17,22 @@ pyzoo ``pipeline/api/keras/layers/self_attention.py``).
   in place of a position table, grouped key/value heads with a head size
   of their own, a causal window per layer (``layer_types``), no biases, and
   any feed-forward layer per block (``RoutedExperts``,
-  ``GatedFeedForward``) and, through ``DecoderStack(attn=)``, any attention
-  layer per block. The post-LN classes above keep their behaviour and
-  their parameter trees.
+  ``GatedFeedForward``) and, through ``DecoderStack(attn=)``, any mixer
+  per block; optionally q/k normalisation per head and a head tied to the
+  token table (``TiedHead``). The post-LN classes above keep their
+  behaviour and their parameter trees.
 * ``LatentAttention`` — multi-head latent attention (MLA): queries and
   keys/values through low-rank latents with an RMSNorm each, rotary
   positions on a slice of the head only, one rotary key head shared by all
   heads. Training computes the expanded form.
+* ``ShortConvMixer`` — the LFM2 family's gated short convolution: a causal
+  depthwise convolution of a few taps between two input-dependent gates,
+  a block's mixer where it is not attention (``layer_types`` ``"conv"``).
 """
 
 from __future__ import annotations
 
+import collections
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import jax
@@ -37,7 +42,8 @@ from analytics_zoo_tpu.ops.attention import (apply_rotary,
                                              dot_product_attention,
                                              merge_heads, rotary_inv_freq,
                                              rotary_tables, split_heads)
-from ..engine import Layer, compute_dtype, get_initializer, param_dtype
+from ..engine import (Layer, compute_dtype, dispatch_layer, get_initializer,
+                      param_dtype)
 from .normalization import LayerNorm, RMSNorm
 
 
@@ -518,13 +524,19 @@ class DecoderAttention(MultiHeadSelfAttention):
     ``rope_parameters`` entry (``ops.attention.rotary_inv_freq``); its
     frequencies and attention factor are made once, here. Routing between
     the Pallas flash kernels and the XLA op is ``_use_flash``'s, as for
-    ``MultiHeadSelfAttention``. Input (B, T, H), or ``[x, (cos, sin)]``
-    with the rotary tables of the call's positions (``DecoderStack`` forms
-    them once per kind of layer)."""
+    ``MultiHeadSelfAttention``. ``qk_norm=True`` (off by default: the
+    parameter tree then has the four matrices alone) puts an RMSNorm over
+    each head's ``head_dim`` columns of q and of k before the rotation,
+    one weight vector of ``head_dim`` each (``q_norm``, ``k_norm``) shared
+    by the heads, at ``epsilon``; device time under ``zoo_attn.qk_norm``.
+    Input (B, T, H), or ``[x, (cos, sin)]`` with the rotary tables of the
+    call's positions (``DecoderStack`` forms them once per kind of
+    layer)."""
 
     def __init__(self, hidden_size: int, n_head: int, n_kv_head: int,
                  head_dim: int, rotary: Mapping[str, Any],
-                 window: Optional[int] = None, **kwargs):
+                 window: Optional[int] = None, qk_norm: bool = False,
+                 epsilon: float = 1e-6, **kwargs):
         Layer.__init__(self, **kwargs)
         if n_head % n_kv_head:
             raise ValueError(f"n_head {n_head} not divisible by n_kv_head "
@@ -533,6 +545,7 @@ class DecoderAttention(MultiHeadSelfAttention):
         self.n_head, self.n_kv_head, self.head_dim = n_head, n_kv_head, head_dim
         self.causal = True
         self.window = window
+        self.qk_norm = RMSNorm(epsilon=epsilon) if qk_norm else None
         self.inv_freq, self.rotary_scale = rotary_inv_freq(head_dim, rotary)
 
     def build(self, rng, input_shape):
@@ -540,10 +553,14 @@ class DecoderAttention(MultiHeadSelfAttention):
         init = get_initializer("glorot_uniform")
         h, q, kv = (self.hidden_size, self.n_head * self.head_dim,
                     self.n_kv_head * self.head_dim)
-        return {"Wq": init(k[0], (h, q), param_dtype()),
-                "Wk": init(k[1], (h, kv), param_dtype()),
-                "Wv": init(k[2], (h, kv), param_dtype()),
-                "Wo": init(k[3], (q, h), param_dtype())}
+        p = {"Wq": init(k[0], (h, q), param_dtype()),
+             "Wk": init(k[1], (h, kv), param_dtype()),
+             "Wv": init(k[2], (h, kv), param_dtype()),
+             "Wo": init(k[3], (q, h), param_dtype())}
+        if self.qk_norm is not None:
+            p["q_norm"] = self.qk_norm.build(k[0], (self.head_dim,))
+            p["k_norm"] = self.qk_norm.build(k[1], (self.head_dim,))
+        return p
 
     def param_sharding(self, params):
         return jax.tree.map(lambda _: None, params)
@@ -562,6 +579,10 @@ class DecoderAttention(MultiHeadSelfAttention):
         q = split_heads(_project(params["Wq"], x, cd), self.n_head)
         k = split_heads(_project(params["Wk"], x, cd), self.n_kv_head)
         v = split_heads(_project(params["Wv"], x, cd), self.n_kv_head)
+        if self.qk_norm is not None:
+            with jax.named_scope("zoo_attn.qk_norm"):
+                q = self.qk_norm.call(params["q_norm"], q)
+                k = self.qk_norm.call(params["k_norm"], k)
         cos, sin = tables if tables is not None else self.tables(t)
         with jax.named_scope("zoo_attn.rope"):
             q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
@@ -598,6 +619,8 @@ class LatentAttention(MultiHeadSelfAttention):
     ``.kv_latent``, ``.expand`` (the two up-projections and the broadcast
     of ``k_pe``), ``.rope``, ``.attend`` and ``.out``. Input (B, T, H), or
     ``[x, (cos, sin)]`` with tables of ``qk_rope_dim`` columns."""
+
+    mixer_kind = "latent"
 
     def __init__(self, hidden_size: int, n_head: int, q_lora_rank: int,
                  kv_lora_rank: int, qk_nope_dim: int, qk_rope_dim: int,
@@ -677,11 +700,93 @@ class LatentAttention(MultiHeadSelfAttention):
             return _project(params["Wo"], merge_heads(out), cd)
 
 
+class ShortConvMixer(Layer):
+    """The gated short convolution of the LFM2 family, a decoder block's
+    mixer that is not attention. With ``a`` (B, T, H):
+
+    * ``[B, C, u] = a Win`` (H -> 3 H, split in that order);
+    * ``v = B * u``; ``c[t] = sum_j conv[:, j] * v[t - (kernel - 1) + j]``
+      with ``v`` zero before position 0: a causal depthwise convolution,
+      one ``kernel``-tap filter a channel (``Conv1d(groups=H,
+      padding=kernel - 1)`` cut to the first T outputs);
+    * ``y = (C * c) Wout`` (H -> H).
+
+    No activation, no biases, no positions: the layer has no ``tables``
+    and ``DecoderStack`` calls it on the hidden states alone. The two
+    products accumulate in float32 (``_project``); the gate-conv-gate chain
+    between them is ``kernel`` shifted multiply-adds over T: ``v`` in the
+    compute dtype, the taps and their sum in float32, the gated result
+    rounded to the compute dtype. Parameters ``Win``
+    (H, 3 H), ``conv`` (H, kernel; uniform +-kernel^-1/2 at the start,
+    ``torch.nn.Conv1d``'s own for this shape) and ``Wout`` (H, H). Device
+    time shows under ``zoo_conv.in_proj``, ``zoo_conv.gate`` and
+    ``zoo_conv.out_proj``."""
+
+    mixer_kind = "conv"
+
+    def __init__(self, hidden_size: int, kernel: int = 3, **kwargs):
+        super().__init__(**kwargs)
+        self.hidden_size, self.kernel = hidden_size, kernel
+
+    def build(self, rng, input_shape):
+        k = jax.random.split(rng, 3)
+        init = get_initializer("glorot_uniform")
+        h, bound = self.hidden_size, self.kernel ** -0.5
+        return {"Win": init(k[0], (h, 3 * h), param_dtype()),
+                "conv": jax.random.uniform(k[1], (h, self.kernel),
+                                           param_dtype(), -bound, bound),
+                "Wout": init(k[2], (h, h), param_dtype())}
+
+    def param_sharding(self, params):
+        return jax.tree.map(lambda _: None, params)
+
+    def call(self, params, x, *, training=False, rng=None):
+        cd = compute_dtype()
+        t = x.shape[1]
+        with jax.named_scope("zoo_conv.in_proj"):
+            bcu = _project(params["Win"], x, cd)
+        with jax.named_scope("zoo_conv.gate"):
+            b, c, u = jnp.split(bcu, 3, axis=-1)
+            v = b * u
+            taps = params["conv"].astype(jnp.float32)
+            conv = taps[:, -1] * v
+            for back in range(1, self.kernel):
+                # v[t - back], zero before position 0
+                shifted = jnp.pad(v, ((0, 0), (back, 0), (0, 0)))[:, :t]
+                conv = conv + taps[:, -1 - back] * shifted
+            y = (c * conv).astype(cd)
+        with jax.named_scope("zoo_conv.out_proj"):
+            return _project(params["Wout"], y, cd)
+
+
+class TiedHead(Layer):
+    """The logits head of a ``DecoderStack(tied_head=True)``: ``x E^T``
+    with ``E`` the stack's token table. It holds no parameter of its own;
+    the stack hands it ``{"W": E^T}`` through the containers' dispatch, so
+    that the fused cross-entropy can take it over as it takes a ``Dense``
+    head (``fused_loss.find_head``)."""
+
+    activation = None
+    tied = True
+
+    def __init__(self, output_dim: int, **kwargs):
+        super().__init__(**kwargs)
+        self.output_dim = output_dim
+
+    def build(self, rng, input_shape):
+        return {}
+
+    def call(self, params, x, *, training=False, rng=None):
+        return _project(params["W"], x, compute_dtype())
+
+
 class DecoderBlock(Layer):
-    """Pre-norm residual block: ``h = x + Attn(RMSNorm(x))``;
-    ``x' = h + FFN(RMSNorm(h))``. ``attn`` is the block's attention layer
-    (``DecoderAttention``, ``LatentAttention``; any layer that takes
-    ``[x, (cos, sin)]``), ``ffn`` its feed-forward layer (``RoutedExperts``,
+    """Pre-norm residual block: ``h = x + Mixer(RMSNorm(x))``;
+    ``x' = h + FFN(RMSNorm(h))``. ``attn`` is the block's mixer
+    (``DecoderAttention``, ``LatentAttention``: any layer that takes
+    ``[x, (cos, sin)]``; ``ShortConvMixer``: any layer from (B, T, H) to
+    (B, T, H), called on ``x`` alone where the block is given no tables),
+    ``ffn`` its feed-forward layer (``RoutedExperts``,
     ``GatedFeedForward``; any layer from (B, T, H) to (B, T, H)), whose
     state, if it keeps one, is the block's."""
 
@@ -749,21 +854,57 @@ def remat_saved_bytes(record: Optional[Mapping[str, int]] = None
     return out
 
 
+#: the kinds of mixer ``zoo_decoder_blocks{mixer=}`` counts
+MIXER_KINDS = ("conv", "full_attention", "sliding_attention", "latent")
+
+
+def decoder_blocks(census: Optional[Mapping[str, int]] = None
+                   ) -> Dict[str, int]:
+    """``zoo_decoder_blocks`` by ``mixer`` (``MIXER_KINDS``): the blocks of
+    the ``DecoderStack`` built last, by what mixes their tokens. ``census``
+    (blocks by kind; a kind left out is 0) sets it first, as the stack does
+    where it is built."""
+    from .....observability import default_registry
+    reg, out = default_registry(), {}
+    for kind in MIXER_KINDS:
+        gauge = reg.gauge(  # zoolint: disable=ZL015 the four of MIXER_KINDS
+            "zoo_decoder_blocks",
+            "blocks of the DecoderStack built last, by the kind of their "
+            "mixer: a gated short convolution (conv), attention over all "
+            "earlier positions (full_attention) or a window of them "
+            "(sliding_attention), latent attention (latent)",
+            labels={"mixer": kind})
+        if census is not None:
+            gauge.set(census.get(kind, 0))
+        out[kind] = int(gauge.value)
+    return out
+
+
 class DecoderStack(Layer):
     """Token embedding (no position table), one ``DecoderBlock`` per entry
     of ``layer_types`` (``"sliding_attention"``: the block's attention has
-    the ``sliding_window``; ``"full_attention"``: none), a final RMSNorm.
-    Input int ids (B, T) -> hidden states (B, T, H); put a
-    ``Dense(vocab, bias=False)`` behind it for an untied head.
+    the ``sliding_window``; ``"full_attention"``: none; ``"conv"``: the
+    block's mixer is a ``ShortConvMixer`` of ``conv_kernel`` taps, no
+    attention), a final RMSNorm. Input int ids (B, T) -> hidden states
+    (B, T, H); put a ``Dense(vocab, bias=False)`` behind it for an untied
+    head. ``tied_head=True`` ends the stack in the head itself, ``logits =
+    RMSNorm(h) E^T`` (B, T, vocab) with ``E`` the token table: one leaf
+    takes the embedding's gradient and the head's, ``fit`` holds one table
+    and one pair of moments, and the fused cross-entropy takes the head
+    over (``fused_head``) as it takes a ``Dense``.
 
     ``rope_parameters`` maps each layer type to its rotary specification
     (or is one specification for all). ``ffn(i)`` returns block ``i``'s
-    feed-forward layer. ``attn(i)``, where given, returns block ``i``'s
-    attention layer (``LatentAttention``; anything with ``tables(t)`` and a
-    call on ``[x, (cos, sin)]``) in place of the ``DecoderAttention`` that
-    ``n_head``, ``n_kv_head``, ``head_dim``, ``rope_parameters`` and
-    ``sliding_window`` describe, which are then not needed; blocks of one
-    layer type share the rotary tables the first of them makes.
+    feed-forward layer. ``qk_norm`` is ``DecoderAttention``'s. ``attn(i)``,
+    where given, returns block ``i``'s mixer (``LatentAttention``: anything
+    with ``tables(t)`` and a call on ``[x, (cos, sin)]``; or a layer
+    without ``tables``, called on ``x`` alone) in place of the
+    ``DecoderAttention`` / ``ShortConvMixer`` that ``n_head``,
+    ``n_kv_head``, ``head_dim``, ``rope_parameters``, ``sliding_window``
+    and ``conv_kernel`` describe (the attention's are needed only where a
+    layer type asks for attention); blocks of one layer type share the
+    rotary tables the first of them makes. ``mixers`` counts the blocks by
+    ``mixer_kind`` (``zoo_decoder_blocks{mixer=}``).
     ``remat=True`` rematerialises each block in the backward pass
     (``jax.checkpoint``): the step keeps one block's activations at a
     time, and of every block its inputs and, where its attention ran on
@@ -771,12 +912,13 @@ class DecoderStack(Layer):
     (``flash_attention.FLASH_SAVED``: one tensor of ``B x heads x T x
     head_dim`` in the compute dtype a layer and one float a row), so the
     backward pass recomputes the projections, norms, rotary and the
-    feed-forward layer but does not run the flash forward a second time.
+    feed-forward layer but does not run the flash forward a second time;
+    a ``conv`` block holds no such name and keeps its input alone.
     What that keeps is counted while the step is traced, in
     ``zoo_remat_saved_bytes{what=}`` and ``model.last_fit_report
     ["remat_saved_bytes"]``."""
 
-    SLIDING, FULL = "sliding_attention", "full_attention"
+    SLIDING, FULL, CONV = "sliding_attention", "full_attention", "conv"
 
     def __init__(self, vocab: int, layer_types: Sequence[str],
                  hidden_size: int, n_head: Optional[int] = None,
@@ -787,7 +929,9 @@ class DecoderStack(Layer):
                  sliding_window: Optional[int] = None,
                  epsilon: float = 1e-6, initializer_range: float = 0.02,
                  remat: bool = False,
-                 attn: Optional[Callable[[int], Layer]] = None, **kwargs):
+                 attn: Optional[Callable[[int], Layer]] = None,
+                 qk_norm: bool = False, conv_kernel: int = 3,
+                 tied_head: bool = False, **kwargs):
         super().__init__(**kwargs)
         self.vocab, self.hidden_size = vocab, hidden_size
         self.layer_types = tuple(layer_types)
@@ -797,15 +941,20 @@ class DecoderStack(Layer):
         if ffn is None:
             raise ValueError("ffn(i), each block's feed-forward layer, is "
                              "needed")
-        if attn is None and None in (n_head, n_kv_head, head_dim,
-                                     rope_parameters):
+        for i, kind in enumerate(self.layer_types):
+            if kind not in (self.SLIDING, self.FULL, self.CONV):
+                raise ValueError(f"layer_types[{i}] = {kind!r}")
+        attends = any(kind != self.CONV for kind in self.layer_types)
+        if attn is None and attends and None in (n_head, n_kv_head, head_dim,
+                                                 rope_parameters):
             raise ValueError("without attn(i), n_head, n_kv_head, head_dim "
                              "and rope_parameters describe the attention")
         for i, kind in enumerate(self.layer_types):
-            if kind not in (self.SLIDING, self.FULL):
-                raise ValueError(f"layer_types[{i}] = {kind!r}")
             if attn is not None:
                 layer = attn(i)
+            elif kind == self.CONV:
+                layer = ShortConvMixer(hidden_size, kernel=conv_kernel,
+                                       name=f"{self.name}_block{i}_conv")
             else:
                 if kind == self.SLIDING and not sliding_window:
                     raise ValueError("sliding_attention layers need "
@@ -814,11 +963,24 @@ class DecoderStack(Layer):
                     hidden_size, n_head, n_kv_head, head_dim,
                     rotary=rope_parameters.get(kind, rope_parameters),
                     window=sliding_window if kind == self.SLIDING else None,
+                    qk_norm=qk_norm, epsilon=epsilon,
                     name=f"{self.name}_block{i}_attn")
             self.blocks.append(DecoderBlock(
                 hidden_size, layer, ffn(i), epsilon=epsilon,
                 name=f"{self.name}_block{i}"))
         self.norm = RMSNorm(epsilon=epsilon)
+        self.head = (TiedHead(vocab, name=f"{self.name}_head")
+                     if tied_head else None)
+        self.mixers: Dict[str, int] = dict(collections.Counter(
+            getattr(blk.attn, "mixer_kind", kind)
+            for blk, kind in zip(self.blocks, self.layer_types)))
+        decoder_blocks(self.mixers)
+
+    def fused_head(self):
+        """``(head, path of the table under the stack's parameters)`` for
+        the fused cross-entropy (``fused_loss.find_head``), or None where
+        the head is not tied."""
+        return None if self.head is None else (self.head, ("wte",))
 
     def build(self, rng, input_shape):
         keys = jax.random.split(rng, len(self.blocks) + 2)
@@ -842,11 +1004,13 @@ class DecoderStack(Layer):
     def apply(self, params, state, x, *, training=False, rng=None):
         ids = x.astype(jnp.int32)
         h = jnp.take(params["wte"], ids, axis=0).astype(compute_dtype())
-        # one pair of tables per kind of layer, shared by its blocks
+        # one pair of tables per kind of layer that has them, shared by
+        # its blocks
         tables = {}
         for blk, kind in zip(self.blocks, self.layer_types):
             if kind not in tables:
-                tables[kind] = blk.attn.tables(ids.shape[1])
+                make = getattr(blk.attn, "tables", None)
+                tables[kind] = make(ids.shape[1]) if make else None
         from analytics_zoo_tpu.ops.pallas.flash_attention import (
             FLASH_SAVED, saved_bytes_log)
         keep = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
@@ -857,8 +1021,9 @@ class DecoderStack(Layer):
                 def run(p, s, h, tab, blk=blk):
                     return blk.apply(p, s, [h, tab], training=training)
                 if self.remat:
-                    # a block whose attention is the XLA op holds no such
-                    # name, and its checkpoint keeps the inputs alone
+                    # a block whose mixer is the XLA op or no attention
+                    # holds no such name, and its checkpoint keeps the
+                    # inputs alone
                     run = jax.checkpoint(run, policy=keep)
                 h, ns = run(params[f"block{i}"],
                             (state or {}).get(f"block{i}", {}), h,
@@ -867,7 +1032,11 @@ class DecoderStack(Layer):
                     new_state[f"block{i}"] = ns
         if self.remat and training:
             remat_saved_bytes(kept)
-        return self.norm.call(params["norm"], h), new_state
+        h = self.norm.call(params["norm"], h)
+        if self.head is not None:
+            h, _ = dispatch_layer(self.head, {"W": params["wte"].T}, {}, h,
+                                  training=training)
+        return h, new_state
 
     def call(self, params, x, *, training=False, rng=None):
         return self.apply(params, {}, x, training=training, rng=rng)[0]

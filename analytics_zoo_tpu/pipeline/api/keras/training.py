@@ -172,6 +172,16 @@ def _aux_loss_sum(state):
     return total
 
 
+def _decoder_mixers(model) -> Dict[str, int]:
+    """Blocks by the kind of their mixer over the ``DecoderStack`` layers a
+    container holds (``DecoderStack.mixers``); empty where it holds none."""
+    out: Dict[str, int] = {}
+    for layer in getattr(model, "layers", None) or ():
+        for kind, n in (getattr(layer, "mixers", None) or {}).items():
+            out[kind] = out.get(kind, 0) + n
+    return out
+
+
 class _FullPassEveryEpoch(Trigger):
     """``EveryEpoch`` over a sliced dataset: fires only when the finished
     slice pass completes a FULL pass over all slices
@@ -730,6 +740,9 @@ class TrainingLoop:
             # (zoo_remat_saved_bytes{what=}): the flash kernels' outputs
             # and row statistics; zeros for any other model
             "remat_saved_bytes": dict(self._remat_saved),
+            # the model's DecoderStack blocks by the kind of their mixer
+            # (zoo_decoder_blocks{mixer=}); empty for any other model
+            "mixers": _decoder_mixers(self.model),
         }
 
     # -- jitted steps -------------------------------------------------------
@@ -802,10 +815,11 @@ class TrainingLoop:
                  "(zoo.train.fused_ce; the (N, V) logits tensor is never "
                  "materialized)", spec.head.name, spec.head.output_dim,
                  " VOCAB-SHARDED over the model axis" if spec.sharded
-                 else "")
+                 else " TIED to the token table" if spec.tied else "")
         labels = {"head": spec.head.name,
                   "vocab": str(spec.head.output_dim),
-                  "sharded": "1" if spec.sharded else "0"}
+                  "sharded": "1" if spec.sharded else "0",
+                  "tied": "1" if spec.tied else "0"}
         if prev is not None and prev != labels:
             # stale-series zeroing, same bounded head=/vocab= set
             self._registry.gauge("zoo_train_fused_ce",  # zoolint: disable=ZL015 bounded label set

@@ -228,7 +228,8 @@ def test_last_fit_report_reconciles(tmp_path):
     m.fit(x, y, batch_size=16, nb_epoch=2)
     r = m.last_fit_report
     assert set(r) == {"wall_s", "steps", "ledger", "host_s", "inflight",
-                      "compile", "state", "remat_saved_bytes"}
+                      "compile", "state", "remat_saved_bytes", "mixers"}
+    assert r["mixers"] == {}            # no DecoderStack in this model
     assert set(r["state"]) == {"source", "bytes", "published"}
     # no rematerialised DecoderStack in this model: nothing kept
     assert r["remat_saved_bytes"] == {"flash_out": 0, "flash_lse": 0}

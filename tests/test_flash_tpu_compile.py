@@ -106,6 +106,60 @@ def test_grouped_window_flash_compiles_for_v5e(one_chip, window):
     assert "bf16[32,8192,128]" not in dkv.split("custom-call(")[0]
 
 
+def test_grouped_flash_at_64_compiles_for_v5e(one_chip):
+    """The LFM2 configuration's signature: 32 query / 8 key-value heads of
+    64 at T = 8192, no window: GPT-1's head width with grouped heads, a
+    pair no other cell has. dk/dv go out at 8 heads."""
+    text = _compile_grads(one_chip, 1, 32, 8192, 8192, 64, jnp.bfloat16,
+                          True, False, group=4).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    names = sorted(ln.split("=")[0].strip() for ln in calls)
+    assert len(names) == 3 and not any("_win" in n for n in names), names
+    dkv = next(ln for ln in calls if "zoo_flash_bwd_dkv" in ln)
+    assert "bf16[8,8192,64]" in dkv.split("custom-call(")[0], dkv[:200]
+    assert "bf16[32,8192,64]" not in dkv.split("custom-call(")[0]
+
+
+def test_a_short_conv_block_keeps_its_chain_in_the_compute_dtype(one_chip):
+    """A rematerialised ``x + ShortConvMixer(RMSNorm(x))`` at the LFM2
+    cell's shapes, forward and backward, compiled by the chip's compiler:
+    no Mosaic call, and the in_proj's (B, T, 3 H) output never stands in
+    float32 (with ``v = B * u`` taken in float32 it did: 805 MB a layer
+    where 403 do; PR 36)."""
+    from analytics_zoo_tpu.common.context import reset_zoo_context
+    from analytics_zoo_tpu.pipeline.api.keras import set_policy
+    from analytics_zoo_tpu.pipeline.api.keras.layers import (RMSNorm,
+                                                             ShortConvMixer)
+    set_policy(compute_dtype="bfloat16", param_dtype="float32")
+    try:
+        mixer, norm = ShortConvMixer(2048), RMSNorm(1e-5)
+        shapes = jax.eval_shape(
+            lambda k: {"mixer": mixer.build(k, (None, 8192, 2048)),
+                       "norm": norm.build(k, (None, 8192, 2048))},
+            jax.random.key(0))
+        params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), shapes)
+        x = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16,
+                                 sharding=one_chip)
+
+        @jax.checkpoint
+        def block(p, x):
+            return x + mixer.call(p["mixer"], norm.call(p["norm"], x))
+
+        def step(p, x, co):
+            y, vjp = jax.vjp(block, p, x)
+            return y, vjp(co)
+        text = jax.jit(step).lower(params, x, x).compile().as_text()
+    finally:
+        reset_zoo_context()             # the float32 default again
+    assert "tpu_custom_call" not in text
+    entry = text[text.index("\nENTRY "):]
+    assert "bf16[4,8192,6144]" in entry
+    assert not re.search(r"= f32\[4,8192,6144\]", entry), re.findall(
+        r"\S+ = f32\[4,8192,6144\]\S* \w+", entry)[:3]
+
+
 def test_latent_attention_flash_compiles_for_v5e_with_the_sequence_resident(
         one_chip):
     """The GLM configuration's signature: 20 query and 20 key heads of 256

@@ -201,6 +201,16 @@ CASES = {
         ffn=lambda i: (L.GatedFeedForward(12) if i == 0
                        else L.RoutedExperts(4, 8, top_k=2, shared_dim=8))),
         (6,), "int"),
+    # the LFM2 family's mixer, q/k normalisation, and a stack of both kinds
+    # of mixer that ends in the head tied to its own table
+    "ShortConvMixer": (lambda: L.ShortConvMixer(8), (6, 8), "float"),
+    "DecoderAttention_qk_norm": (lambda: L.DecoderAttention(
+        8, 4, 2, 4, rotary=_ROPE, qk_norm=True), (6, 8), "float"),
+    "DecoderStack_conv_tied": (lambda: L.DecoderStack(
+        vocab=7, layer_types=["conv", "full_attention", "conv"],
+        hidden_size=8, n_head=4, n_kv_head=2, head_dim=4,
+        rope_parameters=_ROPE, qk_norm=True, tied_head=True,
+        ffn=lambda i: L.GatedFeedForward(12)), (6,), "int"),
 }
 
 

@@ -13,6 +13,7 @@ fine into the MXU; accumulating attention weights in bf16 is not).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Mapping, Optional, Tuple
 
@@ -135,10 +136,80 @@ def rotary_tables(inv_freq, scale: float, t: int):
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
 
 
-def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate (B, n_head, T, d_head) by the tables, ``rotate_half`` pairs
-    (dimension i with i + d/2), in float32; the result has x's dtype."""
+def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                 heads_first: bool = True) -> jax.Array:
+    """Rotate (B, n_head, T, d_head), or with ``heads_first=False``
+    (B, T, n_head, d_head), by the (T, d_head) tables, which broadcast over
+    the head axis wherever it stands: ``rotate_half`` pairs (dimension i
+    with i + d/2), in float32; the result has x's dtype."""
+    if not heads_first:
+        cos, sin = cos[:, None, :], sin[:, None, :]
     xf = x.astype(jnp.float32)
     half = x.shape[-1] // 2
     rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
     return (xf * cos + rot * sin).astype(x.dtype)
+
+
+def rotary_tables_in_place(cos: jax.Array, sin: jax.Array, n_head: int,
+                           head_dim: int, start: int = 0):
+    """The (T, r) tables spread over a (T, n_head * head_dim) plane, for
+    ``apply_rotary_in_place``: every head's lanes ``[start, start + r)``
+    carry ``cos`` and ``sin`` with the sign ``rotate_half`` gives its
+    partner (minus on the first half), its other lanes 1 and 0. Made once
+    a step per kind of layer and shared by the layers of that kind."""
+    t, r = cos.shape
+    pad = ((0, 0), (start, head_dim - start - r))
+    signed = jnp.concatenate([-sin[:, :r // 2], sin[:, r // 2:]], axis=-1)
+    return (jnp.tile(jnp.pad(cos, pad, constant_values=1.0), (1, n_head)),
+            jnp.tile(jnp.pad(signed, pad), (1, n_head)))
+
+
+def apply_rotary_in_place(x: jax.Array, cos_full: jax.Array,
+                          sin_signed: jax.Array, head_dim: int, rope_dim: int,
+                          start: int = 0) -> jax.Array:
+    """``apply_rotary`` on (B, T, n_head * head_dim), the heads side by
+    side along the last axis where a projection wrote them, with the
+    tables of ``rotary_tables_in_place``: the same products and sums, and
+    no (B, T, n_head, head_dim) view of ``x`` (on a TPU that view is
+    another tiling of the same bytes, so every operation on it costs a
+    copy of the tensor). A lane's partner is ``rope_dim / 2`` lanes up in
+    the first half of a head's rotary lanes and as many down in the
+    second; lanes outside them meet a sine of 0. The rotation is
+    orthogonal and its transpose is the rotation by the opposite angle,
+    so the backward rule is this function again with the sine negated
+    (what autodiff makes of the two rolls is slices of the cotangent that
+    XLA materialises whole); the tables get no gradient."""
+    return _rotate_in_place(x, cos_full, sin_signed,
+                            (head_dim, rope_dim, start))
+
+
+def _rotated(x, cos_full, sin_signed, geom, sign: float):
+    head_dim, rope_dim, start = geom
+    half = rope_dim // 2
+    xf = x.astype(jnp.float32)
+    first = (np.arange(x.shape[-1]) % head_dim - start) < half
+    # a permutation: exact in x's own dtype
+    partner = jnp.where(jnp.asarray(first), jnp.roll(x, -half, axis=-1),
+                        jnp.roll(x, half, axis=-1))
+    turned = partner.astype(jnp.float32) * sin_signed
+    return (xf * cos_full + (turned if sign > 0 else -turned)
+            ).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rotate_in_place(x, cos_full, sin_signed, geom):
+    return _rotated(x, cos_full, sin_signed, geom, 1.0)
+
+
+def _rotate_fwd(x, cos_full, sin_signed, geom):
+    return (_rotate_in_place(x, cos_full, sin_signed, geom),
+            (cos_full, sin_signed))
+
+
+def _rotate_bwd(geom, tables, g):
+    cos_full, sin_signed = tables
+    return (_rotated(g, cos_full, sin_signed, geom, -1.0),
+            jnp.zeros_like(cos_full), jnp.zeros_like(sin_signed))
+
+
+_rotate_in_place.defvjp(_rotate_fwd, _rotate_bwd)

@@ -168,7 +168,8 @@ def attention_budget_scale(d: int) -> int:
 def attention_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int,
                          has_mask: bool = False,
                          major: Optional[int] = None,
-                         kernel: Optional[str] = None) -> int:
+                         kernel: Optional[str] = None,
+                         heads: int = 1) -> int:
     """Estimated per-grid-cell VMEM of one flash-attention kernel
     (``kernel`` = ``"fwd"``, ``"dq"`` or ``"dkv"``) at the compute tile
     ``(block_q, block_k)``. Left out: the largest of the three at the
@@ -182,40 +183,46 @@ def attention_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int,
     each in VMEM), its f32 accumulators, and the f32 score-sized tiles its
     loop body keeps live (s and p forward; p, dP and dS backward).
     ``block_k`` prices at the lane floor even as a sublane-position window
-    dim because the score tile needs it lane-aligned anyway."""
+    dim because the score tile needs it lane-aligned anyway. ``heads``: the
+    heads a grid cell runs, 2 in the pair layout (D = 64, two heads a
+    128-lane block): the windows are the 128-wide ones a lone D = 64 head
+    is priced at already, the accumulators and stood-up statistics are one
+    set a head, and so are dkv's row statistics."""
     if kernel is None:
         return max(
             attention_vmem_bytes(
                 block_q, block_k * (ATTENTION_FWD_K_TILES if kern == "fwd"
                                     else 1),
-                d, itemsize, has_mask, major, kern)
+                d, itemsize, has_mask, major, kern, heads)
             for kern in ("fwd", "dq", "dkv"))
     d_eff = round_up(max(d, 1), LANES)
     bq = round_up(max(block_q, 1), SUBLANES)
     bk = round_up(max(block_k, 1), LANES)
     q_blk, k_blk = ((bq, d_eff), itemsize), ((bk, d_eff), itemsize)
-    stat_blk, stat_col = ((1, bq), 4), ((bq, LANES), 4)
+    stat_blk, stat_col = ((heads, 1, bq), 4), ((heads, bq, LANES), 4)
     if kernel == "dkv":
         mq = round_up(max(major or bq, bq), bq)
         return kernel_vmem_bytes(
             operands=[k_blk, k_blk, ((mq, d_eff), itemsize),
                       ((mq, d_eff), itemsize),               # q, dO windows
-                      ((mq // bq, 1, bq), 4), ((mq // bq, 1, bq), 4)],
+                      ((heads * mq // bq, 1, bq), 4),
+                      ((heads * mq // bq, 1, bq), 4)],
             outputs=[k_blk, k_blk],
-            scratch=[((bk, d_eff), 4)] * 2,                  # dk, dv
+            scratch=[((heads, bk, d_eff), 4)] * 2,           # dk, dv
             compute=[((bk, bq), 4)] * 3)
     mk = round_up(max(major or bk, bk), bk)
     kv = [((mk, d_eff), itemsize)] * 2                       # K, V windows
     if has_mask:
         kv.append(((mk // bk, 1, bk), 4))                    # a row a tile
-    carry = [((bq, d_eff), 4), stat_col, stat_col]
+    carry = [((heads, bq, d_eff), 4), stat_col, stat_col]
     if kernel == "fwd":
         return kernel_vmem_bytes(
             operands=[q_blk] + kv, outputs=[q_blk, stat_blk],  # o, lse
             scratch=carry,                                   # acc, m, l
             compute=[((bq, bk), 4)] * 2)
     return kernel_vmem_bytes(                                # dq
-        operands=[q_blk, q_blk, stat_blk, stat_blk] + kv, outputs=[q_blk],
+        operands=[q_blk, q_blk, q_blk, stat_blk] + kv,       # q, dO, O, lse
+        outputs=[q_blk, stat_blk],                           # dq, delta
         scratch=carry,                                       # acc, lse, delta
         compute=[((bq, bk), 4)] * 3)
 

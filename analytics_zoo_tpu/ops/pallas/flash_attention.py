@@ -32,7 +32,8 @@ one float a row, ``f32[B*H, 1, T]`` with q along lanes: what the step keeps
 per layer for the backward is T floats a head. The forward reduces along
 lanes, so it stands its column of statistics down into a row once per q
 block (select-and-sum against an identity pattern: exact, no transpose);
-dq stands it back up once per q block; dkv forms its tile transposed,
+dq stands lse back up once per q block (and lays its own ``delta`` down
+for dkv the same way); dkv forms its tile transposed,
 ``s^T = k q^T`` of shape (block_k, block_q), so the (1, block_q) statistics
 broadcast over sublanes as stored and ``dV += p^T dO``, ``dK += ds^T q``
 are plain products.
@@ -47,8 +48,59 @@ path. In dkv a hidden or padded key only ever touches its own rows of
 dk/dv, so that kernel masks nothing but the diagonal and the wrapper drops
 those rows.
 
+Heads in place (PR 37): the entry takes q ``(B, T, Hq, D)`` and k, v
+``(B, T, Hkv, D)``, what a projection's ``(B, T, H*D)`` output reshapes to
+for nothing, and gives ``(B, T, Hq, D)``. A Mosaic call pins its operands'
+layout, so the ``(B*H, T, D)`` operands the kernels used to take cost a
+materialised transpose of q, k, v, dO on the way in and of o, dq, dk, dv on
+the way out: eleven copies a GPT-1 layer, twelve a latent one, 5-8 % of a
+step. Now the arrays stay ``(B, T, H*D)`` and the BlockSpec index maps
+find a head in them; what a grid cell holds is chosen from the head width
+and the head counts alone (``_head_layout``), one set of kernel bodies:
+
+* ``inplace``, D a multiple of 128 (whole lane tiles): the grid's first
+  axis still counts ``b*h``, and a block that was ``(1, block, D)`` at
+  ``(bh, i, 0)`` is the same block at ``(bh // H, i, bh % H)``. The bodies
+  see what they saw.
+* ``pair``, D = 64 with even head counts and the query heads of a pair on
+  one key/value head or on a pair of their own (``group`` 1 or even): a
+  block is ``(1, block, 128)``, two neighbouring heads, and the grid's
+  first axis counts pairs. K/V of the pair are resident together: the same
+  bytes a head, and lane-dense where a lone 64-wide head filled half of
+  every tile it moved or stored. Inside a cell each head runs the tile
+  loop it ran before, on the two-head blocks as they are: the other
+  head's 64 lanes of the RESIDENT block are zeroed once a cell (q in fwd
+  and dq, with dO; k and v in dkv), so a contraction over all 128 lanes is
+  that head's score tile exactly, at the matrix-unit passes a 64-deep
+  contraction costs anyway, and ``p @ v`` (``ds @ k``, ``p^T dO``,
+  ``ds^T q``) comes out 128 wide with that head's result on its own half
+  and another head's on the other, which the cell drops when it puts the
+  two accumulators' halves side by side. With grouped heads the two query
+  heads of a pair share ONE key/value head, which stands in either half
+  of ITS pair's block (``_Heads.kv_half``): the resident block's half is
+  rotated onto that half (64 lanes, through float32: Mosaic rotates
+  32-bit lanes only) and the result rotated back; in dkv all the query
+  heads of a step belong to one key head, the first half of the steps to
+  the block's first, and the accumulators are folded onto its lanes at
+  the end of either half.
+* ``split``, everything else (the tests' D = 8; odd head counts at
+  D = 64): the wrapper transposes to ``(B*H, T, 1*D)``, the in-place
+  geometry with one head a row.
+
+Row statistics stay ``f32[B*H, 1, T]`` in every layout (a pair cell takes
+two rows of them). The callers keep their side of the bargain: on a TPU a
+``(B, T, H, D)`` VIEW of a ``(B, T, H*D)`` array is another tiling of the
+same bytes, so any XLA operation on such a view costs a copy of the tensor
+(XLA then prefers T-minor layouts and copies on both sides of them). The
+layers therefore rotate q and k in place (``ops.attention.
+apply_rotary_in_place``), the latent layer writes k and v where a head
+belongs through slices of its weight, and ``delta = rowsum(dO * O)``, the
+one per-head reduction the backward needs, is taken inside the dq kernel
+from the dO block it holds and the O block beside it, and handed on to dkv
+as a second output.
+
 Grouped heads and windows (PR 28): q may have ``group`` times the heads of
-k and v. The K/V block specs of fwd and dq index head ``bh // group``, so
+k and v. The K/V block specs of fwd and dq index cell ``c // group``, so
 nothing is repeated in HBM and the query heads of a group find their K/V
 block resident; dkv's grid counts key/value heads and walks the major
 windows of each of the group's query heads in turn into one pair of
@@ -60,10 +112,11 @@ nowhere between. Window calls carry ``_win`` behind the kernel's name.
 With ``group == 1`` and no window every kernel is traced as before.
 
 Backward: the standard two-kernel recompute scheme (no (T, T) tensor is ever
-materialized, unlike the r3 XLA-recompute fallback this replaces):
-``delta = rowsum(dO·O)`` in XLA, then the dq kernel (grid bh, q block, K/V
-window) and the dk/dv kernel (grid bh, k block, q window), each re-forming
-one probability tile at a time from the saved log-sum-exp. Memory stays
+materialized, unlike the r3 XLA-recompute fallback this replaces): the dq
+kernel (grid bh, q block, K/V window), which also takes ``delta =
+rowsum(dO·O)`` once a q block, and the dk/dv kernel (grid bh, k block, q
+window), each re-forming one probability tile at a time from the saved
+log-sum-exp. Memory stays
 O(block²) end to end, which is what makes long-context *training* fit.
 
 What a rematerialising caller keeps (PR 35): the backward needs ``(q, k,
@@ -184,6 +237,77 @@ from .common import VMEM_BYTES_DEFAULT as _VMEM_BYTES_DEFAULT  # noqa: E402
 from .common import VMEM_USABLE_FRACTION as _VMEM_USABLE_FRACTION  # noqa: E402
 
 
+#: half a lane tile: the head width two of which share a block
+_HALF = _LANES // 2
+
+
+class _Heads(NamedTuple):
+    """Where a call's kernels find a head (the module docstring's "Heads in
+    place"). ``layout``: ``inplace`` (operands ``(B, T, H*D)``, a block one
+    head of whole lane tiles), ``pair`` (the same arrays at D = 64, a block
+    two neighbouring heads) or ``split`` (operands ``(B*H, T, D)``, the
+    wrapper transposes). ``cells`` / ``kv_cells``: grid cells a batch row
+    on the query and on the key/value side (heads, or pairs of them);
+    ``group`` query cells share a key/value cell (heads to a head, and so
+    pairs to a pair); ``width`` lanes of a block; a cell runs ``per_cell``
+    heads."""
+    layout: str
+    cells: int
+    kv_cells: int
+    group: int
+    width: int
+    per_cell: int
+
+    def row_and_block(self, cell, cells: int):
+        """(array row, lane block) of grid cell ``cell``, ``cells`` of
+        them a batch row."""
+        if self.layout == "split":
+            return cell, 0
+        return cell // cells, cell % cells
+
+    def kv_half(self, hh: int, q_cell):
+        """Which half of its key/value block query head ``hh`` of pair
+        ``q_cell`` attends: its own without grouped heads, else the half
+        the pair's ONE key/value head stands on. None outside a pair."""
+        if self.per_cell == 1:
+            return None
+        if self.group == 1:
+            return hh
+        return (2 * (q_cell % self.cells)) // self.group % 2
+
+
+def _head_layout(h: int, h_kv: int, d: int) -> _Heads:
+    """The layout a call takes, from what it can see: head width and head
+    counts. Whole lane tiles: in place. Half a tile: pairs, where both
+    sides count an even number of heads and a pair of query heads shares
+    one key/value head or has a pair of its own (``group`` 1 or even).
+    Everything else: the wrapper splits the heads out."""
+    group = h // h_kv
+    if d % _LANES == 0:
+        return _Heads("inplace", h, h_kv, group, d, 1)
+    if d == _HALF and h_kv % 2 == 0 and (group == 1 or group % 2 == 0):
+        return _Heads("pair", h // 2, h_kv // 2, group, _LANES, 2)
+    return _Heads("split", h, h_kv, group, d, 1)
+
+
+def _onto(x, src, dst):
+    """Half ``src`` of ``x``'s 128 lanes standing on half ``dst``, zeros on
+    the other half: how a pair cell cuts one head out of a two-head block
+    and lines it up with the half the other operand keeps it on. Either
+    may be a traced scalar (a grouped pair's key/value half)."""
+    half = (jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+            >= _HALF).astype(jnp.int32)
+    x = jnp.where(half == src, x, jnp.zeros_like(x))
+    if isinstance(src, int) and isinstance(dst, int) and src == dst:
+        return x
+    # Mosaic rotates 32-bit lanes only; once a cell, not once a tile
+    swapped = pltpu.roll(x.astype(jnp.float32), _HALF, x.ndim - 1
+                         ).astype(x.dtype)
+    if isinstance(src, int) and isinstance(dst, int):
+        return swapped
+    return jnp.where(src == dst, x, swapped)
+
+
 class _Tiling(NamedTuple):
     """How one kernel cuts the (q, k) plane: the ``(block_q, block_k)``
     compute tile, and ``major``, the rows of the streamed side each grid
@@ -204,7 +328,8 @@ class _Schedule(NamedTuple):
 
 def select_attention_blocks(t_q: int, t_kv: int, d: int, dtype,
                             causal: bool = False, has_mask: bool = False,
-                            budget_bytes: Optional[int] = None):
+                            budget_bytes: Optional[int] = None,
+                            heads_per_cell: int = 1):
     """VMEM-budget-aware (block_q, block_k): start from the swept
     ``_PREFERRED_BLOCKS``, clamp to the sequence lengths, then shrink the
     larger block until the largest of the three kernels' estimated
@@ -223,7 +348,8 @@ def select_attention_blocks(t_q: int, t_kv: int, d: int, dtype,
     # every shrink step rounds DOWN to the tile floor — halving an
     # already-clamped odd block (bq 56 -> 28, or 200 -> 100) would hand
     # Mosaic an untileable pair on the default path every caller hits
-    while (_kernel_vmem_bytes(bq, bk, d, itemsize, has_mask) > budget
+    while (_kernel_vmem_bytes(bq, bk, d, itemsize, has_mask,
+                              heads=heads_per_cell) > budget
            and (bq > _SUBLANES or bk > _LANES)):
         if bk >= 2 * bq and bk > _LANES:
             bk = max(_LANES, bk // 2 // _LANES * _LANES)
@@ -269,11 +395,11 @@ def _time_blocks(b, h, t_q, t_kv, d, dtype, causal, has_mask, block_q,
     import numpy as np
     rng = np.random.default_rng(0)
     q = jax.device_put(jnp.asarray(
-        rng.normal(size=(b, h, t_q, d)).astype(np.float32), dtype))
+        rng.normal(size=(b, t_q, h, d)).astype(np.float32), dtype))
     k = jax.device_put(jnp.asarray(
-        rng.normal(size=(b, h, t_kv, d)).astype(np.float32), dtype))
+        rng.normal(size=(b, t_kv, h, d)).astype(np.float32), dtype))
     v = jax.device_put(jnp.asarray(
-        rng.normal(size=(b, h, t_kv, d)).astype(np.float32), dtype))
+        rng.normal(size=(b, t_kv, h, d)).astype(np.float32), dtype))
     m = (jax.device_put(jnp.ones((b, t_kv), jnp.float32))
          if has_mask else None)
     sched = _resolve_schedule(t_q, t_kv, d, dtype, has_mask, block_q,
@@ -315,7 +441,8 @@ def _choice_label(sched) -> str:
         for name, t in zip(sched._fields, sched))
 
 
-def _record_block_choice(sig: str, sched, census: dict) -> None:
+def _record_block_choice(sig: str, sched, census: dict,
+                         layout: str) -> None:
     try:
         from ...observability import default_registry
         reg = default_registry()
@@ -326,7 +453,8 @@ def _record_block_choice(sig: str, sched, census: dict) -> None:
             "selected pallas kernel block sizes and resident major "
             "windows per abstract signature (1 = active choice)",
             labels={"kernel": "flash_attention", "sig": sig,
-                    "choice": _choice_label(sched)}).set(1)
+                    "choice": f"{_choice_label(sched)},heads={layout}"}
+        ).set(1)
         for kind, n in census.items():
             reg.gauge(  # zoolint: disable=ZL015 bounded label set
                 "zoo_pallas_flash_tiles",
@@ -356,8 +484,10 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     shape, since wall time does scale with B·H. A window and grouped
     heads change the census and the label, not the tile: they join the
     key and the label only where a call has them."""
-    b, h, t_q, d = q_shape
+    b, t_q, h, d = q_shape
     dt = jnp.dtype(dtype)
+    heads = _head_layout(h, h // group, d)
+    layout, per = heads.layout, heads.per_cell
     from ...common.context import get_zoo_context
     sweep = (bool(get_zoo_context().get("zoo.pallas.block_sweep", False))
              and not interpret and jax.default_backend() == "tpu")
@@ -365,7 +495,7 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
     # with zoo.pallas.vmem_budget_mb must take effect at the next call,
     # not silently keep blocks sized for the old budget
     budget = vmem_usable_bytes() * attention_budget_scale(d)
-    base = (t_q, t_kv, d, dt.name, causal, has_mask)
+    base = (t_q, t_kv, d, dt.name, causal, has_mask, layout)
     if window is not None or group != 1:
         base += (window, group)
     sig = (budget, "sweep", b, h) + base if sweep else (budget,) + base
@@ -374,11 +504,12 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
         return cached
     choice = select_attention_blocks(t_q, t_kv, d, dt, causal=causal,
                                      has_mask=has_mask,
-                                     budget_bytes=budget)
+                                     budget_bytes=budget, heads_per_cell=per)
     if sweep:
         choice = _sweep_blocks(b, h, t_q, t_kv, d, dt, causal, has_mask,
                                choice)
-    sched = _resolve_schedule(t_q, t_kv, d, dt, has_mask, *choice)
+    sched = _resolve_schedule(t_q, t_kv, d, dt, has_mask, *choice,
+                              heads_per_cell=per)
     _BLOCK_CACHE[sig] = sched
     # the metric label mirrors the cache key: heuristic entries apply to
     # EVERY batch/head shape at this (T, D, dtype) signature, so baking
@@ -389,13 +520,15 @@ def _auto_blocks(q_shape, t_kv: int, dtype, causal: bool, has_mask: bool,
         f"{'c' if causal else ''}{'m' if has_mask else ''}"
         + (f"w{window}" if window is not None else "")
         + (f"g{group}" if group != 1 else ""), sched,
-        _tile_census(t_q, t_kv, sched.fwd, causal, has_mask, window))
+        _tile_census(t_q, t_kv, sched.fwd, causal, has_mask, window),
+        layout)
     return sched
 
 
 def _resolve_schedule(t_q: int, t_kv: int, d: int, dtype, has_mask: bool,
                       block_q: int, block_k: int,
-                      budget_bytes: Optional[int] = None) -> _Schedule:
+                      budget_bytes: Optional[int] = None,
+                      heads_per_cell: int = 1) -> _Schedule:
     """The three kernels' tilings from one ``(block_q, block_k)``: dq and
     dkv take it, the forward takes ``_FWD_K_TILES`` k tiles as one. Each is
     clamped to the sequence (back ON the tile floors: a raw min() against
@@ -405,7 +538,11 @@ def _resolve_schedule(t_q: int, t_kv: int, d: int, dtype, has_mask: bool,
     (``select_attention_blocks`` fitted the tile into the usable half of
     it): the whole sequence where that fits, else the sequence cut into
     the fewest equal runs of tiles that do. Pure in its arguments and the
-    context's budget."""
+    context's budget. (A window call's dkv brings a whole q/dO window in
+    at every grid step for three tiles' work; cutting that window to the
+    rows a k block can see was tried and lost: 14.97 against 12.34 ms a
+    call at window 1024 of 8192, four times the grid steps; chip run,
+    PR 37.)"""
     budget = budget_bytes if budget_bytes is not None else \
         vmem_budget_bytes() * attention_budget_scale(d)
     itemsize = jnp.dtype(dtype).itemsize
@@ -421,8 +558,8 @@ def _resolve_schedule(t_q: int, t_kv: int, d: int, dtype, has_mask: bool,
         for parts in range(1, n_tiles + 1):
             tiles = -(-n_tiles // parts)
             if _kernel_vmem_bytes(bq, wide, d, itemsize, has_mask,
-                                  major=tiles * blk,
-                                  kernel=kernel) <= budget:
+                                  major=tiles * blk, kernel=kernel,
+                                  heads=heads_per_cell) <= budget:
                 break
         return _Tiling(bq, wide, tiles * blk)
 
@@ -648,14 +785,19 @@ def _dot(a, b, dims):
 
 def _fwd_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
                 n_major: int, t_q: int, t_kv: int, causal: bool,
-                has_mask: bool, want_lse: bool,
+                has_mask: bool, want_lse: bool, heads: _Heads,
                 window: Optional[int] = None):
-    """Grid cell (bh, qi, kj): one q block against major window ``kj`` of
+    """Grid cell (c, qi, kj): one q block against major window ``kj`` of
     K/V, walked tile by tile up to the causal diagonal (from the window's
-    trailing edge on, where there is a window). q (1, block_q, D);
-    k/v (1, major_k, D); [mask (1, tiles, 1, block_k)]; o (1, block_q, D);
-    lse (1, 1, 1, block_q); scratch acc (block_q, D), m/l (block_q, LANES)
-    carry the online softmax across tiles and major windows."""
+    trailing edge on, where there is a window). q (1, block_q, W);
+    k/v (1, major_k, W); [mask (1, tiles, 1, block_k)]; o (1, block_q, W);
+    lse (P, 1, 1, block_q); scratch acc (P, block_q, W), m/l
+    (P, block_q, LANES) carry the online softmax across tiles and major
+    windows. ``c`` counts heads (P = 1, W = D) or, in the pair layout,
+    pairs of them (P = 2, W = 128: head ``hh``'s lanes of the resident q
+    block stand alone on its key head's half, so a contraction over all
+    128 lanes is that head's score tile, and ``p @ v`` is its output on
+    that half and another head's on the other)."""
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
@@ -676,64 +818,84 @@ def _fwd_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0]
-    if fold_scale:      # a power of two: exact in any float dtype
-        q = q * scale
+    def head(hh: int, kh) -> None:
+        q = q_ref[0]
+        if kh is not None:
+            q = _onto(q, hh, kh)
+        if fold_scale:      # a power of two: exact in any float dtype
+            q = q * scale
 
-    def tile(j, masked: bool):
-        s = _dot(q, _rows(k_ref, j, block_k), _NT)
-        if not fold_scale:
-            s = s * scale
-        m_prev = m_ref[:, :1]
-        if masked:
-            ok = _visibility(
-                qi, base + j, s.shape, block_q=block_q, block_k=block_k,
-                t_kv=t_kv, offset=offset, causal=causal, window=window,
-                mask_row=mask_ref[0, j] if has_mask else None)
-            s = jnp.where(ok, s, -jnp.inf)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            # a row with no visible key yet keeps m = -inf; exp(-inf - 0)
-            # is the 0 its p and its correction need
-            m_use = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        else:
-            # every score is a visible one: m_new is finite, nothing to
-            # guard
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            m_use = m_new
-        p = jnp.exp(s - m_use)
-        corr = jnp.exp(m_prev - m_use)
-        l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=-1,
-                                                     keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + _dot(
-            p.astype(v_ref.dtype), _rows(v_ref, j, block_k), _NN)
-        m_ref[:, :1] = m_new
+        def tile(j, masked: bool):
+            s = _dot(q, _rows(k_ref, j, block_k), _NT)
+            if not fold_scale:
+                s = s * scale
+            m_prev = m_ref[hh, :, :1]
+            if masked:
+                ok = _visibility(
+                    qi, base + j, s.shape, block_q=block_q, block_k=block_k,
+                    t_kv=t_kv, offset=offset, causal=causal, window=window,
+                    mask_row=mask_ref[0, j] if has_mask else None)
+                s = jnp.where(ok, s, -jnp.inf)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                # a row with no visible key yet keeps m = -inf;
+                # exp(-inf - 0) is the 0 its p and its correction need
+                m_use = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+            else:
+                # every score is a visible one: m_new is finite, nothing
+                # to guard
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                m_use = m_new
+            p = jnp.exp(s - m_use)
+            corr = jnp.exp(m_prev - m_use)
+            l_ref[hh, :, :1] = l_ref[hh, :, :1] * corr + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[hh] = acc_ref[hh] * corr + _dot(
+                p.astype(v_ref.dtype), _rows(v_ref, j, block_k), _NN)
+            m_ref[hh, :, :1] = m_new
 
-    _walk_k_tiles(tile, qi, base, tiles, block_q=block_q, block_k=block_k,
-                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask,
-                  window=window)
+        _walk_k_tiles(tile, qi, base, tiles, block_q=block_q,
+                      block_k=block_k, t_q=t_q, t_kv=t_kv, causal=causal,
+                      has_mask=has_mask, window=window)
+
+    halves = [heads.kv_half(hh, pl.program_id(0))
+              for hh in range(heads.per_cell)]
+    for hh, kh in enumerate(halves):
+        head(hh, kh)
 
     @pl.when(kj == n_major - 1)
     def _finish():
-        l = l_ref[:, :1]
-        m = m_ref[:, :1]
-        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
-                    ).astype(o_ref.dtype)
-        if want_lse:
-            # rows with no visible key: +inf sentinel makes every backward
-            # probability exp(s - inf) = 0, matching the zero forward output
-            _store_as_row(lse_ref.at[0, 0], jnp.where(
-                l == 0.0, jnp.inf, m + jnp.log(jnp.where(l == 0.0, 1.0, l))))
+        out = None
+        for hh, kh in enumerate(halves):
+            l = l_ref[hh, :, :1]
+            m = m_ref[hh, :, :1]
+            o = acc_ref[hh] / jnp.where(l == 0.0, 1.0, l)
+            if kh is not None:      # its own half, the other's lanes zero
+                o = _onto(o, kh, hh)
+            out = o if out is None else out + o
+            if want_lse:
+                # rows with no visible key: +inf sentinel makes every
+                # backward probability exp(s - inf) = 0, matching the zero
+                # forward output
+                _store_as_row(lse_ref.at[hh, 0], jnp.where(
+                    l == 0.0, jnp.inf,
+                    m + jnp.log(jnp.where(l == 0.0, 1.0, l))))
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _q_cell_specs(tiling: _Tiling, n_major: int, h: int, d: int,
-                  group: int = 1, window: Optional[int] = None, **geom):
-    """BlockSpecs of a fwd/dq cell (bh, qi, kj): the q block, the K/V major
-    window, and the mask rows of that window. Window ``kj`` is clamped to
-    the ones the q block needs, so a causal step right of the diagonal (or
-    one behind the attention window) repeats a block index and costs no
-    DMA. With grouped heads (``group`` query heads to a key/value head)
-    K/V are indexed by ``bh // group``: nothing is repeated in HBM, and
-    the query heads of a group find their K/V block resident."""
+def _q_cell_specs(tiling: _Tiling, n_major: int, heads: _Heads,
+                  window: Optional[int] = None, **geom):
+    """BlockSpecs of a fwd/dq cell (c, qi, kj): the q block, the K/V major
+    window, the mask rows of that window and a q tile's row statistics.
+    Window ``kj`` is clamped to the ones the q block needs, so a causal
+    step right of the diagonal (or one behind the attention window)
+    repeats a block index and costs no DMA. A head (a pair of them) is a
+    lane block of its batch row's ``(T, H*D)`` plane, or a row of its own
+    in the split layout (``_Heads.row_and_block``). With grouped heads
+    (``group`` query cells to a key/value cell) K/V are indexed by
+    ``cell // group``: nothing is repeated in HBM, and the query heads of
+    a group find their K/V block resident."""
     block_q, block_k, major = tiling
     tiles = major // block_k
 
@@ -749,20 +911,39 @@ def _q_cell_specs(tiling: _Tiling, n_major: int, h: int, d: int,
             mn=jnp.minimum, mx=jnp.maximum, **geom)
         return jnp.clip(kj, lo // tiles, jnp.maximum(hi - 1, 0) // tiles)
 
-    def kv_head(bh):
-        return bh if group == 1 else bh // group
+    def q_at(c, qi, kj):
+        row, blk = heads.row_and_block(c, heads.cells)
+        return row, qi, blk
 
-    return (pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, major, d), lambda bh, qi, kj: (
-                kv_head(bh), major_window(qi, kj), 0)),
-            pl.BlockSpec((1, tiles, 1, block_k), lambda bh, qi, kj: (
-                bh // h, major_window(qi, kj), 0, 0)))
+    def kv_at(c, qi, kj):
+        # c // group is the key/value cell over the whole batch as well as
+        # inside a batch row (cells = group x kv_cells)
+        row, blk = heads.row_and_block(c // heads.group, heads.kv_cells)
+        return row, major_window(qi, kj), blk
+
+    return (pl.BlockSpec((1, block_q, heads.width), q_at),
+            pl.BlockSpec((1, major, heads.width), kv_at),
+            pl.BlockSpec((1, tiles, 1, block_k), lambda c, qi, kj: (
+                c // heads.cells, major_window(qi, kj), 0, 0)),
+            pl.BlockSpec((heads.per_cell, 1, 1, block_q),
+                         lambda c, qi, kj: (c, qi, 0, 0)))
 
 
-def _rows_padded(x, mult: int):
-    """(B, H, T, D) as (B*H, T rounded up to ``mult``, D)."""
-    b, h, t, d = x.shape
-    return pad_to_multiple(x.reshape(b * h, t, d), 1, mult)
+def _rows_padded(x, mult: int, heads: _Heads):
+    """(B, T, H, D) as the kernels take it, T rounded up to ``mult``:
+    ``(B, T, H*D)``, a free reshape, where they find a head in place;
+    ``(B*H, T, D)`` through a transpose in the split layout."""
+    b, t, h, d = x.shape
+    x = (x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+         if heads.layout == "split" else x.reshape(b, t, h * d))
+    return pad_to_multiple(x, 1, mult)
+
+
+def _heads_back(x, heads: _Heads, b: int, h: int, t: int, d: int):
+    """A kernel's output as (B, T, H, D), the padding cut off."""
+    if heads.layout == "split":
+        return x[:, :t, :].reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return x[:, :t, :].reshape(b, t, h, d)
 
 
 def _mask_rows(mask, tiling: _Tiling):
@@ -780,14 +961,15 @@ def _stat_rows(x, t_pad: int, block_q: int):
 
 
 def _compiler_params(kernel: str, tiling: _Tiling, d: int, itemsize: int,
-                     has_mask: bool):
+                     has_mask: bool, heads_per_cell: int = 1):
     """Mosaic's scoped-VMEM default holds every schedule the selector
     makes at the default budget (it fits them into half of it); a call
     whose estimate is over that half (a raised ``zoo.pallas.
     vmem_budget_mb``, or floor tiles that already outgrow it) asks for
     its own limit."""
     est = _kernel_vmem_bytes(tiling.block_q, tiling.block_k, d, itemsize,
-                             has_mask, major=tiling.major, kernel=kernel)
+                             has_mask, major=tiling.major, kernel=kernel,
+                             heads=heads_per_cell)
     if 2 * est <= _VMEM_BYTES_DEFAULT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=2 * est)
@@ -803,13 +985,13 @@ def _compiler_params(kernel: str, tiling: _Tiling, d: int, itemsize: int,
 def _flash_fwd(q, k, v, mask, causal: bool, sched: _Schedule,
                interpret: bool, want_lse: bool,
                window: Optional[int] = None):
-    b, h, t_q, d = q.shape
-    t_kv = k.shape[2]
-    group = h // k.shape[1]
+    b, t_q, h, d = q.shape
+    t_kv = k.shape[1]
+    heads = _head_layout(h, k.shape[2], d)
     scale = 1.0 / float(d) ** 0.5
     block_q, block_k, major = tiling = sched.fwd
-    qr = _rows_padded(q, block_q)
-    kr, vr = _rows_padded(k, major), _rows_padded(v, major)
+    qr = _rows_padded(q, block_q, heads)
+    kr, vr = (_rows_padded(a, major, heads) for a in (k, v))
     n_q = qr.shape[1] // block_q
     n_major = kr.shape[1] // major
     has_mask = mask is not None
@@ -817,10 +999,10 @@ def _flash_fwd(q, k, v, mask, causal: bool, sched: _Schedule,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, fold_scale=_is_pow2(scale), tiling=tiling,
         n_major=n_major, t_q=t_q, t_kv=t_kv, causal=causal,
-        has_mask=has_mask, want_lse=want_lse, window=window)
-    qspec, kspec, mspec = _q_cell_specs(
-        tiling, n_major, h, d, group=group, window=window, t_q=t_q,
-        t_kv=t_kv, causal=causal, has_mask=has_mask)
+        has_mask=has_mask, want_lse=want_lse, heads=heads, window=window)
+    qspec, kspec, mspec, rowspec = _q_cell_specs(
+        tiling, n_major, heads, window=window, t_q=t_q, t_kv=t_kv,
+        causal=causal, has_mask=has_mask)
     in_specs = [qspec, kspec, kspec]
     operands = [qr, kr, vr]
     if has_mask:
@@ -832,27 +1014,27 @@ def _flash_fwd(q, k, v, mask, causal: bool, sched: _Schedule,
         # inference/primal calls skip the lse output entirely — pallas
         # outputs are opaque to XLA DCE, so an unconditional write would
         # cost real HBM traffic on every no-grad forward
-        out_specs.append(pl.BlockSpec((1, 1, 1, block_q),
-                                      lambda bh, qi, kj: (bh, qi, 0, 0)))
+        out_specs.append(rowspec)
         out_shape.append(jax.ShapeDtypeStruct(
-            (qr.shape[0], n_q, 1, block_q), jnp.float32))
+            (b * h, n_q, 1, block_q), jnp.float32))
+    per = heads.per_cell
     res = pl.pallas_call(
         kernel,
-        grid=(b * h, n_q, n_major),
+        grid=(b * heads.cells, n_q, n_major),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),       # acc
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
+            pltpu.VMEM((per, block_q, heads.width), jnp.float32),  # acc
+            pltpu.VMEM((per, block_q, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((per, block_q, _LANES), jnp.float32),  # and denom
         ],
         compiler_params=_compiler_params("fwd", tiling, d, q.dtype.itemsize,
-                                         has_mask),
+                                         has_mask, per),
         interpret=interpret,
         name="zoo_flash_fwd" + _name_suffix(window),
     )(*operands)
-    o = res[0][:, :t_q, :].reshape(b, h, t_q, d)
+    o = _heads_back(res[0], heads, b, h, t_q, d)
     if not want_lse:
         return o
     # the residual is one float a row, whatever the tile: (B*H, 1, T)
@@ -875,17 +1057,25 @@ def _name_suffix(window: Optional[int]) -> str:
 
 def _bwd_dq_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
                    n_major: int, t_q: int, t_kv: int, causal: bool,
-                   has_mask: bool, window: Optional[int] = None):
-    """Grid (bh, qi, kj), the forward's schedule: dq of one q block
-    accumulates over the k tiles of each major window. lse/delta arrive
-    (1, 1, 1, block_q), one float a row along lanes, and are stood up into
-    sublanes once a q block for the (q, k) tile."""
+                   has_mask: bool, heads: _Heads,
+                   window: Optional[int] = None):
+    """Grid (c, qi, kj), the forward's schedule: dq of one q block
+    accumulates over the k tiles of each major window. lse arrives
+    (P, 1, 1, block_q), one float a row along lanes, and is stood up into
+    sublanes once a q block for the (q, k) tile. ``delta = rowsum(dO * O)``
+    is taken HERE, once a q block, from the dO block the cell holds and the
+    O block beside it: it comes out one float a sublane, as the tile wants
+    it, and goes out (P, 1, 1, block_q) for the dkv kernel (in XLA the
+    same sum over a head's lanes of a ``(B, T, H*D)`` array costs a change
+    of layout of the whole product). In the pair layout head ``hh``'s
+    lanes of q and of dO stand alone on its key head's half, as in the
+    forward, and ``ds @ k`` is its dq on that half."""
     if has_mask:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, mask_ref, dq_ref,
-         acc_ref, lse_col, dl_col) = refs
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, mask_ref, dq_ref,
+         dl_ref, acc_ref, lse_col, dl_col) = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, acc_ref,
-         lse_col, dl_col) = refs
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, dl_ref,
+         acc_ref, lse_col, dl_col) = refs
         mask_ref = None
     block_q, block_k, major = tiling
     tiles = major // block_k
@@ -893,165 +1083,238 @@ def _bwd_dq_kernel(*refs, scale: float, fold_scale: bool, tiling: _Tiling,
     kj = pl.program_id(2)
     base = kj * tiles
     offset = t_kv - t_q
+    halves = [heads.kv_half(hh, pl.program_id(0))
+              for hh in range(heads.per_cell)]
 
     @pl.when(kj == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        _store_as_col(lse_col, lse_ref.at[0, 0])
-        _store_as_col(dl_col, dl_ref.at[0, 0])
+        prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        for hh in range(heads.per_cell):
+            _store_as_col(lse_col.at[hh], lse_ref.at[hh, 0])
+            delta = jnp.sum(prod if heads.per_cell == 1
+                            else _onto(prod, hh, hh), axis=-1, keepdims=True)
+            dl_col[hh, :, :1] = delta
+            _store_as_row(dl_ref.at[hh, 0], delta)
 
-    q = q_ref[0]
-    if fold_scale:
-        q = q * scale
-    do = do_ref[0]
+    def head(hh: int, kh) -> None:
+        q, do = q_ref[0], do_ref[0]
+        if kh is not None:
+            q, do = _onto(q, hh, kh), _onto(do, hh, kh)
+        if fold_scale:
+            q = q * scale
 
-    def tile(j, masked: bool):
-        k = _rows(k_ref, j, block_k)
-        s = _dot(q, k, _NT)
-        if not fold_scale:
-            s = s * scale
-        p = jnp.exp(s - lse_col[:, :1])
-        if masked:
-            p = jnp.where(_visibility(
-                qi, base + j, s.shape, block_q=block_q, block_k=block_k,
-                t_kv=t_kv, offset=offset, causal=causal, window=window,
-                mask_row=mask_ref[0, j] if has_mask else None), p, 0.0)
-        dp = _dot(do, _rows(v_ref, j, block_k), _NT)
-        ds = p * (dp - dl_col[:, :1])
-        acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
+        def tile(j, masked: bool):
+            k = _rows(k_ref, j, block_k)
+            s = _dot(q, k, _NT)
+            if not fold_scale:
+                s = s * scale
+            p = jnp.exp(s - lse_col[hh, :, :1])
+            if masked:
+                p = jnp.where(_visibility(
+                    qi, base + j, s.shape, block_q=block_q, block_k=block_k,
+                    t_kv=t_kv, offset=offset, causal=causal, window=window,
+                    mask_row=mask_ref[0, j] if has_mask else None), p, 0.0)
+            dp = _dot(do, _rows(v_ref, j, block_k), _NT)
+            ds = p * (dp - dl_col[hh, :, :1])
+            acc_ref[hh] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _walk_k_tiles(tile, qi, base, tiles, block_q=block_q, block_k=block_k,
-                  t_q=t_q, t_kv=t_kv, causal=causal, has_mask=has_mask,
-                  window=window)
+        _walk_k_tiles(tile, qi, base, tiles, block_q=block_q,
+                      block_k=block_k, t_q=t_q, t_kv=t_kv, causal=causal,
+                      has_mask=has_mask, window=window)
+
+    for hh, kh in enumerate(halves):
+        head(hh, kh)
 
     @pl.when(kj == n_major - 1)
     def _finish():
-        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+        dq = None
+        for hh, kh in enumerate(halves):
+            part = acc_ref[hh] * scale
+            if kh is not None:
+                part = _onto(part, kh, hh)
+            dq = part if dq is None else dq + part
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
                     dv_ref, dk_acc, dv_acc, *, scale: float,
                     fold_scale: bool, tiling: _Tiling, n_major: int,
-                    t_q: int, t_kv: int, causal: bool, group: int = 1,
+                    t_q: int, t_kv: int, causal: bool, heads: _Heads,
                     window: Optional[int] = None):
-    """Grid (bh, ki, qj): dk/dv of one k block accumulate over the q tiles
+    """Grid (c, ki, qj): dk/dv of one k block accumulate over the q tiles
     of each major window, from the causal diagonal on (up to the window's
-    trailing edge, where there is a window). With grouped heads ``bh``
-    counts key/value heads and ``qj`` runs over the major windows of each
-    of the ``group`` query heads that share it, one after the other, into
-    the same accumulators. The tile is formed transposed, ``s^T = k q^T``
-    (block_k, block_q): the row statistics (1, block_q) broadcast over its
-    sublanes as they are stored, and ``dV += p^T dO``, ``dK += ds^T q``
-    are plain products."""
+    trailing edge, where there is a window). ``c`` counts key/value cells
+    and ``qj`` runs over the major windows of each of the ``group`` query
+    cells that share it, one after the other, into the same accumulators.
+    The tile is formed transposed, ``s^T = k q^T`` (block_k, block_q): the
+    row statistics (1, block_q) broadcast over its sublanes as they are
+    stored, and ``dV += p^T dO``, ``dK += ds^T q`` are plain products.
+
+    Pair layout: the q/dO window holds two query heads; for head ``hh`` of
+    them the resident k and v blocks keep their key head's lanes alone,
+    moved onto ``hh``'s half, so the contractions over 128 lanes are that
+    head's, and ``p^T dO`` / ``ds^T q`` carry its share of dv / dk on
+    ``hh``'s half of accumulator ``hh``. Without grouped heads half ``hh``
+    IS key head ``hh`` and the two halves are the block. With them all the
+    query heads of a step belong to ONE key head, the first half of the
+    steps to the block's first: at the end of either half of the steps the
+    two accumulators' halves are summed onto that key head's lanes."""
     block_q, block_k, major = tiling
     tiles = major // block_q
+    group, per = heads.group, heads.per_cell
     ki = pl.program_id(1)
     qj = pl.program_id(2)
     base = (qj if group == 1 else qj % n_major) * tiles
     offset = t_kv - t_q
+    # a grouped pair: the key head (half of the k block) this step's two
+    # query heads attend
+    step_half = (2 * (qj // n_major)) // group if per == 2 and group > 1 \
+        else None
 
     @pl.when(qj == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    k = k_ref[0]
-    if fold_scale:
-        k = k * scale
-    v = v_ref[0]
+    def head(hh: int) -> None:
+        k, v = k_ref[0], v_ref[0]
+        if per == 2:
+            src = hh if step_half is None else step_half
+            k, v = _onto(k, src, hh), _onto(v, src, hh)
+        if fold_scale:
+            k = k * scale
 
-    def tile(i, masked: bool):
-        q = _rows(q_ref, i, block_q)
-        do = _rows(do_ref, i, block_q)
-        st = _dot(k, q, _NT)
-        if not fold_scale:
-            st = st * scale
-        pt = jnp.exp(st - lse_ref[0, i])
-        if masked:      # the causal diagonal, on a (k, q) tile
-            q_pos = (base + i) * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, st.shape, 1)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, st.shape, 0)
-            ok = k_pos <= q_pos + offset
-            if window is not None:
-                ok = ok & (k_pos > q_pos + (offset - window))
-            pt = jnp.where(ok, pt, 0.0)
-        dv_acc[:] += _dot(pt.astype(do.dtype), do, _NN)
-        dst = pt * (_dot(v, do, _NT) - dl_ref[0, i])
-        dk_acc[:] += _dot(dst.astype(q.dtype), q, _NN)
+        def tile(i, masked: bool):
+            q = _rows(q_ref, i, block_q)
+            do = _rows(do_ref, i, block_q)
+            st = _dot(k, q, _NT)
+            if not fold_scale:
+                st = st * scale
+            pt = jnp.exp(st - lse_ref[hh, i])
+            if masked:      # the causal diagonal, on a (k, q) tile
+                q_pos = (base + i) * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 1)
+                k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 0)
+                ok = k_pos <= q_pos + offset
+                if window is not None:
+                    ok = ok & (k_pos > q_pos + (offset - window))
+                pt = jnp.where(ok, pt, 0.0)
+            dv_acc[hh] += _dot(pt.astype(do.dtype), do, _NN)
+            dst = pt * (_dot(v, do, _NT) - dl_ref[hh, i])
+            dk_acc[hh] += _dot(dst.astype(q.dtype), q, _NN)
 
-    geom = dict(block_q=block_q, block_k=block_k, t_q=t_q, t_kv=t_kv,
-                causal=causal, mn=jnp.minimum, mx=jnp.maximum)
-    if window is None:
-        lo, full, hi = (jnp.clip(n - base, 0, tiles)
-                        for n in _q_tile_range(ki, **geom))
-        if causal:
-            _tile_loop(lo, full, lambda i: tile(i, True))
-        _tile_loop(full, hi, lambda i: tile(i, False))
+        geom = dict(block_q=block_q, block_k=block_k, t_q=t_q, t_kv=t_kv,
+                    causal=causal, mn=jnp.minimum, mx=jnp.maximum)
+        if window is None:
+            lo, full, hi = (jnp.clip(n - base, 0, tiles)
+                            for n in _q_tile_range(ki, **geom))
+            if causal:
+                _tile_loop(lo, full, lambda i: tile(i, True))
+            _tile_loop(full, hi, lambda i: tile(i, False))
+        else:
+            lo, b, c, hi = (jnp.clip(n - base, 0, tiles) for n in
+                            _q_tile_bands(ki, window=window, **geom))
+            _tile_loop(lo, b, lambda i: tile(i, True))
+            _tile_loop(b, c, lambda i: tile(i, False))
+            _tile_loop(c, hi, lambda i: tile(i, True))
+
+    for hh in range(per):
+        head(hh)
+
+    def own_halves(acc):
+        """Accumulator ``hh``'s half ``hh``, side by side."""
+        return _onto(acc[0], 0, 0) + _onto(acc[1], 1, 1)
+
+    last = group * n_major - 1
+    if per == 1:
+        @pl.when(qj == last)
+        def _finish():
+            dk_ref[0] = (dk_acc[0] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[0].astype(dv_ref.dtype)
+    elif step_half is None:
+        @pl.when(qj == last)
+        def _finish_pair():
+            dk_ref[0] = (own_halves(dk_acc) * scale).astype(dk_ref.dtype)
+            dv_ref[0] = own_halves(dv_acc).astype(dv_ref.dtype)
     else:
-        lo, b, c, hi = (jnp.clip(n - base, 0, tiles)
-                        for n in _q_tile_bands(ki, window=window, **geom))
-        _tile_loop(lo, b, lambda i: tile(i, True))
-        _tile_loop(b, c, lambda i: tile(i, False))
-        _tile_loop(c, hi, lambda i: tile(i, True))
+        def summed(acc):
+            """Both query heads' shares, on both halves."""
+            both = own_halves(acc)
+            return both + pltpu.roll(both, _HALF, 1)
 
-    @pl.when(qj == group * n_major - 1)
-    def _finish():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        @pl.when(qj == last // 2)
+        def _first_key_head():
+            # whole blocks: the second key head's lanes are overwritten
+            # at the last step
+            dk_ref[0] = (summed(dk_acc) * scale).astype(dk_ref.dtype)
+            dv_ref[0] = summed(dv_acc).astype(dv_ref.dtype)
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
+
+        @pl.when(qj == last)
+        def _second_key_head():
+            upper = jax.lax.broadcasted_iota(
+                jnp.int32, dk_ref.shape[1:], 1) >= _HALF
+            dk_ref[0] = jnp.where(
+                upper, (summed(dk_acc) * scale).astype(dk_ref.dtype),
+                dk_ref[0])
+            dv_ref[0] = jnp.where(
+                upper, summed(dv_acc).astype(dv_ref.dtype), dv_ref[0])
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9, 10), inline=True)
 def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
                interpret, window: Optional[int] = None):
-    b, h, t_q, d = q.shape
-    t_kv = k.shape[2]
-    h_kv = k.shape[1]
-    group = h // h_kv
+    b, t_q, h, d = q.shape
+    t_kv, h_kv = k.shape[1], k.shape[2]
+    heads = _head_layout(h, h_kv, d)
+    group, per = heads.group, heads.per_cell
     scale = 1.0 / float(d) ** 0.5
     fold = _is_pow2(scale)
     has_mask = mask is not None
     itemsize = q.dtype.itemsize
-    # delta_i = sum_d dO_id * O_id — rowwise, cheap in XLA (no (T,T) tensor),
-    # and like lse one float a row
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(b * h, 1, t_q)
     geom = dict(t_q=t_q, t_kv=t_kv, causal=causal)
 
     block_q, block_k, major = tiling = sched.dq
-    qr, gr = _rows_padded(q, block_q), _rows_padded(g, block_q)
-    kr, vr = _rows_padded(k, major), _rows_padded(v, major)
+    qr, gr, outr = (_rows_padded(a, block_q, heads) for a in (q, g, out))
+    kr, vr = (_rows_padded(a, major, heads) for a in (k, v))
     n_major = kr.shape[1] // major
-    qspec, kspec, mspec = _q_cell_specs(tiling, n_major, h, d, group=group,
-                                        window=window, has_mask=has_mask,
-                                        **geom)
-    rowspec = pl.BlockSpec((1, 1, 1, block_q),
-                           lambda bh, qi, kj: (bh, qi, 0, 0))
-    dq = pl.pallas_call(
+    qspec, kspec, mspec, rowspec = _q_cell_specs(
+        tiling, n_major, heads, window=window, has_mask=has_mask, **geom)
+    n_q = qr.shape[1] // block_q
+    # delta_i = sum_d dO_id * O_id, like lse one float a row, comes out of
+    # the dq kernel beside dq
+    dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, fold_scale=fold,
                           tiling=tiling, n_major=n_major, has_mask=has_mask,
-                          window=window, **geom),
-        grid=(b * h, qr.shape[1] // block_q, n_major),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec]
+                          heads=heads, window=window, **geom),
+        grid=(b * heads.cells, n_q, n_major),
+        in_specs=[qspec, kspec, kspec, qspec, qspec, rowspec]
                  + ([mspec] if has_mask else []),
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32),  # lse
-                        pltpu.VMEM((block_q, _LANES), jnp.float32)],  # delta
+        out_specs=[qspec, rowspec],
+        out_shape=[jax.ShapeDtypeStruct(qr.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b * h, n_q, 1, block_q),
+                                        jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((per, block_q, heads.width), jnp.float32),
+            pltpu.VMEM((per, block_q, _LANES), jnp.float32),  # lse
+            pltpu.VMEM((per, block_q, _LANES), jnp.float32)],  # delta
         compiler_params=_compiler_params("dq", tiling, d, itemsize,
-                                         has_mask),
+                                         has_mask, per),
         interpret=interpret,
         name="zoo_flash_bwd_dq" + _name_suffix(window),
-    )(qr, kr, vr, gr, _stat_rows(lse, qr.shape[1], block_q),
-      _stat_rows(delta, qr.shape[1], block_q),
+    )(qr, kr, vr, gr, outr, _stat_rows(lse, qr.shape[1], block_q),
       *([_mask_rows(mask, tiling)] if has_mask else []))
+    delta = delta.reshape(b * h, 1, -1)[:, :, :t_q]
 
-    # dk/dv grid: (bh, ki, qj) — the q side is the resident major window,
-    # from the first one the k block's causal diagonal reaches
+    # dk/dv grid: (c, ki, qj) — c counts key/value cells, the q side is
+    # the resident major window, from the first one the k block's causal
+    # diagonal reaches
     block_q, block_k, major = tiling = sched.dkv
-    qr, gr = _rows_padded(q, major), _rows_padded(g, major)
-    kr, vr = _rows_padded(k, block_k), _rows_padded(v, block_k)
+    qr, gr = (_rows_padded(a, major, heads) for a in (q, g))
+    kr, vr = (_rows_padded(a, block_k, heads) for a in (k, v))
     tiles = major // block_q
     n_major = qr.shape[1] // major
 
@@ -1065,43 +1328,50 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, sched: _Schedule,
             mn=jnp.minimum, mx=jnp.maximum, **geom)
         return jnp.clip(qj, lo // tiles, jnp.maximum(hi - 1, 0) // tiles)
 
-    def q_head(bh, qj):
-        # bh counts key/value heads; step qj belongs to query head
-        # qj // n_major of its group
-        return bh if group == 1 else bh * group + qj // n_major
+    def q_cell(c, qj):
+        # step qj belongs to query cell qj // n_major of c's group
+        return c if group == 1 else c * group + qj // n_major
 
-    qspec2 = pl.BlockSpec((1, major, d), lambda bh, ki, qj: (
-        q_head(bh, qj), window2(ki, qj), 0))
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda bh, ki, qj: (bh, ki, 0))
-    rowspec2 = pl.BlockSpec((1, tiles, 1, block_q), lambda bh, ki, qj: (
-        q_head(bh, qj), window2(ki, qj), 0, 0))
+    def q_at(c, ki, qj):
+        row, blk = heads.row_and_block(q_cell(c, qj), heads.cells)
+        return row, window2(ki, qj), blk
+
+    def k_at(c, ki, qj):
+        row, blk = heads.row_and_block(c, heads.kv_cells)
+        return row, ki, blk
+
+    qspec2 = pl.BlockSpec((1, major, heads.width), q_at)
+    kspec2 = pl.BlockSpec((1, block_k, heads.width), k_at)
+    rowspec2 = pl.BlockSpec((per, tiles, 1, block_q), lambda c, ki, qj: (
+        q_cell(c, qj), window2(ki, qj), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, fold_scale=fold,
-                          tiling=tiling, n_major=n_major, group=group,
+                          tiling=tiling, n_major=n_major, heads=heads,
                           window=window, **geom),
-        grid=(b * h_kv, kr.shape[1] // block_k, group * n_major),
+        grid=(b * heads.kv_cells, kr.shape[1] // block_k, group * n_major),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
         out_shape=[jax.ShapeDtypeStruct(kr.shape, k.dtype),
                    jax.ShapeDtypeStruct(vr.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_compiler_params("dkv", tiling, d, itemsize, False),
+        scratch_shapes=[pltpu.VMEM((per, block_k, heads.width), jnp.float32),
+                        pltpu.VMEM((per, block_k, heads.width), jnp.float32)],
+        compiler_params=_compiler_params("dkv", tiling, d, itemsize, False,
+                                         per),
         interpret=interpret,
         name="zoo_flash_bwd_dkv" + _name_suffix(window),
     )(qr, kr, vr, gr, _stat_rows(lse, qr.shape[1], block_q),
       _stat_rows(delta, qr.shape[1], block_q))
 
-    dq = dq[:, :t_q, :].reshape(b, h, t_q, d)
-    dk = dk[:, :t_kv, :].reshape(b, h_kv, t_kv, d)
-    dv = dv[:, :t_kv, :].reshape(b, h_kv, t_kv, d)
+    dq = _heads_back(dq, heads, b, h, t_q, d)
+    dk = _heads_back(dk, heads, b, h_kv, t_kv, d)
+    dv = _heads_back(dv, heads, b, h_kv, t_kv, d)
     dmask = None
     if has_mask:
         # a hidden key takes part in no row's softmax, so it only ever
         # touches its own rows of dk/dv: the dkv kernel leaves them
         # unmasked and they are dropped here (a select, not a product —
         # exp(s - lse) of a hidden key is unbounded)
-        keep = (mask >= 1.0)[:, None, :, None]
+        keep = (mask >= 1.0)[:, :, None, None]
         dk = jnp.where(keep, dk, jnp.zeros_like(dk))
         dv = jnp.where(keep, dv, jnp.zeros_like(dv))
         dmask = jnp.zeros_like(mask, dtype=jnp.float32)
@@ -1171,11 +1441,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
-    """Blockwise-softmax attention: q (B, Hq, T, D), k/v (B, Hkv, T, D)
-    with ``Hq % Hkv == 0`` → (B, Hq, Tq, D). Query head ``h`` attends
-    key/value head ``h // (Hq / Hkv)``; K/V are indexed so by the block
-    specs (nothing is repeated in HBM) and dk/dv accumulate over the query
-    heads of a group inside the dkv kernel.
+    """Blockwise-softmax attention: q (B, Tq, Hq, D), k/v (B, Tk, Hkv, D)
+    with ``Hq % Hkv == 0`` → (B, Tq, Hq, D): the layout a projection's
+    output reshapes to for nothing. The kernels find a head in place where
+    its width allows (D a multiple of 128: a lane block of the ``(T, H*D)``
+    plane; D = 64 with even head counts: two heads a block) and the
+    wrapper splits the heads out where it does not (the module docstring's
+    "Heads in place"); the choice is made from the head width and counts
+    alone. Query head ``h`` attends key/value head ``h // (Hq / Hkv)``;
+    K/V are indexed so by the block specs (nothing is repeated in HBM) and
+    dk/dv accumulate over the query heads of a group inside the dkv kernel.
 
     ``window`` (causal calls): a query sees the ``window`` latest keys up
     to and with its own position, ``i - window < j <= i``. The k-tile loop
@@ -1186,7 +1461,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``mask``: optional per-batch key-padding keep-mask, (B, Tk), a BINARY
     contract: values >= 1.0 attend, anything below is hidden — matching the
     XLA oracle's additive ``-1e9*(1-mask)`` on stray soft values (the BERT
-    ``attention_mask``; full (B, H, Tq, Tk) masks stay on the XLA op). Numerically equivalent to
+    ``attention_mask``; full (B, H, Tq, Tk) masks stay on the XLA op).
+    Numerically equivalent to
     ``ops.attention.dot_product_attention`` (minus dropout — that path
     stays on the XLA op). Forward and backward are both Pallas kernels with
     O(block²) memory; gradients flow to q/k/v (the mask gets zeros).
@@ -1217,27 +1493,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              f"at the layer level")
         mask = jax.lax.stop_gradient(mask.astype(jnp.float32))
     has_mask = mask is not None
-    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
-        raise ValueError(f"flash_attention: {q.shape[1]} query heads do not "
-                         f"divide over {k.shape[1]} key / {v.shape[1]} "
+    if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"flash_attention: {q.shape[2]} query heads do not "
+                         f"divide over {k.shape[2]} key / {v.shape[2]} "
                          f"value heads")
-    group = q.shape[1] // k.shape[1]
+    group = q.shape[2] // k.shape[2]
     if window is not None:
         if not causal or window < 1:
             raise ValueError("flash_attention: window needs causal=True "
                              "and window >= 1")
-        if window >= k.shape[2]:
+        if window >= k.shape[1]:
             window = None
     if block_q is None and block_k is None:
-        sched = _auto_blocks(q.shape, k.shape[2], q.dtype, causal, has_mask,
+        sched = _auto_blocks(q.shape, k.shape[1], q.dtype, causal, has_mask,
                              interpret, window, group)
     else:
         if block_q is None or block_k is None:
-            auto = _auto_blocks(q.shape, k.shape[2], q.dtype, causal,
+            auto = _auto_blocks(q.shape, k.shape[1], q.dtype, causal,
                                 has_mask, interpret, window, group)
             block_q = block_q if block_q is not None else auto.dq.block_q
             block_k = block_k if block_k is not None else auto.dq.block_k
-        sched = _resolve_schedule(q.shape[2], k.shape[2], q.shape[3],
-                                  q.dtype, has_mask, block_q, block_k)
+        sched = _resolve_schedule(
+            q.shape[1], k.shape[1], q.shape[3], q.dtype, has_mask, block_q,
+            block_k, heads_per_cell=_head_layout(
+                q.shape[2], k.shape[2], q.shape[3]).per_cell)
     return _flash_per_data_shard(q, k, v, mask, causal, sched, interpret,
                                  window)

@@ -37,8 +37,11 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from analytics_zoo_tpu.ops.attention import (apply_rotary,
+                                             apply_rotary_in_place,
+                                             rotary_tables_in_place,
                                              dot_product_attention,
                                              merge_heads, rotary_inv_freq,
                                              rotary_tables, split_heads)
@@ -267,10 +270,12 @@ class MultiHeadSelfAttention(Layer):
         r1 = r2 = None
         if rng is not None:
             r1, r2 = jax.random.split(rng)
-        qh, kh, vh = (split_heads(a, self.n_head) for a in (q, k, v))
         drop = self.attn_drop if training else 0.0
-        ring_mesh = self._ring_mesh(mask, drop, (qh.shape[0], qh.shape[2]),
-                                    rng=r1)
+        ring_mesh = self._ring_mesh(mask, drop, q.shape[:2], rng=r1)
+        # the flash entry finds a head where the projection wrote it; the
+        # ring and the XLA op take the heads split out
+        flash = ring_mesh is None and self._use_flash(mask, drop, q.shape[1])
+        qh, kh, vh = (_heads(a, self.n_head, flash) for a in (q, k, v))
         if ring_mesh is not None:
             from .....parallel import mesh as mesh_lib
             from .....parallel.ring_attention import (ring_self_attention,
@@ -285,7 +290,7 @@ class MultiHeadSelfAttention(Layer):
             out = route(qh, kh, vh, mesh=ring_mesh, causal=self.causal,
                         mask=kv_mask, dropout_rate=drop,
                         dropout_rng=r1 if drop > 0.0 else None)
-        elif self._use_flash(mask, drop, qh.shape[2]):
+        elif flash:
             from .....ops.pallas import flash_attention
             out = flash_attention(qh, kh, vh, mask=self._kv_mask(mask),
                                   causal=self.causal)
@@ -293,7 +298,7 @@ class MultiHeadSelfAttention(Layer):
             out = dot_product_attention(qh, kh, vh, mask=mask,
                                         causal=self.causal,
                                         dropout_rate=drop, dropout_rng=r1)
-        out = _dense(params["proj"], merge_heads(out), cd)
+        out = _dense(params["proj"], _merged(out, flash), cd)
         return _dropout(out, self.out_drop, r2, training)
 
 
@@ -514,6 +519,21 @@ def _project(w, x, cd):
                       preferred_element_type=jnp.float32).astype(cd)
 
 
+def _heads(x, n_head: int, in_place: bool):
+    """(B, T, n_head * d) as the attention op of the branch takes it: the
+    flash entry's (B, T, n_head, d), a reshape that moves nothing, or
+    ``split_heads``' (B, n_head, T, d) for the XLA op."""
+    if not in_place:
+        return split_heads(x, n_head)
+    return x.reshape(x.shape[:2] + (n_head, x.shape[2] // n_head))
+
+
+def _merged(out, in_place: bool):
+    """The attention op's output as (B, T, n_head * d)."""
+    return out.reshape(out.shape[:2] + (-1,)) if in_place \
+        else merge_heads(out)
+
+
 class DecoderAttention(MultiHeadSelfAttention):
     """Causal self-attention of a pre-norm decoder: ``n_head`` query heads
     and ``n_kv_head`` key/value heads of ``head_dim`` (query head ``h``
@@ -565,10 +585,25 @@ class DecoderAttention(MultiHeadSelfAttention):
     def param_sharding(self, params):
         return jax.tree.map(lambda _: None, params)
 
+    def _rotates_in_place(self, t: int) -> bool:
+        """Whether a call at ``t`` positions rotates q and k as
+        (B, T, heads * head_dim): on the flash branch, whose entry finds a
+        head where the projection wrote it, unless a q/k norm needs a
+        head's columns as an axis of their own."""
+        return self.qk_norm is None and self._use_flash(None, 0.0, t)
+
     def tables(self, t: int):
-        """float32 (cos, sin) of positions 0..t-1 for this layer's kind."""
+        """float32 (cos, sin) of positions 0..t-1 for this layer's kind,
+        each (t, head_dim); where the call rotates in place
+        (``_rotates_in_place``), spread over the query heads' lanes
+        (``rotary_tables_in_place``: (t, n_head * head_dim), the sine
+        signed)."""
         with jax.named_scope("zoo_attn.rope"):
-            return rotary_tables(self.inv_freq, self.rotary_scale, t)
+            cos, sin = rotary_tables(self.inv_freq, self.rotary_scale, t)
+            if self._rotates_in_place(t):
+                return rotary_tables_in_place(cos, sin, self.n_head,
+                                              self.head_dim)
+            return cos, sin
 
     def call(self, params, x, *, training=False, rng=None):
         tables = None
@@ -576,23 +611,32 @@ class DecoderAttention(MultiHeadSelfAttention):
             x, tables = x
         cd = compute_dtype()
         t = x.shape[1]
-        q = split_heads(_project(params["Wq"], x, cd), self.n_head)
-        k = split_heads(_project(params["Wk"], x, cd), self.n_kv_head)
-        v = split_heads(_project(params["Wv"], x, cd), self.n_kv_head)
-        if self.qk_norm is not None:
-            with jax.named_scope("zoo_attn.qk_norm"):
-                q = self.qk_norm.call(params["q_norm"], q)
-                k = self.qk_norm.call(params["k_norm"], k)
+        flash = self._use_flash(None, 0.0, t)
+        in_place = self._rotates_in_place(t)
+        q, k, v = (_project(params[w], x, cd) for w in ("Wq", "Wk", "Wv"))
         cos, sin = tables if tables is not None else self.tables(t)
-        with jax.named_scope("zoo_attn.rope"):
-            q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-        if self._use_flash(None, 0.0, t):
+        if in_place:
+            d, kv = self.head_dim, k.shape[-1]
+            with jax.named_scope("zoo_attn.rope"):
+                q = apply_rotary_in_place(q, cos, sin, d, d)
+                k = apply_rotary_in_place(k, cos[:, :kv], sin[:, :kv], d, d)
+        q = _heads(q, self.n_head, flash)
+        k, v = (_heads(a, self.n_kv_head, flash) for a in (k, v))
+        if not in_place:
+            if self.qk_norm is not None:
+                with jax.named_scope("zoo_attn.qk_norm"):
+                    q = self.qk_norm.call(params["q_norm"], q)
+                    k = self.qk_norm.call(params["k_norm"], k)
+            with jax.named_scope("zoo_attn.rope"):
+                q, k = (apply_rotary(a, cos, sin, heads_first=not flash)
+                        for a in (q, k))
+        if flash:
             from .....ops.pallas import flash_attention
             out = flash_attention(q, k, v, causal=True, window=self.window)
         else:
             out = dot_product_attention(q, k, v, causal=True,
                                         window=self.window)
-        return _project(params["Wo"], merge_heads(out), cd)
+        return _project(params["Wo"], _merged(out, flash), cd)
 
 
 class LatentAttention(MultiHeadSelfAttention):
@@ -657,10 +701,43 @@ class LatentAttention(MultiHeadSelfAttention):
     def param_sharding(self, params):
         return jax.tree.map(lambda _: None, params)
 
+    def _in_place(self, t: int) -> bool:
+        """Whether a call at ``t`` positions takes the flash kernels: one
+        head size a call (the value heads as wide as the key heads), under
+        ``_use_flash``'s rule. It then keeps q, k and v as (B, T, n_head *
+        width) from the projections to the kernels."""
+        return (self.v_dim == self.qk_nope_dim + self.qk_rope_dim
+                and self._use_flash(None, 0.0, t))
+
     def tables(self, t: int):
-        """float32 (cos, sin) of positions 0..t-1, ``qk_rope_dim`` wide."""
+        """float32 (cos, sin) of positions 0..t-1, ``qk_rope_dim`` wide;
+        where the call stays in place (``_in_place``), followed by the pair
+        spread over the query heads' lanes (``rotary_tables_in_place``)."""
         with jax.named_scope("zoo_mla.rope"):
-            return rotary_tables(self.inv_freq, self.rotary_scale, t)
+            cos, sin = rotary_tables(self.inv_freq, self.rotary_scale, t)
+            if self._in_place(t):
+                return (cos, sin) + rotary_tables_in_place(
+                    cos, sin, self.n_head,
+                    self.qk_nope_dim + self.qk_rope_dim, self.qk_nope_dim)
+            return cos, sin
+
+    def _key_value_weights(self, wkvb, cd):
+        """``Wkvb`` as the two matrices that write k and v in place:
+        ``(kv_lora_rank + qk_rope_dim, n_head * qk)``, every head's
+        ``k_nope`` columns of ``Wkvb`` over 0/1 rows that set the one
+        rotated ``k_pe`` behind them (a product with 1 and sums with 0:
+        the concatenation's values exactly, written by the matrix unit
+        where a head belongs), and ``(kv_lora_rank, n_head * v_dim)``, its
+        value columns. Slices of a weight, a thousandth of the activations
+        they save slicing."""
+        n, nope, rope = self.n_head, self.qk_nope_dim, self.qk_rope_dim
+        w = wkvb.astype(cd).reshape(self.kv_lora_rank, n, nope + self.v_dim)
+        w_k = jnp.pad(w[..., :nope], ((0, 0), (0, 0), (0, rope)))
+        place = np.zeros((rope, n, nope + rope), np.float32)
+        place[np.arange(rope), :, nope + np.arange(rope)] = 1.0
+        w_k = jnp.concatenate([w_k, jnp.asarray(place, cd)], axis=0)
+        return (w_k.reshape(w_k.shape[0], -1),
+                w[..., nope:].reshape(self.kv_lora_rank, -1))
 
     def call(self, params, x, *, training=False, rng=None):
         tables = None
@@ -668,6 +745,7 @@ class LatentAttention(MultiHeadSelfAttention):
             x, tables = x
         cd = compute_dtype()
         t, n, nope = x.shape[1], self.n_head, self.qk_nope_dim
+        flash = self._in_place(t)
         with jax.named_scope("zoo_mla.q_latent"):
             c_q = self.q_norm.call(params["q_norm"],
                                    _project(params["Wqa"], x, cd))
@@ -675,11 +753,33 @@ class LatentAttention(MultiHeadSelfAttention):
             kva = _project(params["Wkva"], x, cd)
             c_kv = self.kv_norm.call(params["kv_norm"],
                                      kva[..., :self.kv_lora_rank])
-            k_pe = kva[..., None, :, self.kv_lora_rank:]     # (B, 1, T, r)
+            k_pe = kva[..., self.kv_lora_rank:]              # (B, T, r)
+        cos, sin, *spread = tables if tables is not None else self.tables(t)
+        if flash:
+            # q, k and v stay (B, T, n * width) from the projections to
+            # the kernels: a (B, T, n, width) view is another tiling of
+            # the same bytes on a TPU, and every operation on one costs a
+            # copy of the tensor
+            with jax.named_scope("zoo_mla.rope"):
+                k_pe = apply_rotary(k_pe, cos, sin)
+            with jax.named_scope("zoo_mla.expand"):
+                q = _project(params["Wqb"], c_q, cd)
+                w_k, w_v = self._key_value_weights(params["Wkvb"], cd)
+                k = _project(w_k, jnp.concatenate([c_kv, k_pe], axis=-1), cd)
+                v = _project(w_v, c_kv, cd)
+            with jax.named_scope("zoo_mla.rope"):
+                q = apply_rotary_in_place(q, *spread, nope + self.qk_rope_dim,
+                                          self.qk_rope_dim, nope)
+            with jax.named_scope("zoo_mla.attend"):
+                from .....ops.pallas import flash_attention
+                out = flash_attention(*(_heads(a, n, True)
+                                        for a in (q, k, v)), causal=True)
+            with jax.named_scope("zoo_mla.out"):
+                return _project(params["Wo"], _merged(out, True), cd)
+        k_pe = k_pe[:, None]                                 # (B, 1, T, r)
         with jax.named_scope("zoo_mla.expand"):
             q = split_heads(_project(params["Wqb"], c_q, cd), n)
             kv = split_heads(_project(params["Wkvb"], c_kv, cd), n)
-        cos, sin = tables if tables is not None else self.tables(t)
         with jax.named_scope("zoo_mla.rope"):
             q_pe = apply_rotary(q[..., nope:], cos, sin)
             k_pe = apply_rotary(k_pe, cos, sin)
@@ -691,11 +791,7 @@ class LatentAttention(MultiHeadSelfAttention):
                 axis=-1)
             v = kv[..., nope:]
         with jax.named_scope("zoo_mla.attend"):
-            if v.shape[-1] == q.shape[-1] and self._use_flash(None, 0.0, t):
-                from .....ops.pallas import flash_attention
-                out = flash_attention(q, k, v, causal=True)
-            else:
-                out = dot_product_attention(q, k, v, causal=True)
+            out = dot_product_attention(q, k, v, causal=True)
         with jax.named_scope("zoo_mla.out"):
             return _project(params["Wo"], merge_heads(out), cd)
 

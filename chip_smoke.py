@@ -308,6 +308,15 @@ def train_step_kernels(model, x_batch, y_batch, per_device: bool = False):
     return dict(routed), mosaic_kernel_names(lowered.as_text()), shapes
 
 
+def _flash_heads_first(q, k, v, **kw):
+    """The flash entry on (B, H, T, D) operands, the layout the XLA
+    reference takes: the entry itself wants (B, T, H, D), where a
+    projection's output reshapes to for nothing."""
+    from analytics_zoo_tpu.ops.pallas import flash_attention
+    return flash_attention(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                           **kw).transpose(0, 2, 1, 3)
+
+
 def flash_parity(*, n_head: int, seq_len: int, head_dim: int,
                  require_mosaic: bool = True) -> Dict[str, float]:
     """Flash forward and dq/dk/dv, causal (B=1) and key-padding (B=2, the
@@ -325,7 +334,6 @@ def flash_parity(*, n_head: int, seq_len: int, head_dim: int,
     import jax.numpy as jnp
 
     from analytics_zoo_tpu.ops.attention import dot_product_attention
-    from analytics_zoo_tpu.ops.pallas import flash_attention
 
     errors: Dict[str, float] = {}
     rng = np.random.default_rng(31)
@@ -342,7 +350,7 @@ def flash_parity(*, n_head: int, seq_len: int, head_dim: int,
 
         def kernel(q, k, v):
             def f(q, k, v):
-                o = flash_attention(q, k, v, mask=mask, causal=causal)
+                o = _flash_heads_first(q, k, v, mask=mask, causal=causal)
                 return jnp.sum(o.astype(jnp.float32) * g), o
             (_, o), grads = jax.value_and_grad(f, argnums=(0, 1, 2),
                                                has_aux=True)(q, k, v)
@@ -581,7 +589,6 @@ def grouped_flash_parity(*, n_head: int, n_kv_head: int, seq_len: int,
     import jax.numpy as jnp
 
     from analytics_zoo_tpu.ops.attention import dot_product_attention
-    from analytics_zoo_tpu.ops.pallas import flash_attention
 
     group = n_head // n_kv_head
     errors: Dict[str, float] = {}
@@ -594,7 +601,7 @@ def grouped_flash_parity(*, n_head: int, n_kv_head: int, seq_len: int,
     for tag, win in (("window", window), ("full", None)):
         def kernel(q, k, v, win=win):
             def f(q, k, v):
-                o = flash_attention(q, k, v, causal=True, window=win)
+                o = _flash_heads_first(q, k, v, causal=True, window=win)
                 return jnp.sum(o.astype(jnp.float32) * g), o
             (_, o), grads = jax.value_and_grad(f, argnums=(0, 1, 2),
                                                has_aux=True)(q, k, v)

@@ -151,6 +151,43 @@ def test_yarn_table_against_the_closed_form():
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("heads,width,rope,start", [
+    (4, 16, 16, 0),         # a decoder's heads: every lane rotates
+    (1, 16, 16, 0),
+    (3, 24, 8, 16),         # a latent head: [nope 16 | rope 8]
+    (2, 32, 8, 8),          # rotary lanes in the middle of a head
+], ids=["whole_heads", "one_head", "latent_tail", "middle"])
+def test_rotary_in_place_is_rotary_on_the_heads_view(heads, width, rope,
+                                                     start):
+    """``apply_rotary_in_place`` on (B, T, heads * width) with the spread
+    tables gives ``apply_rotary``'s values on the (B, T, heads, width)
+    view's rotary lanes and leaves the others, bit for bit, and its
+    backward rule is the transpose autodiff finds for ``apply_rotary``."""
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(2, 10, heads * width)), jnp.float32)
+    co = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    inv_freq, scale = attn_ops.rotary_inv_freq(rope, {"rope_theta": 100.0})
+    cos, sin = attn_ops.rotary_tables(inv_freq, scale, 10)
+
+    def on_view(x):
+        v = x.reshape(2, 10, heads, width)
+        turned = attn_ops.apply_rotary(v[..., start:start + rope], cos, sin,
+                                       heads_first=False)
+        return jnp.concatenate([v[..., :start], turned,
+                                v[..., start + rope:]], -1).reshape(x.shape)
+
+    def in_place(x):
+        spread = attn_ops.rotary_tables_in_place(cos, sin, heads, width,
+                                                 start)
+        assert spread[0].shape == (10, heads * width)
+        return attn_ops.apply_rotary_in_place(x, *spread, width, rope, start)
+
+    np.testing.assert_array_equal(in_place(x), on_view(x))
+    got = jax.grad(lambda x: jnp.sum(in_place(x) * co))(x)
+    want = jax.grad(lambda x: jnp.sum(on_view(x) * co))(x)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.parametrize("window", [None, 8])
 def test_decoder_attention_routes_agree(window):
     """Flash forced on (the interpreter) and the XLA op give the same

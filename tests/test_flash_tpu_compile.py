@@ -34,8 +34,8 @@ def one_chip():
 
 def _compile_grads(one_chip, b, h, t_q, t_kv, d, dtype, causal, masked,
                    window=None, group=1):
-    q = jax.ShapeDtypeStruct((b, h, t_q, d), dtype, sharding=one_chip)
-    k = jax.ShapeDtypeStruct((b, h // group, t_kv, d), dtype,
+    q = jax.ShapeDtypeStruct((b, t_q, h, d), dtype, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, t_kv, h // group, d), dtype,
                              sharding=one_chip)
     keep = jax.ShapeDtypeStruct((b, t_kv), jnp.float32, sharding=one_chip)
 
@@ -54,7 +54,8 @@ def _compile_grads(one_chip, b, h, t_q, t_kv, d, dtype, causal, masked,
 
 
 @pytest.mark.parametrize("b,h,t_q,t_kv,d,dtype,causal,masked", [
-    # the benchmark's GPT-1 cell: whole sequence resident, tile (512, 512)
+    # the benchmark's GPT-1 cell: whole sequence resident, tile (512, 512),
+    # two heads a cell (as every D = 64 case below with an even head count)
     (8, 12, 4096, 4096, 64, jnp.bfloat16, True, False),
     # long context: several major windows, clamped causal index maps
     (1, 12, 32768, 32768, 64, jnp.bfloat16, True, False),
@@ -66,7 +67,10 @@ def _compile_grads(one_chip, b, h, t_q, t_kv, d, dtype, causal, masked,
     (2, 4, 2048, 4096, 128, jnp.bfloat16, True, False),
     (2, 4, 4096, 2048, 64, jnp.float32, True, False),
     (1, 2, 8192, 8192, 256, jnp.float32, False, False),
+    # a head width that is no lane tile nor half of one, and an odd count
+    # of half-tile heads: the wrapper splits the heads out
     (1, 2, 40, 40, 8, jnp.float32, True, False),
+    (2, 3, 2048, 2048, 64, jnp.bfloat16, True, False),
 ])
 def test_flash_kernels_compile_for_v5e(one_chip, b, h, t_q, t_kv, d, dtype,
                                        causal, masked):
@@ -89,7 +93,9 @@ def test_flash_kernels_compile_for_v5e(one_chip, b, h, t_q, t_kv, d, dtype,
 def test_grouped_window_flash_compiles_for_v5e(one_chip, window):
     """The two signatures of the decoder configuration's step: 32 query /
     4 key-value heads of 128 at T = 8192, a 1024-key window and full. K/V
-    come in at 4 heads and dk/dv go out at 4: nothing is repeated."""
+    come in at 4 heads and dk/dv go out at 4, side by side in one
+    (T, 4 x 128) plane as the projections wrote them: nothing is repeated
+    and nothing transposed."""
     text = _compile_grads(one_chip, 1, 32, 8192, 8192, 128, jnp.bfloat16,
                           True, False, window=window, group=8).as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
@@ -102,14 +108,15 @@ def test_grouped_window_flash_compiles_for_v5e(one_chip, window):
         if not window:
             assert not any("_win" in n for n in names), names
     dkv = next(ln for ln in calls if "zoo_flash_bwd_dkv" in ln)
-    assert "bf16[4,8192,128]" in dkv.split("custom-call(")[0], dkv[:200]
-    assert "bf16[32,8192,128]" not in dkv.split("custom-call(")[0]
+    assert "bf16[1,8192,512]" in dkv.split("custom-call(")[0], dkv[:200]
+    assert "bf16[1,8192,4096]" not in dkv.split("custom-call(")[0]
 
 
 def test_grouped_flash_at_64_compiles_for_v5e(one_chip):
     """The LFM2 configuration's signature: 32 query / 8 key-value heads of
     64 at T = 8192, no window: GPT-1's head width with grouped heads, a
-    pair no other cell has. dk/dv go out at 8 heads."""
+    pair no other cell has. dk/dv go out at 8 heads, in place as four
+    pairs of them."""
     text = _compile_grads(one_chip, 1, 32, 8192, 8192, 64, jnp.bfloat16,
                           True, False, group=4).as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
@@ -117,8 +124,8 @@ def test_grouped_flash_at_64_compiles_for_v5e(one_chip):
     names = sorted(ln.split("=")[0].strip() for ln in calls)
     assert len(names) == 3 and not any("_win" in n for n in names), names
     dkv = next(ln for ln in calls if "zoo_flash_bwd_dkv" in ln)
-    assert "bf16[8,8192,64]" in dkv.split("custom-call(")[0], dkv[:200]
-    assert "bf16[32,8192,64]" not in dkv.split("custom-call(")[0]
+    assert "bf16[1,8192,512]" in dkv.split("custom-call(")[0], dkv[:200]
+    assert "bf16[1,8192,2048]" not in dkv.split("custom-call(")[0]
 
 
 def test_a_short_conv_block_keeps_its_chain_in_the_compute_dtype(one_chip):
@@ -168,14 +175,14 @@ def test_latent_attention_flash_compiles_for_v5e_with_the_sequence_resident(
     (512, 512) and the whole sequence resident, as at D = 64 and 128; the
     calls ask Mosaic for their own limit and the chip's compiler takes
     them."""
-    sched = fa._auto_blocks((1, 20, 8192, 256), 8192, jnp.bfloat16, True,
+    sched = fa._auto_blocks((1, 8192, 20, 256), 8192, jnp.bfloat16, True,
                             False, False)
     assert fa._choice_label(sched) == (
         "fwd=512x1024/kmajor8192,dq=512x512/kmajor8192,"
         "dkv=512x512/qmajor8192")
     for d in (64, 128):     # the accepted cells' choices stand
         assert fa._choice_label(fa._auto_blocks(
-            (1, 12, 8192, d), 8192, jnp.bfloat16, True, False, False)) == (
+            (1, 8192, 12, d), 8192, jnp.bfloat16, True, False, False)) == (
             "fwd=512x1024/kmajor8192,dq=512x512/kmajor8192,"
             "dkv=512x512/qmajor8192")
     text = _compile_grads(one_chip, 1, 20, 8192, 8192, 256, jnp.bfloat16,
@@ -188,6 +195,102 @@ def test_latent_attention_flash_compiles_for_v5e_with_the_sequence_resident(
         assert sum(marker in n for n in names) == 1, (marker, names)
 
 
+def _attention_layers():
+    """name -> (layer, input shape, layout its flash call takes): one
+    attention layer of each configuration that trains through the
+    kernels, at its cell's shapes."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import (
+        DecoderAttention, LatentAttention, MultiHeadSelfAttention)
+    rot = {"rope_theta": 10000.0}
+    return {
+        "gpt1": (MultiHeadSelfAttention(768, 12, causal=True),
+                 (8, 4096, 768), "pair"),
+        "gpt1_s2048_b16": (MultiHeadSelfAttention(768, 12, causal=True),
+                           (16, 2048, 768), "pair"),
+        "mellum_window": (DecoderAttention(2304, 32, 4, 128, rot,
+                                           window=1024),
+                          (4, 8192, 2304), "inplace"),
+        "mellum_full": (DecoderAttention(2304, 32, 4, 128, rot),
+                        (4, 8192, 2304), "inplace"),
+        "glm_latent": (LatentAttention(2048, 20, 768, 512, 192, 64, 256,
+                                       rot), (4, 8192, 2048), "inplace"),
+        "lfm2_qk_norm": (DecoderAttention(2048, 32, 8, 64, rot,
+                                          qk_norm=True),
+                         (4, 8192, 2048), "pair"),
+    }
+
+
+@pytest.mark.parametrize("name", ["gpt1", "gpt1_s2048_b16", "mellum_window",
+                                  "mellum_full", "glm_latent",
+                                  "lfm2_qk_norm"])
+def test_an_attention_layer_compiles_with_no_copy_of_a_head_shaped_tensor(
+        one_chip, monkeypatch, name):
+    """One attention layer, forward and backward through its own ``call``,
+    compiled by the chip's compiler at its cell's shapes: beside the three
+    kernels the program holds NO ``copy`` of an activation (XLA used to
+    put eleven round a GPT-1 layer, twelve round a latent one: every
+    change of layout between the projections' (B, T, H*D) and the
+    kernels' (B*H, T, D)). The layers keep q, k and v as (B, T, H*D) all
+    the way: a (B, T, H, D) view is another TILING of the same bytes on a
+    TPU, so the rotary pass runs in place too. The one layer that still
+    pays is LFM2's: its q/k norm needs a head's 64 columns as an axis of
+    their own, and XLA puts a float32 copy on either side of that view,
+    four of q's size and four of k's (on the chip the cell still reads
+    0.7 % above the parent's ten copies; PERF.md section 6)."""
+    from analytics_zoo_tpu.common.context import (init_zoo_context,
+                                                  reset_zoo_context)
+    from analytics_zoo_tpu.parallel import mesh as mesh_lib
+    from analytics_zoo_tpu.pipeline.api.keras import set_policy
+    layer, shape, layout = _attention_layers()[name]
+    b, t = shape[:2]
+    # route as on the chip (`_use_flash` and the entry ask the backend),
+    # on a mesh of one device (the context's is the test process's 8 CPUs)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    init_zoo_context()
+    set_policy(compute_dtype="bfloat16", param_dtype="float32")
+    mesh_lib.set_global_mesh(mesh_lib.create_mesh(devices=jax.devices()[:1]))
+    try:
+        n_kv = getattr(layer, "n_kv_head", layer.n_head)
+        d = getattr(layer, "head_dim", None) or (
+            layer.qk_nope_dim + layer.qk_rope_dim
+            if hasattr(layer, "qk_nope_dim")
+            else layer.hidden_size // layer.n_head)
+        assert fa._head_layout(layer.n_head, n_kv, d).layout == layout
+        shapes = jax.eval_shape(
+            lambda k: layer.build(k, (None,) + shape[1:]), jax.random.key(0))
+        params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), shapes)
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+        def step(p, x, co):
+            y, vjp = jax.vjp(lambda p, x: layer.call(p, x), p, x)
+            return y, vjp(co)
+        text = jax.jit(step).lower(params, x, x).compile().as_text()
+    finally:
+        reset_zoo_context()
+        mesh_lib.reset_global_mesh()
+    names = [ln.split("=")[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    for marker in ("zoo_flash_fwd", "zoo_flash_bwd_dq", "zoo_flash_bwd_dkv"):
+        assert sum(marker in n for n in names) == 1, (marker, names)
+    # copies of activations: B first, T among the dimensions, at least a
+    # key/value tensor's size (the rotary tables, (T, H*D), are made once
+    # a step and shared by the layers of a kind; weights are smaller)
+    entry = text[text.index("\nENTRY "):]
+    copies = []
+    for dtype, dims in re.findall(r"= (\w+)\[([\d,]+)\]\S* copy\(", entry):
+        dims = [int(n) for n in dims.split(",")]
+        size = 1
+        for n in dims:
+            size *= n
+        if dims[0] == b and t in dims and size >= b * t * n_kv * d:
+            copies.append(f"{dtype}{dims}")
+    if getattr(layer, "qk_norm", None) is not None:
+        assert len(copies) <= 8, copies
+    else:
+        assert not copies, copies
+
+
 @pytest.mark.parametrize("policy,forwards", [(False, 2), (True, 1)],
                          ids=["bare_checkpoint", "keeps_flash_saved"])
 def test_a_checkpoint_that_keeps_the_named_residuals_compiles_one_forward(
@@ -196,7 +299,7 @@ def test_a_checkpoint_that_keeps_the_named_residuals_compiles_one_forward(
     two projections under ``jax.checkpoint``, compiled by the chip's
     compiler: the program holds the forward kernel twice under a bare
     checkpoint, once where the policy keeps ``FLASH_SAVED``."""
-    shape = (1, 20, 8192, 256)
+    shape = (1, 8192, 20, 256)
     sched = fa._auto_blocks(shape, 8192, jnp.bfloat16, True, False, False)
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((256, 256), jnp.bfloat16, sharding=one_chip)

@@ -7,9 +7,19 @@ import numpy as np
 import pytest
 
 from analytics_zoo_tpu.ops.attention import dot_product_attention
-from analytics_zoo_tpu.ops.pallas import flash_attention, int8_matmul
+from analytics_zoo_tpu.ops.pallas import flash_attention as flash_entry
+from analytics_zoo_tpu.ops.pallas import int8_matmul
 
 RTOL, ATOL = 2e-4, 2e-5
+
+
+def flash_attention(q, k, v, **kw):
+    """The entry on the oracle's (B, H, T, D) operands: it takes and gives
+    (B, T, H, D) itself. The cases below that go through here keep the
+    shapes they had (D = 8: the layout the wrapper splits); the in-place
+    and pair layouts have their own cases at the entry's own layout."""
+    return flash_entry(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                       **kw).transpose(0, 2, 1, 3)
 
 
 def _qkv(b, h, tq, tk, d, seed=0):
@@ -259,6 +269,99 @@ def test_attention_layer_flash_handles_bert_mask():
 
 
 # ---------------------------------------------------------------------------
+# heads in place: the entry's own (B, T, H, D) layout, the three ways the
+# kernels find a head in it
+# ---------------------------------------------------------------------------
+
+#: name -> (layout, B, T, Hq, Hkv, D, causal, window, masked). Blocks are
+#: pinned at (64, 128): several q blocks and k tiles at these lengths.
+_LAYOUT_CASES = {
+    # whole lane tiles: a head is a lane block of the (T, H * D) plane
+    "inplace_d128": ("inplace", 2, 256, 2, 2, 128, True, None, False),
+    "inplace_d256": ("inplace", 1, 128, 2, 2, 256, True, None, False),
+    "inplace_grouped": ("inplace", 1, 256, 4, 2, 128, True, None, False),
+    "inplace_grouped_window": ("inplace", 1, 256, 4, 2, 128, True, 100,
+                               False),
+    "inplace_key_mask": ("inplace", 2, 200, 2, 2, 128, False, None, True),
+    # half a lane tile: two heads a block
+    "pair": ("pair", 2, 256, 2, 2, 64, True, None, False),
+    "pair_key_mask": ("pair", 2, 256, 4, 4, 64, False, None, True),
+    "pair_unaligned": ("pair", 1, 200, 4, 4, 64, True, None, False),
+    # grouped 4:1: both heads of a pair attend ONE key head, which stands
+    # in the first half of its block for pairs 0-1, in the second for 2-3
+    "pair_grouped_4to1": ("pair", 1, 256, 8, 2, 64, True, None, False),
+    "pair_grouped_2to1_window": ("pair", 1, 200, 8, 4, 64, True, 70, False),
+    # neither: the wrapper splits the heads out
+    "split_d8": ("split", 2, 40, 2, 2, 8, True, None, False),
+    "split_odd_heads_d64": ("split", 1, 256, 3, 3, 64, True, None, False),
+    "split_grouped_3to1_d64": ("split", 1, 128, 6, 2, 64, True, None, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_LAYOUT_CASES))
+def test_flash_entry_layouts_match_xla(name):
+    """The entry on (B, T, H, D) against ``dot_product_attention``, values
+    and the three gradients through the interpreter, and the call takes
+    the layout the case names."""
+    import importlib
+    fa_mod = importlib.import_module(
+        "analytics_zoo_tpu.ops.pallas.flash_attention")
+    layout, b, t, h, h_kv, d, causal, window, masked = _LAYOUT_CASES[name]
+    assert fa_mod._head_layout(h, h_kv, d).layout == layout
+    rng = np.random.default_rng(7)
+    q, g = (jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(b, t, h_kv, d)), jnp.float32)
+            for _ in range(2))
+    mask = None
+    if masked:
+        keep = np.ones((b, t), np.float32)
+        keep[1, t * 5 // 8:] = 0.0
+        keep[0, 3] = 0.0
+        mask = jnp.asarray(keep)
+
+    def flash(q, k, v):
+        return flash_entry(q, k, v, mask=mask, causal=causal, window=window,
+                           block_q=64, block_k=128)
+
+    def oracle(q, k, v):
+        m4 = None if mask is None else mask[:, None, None, :]
+        return dot_product_attention(
+            *(a.transpose(0, 2, 1, 3) for a in (q, k, v)), mask=m4,
+            causal=causal, window=window).transpose(0, 2, 1, 3)
+
+    out = flash(q, k, v)
+    assert out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle(q, k, v)),
+                               rtol=RTOL, atol=ATOL)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * g), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(oracle(*a) * g), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   rtol=2e-3, atol=2e-4)
+
+
+def test_pair_cells_run_in_bf16_as_on_the_chip():
+    """The pair layout at the operands' real dtype: the lane selects and
+    the half-tile rotation of a grouped pair go through float32 inside the
+    cell and must come back exact."""
+    rng = np.random.default_rng(9)
+    q = jnp.asarray(rng.normal(size=(1, 256, 8, 64)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.normal(size=(1, 256, 2, 64)), jnp.bfloat16)
+            for _ in range(2))
+    out = flash_entry(q, k, v, causal=True, block_q=64, block_k=128)
+    ref = dot_product_attention(*(a.transpose(0, 2, 1, 3)
+                                  for a in (q, k, v)), causal=True)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref.transpose(0, 2, 1, 3),
+                                          np.float32), rtol=2e-2, atol=2e-2)
+
+
+# ---------------------------------------------------------------------------
 # the tile schedule: resident major windows, diagonal-bounded loops,
 # mask-free interior tiles
 # ---------------------------------------------------------------------------
@@ -487,7 +590,7 @@ def test_auto_schedule_metric_names_windows_and_census():
     from analytics_zoo_tpu.observability import default_registry
     fa_mod = importlib.import_module(
         "analytics_zoo_tpu.ops.pallas.flash_attention")
-    sched = fa_mod._auto_blocks((8, 12, 4096, 64), 4096, jnp.bfloat16, True,
+    sched = fa_mod._auto_blocks((8, 4096, 12, 64), 4096, jnp.bfloat16, True,
                                 False, True)
     assert sched.fwd == (512, 1024, 4096)
     assert sched.dq == sched.dkv == (512, 512, 4096)
@@ -495,9 +598,11 @@ def test_auto_schedule_metric_names_windows_and_census():
     sig = 'sig="tq4096tk4096d64bfloat16c"'
     choice = [k for k in snap if k.startswith("zoo_pallas_block_choice")
               and sig in k]
+    # ... and ends in the layout the call takes: 12 heads of 64, in pairs
     assert len(choice) == 1 and ("fwd=512x1024/kmajor4096,"
                                  "dq=512x512/kmajor4096,"
-                                 "dkv=512x512/qmajor4096") in choice[0]
+                                 "dkv=512x512/qmajor4096,"
+                                 "heads=pair") in choice[0]
     tiles = {kind: snap[k]["value"] for kind in
              ("interior", "masked", "skipped_steps")
              for k in snap if k.startswith("zoo_pallas_flash_tiles")
@@ -510,7 +615,7 @@ def test_auto_schedule_metric_names_windows_and_census():
     assert tiles["masked"] == 8          # one diagonal tile a q block
     # the long-context signature cannot keep 32k keys resident: several
     # windows, and the causal steps right of the diagonal are counted
-    long = fa_mod._auto_blocks((1, 12, 32768, 64), 32768, jnp.bfloat16,
+    long = fa_mod._auto_blocks((1, 32768, 12, 64), 32768, jnp.bfloat16,
                                True, False, True)
     assert long.fwd.major < 32768 and long.dkv.major < 32768
     snap = default_registry().snapshot()
@@ -614,7 +719,7 @@ def test_auto_blocks_cached_and_metric_emitted():
     # batch/heads must not fragment the cache (a ragged final batch
     # would re-resolve), but a changed VMEM budget must
     budget = int(fa_mod._VMEM_BYTES_DEFAULT * fa_mod._VMEM_USABLE_FRACTION)
-    sig = (budget, 40, 40, 8, "float32", True, False)
+    sig = (budget, 40, 40, 8, "float32", True, False, "split")
     assert sig in cache, f"signature not cached: {sorted(cache)}"
     n_before = len(cache)
     flash_attention(q, k, v, causal=True)              # second call: cached
@@ -639,11 +744,12 @@ def test_block_cache_respects_budget_reconfiguration():
     try:
         reset_zoo_context()
         init_zoo_context(conf={"zoo.pallas.vmem_budget_mb": 4})
-        small = fa_mod._auto_blocks(q.shape, 2048, q.dtype, False, False,
+        shape = (1, 2048, 1, 256)
+        small = fa_mod._auto_blocks(shape, 2048, q.dtype, False, False,
                                     True)
         reset_zoo_context()
         init_zoo_context()                   # default 16 MiB budget
-        big = fa_mod._auto_blocks(q.shape, 2048, q.dtype, False, False,
+        big = fa_mod._auto_blocks(shape, 2048, q.dtype, False, False,
                                   True)
         assert small != big, "budget change did not re-resolve the blocks"
     finally:
@@ -977,11 +1083,11 @@ def test_flash_lowers_for_tpu_on_a_data_parallel_mesh():
 
     mesh = init_zoo_context().mesh                      # {data: 8}
     bsh = mesh_lib.batch_sharding(mesh)
-    q = jax.ShapeDtypeStruct((8, 2, 256, 64), jnp.bfloat16, sharding=bsh)
+    q = jax.ShapeDtypeStruct((8, 256, 2, 64), jnp.bfloat16, sharding=bsh)
     keep = jax.ShapeDtypeStruct((8, 256), jnp.float32, sharding=bsh)
 
     def grads(q, k, v, keep):
-        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        return jax.grad(lambda q, k, v: jnp.sum(flash_entry(
             q, k, v, mask=keep, interpret=False).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 

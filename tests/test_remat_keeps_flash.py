@@ -212,7 +212,7 @@ def test_outside_a_checkpoint_the_names_lower_to_nothing(flash, monkeypatch):
     the two names, the lowered module is the text it is without them: the
     same operations line for line (a name advances the counter behind the
     private functions' symbols, ``@_pad_70`` for ``@_pad_69``, no more)."""
-    q, k, v = (jax.random.normal(key, (2, 4, 40, 16))
+    q, k, v = (jax.random.normal(key, (2, 40, 4, 16))       # (B, T, H, D)
                for key in jax.random.split(jax.random.key(0), 3))
 
     def both():
@@ -233,7 +233,7 @@ def test_outside_a_checkpoint_the_names_lower_to_nothing(flash, monkeypatch):
 def test_the_log_counts_forward_rules_and_nothing_else(flash):
     """``saved_bytes_log`` adds up the forward rules traced inside it: none
     for a forward alone, none once it is closed."""
-    q = jax.random.normal(jax.random.key(0), (2, 4, 40, 16))
+    q = jax.random.normal(jax.random.key(0), (2, 40, 4, 16))  # (B, T, H, D)
 
     def loss(q):
         return jnp.sum(F.flash_attention(q, q, q, causal=True))

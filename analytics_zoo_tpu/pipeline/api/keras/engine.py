@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ....observability.step_ledger import LAYER_SCOPE
+
 # --------------------------------------------------------------------------
 # dtype policy
 # --------------------------------------------------------------------------
@@ -184,12 +186,21 @@ def intercept_layer_calls(hook):
 
 
 def dispatch_layer(layer, params, state, x, *, training=False, rng=None):
-    hook = _LAYER_HOOK.get()
-    if hook is not None:
-        out = hook(layer, params, state, x, training, rng)
-        if out is not None:
-            return out
-    return layer.apply(params, state, x, training=training, rng=rng)
+    """One container-dispatched layer call, a hook's substitute included,
+    under the device scope ``zoo_layer.<ClassName>``: the fallback of
+    ``observability/step_ledger.py::SCOPES`` for every model a container
+    builds. A layer that opens scopes of its own says ``layer_scope =
+    False`` and gets none."""
+    scope = (jax.named_scope(LAYER_SCOPE + type(layer).__name__)
+             if getattr(layer, "layer_scope", True)
+             else contextlib.nullcontext())
+    with scope:
+        hook = _LAYER_HOOK.get()
+        if hook is not None:
+            out = hook(layer, params, state, x, training, rng)
+            if out is not None:
+                return out
+        return layer.apply(params, state, x, training=training, rng=rng)
 
 
 # --------------------------------------------------------------------------
@@ -208,6 +219,12 @@ class Layer:
 
     Stateful layers instead override ``initial_state`` and ``apply``.
     """
+
+    #: ``dispatch_layer`` opens the device scope ``zoo_layer.<ClassName>``
+    #: round the call. False for a layer that opens ``zoo_*`` scopes where
+    #: its work happens (inside its loops and checkpoints, so that no
+    #: ``while`` / ``conditional`` carries one) and for a container
+    layer_scope = True
 
     def __init__(self, name: Optional[str] = None, input_shape: Optional[Tuple] = None):
         # _auto_name marks names eligible for deterministic renaming when the
@@ -401,6 +418,8 @@ class KerasNet(Layer):
     abstract ``KerasNet`` (``Topology.scala:63-600``). Training methods
     (``compile/fit/evaluate/predict``) are attached in ``training.py`` to keep
     the graph engine free of the optimizer machinery."""
+
+    layer_scope = False     # a nested container's layers bring their own
 
     def __init__(self, name: Optional[str] = None):
         super().__init__(name=name)

@@ -106,7 +106,7 @@ class FusedHeadSpec:
 
     def apply_and_loss(self, model, params, net_state, x, y, *, rng=None):
         """(loss, new_state) with the head fused into the loss."""
-        import jax.numpy as jnp
+        import jax
 
         from .engine import intercept_layer_calls
         head = self.head
@@ -118,7 +118,13 @@ class FusedHeadSpec:
 
         with intercept_layer_calls(hook):
             h, ns = model.apply(params, net_state, x, training=True, rng=rng)
-        hp = self.head_params(params)
+        with jax.named_scope("zoo_loss"):
+            return self._fused_loss(self.head_params(params), h, y), ns
+
+    def _fused_loss(self, hp, h, y):
+        """The fused blockwise cross-entropy of hidden states ``h`` against
+        labels ``y`` over the head's ``W`` (and ``b``)."""
+        import jax.numpy as jnp
         w = hp["W"]
         # the objectives oracle indexes numpy-style: a label in [-V, -1]
         # WRAPS (take_along_axis picks logits[V+label]) and still counts
@@ -135,11 +141,9 @@ class FusedHeadSpec:
         labels = jnp.where(labels < -v, v,
                            jnp.where(labels < 0, labels + v, labels))
         if self.sharded:
-            loss = sharded_fused_sparse_cross_entropy(labels, h, w,
+            return sharded_fused_sparse_cross_entropy(labels, h, w,
                                                       hp.get("b"))
-        else:
-            loss = fused_sparse_cross_entropy(labels, h, w, hp.get("b"))
-        return loss, ns
+        return fused_sparse_cross_entropy(labels, h, w, hp.get("b"))
 
 
 def _mode() -> str:

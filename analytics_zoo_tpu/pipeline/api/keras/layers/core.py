@@ -140,6 +140,8 @@ class GatedFeedForward(Layer):
     model's dense layers; ``RoutedExperts(shared_dim=)`` holds one as its
     shared expert. Device time shows under the scope ``zoo_ffn.gated``."""
 
+    layer_scope = False
+
     def __init__(self, hidden_dim: int, init: str = "glorot_uniform",
                  **kwargs):
         super().__init__(**kwargs)

@@ -549,6 +549,8 @@ class RoutedExperts(Layer):
     them that ran over ``C`` rows, ``moe_gmm_tile_rows``: rows of the row
     tiles the grouped products' kernels visited (0 where XLA's run)."""
 
+    layer_scope = False
+
     def __init__(self, num_experts: int, hidden_dim: int, top_k: int = 2,
                  held=None, norm_topk: bool = True,
                  token_chunk: Optional[int] = None,

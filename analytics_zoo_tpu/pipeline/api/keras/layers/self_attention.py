@@ -90,7 +90,13 @@ def _stack_param_sharding(blocks, params, embed_keys=()):
 
 class MultiHeadSelfAttention(Layer):
     """Fused-QKV multi-head self-attention. Input (B, T, H) (optionally with a
-    (B, 1, 1, T) keep-mask) → (B, T, H)."""
+    (B, 1, 1, T) keep-mask) → (B, T, H). Device time shows under
+    ``zoo_attn.proj`` (the fused q/k/v product), ``zoo_attn.attend`` (the
+    flash kernels, the XLA softmax attention or the ring, with the heads'
+    layout round them) and ``zoo_attn.out`` (the output product and its
+    dropout)."""
+
+    layer_scope = False
 
     def __init__(self, hidden_size: int, n_head: int, causal: bool = False,
                  attn_drop: float = 0.0, out_drop: float = 0.0, **kwargs):
@@ -265,8 +271,9 @@ class MultiHeadSelfAttention(Layer):
         if isinstance(x, (list, tuple)):
             x, mask = x
         cd = compute_dtype()
-        qkv = _dense(params["qkv"], x, cd)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("zoo_attn.proj"):
+            qkv = _dense(params["qkv"], x, cd)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
         r1 = r2 = None
         if rng is not None:
             r1, r2 = jax.random.split(rng)
@@ -275,6 +282,15 @@ class MultiHeadSelfAttention(Layer):
         # the flash entry finds a head where the projection wrote it; the
         # ring and the XLA op take the heads split out
         flash = ring_mesh is None and self._use_flash(mask, drop, q.shape[1])
+        with jax.named_scope("zoo_attn.attend"):
+            out = self._attend(q, k, v, mask, drop, r1, ring_mesh, flash)
+        with jax.named_scope("zoo_attn.out"):
+            out = _dense(params["proj"], out, cd)
+            return _dropout(out, self.out_drop, r2, training)
+
+    def _attend(self, q, k, v, mask, drop, rng, ring_mesh, flash):
+        """Attention over q, k, v (B, T, H) on the branch ``call`` chose;
+        (B, T, H)."""
         qh, kh, vh = (_heads(a, self.n_head, flash) for a in (q, k, v))
         if ring_mesh is not None:
             from .....parallel import mesh as mesh_lib
@@ -289,7 +305,7 @@ class MultiHeadSelfAttention(Layer):
                      else ring_self_attention)
             out = route(qh, kh, vh, mesh=ring_mesh, causal=self.causal,
                         mask=kv_mask, dropout_rate=drop,
-                        dropout_rng=r1 if drop > 0.0 else None)
+                        dropout_rng=rng if drop > 0.0 else None)
         elif flash:
             from .....ops.pallas import flash_attention
             out = flash_attention(qh, kh, vh, mask=self._kv_mask(mask),
@@ -297,14 +313,18 @@ class MultiHeadSelfAttention(Layer):
         else:
             out = dot_product_attention(qh, kh, vh, mask=mask,
                                         causal=self.causal,
-                                        dropout_rate=drop, dropout_rng=r1)
-        out = _dense(params["proj"], _merged(out, flash), cd)
-        return _dropout(out, self.out_drop, r2, training)
+                                        dropout_rate=drop, dropout_rng=rng)
+        return _merged(out, flash)
 
 
 class TransformerBlock(Layer):
     """Post-LN residual block: x = LN1(x + Attn(x)); x = LN2(x + FFN(x)).
-    FFN = gelu (``TransformerLayer.scala`` uses gelu, as does BERT)."""
+    FFN = gelu (``TransformerLayer.scala`` uses gelu, as does BERT). Device
+    time shows under ``zoo_norm`` (each add and its LayerNorm) and
+    ``zoo_ffn.dense`` (the two products, gelu, dropout), the attention's
+    under its own ``zoo_attn.*``."""
+
+    layer_scope = False
 
     def __init__(self, hidden_size: int, n_head: int,
                  intermediate_size: Optional[int] = None,
@@ -357,19 +377,25 @@ class TransformerBlock(Layer):
         cd = compute_dtype()
         a = self.attn.call(params["attn"], [x, mask] if mask is not None else x,
                            training=training, rng=r1)
-        x = self.ln1.call(params["ln1"], x + a)
-        h = jax.nn.gelu(_dense(params["fc"], x, cd),
-                        approximate=self.gelu_approximate)
-        h = _dropout(_dense(params["out"], h, cd), self.hidden_drop, r2,
-                     training)
-        return self.ln2.call(params["ln2"], x + h)
+        with jax.named_scope("zoo_norm"):
+            x = self.ln1.call(params["ln1"], x + a)
+        with jax.named_scope("zoo_ffn.dense"):
+            h = jax.nn.gelu(_dense(params["fc"], x, cd),
+                            approximate=self.gelu_approximate)
+            h = _dropout(_dense(params["out"], h, cd), self.hidden_drop, r2,
+                         training)
+        with jax.named_scope("zoo_norm"):
+            return self.ln2.call(params["ln2"], x + h)
 
 
 class TransformerLayer(Layer):
     """GPT-style decoder stack — ``TransformerLayer.scala:56`` /
     pyzoo ``self_attention.py``. Input int ids (B, T) → hidden states
     (B, T, H). ``bidirectional=False`` applies the causal mask (the
-    reference's ``maskAttention``)."""
+    reference's ``maskAttention``). The two lookups, their sum and the
+    embedding dropout run under the device scope ``zoo_embed``."""
+
+    layer_scope = False
 
     def __init__(self, vocab: int, seq_len: int, n_block: int = 12,
                  hidden_size: int = 768, n_head: int = 12,
@@ -411,12 +437,13 @@ class TransformerLayer(Layer):
     def call(self, params, x, *, training=False, rng=None):
         ids = x.astype(jnp.int32)
         t = ids.shape[1]
-        h = (jnp.take(params["wte"], ids, axis=0)
-             + params["wpe"][None, :t, :]).astype(compute_dtype())
         r = rng
-        if rng is not None:
-            r, re = jax.random.split(rng)
-            h = _dropout(h, self.embedding_drop, re, training)
+        with jax.named_scope("zoo_embed"):
+            h = (jnp.take(params["wte"], ids, axis=0)
+                 + params["wpe"][None, :t, :]).astype(compute_dtype())
+            if rng is not None:
+                r, re = jax.random.split(rng)
+                h = _dropout(h, self.embedding_drop, re, training)
         for i, blk in enumerate(self.blocks):
             br = jax.random.fold_in(r, i) if r is not None else None
             h = blk.call(params[f"block{i}"], h, training=training, rng=br)
@@ -426,7 +453,11 @@ class TransformerLayer(Layer):
 class BERT(Layer):
     """BERT encoder — ``BERT.scala:66``. Input
     ``[token_ids, token_type_ids, position_ids, attention_mask]`` (mask is
-    (B, 1, 1, T), 1.0 = attend) → ``[sequence_output, pooled_output]``."""
+    (B, 1, 1, T), 1.0 = attend) → ``[sequence_output, pooled_output]``.
+    The three lookups, their LayerNorm and dropout run under the device
+    scope ``zoo_embed``, the pooler (a head) under ``zoo_loss``."""
+
+    layer_scope = False
 
     def __init__(self, vocab: int = 40990, hidden_size: int = 768,
                  n_block: int = 12, n_head: int = 12, seq_len: int = 512,
@@ -489,24 +520,26 @@ class BERT(Layer):
         # cast tables to the compute dtype BEFORE the gather: halves the
         # gather read and (more importantly) the backward scatter-add
         # traffic under bf16 — the table-sized cast is one cheap pass
-        h = (jnp.take(params["word"].astype(cd), ids.astype(jnp.int32),
-                      axis=0)
-             + jnp.take(params["position"].astype(cd),
-                        pos.astype(jnp.int32), axis=0)
-             + jnp.take(params["token_type"].astype(cd),
-                        token_type.astype(jnp.int32), axis=0))
-        h = self.emb_ln.call(params["emb_ln"], h).astype(cd)
         r = rng
-        if rng is not None:
-            r, re = jax.random.split(rng)
-            h = _dropout(h, self.hidden_drop, re, training)
+        with jax.named_scope("zoo_embed"):
+            h = (jnp.take(params["word"].astype(cd), ids.astype(jnp.int32),
+                          axis=0)
+                 + jnp.take(params["position"].astype(cd),
+                            pos.astype(jnp.int32), axis=0)
+                 + jnp.take(params["token_type"].astype(cd),
+                            token_type.astype(jnp.int32), axis=0))
+            h = self.emb_ln.call(params["emb_ln"], h).astype(cd)
+            if rng is not None:
+                r, re = jax.random.split(rng)
+                h = _dropout(h, self.hidden_drop, re, training)
         if mask is not None and mask.ndim == 2:  # (B, T) → (B, 1, 1, T)
             mask = mask[:, None, None, :]
         for i, blk in enumerate(self.blocks):
             br = jax.random.fold_in(r, i) if r is not None else None
             h = blk.call(params[f"block{i}"], [h, mask], training=training,
                          rng=br)
-        pooled = jnp.tanh(_dense(params["pooler"], h[:, 0, :], cd))
+        with jax.named_scope("zoo_loss"):
+            pooled = jnp.tanh(_dense(params["pooler"], h[:, 0, :], cd))
         return [h, pooled]
 
 
@@ -548,7 +581,9 @@ class DecoderAttention(MultiHeadSelfAttention):
     parameter tree then has the four matrices alone) puts an RMSNorm over
     each head's ``head_dim`` columns of q and of k before the rotation,
     one weight vector of ``head_dim`` each (``q_norm``, ``k_norm``) shared
-    by the heads, at ``epsilon``; device time under ``zoo_attn.qk_norm``.
+    by the heads, at ``epsilon``; device time under ``zoo_attn.qk_norm``,
+    beside ``zoo_attn.proj`` (the three products), ``zoo_attn.rope``,
+    ``zoo_attn.attend`` and ``zoo_attn.out`` (``Wo``).
     Input (B, T, H), or ``[x, (cos, sin)]`` with the rotary tables of the
     call's positions (``DecoderStack`` forms them once per kind of
     layer)."""
@@ -613,15 +648,18 @@ class DecoderAttention(MultiHeadSelfAttention):
         t = x.shape[1]
         flash = self._use_flash(None, 0.0, t)
         in_place = self._rotates_in_place(t)
-        q, k, v = (_project(params[w], x, cd) for w in ("Wq", "Wk", "Wv"))
+        with jax.named_scope("zoo_attn.proj"):
+            q, k, v = (_project(params[w], x, cd)
+                       for w in ("Wq", "Wk", "Wv"))
         cos, sin = tables if tables is not None else self.tables(t)
         if in_place:
             d, kv = self.head_dim, k.shape[-1]
             with jax.named_scope("zoo_attn.rope"):
                 q = apply_rotary_in_place(q, cos, sin, d, d)
                 k = apply_rotary_in_place(k, cos[:, :kv], sin[:, :kv], d, d)
-        q = _heads(q, self.n_head, flash)
-        k, v = (_heads(a, self.n_kv_head, flash) for a in (k, v))
+        with jax.named_scope("zoo_attn.attend"):
+            q = _heads(q, self.n_head, flash)
+            k, v = (_heads(a, self.n_kv_head, flash) for a in (k, v))
         if not in_place:
             if self.qk_norm is not None:
                 with jax.named_scope("zoo_attn.qk_norm"):
@@ -630,13 +668,17 @@ class DecoderAttention(MultiHeadSelfAttention):
             with jax.named_scope("zoo_attn.rope"):
                 q, k = (apply_rotary(a, cos, sin, heads_first=not flash)
                         for a in (q, k))
-        if flash:
-            from .....ops.pallas import flash_attention
-            out = flash_attention(q, k, v, causal=True, window=self.window)
-        else:
-            out = dot_product_attention(q, k, v, causal=True,
-                                        window=self.window)
-        return _project(params["Wo"], _merged(out, flash), cd)
+        with jax.named_scope("zoo_attn.attend"):
+            if flash:
+                from .....ops.pallas import flash_attention
+                out = flash_attention(q, k, v, causal=True,
+                                      window=self.window)
+            else:
+                out = dot_product_attention(q, k, v, causal=True,
+                                            window=self.window)
+            out = _merged(out, flash)
+        with jax.named_scope("zoo_attn.out"):
+            return _project(params["Wo"], out, cd)
 
 
 class LatentAttention(MultiHeadSelfAttention):
@@ -819,6 +861,7 @@ class ShortConvMixer(Layer):
     ``zoo_conv.out_proj``."""
 
     mixer_kind = "conv"
+    layer_scope = False
 
     def __init__(self, hidden_size: int, kernel: int = 3, **kwargs):
         super().__init__(**kwargs)
@@ -864,6 +907,7 @@ class TiedHead(Layer):
 
     activation = None
     tied = True
+    layer_scope = False     # the stack calls it under ``zoo_loss``
 
     def __init__(self, output_dim: int, **kwargs):
         super().__init__(**kwargs)
@@ -884,7 +928,10 @@ class DecoderBlock(Layer):
     (B, T, H), called on ``x`` alone where the block is given no tables),
     ``ffn`` its feed-forward layer (``RoutedExperts``,
     ``GatedFeedForward``; any layer from (B, T, H) to (B, T, H)), whose
-    state, if it keeps one, is the block's."""
+    state, if it keeps one, is the block's. The two norms and the two adds
+    (a block's glue) run under the device scope ``zoo_norm``."""
+
+    layer_scope = False
 
     def __init__(self, hidden_size: int, attn: Layer, ffn: Layer,
                  epsilon: float = 1e-6, **kwargs):
@@ -909,14 +956,18 @@ class DecoderBlock(Layer):
         tables = None
         if isinstance(x, (list, tuple)):
             x, tables = x
-        a = self.ln1.call(params["ln1"], x)
-        h = x + self.attn.call(params["attn"],
+        with jax.named_scope("zoo_norm"):
+            a = self.ln1.call(params["ln1"], x)
+        mixed = self.attn.call(params["attn"],
                                [a, tables] if tables is not None else a,
                                training=training)
+        with jax.named_scope("zoo_norm"):
+            h = x + mixed
+            normed = self.ln2.call(params["ln2"], h)
         f, ns = self.ffn.apply(params["ffn"], (state or {}).get("ffn", {}),
-                               self.ln2.call(params["ln2"], h),
-                               training=training, rng=rng)
-        return h + f, ({"ffn": ns} if ns else {})
+                               normed, training=training, rng=rng)
+        with jax.named_scope("zoo_norm"):
+            return h + f, ({"ffn": ns} if ns else {})
 
     def call(self, params, x, *, training=False, rng=None):
         return self.apply(params, {}, x, training=training, rng=rng)[0]
@@ -1012,9 +1063,13 @@ class DecoderStack(Layer):
     a ``conv`` block holds no such name and keeps its input alone.
     What that keeps is counted while the step is traced, in
     ``zoo_remat_saved_bytes{what=}`` and ``model.last_fit_report
-    ["remat_saved_bytes"]``."""
+    ["remat_saved_bytes"]``. Device scopes of the stack's own work:
+    ``zoo_embed`` (the lookup; the rotary tables stay under their layers'
+    ``zoo_attn.rope`` / ``zoo_mla.rope``), ``zoo_norm`` (the final norm),
+    ``zoo_loss`` (a tied head's product)."""
 
     SLIDING, FULL, CONV = "sliding_attention", "full_attention", "conv"
+    layer_scope = False
 
     def __init__(self, vocab: int, layer_types: Sequence[str],
                  hidden_size: int, n_head: Optional[int] = None,
@@ -1099,7 +1154,8 @@ class DecoderStack(Layer):
 
     def apply(self, params, state, x, *, training=False, rng=None):
         ids = x.astype(jnp.int32)
-        h = jnp.take(params["wte"], ids, axis=0).astype(compute_dtype())
+        with jax.named_scope("zoo_embed"):
+            h = jnp.take(params["wte"], ids, axis=0).astype(compute_dtype())
         # one pair of tables per kind of layer that has them, shared by
         # its blocks
         tables = {}
@@ -1128,10 +1184,12 @@ class DecoderStack(Layer):
                     new_state[f"block{i}"] = ns
         if self.remat and training:
             remat_saved_bytes(kept)
-        h = self.norm.call(params["norm"], h)
+        with jax.named_scope("zoo_norm"):
+            h = self.norm.call(params["norm"], h)
         if self.head is not None:
-            h, _ = dispatch_layer(self.head, {"W": params["wte"].T}, {}, h,
-                                  training=training)
+            with jax.named_scope("zoo_loss"):
+                h, _ = dispatch_layer(self.head, {"W": params["wte"].T}, {},
+                                      h, training=training)
         return h, new_state
 
     def call(self, params, x, *, training=False, rng=None):

@@ -41,7 +41,8 @@ from ....common.reliability import RetryBudget
 from ....common.triggers import (EveryEpoch, MaxEpoch, SeveralIteration,
                                  TrainLoopState, Trigger)
 from ....feature.feature_set import FeatureSet, prefetch_to_device
-from ....observability import default_registry, instrument_jit, span
+from ....observability import (default_registry, instrument_jit, span,
+                                step_ledger)
 from ....observability.compile import xla_compile_totals
 from ....observability.goodput import (GoodputLedger, InflightProbe,
                                        goodput_enabled)
@@ -665,6 +666,9 @@ class TrainingLoop:
         self._state_report: Dict[str, Any] = {}
         # last_fit_report["remat_saved_bytes"], set where the step is traced
         self._remat_saved: Dict[str, int] = {}
+        # last_fit_report["step_census"], taken where zoo.metrics.flops
+        # compiles the step for its cost analysis
+        self._step_census: Optional[Dict[str, Any]] = None
 
     # -- goodput attribution -------------------------------------------------
     def _gp_note(self, category: str) -> None:
@@ -719,7 +723,7 @@ class TrainingLoop:
             delta = {k: v - before.get(k, 0.0) for k, v in now.items()}
             if any(delta.values()):
                 compiled[fn] = delta
-        return {
+        report = {
             "wall_s": t_end - t_open,
             "steps": self._probe.steps,
             "ledger": (self._goodput.seconds()
@@ -744,6 +748,12 @@ class TrainingLoop:
             # (zoo_decoder_blocks{mixer=}); empty for any other model
             "mixers": _decoder_mixers(self.model),
         }
+        if self._step_census is not None:
+            # the compiled step's instructions by device scope and pass
+            # (observability/step_ledger.py::census); only with
+            # zoo.metrics.flops on, whose compilation it is read from
+            report["step_census"] = self._step_census
+        return report
 
     # -- jitted steps -------------------------------------------------------
     #: the labels of the most recent fused-CE gauge write in this process —
@@ -808,7 +818,8 @@ class TrainingLoop:
                         embed_scope():
                     yp, ns = model.apply(p, net_state, x, training=True,
                                          rng=rng)
-                return loss_fn(y, yp), ns
+                with jax.named_scope("zoo_loss"):
+                    return loss_fn(y, yp), ns
             self._apply_loss = apply_loss
             return apply_loss
         log.info("fused LM-head cross-entropy engaged: head=%s vocab=%d%s "
@@ -917,8 +928,9 @@ class TrainingLoop:
         def backward(params, net_state, x, y, rng):
             def lfn(p):
                 l, ns = apply_loss(p, net_state, x, y, rng)
-                aux = _aux_loss_sum(ns)
-                return (l if aux is None else l + aux), ns
+                with jax.named_scope("zoo_loss"):
+                    aux = _aux_loss_sum(ns)
+                    return (l if aux is None else l + aux), ns
             # trace time, once a compiled step: a rematerialised
             # DecoderStack counts what its checkpoints keep while the
             # gradient is traced; a model without one reads 0
@@ -927,18 +939,23 @@ class TrainingLoop:
             self._remat_saved = remat_saved_bytes()
             return out
 
+        def update(params, opt_state, grads):
+            with jax.named_scope("zoo_opt.update"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                opt_state = self._pin_opt_state(opt_state)
+                return self._pin_params(
+                    optax.apply_updates(params, updates)), opt_state
+
         if not cfg.active:
             def plain(params, opt_state, net_state, rng, x, y):
                 (l, ns), grads = backward(params, net_state, x, y, rng)
-                updates, opt_state = opt.update(grads, opt_state, params)
-                opt_state = self._pin_opt_state(opt_state)
-                params = self._pin_params(
-                    optax.apply_updates(params, updates))
+                params, opt_state = update(params, opt_state, grads)
                 return params, opt_state, ns, l
             return plain, cfg
 
-        def guarded(params, opt_state, net_state, sstate, rng, fault, x, y):
-            (l, ns), grads = backward(params, net_state, x, y, rng)
+        def guard(l, grads, sstate, fault):
+            """The sentinels' checks and the clip (device scope
+            ``zoo_opt.guard``): ``(loss, grads, sentinel state, flags)``."""
             if cfg.faults:
                 # chaos only (zoo.faults.enabled at build time): apply
                 # the host-scheduled train.grads poison code on device
@@ -955,6 +972,12 @@ class TrainingLoop:
                     grads, gnorm, cfg.grad_clip)
                 flags = flags | jnp.where(engaged, anomaly.GRAD_CLIPPED,
                                           0).astype(jnp.int32)
+            return l, grads, sstate, flags
+
+        def guarded(params, opt_state, net_state, sstate, rng, fault, x, y):
+            (l, ns), grads = backward(params, net_state, x, y, rng)
+            with jax.named_scope("zoo_opt.guard"):
+                l, grads, sstate, flags = guard(l, grads, sstate, fault)
             if cfg.mode == "recover":
                 # skip-batch: an anomalous step's update is not applied —
                 # params/opt-state/net-state keep their pre-step values
@@ -965,14 +988,12 @@ class TrainingLoop:
                 # per-leaf select costs extra full passes over params +
                 # moments every step (measured ~30% on the NCF bench
                 # shape), while the untaken skip branch costs nothing
-                bad = (flags & anomaly.ANOMALY_MASK) > 0
+                with jax.named_scope("zoo_opt.guard"):
+                    bad = (flags & anomaly.ANOMALY_MASK) > 0
 
                 def _apply(operand):
                     p, o, g, new_ns = operand
-                    updates, new_opt = opt.update(g, o, p)
-                    new_opt = self._pin_opt_state(new_opt)
-                    return (self._pin_params(
-                        optax.apply_updates(p, updates)), new_opt, new_ns)
+                    return update(p, o, g) + (new_ns,)
 
                 def _skip(operand):
                     p, o, _g, _new_ns = operand
@@ -981,10 +1002,7 @@ class TrainingLoop:
                 params, opt_state, net_state = jax.lax.cond(
                     bad, _skip, _apply, (params, opt_state, grads, ns))
             else:
-                updates, opt_state = opt.update(grads, opt_state, params)
-                opt_state = self._pin_opt_state(opt_state)
-                params = self._pin_params(
-                    optax.apply_updates(params, updates))
+                params, opt_state = update(params, opt_state, grads)
                 net_state = ns
             return params, opt_state, net_state, sstate, l, flags
 
@@ -1135,7 +1153,9 @@ class TrainingLoop:
         it on buffers the subsequent dispatch donates is safe. Returns the
         seconds spent so the caller can exclude the compile from the
         epoch-timing window (the metrics this pass feeds must not be skewed
-        by it)."""
+        by it). The same compiled object's text gives ``model.
+        last_fit_report["step_census"]``: its instructions by device scope
+        and pass (``observability/step_ledger.py``)."""
         if self._flops_per_example is not None:
             return 0.0
         if not get_zoo_context().get("zoo.metrics.flops", False):
@@ -1146,11 +1166,13 @@ class TrainingLoop:
         from ....utils import profiling
         self._gp_note("device_step")    # close the step interval first
         t = time.perf_counter()
+        flops = None
         try:
-            flops = profiling.compiled_flops(
-                self._train_step.lower(*args).compile())
+            compiled = self._train_step.lower(*args).compile()
+            flops = profiling.compiled_flops(compiled)
+            self._step_census = step_ledger.census(compiled.as_text())
         except Exception:   # backend-dependent; never fail a fit for MFU
-            flops = None
+            pass
         # 0.0 latches "tried and unavailable" so the compile isn't retried
         self._flops_per_example = flops / batch_size if flops else 0.0
         self._gp_note("compile")
